@@ -69,7 +69,7 @@ log = logging.getLogger("flink_ml_tpu.kernels")
 
 #: bump when the entry layout / key recipe changes: old entries become
 #: fingerprint-skewed (quarantined on contact), never misread
-AOT_FORMAT = 1
+AOT_FORMAT = 2   # 2: meta records the executable's device ids
 
 _EXEC_DIR = "exec"
 _TUNE_DIR = "autotune"
@@ -89,15 +89,11 @@ def env_fingerprint() -> Dict[str, Any]:
     import jax
     import jaxlib
 
-    try:
-        device_kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no devices: fingerprint still total
-        device_kind = "unknown"
     return {
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
-        "device_kind": device_kind,
+        "device_kind": jax.devices()[0].device_kind,
         "format": AOT_FORMAT,
     }
 
@@ -303,6 +299,7 @@ class ExecutableCache:
         return os.path.join(self.root, _EXEC_DIR, key)
 
     def _load_entry(self, key: str):
+        import jax
         from jax.experimental import serialize_executable as se
 
         from ..robustness.durability import (CorruptStateError, quarantine,
@@ -325,7 +322,13 @@ class ExecutableCache:
                 in_tree, out_tree = pickle.load(f)
             with open(os.path.join(entry, _PAYLOAD), "rb") as f:
                 payload = f.read()
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            # load onto the devices the stored program was compiled for:
+            # the default is ALL local devices, which a one-device
+            # program cannot execute on
+            by_id = {d.id: d for d in jax.devices()}
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in meta["device_ids"]])
         except CorruptStateError as exc:
             log.warning("AOT cache entry failed validation (%s); "
                         "quarantining and recompiling live", exc)
@@ -362,6 +365,8 @@ class ExecutableCache:
             return
         try:
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
         except Exception as exc:  # noqa: BLE001 — backend w/o serialization
             kernel_stats.record_aot(label, event="unserializable")
             log.info("executable for %s is not serializable on this "
@@ -380,6 +385,7 @@ class ExecutableCache:
             with open(os.path.join(tmp, _META), "w") as f:
                 json.dump({"format": AOT_FORMAT, "label": label,
                            "key": key, "fingerprint": self._fingerprint,
+                           "device_ids": device_ids,
                            "payload_bytes": len(payload)}, f, indent=1,
                           sort_keys=True)
             commit_dir(tmp)

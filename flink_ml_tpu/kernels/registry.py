@@ -99,6 +99,14 @@ class KernelEntry:
     lookup bypasses ``available`` — tests and bench A/B legs run Pallas
     kernels in interpret mode on CPU — but never ``supports``: a shape
     the kernel cannot express must fail loudly, not fall back silently.
+
+    ``forced_only`` is THE way an entry stays out of automatic
+    selection on every device: the reason, in words (the TPU compiler's
+    refusal of a parked kernel, a measured mismatch with the XLA twin,
+    operands only one caller builds).  :func:`lookup` never picks such
+    an entry by itself; a forced ``backend=`` lookup still reaches it
+    (the interpret-mode parity matrix), and ``chip_smoke.py``'s
+    op -> backend table prints the reason.
     """
 
     op: str
@@ -108,11 +116,14 @@ class KernelEntry:
     supports: Optional[Callable[[tuple], bool]] = None
     available: Optional[Callable[[], bool]] = None
     convention: str = "impl"   # "impl" | "stage"
+    forced_only: Optional[str] = None
 
     def supports_sig(self, sig: tuple) -> bool:
         return self.supports is None or bool(self.supports(sig))
 
     def is_available(self) -> bool:
+        if self.forced_only is not None:
+            return False
         return self.available is None or bool(self.available())
 
 
@@ -148,14 +159,15 @@ def register_kernel(op: str, backend: str, fn: Callable, *,
                     priority: int = 0,
                     supports: Optional[Callable[[tuple], bool]] = None,
                     available: Optional[Callable[[], bool]] = None,
-                    convention: str = "impl") -> KernelEntry:
+                    convention: str = "impl",
+                    forced_only: Optional[str] = None) -> KernelEntry:
     """Register (or replace — module reloads must not duplicate) the
     implementation of ``op`` on ``backend``."""
     if convention not in ("impl", "stage"):
         raise ValueError(f"unknown convention {convention!r}")
     entry = KernelEntry(op=op, backend=backend, fn=fn, priority=priority,
                         supports=supports, available=available,
-                        convention=convention)
+                        convention=convention, forced_only=forced_only)
     with _REG_LOCK:
         _REGISTRY.setdefault(op, {})[backend] = entry
     return entry

@@ -118,13 +118,12 @@ def apply_bins_device(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(jnp.isnan(X), edges.shape[1], count)
 
 
-#: histogram implementation: "auto" (the kernel registry picks — MXU
-#: one-hot matmuls on TPU, where the systolic array beats segment_sum's
-#: per-element random accumulation, XLA segment_sum elsewhere),
-#: "segsum" (force the XLA scatter-adds, the r1-r4 path) or "mxu"
-#: (force the double one-hot matmul).  Module-level so the bench can
-#: measure both and a chip verdict can pin the default; both are exact
-#: up to f32 summation order.
+#: histogram implementation: "auto" (the kernel registry picks — XLA
+#: segment_sum everywhere today, see the registrations at the end of
+#: this module), "segsum" (force the XLA scatter-adds, the r1-r4 path)
+#: or "mxu" (force the double one-hot matmul: exact up to f32 summation
+#: order off TPU, bf16-truncated addends on the MXU).  Module-level so
+#: the bench can measure both and a chip verdict can pin the default.
 HIST_IMPL = "auto"
 
 
@@ -363,9 +362,9 @@ def _train_one_tree(binned, g, h, d: int, config: GBTConfig):
 
 def _maybe_autotune_hist(binned, g, h, d: int, bins: int) -> None:
     """First-encounter autotune of the histogram backend (ISSUE 12):
-    when several registry backends are AVAILABLE on this device (TPU has
-    mxu + xla; CPU has one, so nothing to search) and a persistent cache
-    root is configured, time both on a probe slice of the REAL binned
+    when several registry backends are AVAILABLE on this device (none
+    today: ``mxu`` is forced-lookup only, so there is nothing to search)
+    and a persistent cache root is configured, time both on a probe slice of the REAL binned
     data and record the winner — ``resolve_hist_impl("auto")`` then
     resolves through ``registry.lookup``, which honors the decision in
     this and every later process.  A recorded decision short-circuits
@@ -888,20 +887,27 @@ def predict_forest(X: np.ndarray, forest: Forest) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# kernel-registry entries: op ``gbt_level_histograms``.  The MXU form is
-# the TPU default (PR 10 hot path: histogramming as one-hot systolic
-# matmuls instead of segment_sum's per-element random accumulation —
-# the decision-forest-literature TPU-histogram trick); segsum stays the
-# registered XLA fallback and the forced oracle.  Both are exact up to
-# f32 summation order, feeding the streamed histogram carry unchanged
-# (accumulation over batches is a plain add either way).
+# kernel-registry entries: op ``gbt_level_histograms``.  segsum is what
+# "auto" plans on every device.  The MXU form (PR 10: histogramming as
+# one-hot systolic matmuls instead of segment_sum's per-element random
+# accumulation — the decision-forest-literature TPU-histogram trick) was
+# the TPU default until it first ran on a chip (PR 21): its contraction
+# runs at default MXU precision, so every gradient/hessian is truncated to
+# bf16 before it is summed, and it no longer matches segsum to f32
+# summation order.  It stays registered for a forced lookup and
+# ``HIST_IMPL = "mxu"``; whether a higher-precision contraction still
+# beats segment_sum is ROADMAP S3.  Both feed the streamed histogram carry
+# unchanged (accumulation over batches is a plain add either way).
 # ---------------------------------------------------------------------------
 
 def _register_gbt_kernels() -> None:
-    from ...kernels.registry import register_kernel, tpu_only
+    from ...kernels.registry import register_kernel
 
-    register_kernel("gbt_level_histograms", "mxu", _level_histograms_mxu,
-                    priority=10, available=tpu_only)
+    register_kernel(
+        "gbt_level_histograms", "mxu", _level_histograms_mxu, priority=10,
+        forced_only="bf16-truncated gradients: max |diff| 0.0079 from "
+                    "segment_sum on sums of ~3 (TPU v5 lite, rtol 1e-4 "
+                    "wanted)")
     register_kernel("gbt_level_histograms", "xla", _level_histograms_segsum)
 
 

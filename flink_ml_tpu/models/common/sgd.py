@@ -70,7 +70,7 @@ class SGDConfig:
     #: MXU precision of the fused ELL kernels' in-kernel one-hot
     #: contractions.  "default" (one bf16 pass) measured 4.39 ms/step at
     #: bench shape vs 10.49 for "highest" (multi-pass f32) and 11.0 for
-    #: the XLA oracle (TPU_FUSED_STEP_r04.txt), and passes the bench's
+    #: the XLA oracle (r4 chip run, 2026-07-31), and passes the bench's
     #: epoch-level parity gate (rtol=1e-3): the contracted residuals are
     #: batch-normalized, so their ~2^-8 relative truncation lands below
     #: the f32 summation-order noise every ELL path already carries.
@@ -700,7 +700,7 @@ def _mixed_update_ell(loss_fn: LossFn, config: SGDConfig,
     static ELL routing's fused Mosaic kernels (``ops/ell_scatter.py``)
     instead of XLA's per-element gather/scatter: measured 1.02 ms/step
     vs the 10.86 ms XLA oracle at bench shape, same run, v5e
-    (TPU_FUSED_STEP_r04.txt).  The extra batch arguments (src, pos,
+    (r4 chip run, 2026-07-31).  The extra batch arguments (src, pos,
     mask, ovf_idx, ovf_src, heavy_idx, heavy_cnt) are the per-step
     layout stacks produced by ``ell_layout`` at fit time — the raw
     ``cat`` tensor itself is not an input; results differ from the XLA
@@ -734,10 +734,9 @@ def _mixed_update_ell(loss_fn: LossFn, config: SGDConfig,
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map with the repo's compat shims — one shared copy in
-    ``parallel/collectives.py`` (handles the older-JAX import path and
-    turns the replication check off on every version, since pallas_call
-    out_shapes carry no varying-mesh-axes annotation)."""
+    """``jax.shard_map`` with the repo's default flag — one shared copy
+    in ``parallel/collectives.py`` (the varying-axes check is off, since
+    pallas_call out_shapes carry no varying-mesh-axes annotation)."""
     from ...parallel.collectives import shard_map_fn
 
     return shard_map_fn(fn, mesh, in_specs=in_specs, out_specs=out_specs)
@@ -1425,7 +1424,7 @@ def sgd_fit_outofcore(loss_fn: LossFn, make_reader: Callable, *,
     ``ell_ovf_cap`` is deliberately generous (``max(1024, batch)``)
     because the cap cannot change mid-stream; the XLA overflow
     scatter's cost scales with the STATIC cap (~0.2 us per cap slot per
-    step, r4 TPU_STEP_BREAKDOWN), so deployments whose collision rate
+    step, r4 chip run), so deployments whose collision rate
     is known should pass a tight ``ell_ovf_cap`` — in-memory fits size
     it from the measured need automatically.
 
@@ -1441,8 +1440,9 @@ def sgd_fit_outofcore(loss_fn: LossFn, make_reader: Callable, *,
     ``device_put`` of chunk N+1 overlaps compute on chunk N) and one
     jitted ``lax.scan`` with a donated carry runs all ``W`` optimizer
     steps, so an epoch costs ``ceil(n_batches / W)`` dispatches instead
-    of ``n_batches`` — the fixed per-dispatch host round-trip (dominant
-    on tunneled/relay transports) amortizes ``W``-fold.  The final
+    of ``n_batches`` — the fixed per-dispatch host cost amortizes
+    ``W``-fold (how large that cost is on a local TPU is unverified:
+    ROADMAP S1).  The final
     short chunk pads with a validity mask whose dead steps freeze the
     carry, so results are BIT-EXACT vs ``W=1`` (asserted in tests);
     mid-epoch checkpoint cuts land at chunk boundaries.  Process-

@@ -1025,7 +1025,6 @@ def _build_reduced_sharded_step(mesh, gr, sharded_params, opt, opt_state,
     from ...parallel.collectives import shard_map_fn
 
     axes, n_red, batch_axis = GR.mesh_layout(gr, mesh)
-    auto_axes = frozenset(n for n in mesh.axis_names if n not in axes)
 
     def split(tree):
         tables = {k: tree[k] for k in _LAZY_TABLE_KEYS}
@@ -1062,7 +1061,7 @@ def _build_reduced_sharded_step(mesh, gr, sharded_params, opt, opt_state,
         in_specs=(P(), P(batch_axis, None), P(batch_axis, None),
                   P(batch_axis), P(batch_axis)),
         out_specs=(P(), P(), P(batch_axis)),
-        auto=auto_axes)
+        axis_names=frozenset(axes))
 
     # Stage 2 — the compressed reduction runs FULLY manual (every mesh
     # axis bound): this XLA's partitioner aborts on lax.top_k inside a

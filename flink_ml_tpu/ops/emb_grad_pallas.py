@@ -1,7 +1,7 @@
 """Fused Mosaic fold for the routed embedding gradient — the Wide&Deep
 backward hot path, stage 2 of ``ops/emb_grad.py`` in ONE VMEM pass.
 
-BENCH_r05 put the routed embedding-gradient step at the top of the
+The r5 round put the routed embedding-gradient step at the top of the
 Wide&Deep profile: the dense towers ride the MXU while the table
 gradient is bounded by HBM streaming.  The XLA routed path is already
 scatter-free, but its segmented suffix-fold materialises the full
@@ -31,8 +31,10 @@ XLA: the permutation gather and the ``pos_map`` placement gather are
 single streaming passes XLA already lowers well.
 
 Registered as the ``pallas`` backend of registry op
-``routed_table_grad`` (gather placement, ``fold_passes >= 1``);
-``EmbGradRoute.resolve_apply`` picks it up on TPU automatically.
+``routed_table_grad`` (gather placement, ``fold_passes >= 1``) but
+PARKED — forced lookup only: the kernel lowers for TPU and Mosaic then
+refuses it (see :func:`_register`), so on a chip
+``EmbGradRoute.resolve_apply`` plans the XLA fold.
 """
 
 from __future__ import annotations
@@ -183,11 +185,24 @@ def _fused_route_supported(sig: tuple) -> bool:
 
 
 def _register() -> None:
-    from ..kernels.registry import register_kernel, tpu_only
+    from ..kernels.registry import register_kernel
 
-    register_kernel("routed_table_grad", "pallas", routed_apply_fused,
-                    priority=20, supports=_fused_route_supported,
-                    available=tpu_only)
+    # Parked (PR 21, TPU v5 lite, libtpu 0.0.34).  Every fold_passes 1-4 x
+    # E in (8, 64) case fails the same way on the chip:
+    #   Mosaic failed to compile TPU kernel: Not implemented: Input
+    #   offsets outside of the first tile
+    #   %17 = "tpu.concatenate"(%15, %16) <{dimension = 0 : i32}> :
+    #   (vector<256xi32>, vector<256xi32>) -> vector<512xi32>
+    # — the 1-D id concatenation at the top of the kernel body; the 1-D
+    # bool concatenations and the 1/2/4-sublane shifts after it have not
+    # been reached.  Carrying the run flags some other way is a rewrite,
+    # not a layout change (ROADMAP S2/S5b decide: rewrite or delete).
+    register_kernel(
+        "routed_table_grad", "pallas", routed_apply_fused, priority=20,
+        supports=_fused_route_supported,
+        forced_only='Mosaic: "Not implemented: Input offsets outside of '
+                    'the first tile" (tpu.concatenate of two 1-D i32 '
+                    'vectors)')
 
 
 _register()

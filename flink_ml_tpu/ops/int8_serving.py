@@ -93,24 +93,19 @@ def int8_widedeep_scores(static, params, cols):
     return {scol: scores}
 
 
-def _quantized_params_only() -> bool:
-    """Availability gate that always refuses: int8 entries consume the
-    quantized param pytree only the servable bind path builds, so
-    auto-pick (which would hand them the f32 params) must never see
-    them.  Forced ``lookup(op, backend="int8")`` bypasses this by the
-    registry's own contract — that asymmetry IS the admission path."""
-    return False
-
-
 def _register_int8_kernels() -> None:
     from ..kernels.registry import register_kernel
 
-    register_kernel("linear_margins", "int8", int8_linear_margins,
-                    convention="stage", available=_quantized_params_only)
-    register_kernel("kmeans_assign", "int8", int8_kmeans_assign,
-                    convention="stage", available=_quantized_params_only)
-    register_kernel("widedeep_scores", "int8", int8_widedeep_scores,
-                    convention="stage", available=_quantized_params_only)
+    # int8 entries consume the quantized param pytree only the servable
+    # bind path builds, so auto-pick (which would hand them the f32
+    # params) must never see them; the bind path's forced
+    # ``lookup(op, backend="int8")`` IS the admission path
+    quantized = "takes the quantized params only the servable bind builds"
+    for op, fn in (("linear_margins", int8_linear_margins),
+                   ("kmeans_assign", int8_kmeans_assign),
+                   ("widedeep_scores", int8_widedeep_scores)):
+        register_kernel(op, "int8", fn, convention="stage",
+                        forced_only=quantized)
 
 
 _register_int8_kernels()

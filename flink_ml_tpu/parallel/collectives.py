@@ -79,13 +79,8 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str) -> int:
-    """Static size of a bound mesh axis.  Newer JAX has ``lax.axis_size``;
-    on older releases ``lax.psum(1, axis)`` of a Python literal constant-
-    folds to the same static int."""
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis)
-    return lax.psum(1, axis)
+    """Static size of a bound mesh axis."""
+    return lax.axis_size(axis)
 
 
 def sparse_all_reduce(idx: jnp.ndarray, vals: jnp.ndarray, n: int,
@@ -384,24 +379,12 @@ def ppermute_ring(x: Any, axis: str, *, shift: int = 1) -> Any:
 
 
 def shard_map_fn(fn: Callable, mesh: Mesh, in_specs, out_specs,
-                 check_vma: bool = False, **kwargs) -> Callable:
-    """``jax.shard_map`` with this framework's default flags — THE compat
-    shim for every explicit-SPMD body in the repo: newer JAX exposes
-    ``jax.shard_map(check_vma=...)``, older releases only
-    ``jax.experimental.shard_map.shard_map(check_rep=...)``; both mean
-    "skip the replication/varying-axes check" (off here because
-    ``pallas_call`` out_shapes carry no varying-mesh-axes annotation).
-    Extra ``kwargs`` (e.g. ``auto=``) pass through untouched."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # older JAX
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kwargs["check_vma"] = check_vma
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check_vma
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
+                 check_vma: bool = False,
+                 axis_names: frozenset = frozenset()) -> Callable:
+    """``jax.shard_map`` with this framework's default flag: the
+    varying-mesh-axes check is off, because ``pallas_call`` out_shapes
+    carry no such annotation.  ``axis_names`` names the axes the body
+    handles manually (empty: all of the mesh's)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=check_vma)

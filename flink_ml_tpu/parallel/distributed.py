@@ -130,10 +130,12 @@ def hybrid_mesh(ici_axes: Mapping[str, int], dcn_axis: str = "dcn") -> Mesh:
     from jax.experimental import mesh_utils
 
     # create_hybrid_device_mesh takes same-rank per-granule and DCN shapes;
-    # the total mesh is their elementwise product.
+    # the total mesh is their elementwise product.  The DCN granule is the
+    # process (one host's chips), which is what the leading axis is sized by.
     dev_array = mesh_utils.create_hybrid_device_mesh(
         mesh_shape=[1] + ici_sizes,
         dcn_mesh_shape=[n_proc] + [1] * len(ici_sizes),
+        process_is_granule=True,
     )
     return Mesh(dev_array.reshape((n_proc, *ici_sizes)),
                 axis_names=(dcn_axis, *ici_axes.keys()))

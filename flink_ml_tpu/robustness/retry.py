@@ -3,7 +3,7 @@
 The reference rides Flink's restart strategies (fixed-delay /
 failure-rate) for transient task failures; the TPU-native stack needs
 the same distinction at its I/O seams: a flaky NFS read or a brief
-relay drop should cost one backoff sleep, while a corrupt checkpoint or
+network drop should cost one backoff sleep, while a corrupt checkpoint or
 a schema error must fail fast so the *recovery* layer (restore +
 replay, :mod:`.supervisor`) — not a blind retry loop — handles it.
 

@@ -377,7 +377,7 @@ class _PipelineServable(ServableModel):
             plan = compile_pipeline(model, example,
                                     min_bucket=self.min_bucket)
             self._plan = plan if plan.worthwhile else None
-        except Exception:           # unported stage mix: stagewise serve
+        except NotImplementedError:  # unported stage mix: stagewise serve
             self._plan = None
 
     def _run(self, table: Table) -> Table:

@@ -2,9 +2,11 @@
 
 One place owns the rules — invoke make incrementally on every first load
 (a no-op when fresh, guarantees .cpp edits are picked up; a stale .so
-would silently serve old native code otherwise), tolerate a failed make
-when a previously built .so exists, and degrade to ``None`` (callers keep
-their pure-Python fallback) when the toolchain is absent.
+would silently serve old native code otherwise).  A make that runs and
+fails is an error, whether or not an older .so is lying around: what
+loads is built from the sources in ``native/``.  Only a machine with no
+``make`` and no built library degrades to ``None`` (callers keep their
+pure-Python path).
 """
 
 from __future__ import annotations
@@ -41,6 +43,11 @@ def _run_make_locked() -> None:
         try:
             subprocess.run(["make", "-C", NATIVE_DIR], check=True,
                            capture_output=True, timeout=120)
+        except subprocess.CalledProcessError as exc:
+            raise RuntimeError(
+                f"native build failed (make -C {NATIVE_DIR}, exit "
+                f"{exc.returncode}):\n"
+                f"{exc.stderr.decode(errors='replace')[-2000:]}") from exc
         finally:
             fcntl.flock(lock_file, fcntl.LOCK_UN)
 
@@ -58,9 +65,8 @@ def load_native_lib(lib_name: str) -> Optional[ctypes.CDLL]:
         _MAKE_RAN = True
         try:
             _run_make_locked()
-        except Exception:
-            if not os.path.exists(so_path):
-                return None
+        except FileNotFoundError:   # no make on this machine
+            pass
     try:
         # shared lock around dlopen: a concurrent process rebuilding the
         # library (exclusive lock) writes -o straight onto this path, and

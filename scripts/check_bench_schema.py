@@ -35,7 +35,7 @@ SCHEMA = os.path.join(REPO, "BENCH_SCHEMA.md")
 #: summary-line fields (also the driver capture's `parsed` object) and
 #: envelope keys of the driver capture files themselves
 _SUMMARY_KEYS = {"metric", "value", "unit", "vs_baseline", "summary",
-                 "backend", "lr_impl", "tpu_unavailable"}
+                 "backend", "device", "lr_impl", "failed_legs"}
 _CAPTURE_ENVELOPE = {"n", "cmd", "rc", "tail", "parsed"}
 
 

@@ -266,7 +266,7 @@ def test_code_fingerprint_is_transitive_over_helpers():
 _CHILD = textwrap.dedent("""\
     import json, os, sys
     import numpy as np
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
     from flink_ml_tpu import Table
     from flink_ml_tpu.models.classification.logisticregression import (
         LogisticRegressionModel)
@@ -281,11 +281,11 @@ _CHILD = textwrap.dedent("""\
     feats = Table({"features": rng.normal(size=(64, 12))
                    .astype(np.float32)})
     servable = make_servable(model, feats.take(2), max_batch_rows=32)
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         servable.warm_up()
         out = servable.predict(feats.take(5))
     print(json.dumps({
-        "lowerings": count[0],
+        "lowerings": count(),
         "aot": kernel_stats.snapshot()["aot"],
         "warmup": servable.warmup_report,
         "out": {n: np.asarray(out[n]).tolist()
@@ -470,7 +470,7 @@ def _gbt_fixture():
 
 def test_gbt_train_forest_through_aot_cache(cache):
     from flink_ml_tpu.models.common.gbt import train_forest
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     X, y, grad_hess, cfg = _gbt_fixture()
     aot.set_cache(None)
@@ -480,10 +480,10 @@ def test_gbt_train_forest_through_aot_cache(cache):
     first = train_forest(X, y, grad_hess, 0.0, cfg)   # compile + store
 
     aot.set_cache(aot.ExecutableCache(cache.root))    # restarted process
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         second = train_forest(X, y, grad_hess, 0.0, cfg)
-    assert count[0] == 0, (
-        f"{count[0]} lowerings on the warm-cache GBT run — the aot_jit "
+    assert count() == 0, (
+        f"{count()} lowerings on the warm-cache GBT run — the aot_jit "
         "wrapper did not cover the training step builders")
 
     for a, b in ((baseline, first), (baseline, second)):

@@ -496,7 +496,7 @@ def test_fused_dispatch_count_is_one_per_segment():
 # -- zero recompiles ----------------------------------------------------------
 
 def test_zero_recompile_steady_state_warmed_buckets():
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     t = _table(n=128, d=8, seed=2)
     stages, t3 = _scaler_chain(t)
@@ -506,11 +506,11 @@ def test_zero_recompile_steady_state_warmed_buckets():
     feats = t.drop("label")
     for n in (8, 16, 32, 64, 128):               # warm the bucket ladder
         pm.transform(feats.take(n))
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         for n in (1, 3, 8, 9, 16, 23, 33, 64, 100, 128):
             pm.transform(feats.take(n))
-    assert count[0] == 0, (
-        f"{count[0]} new XLA lowerings in steady state — bucket padding "
+    assert count() == 0, (
+        f"{count()} new XLA lowerings in steady state — bucket padding "
         "or plan caching regressed")
 
 
@@ -518,7 +518,7 @@ def test_dtype_hygiene_f64_f32_share_one_compile():
     """numpy float64 input columns must NOT retrace: segment entry
     normalizes to f32 on host, so f64 and f32 views of the same data hit
     one compiled program (and produce identical derived columns)."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     t = _table(n=64, d=8, seed=11)               # f64 features
     stages, t3 = _scaler_chain(t)
@@ -528,11 +528,11 @@ def test_dtype_hygiene_f64_f32_share_one_compile():
     f64 = t.drop("label")
     f32 = Table({"features": np.asarray(t["features"], np.float32)})
     pm.transform(f64)                            # warm once, f64 entry
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         (a,) = pm.transform(f64)
         (b,) = pm.transform(f32)
-    assert count[0] == 0, (
-        f"{count[0]} new lowerings — f64 input retraced the segment")
+    assert count() == 0, (
+        f"{count()} new lowerings — f64 input retraced the segment")
     # derived columns identical (the untouched passthrough input keeps
     # its caller dtype by design)
     _assert_tables_equal(
@@ -564,7 +564,7 @@ def test_pipeline_servable_honors_min_bucket():
     """The servable's fused plan must pad with the servable's OWN bucket
     floor: warm_up tiles buckets from min_bucket, so a plan padding to a
     different ladder would compile on the serving path after ready."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     from flink_ml_tpu.serving.executor import make_servable
 
@@ -577,11 +577,11 @@ def test_pipeline_servable_honors_min_bucket():
     servable = make_servable(pm, feats.take(2), min_bucket=64,
                              max_batch_rows=128)
     servable.warm_up()
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         for n in (3, 40, 100):
             servable.predict(feats.take(n))
-    assert count[0] == 0, (
-        f"{count[0]} new lowerings post-warm-up — the fused plan pads a "
+    assert count() == 0, (
+        f"{count()} new lowerings post-warm-up — the fused plan pads a "
         "different bucket ladder than warm_up compiled")
 
 

@@ -252,7 +252,7 @@ def test_second_int8_tenant_admits_with_zero_new_lowerings():
     already-served int8 schema warms entirely out of the shared caches
     — zero new XLA lowerings, and the admission report says so at
     precision int8."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     feats = _feats(seed=7)
     s = SharedScheduler(max_batch_rows=64, max_wait_ms=0.5,
@@ -264,12 +264,12 @@ def test_second_int8_tenant_admits_with_zero_new_lowerings():
         for n in (1, 2, 64):            # settle wave, as in the f32 test
             s.predict("q1", feats.take(n))
         model2 = _fit_lr(seed=2)
-        with jtu.count_jit_and_pmap_lowerings() as count:
+        with count_compiles() as count:
             tenant = s.add_tenant("q2", model2, feats.take(2),
                                   slo=SLO_BULK, precision="int8")
             out = s.predict("q2", feats.take(5))
-        assert count[0] == 0, (
-            f"{count[0]} new lowerings admitting a same-schema int8 "
+        assert count() == 0, (
+            f"{count()} new lowerings admitting a same-schema int8 "
             "tenant — quantized admission must be placement only")
         report = tenant.admission_report
         assert report is not None and report["compiled"] == 0

@@ -287,7 +287,7 @@ def test_cv_pipeline_fused_scoring_reuses_compiled_segments():
     a whole repeat grid x fold sweep at the same shapes adds ZERO new
     XLA lowerings — fold models share one compiled program per
     (schema, bucket) instead of recompiling per fold."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     from flink_ml_tpu import Pipeline
     from flink_ml_tpu.api import chain
@@ -321,10 +321,10 @@ def test_cv_pipeline_fused_scoring_reuses_compiled_segments():
         folds.append((pipe.fit(train), val))
     m0, v0 = folds[0]
     m0.transform(v0)                        # warm fold
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         preds = [m.transform(v)[0] for m, v in folds]
-    assert count[0] == 0, (
-        f"{count[0]} new XLA lowerings across fold scoring — fold "
+    assert count() == 0, (
+        f"{count()} new XLA lowerings across fold scoring — fold "
         "models are not sharing the plan-static segment compiles")
     for (m, v), pred in zip(folds, preds):
         with chain.chain_disabled():

@@ -415,7 +415,7 @@ def test_chunked_fit_probe_lowerings_do_not_scale_with_chunks():
     warmed probed fit lowers the same count at 1 epoch and at 4 (12
     chunk dispatches) — zero per-chunk/per-epoch retraces with the
     probe attached."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     from flink_ml_tpu.models.common.losses import squared_loss
     from flink_ml_tpu.models.common.sgd import SGDConfig, sgd_fit_outofcore
@@ -433,11 +433,11 @@ def test_chunked_fit_probe_lowerings_do_not_scale_with_chunks():
 
     def lowerings(epochs: int) -> int:
         cfg = SGDConfig(max_epochs=epochs, tol=0.0)
-        with jtu.count_jit_and_pmap_lowerings() as count:
+        with count_compiles() as count:
             sgd_fit_outofcore(squared_loss, mk(), num_features=4,
                               config=cfg, steps_per_dispatch=4,
                               cache_decoded=False, step_probe=True)
-        return count[0]
+        return count()
 
     lowerings(1)                          # one-time compiles warm here
     assert lowerings(1) == lowerings(4)
@@ -575,7 +575,7 @@ def test_serving_with_tracing_adds_zero_lowerings():
     """Tracing is pure host bookkeeping: enabling it on a warmed
     endpoint compiles NOTHING (lowering-counter asserted) while the
     request-path spans all appear."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     from flink_ml_tpu.models.classification.logisticregression import (
         LogisticRegression,
@@ -593,10 +593,10 @@ def test_serving_with_tracing_adds_zero_lowerings():
     try:
         endpoint.predict(feats.take(5))           # tracing off, warm
         tracer.enable()
-        with jtu.count_jit_and_pmap_lowerings() as count:
+        with count_compiles() as count:
             endpoint.predict(feats.take(5))
-        assert count[0] == 0, (
-            f"{count[0]} new lowerings with tracing enabled — the "
+        assert count() == 0, (
+            f"{count()} new lowerings with tracing enabled — the "
             "tracer leaked into a traced program")
         for name in ("queue_wait", "serve_batch", "request",
                      "registry_dispatch", "device_execute", "bucket_pad"):

@@ -257,8 +257,11 @@ def _run_rd(p, idx, vals, n):
         dense, fill = col.sparse_all_reduce_rd(i[0], v[0], n, "data")
         return dense[None], fill[None]
 
-    fn = col.shard_map_fn(body, mesh, in_specs=(P("data"), P("data")),
-                          out_specs=(P("data"), P("data")))
+    # jitted, as every product caller runs it: an eager shard_map executes
+    # the body primitive by primitive, hundreds of 8-device compiles
+    fn = jax.jit(col.shard_map_fn(body, mesh,
+                                  in_specs=(P("data"), P("data")),
+                                  out_specs=(P("data"), P("data"))))
     dense, fill = fn(jnp.asarray(idx), jnp.asarray(vals))
     return np.asarray(dense), np.asarray(fill)
 

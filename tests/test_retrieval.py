@@ -328,7 +328,7 @@ def test_index_tenant_admits_with_zero_new_lowerings():
     """The registry dividend extends to the first NON-model servable:
     index tenant N+1 of a served (nprobe, k, dim, pq) schema warms
     entirely out of the shared jit cache."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     a, b = _built_pair()
     q = Table({"query": _gaussian(n=16, d=16, seed=29)})
@@ -340,11 +340,11 @@ def test_index_tenant_admits_with_zero_new_lowerings():
         for n in (1, 2, 16):        # settle lazy one-time work
             s.predict("idx-a", q.take(n))
         ref_b = b.transform(q.take(5))[0]["neighbors"]
-        with jtu.count_jit_and_pmap_lowerings() as count:
+        with count_compiles() as count:
             s.add_tenant("idx-b", b, q.take(2), slo=SLO_INTERACTIVE)
             out = s.predict("idx-b", q.take(5))
-        assert count[0] == 0, (
-            f"{count[0]} new lowerings admitting a same-schema index "
+        assert count() == 0, (
+            f"{count()} new lowerings admitting a same-schema index "
             "tenant")
         np.testing.assert_array_equal(out["neighbors"], ref_b)
     finally:

@@ -301,7 +301,7 @@ def test_second_tenant_of_served_schema_admits_with_zero_new_lowerings():
     model shares an already-served schema warms entirely out of the
     shared jit cache — zero new XLA lowerings, and the admission report
     says so."""
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     feats = _feats(seed=7)
     s = SharedScheduler(max_batch_rows=64, max_wait_ms=0.5,
@@ -316,12 +316,12 @@ def test_second_tenant_of_served_schema_admits_with_zero_new_lowerings():
         model2 = _fit_lr(seed=2)     # the FIT is training-side work;
         ref2 = model2.transform(      # admission is what must be free
             feats.take(5))[0]["rawPrediction"]
-        with jtu.count_jit_and_pmap_lowerings() as count:
+        with count_compiles() as count:
             tenant = s.add_tenant("t2", model2, feats.take(2),
                                   slo=SLO_BULK)
             out = s.predict("t2", feats.take(5))
-        assert count[0] == 0, (
-            f"{count[0]} new lowerings admitting a same-schema tenant — "
+        assert count() == 0, (
+            f"{count()} new lowerings admitting a same-schema tenant — "
             "the scheduler must be purely admission + placement")
         report = tenant.admission_report
         assert report is not None and report["compiled"] == 0
@@ -560,7 +560,7 @@ def test_embcache_rejects_non_widedeep():
 
 
 def test_cached_widedeep_zero_retraces_after_warmup():
-    from jax._src import test_util as jtu
+    from flink_ml_tpu.utils.backend import count_compiles
 
     model, t = _widedeep(seed=9)
     feats = t.drop("label")
@@ -570,11 +570,11 @@ def test_cached_widedeep_zero_retraces_after_warmup():
     servable.warm_up()
     for n in (1, 2, 64):
         servable.predict(feats.take(n))         # settle wave
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_compiles() as count:
         for n in (1, 3, 7, 8, 11, 16, 33, 64):
             servable.predict(feats.take(n))
-    assert count[0] == 0, (
-        f"{count[0]} new lowerings in cached-WideDeep steady state — "
+    assert count() == 0, (
+        f"{count()} new lowerings in cached-WideDeep steady state — "
         "pool shapes must stay constant under residency churn")
 
 

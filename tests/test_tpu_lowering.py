@@ -1,0 +1,222 @@
+"""Cross-lowering for TPU, from the CPU sandbox, in seconds.
+
+``jax.export.export(jax.jit(fn), platforms=["tpu"])`` runs Pallas' TPU
+lowering rules without a chip, which is where the block-shape class of
+error lives (a ``(1, d)`` block, a batched dot, a 3-D gather).  Every
+``pallas`` entry the registry selects by itself on a TPU lowers
+here at its smallest supported shape and at the full width
+``chip_smoke.py`` runs (or, off the smoke's path, the bench shape).
+
+Lowering is not compiling: Mosaic inside libtpu can still refuse what
+lowers (VMEM limit, unaligned slices).  That is ``tests_tpu``'s job, on
+the chip.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import ShapeDtypeStruct as Shape
+
+from flink_ml_tpu.kernels import registry
+
+F32, I32, I8 = jnp.float32, jnp.int32, jnp.int8
+
+
+def _ell(rows, batch):
+    """(w, r_ext, src, pos, mask/val grids, m_len) of an ELL step over a
+    ``rows x 128`` table and a ``batch``-row minibatch."""
+    from flink_ml_tpu.models.common.sgd import _ext_len
+
+    m_len = _ext_len(batch)
+    grid_i, grid_f = Shape((rows, 128), I32), Shape((rows, 128), F32)
+    return (Shape((rows * 128,), F32), Shape((m_len,), F32), grid_i, grid_f,
+            m_len)
+
+
+def _ell_margin(rows, batch, with_val):
+    from flink_ml_tpu.ops.ell_scatter import ell_margin_fused
+
+    w, _, gi, gf, m_len = _ell(rows, batch)
+    if with_val:
+        return (lambda w, s, p, m, v: ell_margin_fused(
+            w, s, p, m, m_len=m_len, val=v)), (w, gi, gi, gf, gf)
+    return partial(ell_margin_fused, m_len=m_len), (w, gi, gi, gf)
+
+
+def _ell_scatter(fn_name, rows, batch, precision="default"):
+    from flink_ml_tpu.ops import ell_scatter
+
+    w, r_ext, gi, gf, _ = _ell(rows, batch)
+    fn = partial(getattr(ell_scatter, fn_name), lr=0.5, precision=precision)
+    return fn, (w, r_ext, gi, gi, gf)
+
+
+def _lr_step(d, batch, n_dense):
+    """The whole ``_mixed_update_ell`` step with the Pallas entries
+    forced — what ``LogisticRegression.fit`` scans on the chip."""
+    from flink_ml_tpu.models.common.losses import LOSSES
+    from flink_ml_tpu.models.common.sgd import SGDConfig, _mixed_update_ell
+
+    rows = d // 128
+    gi, gf = Shape((rows, 128), I32), Shape((rows, 128), F32)
+    update = _mixed_update_ell(
+        LOSSES["logistic"], SGDConfig(global_batch_size=batch),
+        backend="pallas")
+    params = {"w": Shape((d,), F32), "b": Shape((), F32)}
+    return update, (params, Shape((batch, n_dense), F32), gi, gi, gf,
+                    Shape((1024,), I32), Shape((1024,), I32),
+                    Shape((16,), I32), Shape((16, batch), jnp.int16),
+                    Shape((batch,), F32), Shape((batch,), F32))
+
+
+def _kmeans_stats(n, d, k, block_n, tie_policy):
+    from flink_ml_tpu.ops.kmeans_pallas import kmeans_update_stats
+
+    return (partial(kmeans_update_stats, block_n=block_n,
+                    tie_policy=tie_policy),
+            (Shape((n, d), F32), Shape((k, d), F32)))
+
+
+def _kmeans_workset(n, d, k, block_n):
+    from flink_ml_tpu.ops.kmeans_pallas import kmeans_workset_update
+
+    row = Shape((n,), F32)
+    return (partial(kmeans_workset_update, block_n=block_n),
+            (Shape((n, d), F32), Shape((k, d), F32), Shape((n,), I32), row,
+             row))
+
+
+def _retrieve(b, dim, nlist, block, m=0, ksub=16, nprobe=4, k=10):
+    from flink_ml_tpu.ops import retrieve_pallas as rp
+
+    common = dict(nprobe=nprobe, k=k, nlist=nlist, block=block)
+    q, cents = Shape((b, dim), F32), Shape((nlist, dim), F32)
+    ids = Shape((nlist, block), I32)
+    if not m:
+        return (partial(rp.retrieve_flat_fused, **common),
+                (q, cents, ids, Shape((nlist * block, dim), F32)))
+    return (partial(rp.retrieve_pq_fused, m=m, **common),
+            (q, cents, ids, Shape((nlist * block, m), I8),
+             Shape((m, ksub, dim // m), I8), Shape((m, ksub), F32)))
+
+
+# (op, backend) -> {case id: thunk returning (fn, abstract args)}.  The
+# smallest ELL table is 128 rows; full width is the Criteo step
+# (d = 2^20, batch 32768) and the KMeans fit (n = 2^20, d = 64, k = 256)
+# of chip_smoke.py.  (routed_table_grad/pallas lowers too, but Mosaic
+# refuses it on the chip; gbt_level_histograms/mxu is plain XLA and
+# misses its twin's numbers there.  Both are forced-lookup only.)
+CASES = {
+    ("ell_margin", "pallas"): {
+        "smallest": lambda: _ell_margin(128, 64, False),
+        "smallest-values": lambda: _ell_margin(128, 64, True),
+        "full-width": lambda: _ell_margin(8192, 1 << 15, False),
+    },
+    ("ell_scatter_apply", "pallas"): {
+        "smallest": lambda: _ell_scatter("ell_scatter_apply_fused", 128, 64),
+        "smallest-highest": lambda: _ell_scatter(
+            "ell_scatter_apply_fused", 128, 64, "highest"),
+        "full-width": lambda: _ell_scatter(
+            "ell_scatter_apply_fused", 8192, 1 << 15),
+        "full-width-step": lambda: _lr_step(1 << 20, 1 << 15, 13),
+    },
+    ("ell_scatter_apply", "pallas-pair"): {
+        "smallest": lambda: _ell_scatter("ell_scatter_apply_pair", 128, 64),
+        "full-width": lambda: _ell_scatter(
+            "ell_scatter_apply_pair", 8192, 1 << 15),
+    },
+    ("kmeans_update_stats", "pallas"): {
+        **{f"smallest-{tie}": partial(_kmeans_stats, 128, 8, 4, 128, tie)
+           for tie in ("first", "fast", "split")},
+        "full-width": lambda: _kmeans_stats(1 << 20, 64, 256, 8192, "first"),
+    },
+    ("kmeans_workset_update", "pallas"): {
+        "smallest": lambda: _kmeans_workset(128, 8, 4, 128),
+        "full-width": lambda: _kmeans_workset(1 << 20, 64, 256, 4096),
+    },
+    ("retrieve", "pallas"): {
+        **{f"flat-rows{b}": partial(_retrieve, b, 128, 16, 128)
+           for b in (1, 8, 16)},
+        "flat-full-width": lambda: _retrieve(256, 128, 1024, 1024,
+                                             nprobe=16),
+    },
+}
+
+# The kernel the registry no longer selects by itself because Pallas' TPU
+# lowering refuses it (the registration holds the refusal).  xfail is
+# strict, so the day it lowers the test fails and the entry can be planned
+# again (ops/retrieve_pallas.py::_register).
+PARKED = {
+    "retrieve-pq-rows1": lambda: _retrieve(1, 32, 16, 64, m=4),
+    "retrieve-pq-rows8": lambda: _retrieve(8, 32, 16, 64, m=4),
+}
+
+
+def _lower_for_tpu(case):
+    fn, args = case()
+    jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+
+
+def test_every_auto_selectable_entry_has_lowering_cases():
+    auto = set()
+    for op in registry.ops():
+        for backend in registry.backends(op):
+            entry = registry.lookup(op, backend=backend)
+            if entry.available is registry.tpu_only:
+                auto.add((op, backend))
+    assert auto == set(CASES), (
+        f"no lowering case: {sorted(auto - set(CASES))}; "
+        f"stale: {sorted(set(CASES) - auto)}")
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(case, id=f"{op}/{backend}:{name}")
+    for (op, backend), cases in CASES.items()
+    for name, case in cases.items()])
+def test_lowers_for_tpu(case):
+    _lower_for_tpu(case)
+
+
+def test_sharded_lr_step_lowers_for_tpu():
+    """``_mixed_update_ell_sharded`` at full width on a four-device
+    ``data`` mesh — Pallas inside ``shard_map``, chip_smoke.py's mesh4
+    leg — lowered here because a four-chip run costs four times the chip
+    time of finding the same error on one."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.models.common.losses import LOSSES
+    from flink_ml_tpu.models.common.sgd import (SGDConfig,
+                                                _mixed_update_ell_sharded)
+    from flink_ml_tpu.parallel.mesh import device_mesh
+
+    d, batch, n_dev = 1 << 20, 1 << 15, 4
+    local = batch // n_dev
+    mesh = device_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
+
+    def arg(shape, dtype, *spec):
+        return Shape(shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    grid = (n_dev, d // 128, 128)
+    update = _mixed_update_ell_sharded(
+        LOSSES["logistic"], SGDConfig(global_batch_size=batch), mesh, d,
+        backend="pallas")
+    _lower_for_tpu(lambda: (update, (
+        {"w": arg((d,), F32), "b": arg((), F32)},
+        arg((batch, 13), F32, "data"),
+        arg(grid, I32, "data"), arg(grid, I32, "data"),
+        arg(grid, F32, "data"),
+        arg((n_dev, 1024), I32, "data"), arg((n_dev, 1024), I32, "data"),
+        arg((n_dev, 16), I32, "data"),
+        arg((n_dev, 16, local), jnp.int16, "data"),
+        arg((batch,), F32, "data"), arg((batch,), F32, "data"))))
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(case, id=name) for name, case in PARKED.items()])
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason=registry.lookup("retrieve", backend="pallas-pq").forced_only)
+def test_parked_kernel_still_does_not_lower(case):
+    _lower_for_tpu(case)

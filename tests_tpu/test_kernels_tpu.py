@@ -1,14 +1,52 @@
-"""Tiny-shape Mosaic compile + XLA-twin parity for every kernel the bench
-times.  Shapes are the smallest each kernel supports, so a failure here
-is a compiler/runtime break, never an OOM or capacity artifact."""
+"""Mosaic compile + XLA-twin parity for every kernel the registry can
+select by itself on a TPU, plus the forced-lookup entries: the three
+int8 kernels, and the parked fold and GBT-MXU kernels as expected
+failures that turn into errors the day they pass.  Most shapes are the
+smallest each kernel supports, so a failure there is a compiler/runtime
+break, never an OOM or capacity artifact; the
+``full-width`` cases repeat the kernels of the Criteo LR step and the
+KMeans fit at the block sizes ``chip_smoke.py`` runs them at."""
 
 import numpy as np
 import pytest
 
 pytestmark = pytest.mark.tpu
 
+#: every (op, backend) the registry auto-selects on a TPU and the test
+#: that compiles it here; test_every_auto_selected_entry_is_covered keeps
+#: this equal to the registry, so a new Pallas entry cannot land untested
+COVERED = {
+    ("ell_margin", "pallas"): "test_ell_margin_kernel_parity",
+    ("ell_scatter_apply", "pallas"): "test_ell_fused_gather_kernel_parity",
+    ("ell_scatter_apply", "pallas-pair"):
+        "test_ell_scatter_mixed_kernel_parity",
+    ("kmeans_update_stats", "pallas"): "test_kmeans_kernel_parity",
+    ("kmeans_workset_update", "pallas"): "test_kmeans_workset_kernel_parity",
+    ("retrieve", "pallas"): "test_retrieve_flat_kernel_parity",
+}
 
-def test_ell_scatter_mixed_kernel_parity(tpu, rng):
+
+def test_every_auto_selected_entry_is_covered(tpu):
+    from flink_ml_tpu.kernels import registry
+
+    auto = set()
+    for op in registry.ops():
+        for backend in registry.backends(op):
+            entry = registry.lookup(op, backend=backend)
+            if backend != "xla" and entry.is_available():
+                auto.add((op, backend))
+    assert auto == set(COVERED), (
+        f"uncovered: {sorted(auto - set(COVERED))}; "
+        f"stale: {sorted(set(COVERED) - auto)}")
+    for name in COVERED.values():
+        assert name in globals(), name
+
+
+# smallest supported table (one 128-row grid block), and the Criteo
+# table, whose 8192 rows run as 2048-row blocks
+@pytest.mark.parametrize("d", [128 * 128, 1 << 20],
+                         ids=["smallest", "full-width"])
+def test_ell_scatter_mixed_kernel_parity(tpu, rng, d):
     import jax.numpy as jnp
 
     from flink_ml_tpu.ops.ell_scatter import (
@@ -17,7 +55,6 @@ def test_ell_scatter_mixed_kernel_parity(tpu, rng):
         ell_scatter_apply_xla,
     )
 
-    d = 128 * 128          # smallest supported table
     cat = rng.integers(0, d, size=(1, 64, 8)).astype(np.int32)
     lay = ell_layout(cat, d)
     u = rng.normal(size=(d // 128, 128)).astype(np.float32)
@@ -29,10 +66,15 @@ def test_ell_scatter_mixed_kernel_parity(tpu, rng):
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
-def test_ell_full_step_matches_xla_update(tpu, rng):
+@pytest.mark.parametrize(
+    "d,batch,nnz,nd", [(128 * 128, 64, 4, 3), (1 << 20, 1 << 15, 26, 13)],
+    ids=["smallest", "full-width"])
+def test_ell_full_step_matches_xla_update(tpu, rng, d, batch, nnz, nd):
     """One whole _mixed_update_ell step (gather + kernel + overflow +
-    heavy) against the plain-XLA mixed update — the exact pre-timing
-    assert the bench runs, on a 64-row batch."""
+    heavy) against the plain-XLA mixed update, on a 64-row batch and at
+    the Criteo shape chip_smoke.py fits.  The step starts from random
+    weights: from zeros every margin is 0 and every update a power of
+    two, which any precision gets right."""
     import jax
     import jax.numpy as jnp
 
@@ -44,25 +86,38 @@ def test_ell_full_step_matches_xla_update(tpu, rng):
     )
     from flink_ml_tpu.ops.ell_scatter import ell_layout
 
-    d, batch, nnz, nd = 128 * 128, 64, 4, 3
     dense = rng.normal(size=(batch, nd)).astype(np.float32)
     cat = rng.integers(nd, d, size=(1, batch, nnz)).astype(np.int32)
     y = rng.integers(0, 2, size=batch).astype(np.float32)
     wb = np.ones(batch, np.float32)
     lay = ell_layout(cat, d)
-    cfg = SGDConfig(learning_rate=0.5, global_batch_size=batch)
-    params = {"w": jnp.zeros((d,), jnp.float32),
-              "b": jnp.zeros((), jnp.float32)}
+    w0 = (0.1 * rng.normal(size=d)).astype(np.float32)
+    # residuals are batch-normalized: scale lr with the batch so one
+    # slot's update (~4e-3) stays far above f32 rounding of w0 + update
+    lr = 0.5 * batch / 64
 
-    p_ell, v_ell = jax.jit(_mixed_update_ell(LOSSES["logistic"], cfg))(
-        params, dense, lay.src[0], lay.pos[0], lay.mask[0],
-        lay.ovf_idx[0], lay.ovf_src[0], lay.heavy_idx[0], lay.heavy_cnt[0],
-        y, wb)
-    p_xla, v_xla = jax.jit(_mixed_update(LOSSES["logistic"], cfg))(
-        params, dense, cat[0], y, wb)
-    np.testing.assert_allclose(float(v_ell), float(v_xla), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(p_ell["w"]),
-                               np.asarray(p_xla["w"]), atol=1e-4)
+    def params():       # fresh buffers per call: the steps may donate
+        return {"w": jnp.asarray(w0), "b": jnp.zeros((), jnp.float32)}
+
+    xla_cfg = SGDConfig(learning_rate=lr, global_batch_size=batch)
+    p_xla, v_xla = jax.jit(_mixed_update(LOSSES["logistic"], xla_cfg))(
+        params(), dense, cat[0], y, wb)
+    step_xla = np.asarray(p_xla["w"]) - w0
+    # "default" (what fits run) truncates the in-kernel one-hot
+    # contractions' operands to bf16, ~2^-8 relative per gathered term;
+    # "highest" is the multi-pass f32 mode, exact against the XLA gather
+    for precision, tol in (("highest", 1e-4), ("default", 5e-2)):
+        cfg = SGDConfig(learning_rate=lr, global_batch_size=batch,
+                        ell_precision=precision)
+        p_ell, v_ell = jax.jit(_mixed_update_ell(LOSSES["logistic"], cfg))(
+            params(), dense, lay.src[0], lay.pos[0], lay.mask[0],
+            lay.ovf_idx[0], lay.ovf_src[0], lay.heavy_idx[0],
+            lay.heavy_cnt[0], y, wb)
+        step_ell = np.asarray(p_ell["w"]) - w0
+        np.testing.assert_allclose(float(v_ell), float(v_xla), rtol=tol,
+                                   err_msg=precision)
+        err = np.linalg.norm(step_ell - step_xla) / np.linalg.norm(step_xla)
+        assert err < tol, f"{precision}: relative step error {err}"
 
 
 def test_ell_scatter_values_kernel_parity(tpu, rng):
@@ -90,44 +145,116 @@ def test_ell_scatter_values_kernel_parity(tpu, rng):
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
+def _separated_clusters(rng, n, dcol, k):
+    """Points around k centers no MXU pass can confuse: this tier tests
+    the Mosaic compile, not matmul tie-breaking — with overlapping
+    random-normal data the TPU's reduced-precision MXU pass flips ~0.1%
+    of near-boundary assignments vs a float64 oracle (observed r4), which
+    is fit-quality noise, not a kernel bug.  Centers are the k-bit codes
+    of their index times 16 (exact in bf16, >= 16 apart, sigma = 1
+    noise), so every margin is precision-proof at any k <= 2^dcol."""
+    bits = (np.arange(k)[:, None] >> np.arange(dcol)[None, :]) & 1
+    true_c = (16.0 * bits).astype(np.float32)
+    label = rng.integers(0, k, size=n)
+    pts = (true_c[label] + rng.normal(size=(n, dcol))).astype(np.float32)
+    cents = (true_c + 0.5 * rng.normal(size=(k, dcol))).astype(np.float32)
+    return pts, cents
+
+
+def _lloyd_oracle(pts, cents):
+    """float64 single-assignment Lloyd's stats (separated clusters have
+    no ties, so all tie policies must agree with it)."""
+    p, c = pts.astype(np.float64), cents.astype(np.float64)
+    d2 = (p * p).sum(1)[:, None] - 2.0 * p @ c.T + (c * c).sum(1)[None, :]
+    assign = d2.argmin(1)
+    counts = np.bincount(assign, minlength=len(c)).astype(np.float64)
+    sums = np.zeros_like(c)
+    np.add.at(sums, assign, p)
+    return assign, d2, sums, counts
+
+
+# one block_n tile at the smallest shape; two 8192-row tiles at the
+# k = 256, d = 64 shape chip_smoke.py fits (the VMEM-budget case)
+_KMEANS_SHAPES = pytest.mark.parametrize(
+    "n,dcol,k", [(8192, 8, 4), (16384, 64, 256)],
+    ids=["smallest", "full-width"])
+
+
+@_KMEANS_SHAPES
 @pytest.mark.parametrize("tie_policy", ["first", "split", "fast"])
-def test_kmeans_kernel_parity(tpu, rng, tie_policy):
-    """kmeans_update_stats (the fused Lloyd's kernel) vs the XLA epoch
-    body on one tiny block."""
+def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k):
+    """kmeans_update_stats (the fused Lloyd's kernel) vs a numpy oracle."""
     import jax.numpy as jnp
 
     from flink_ml_tpu.ops.kmeans_pallas import kmeans_update_stats
 
-    n, dcol, k = 8192, 8, 4   # one block_n tile
-    # Well-separated clusters: this tier tests the Mosaic compile, not
-    # matmul tie-breaking — with overlapping random-normal data the TPU's
-    # reduced-precision MXU pass flips ~0.1% of near-boundary assignments
-    # vs a float64 oracle (observed r4), which is fit-quality noise, not
-    # a kernel bug.  20-unit center spacing vs sigma=1 noise makes every
-    # margin precision-proof.
-    true_c = np.zeros((k, dcol), np.float32)
-    true_c[:, 0] = 20.0 * np.arange(k)
-    label = rng.integers(0, k, size=n)
-    pts = (true_c[label] + rng.normal(size=(n, dcol))).astype(np.float32)
-    cents = (true_c + 0.5 * rng.normal(size=(k, dcol))).astype(np.float32)
+    pts, cents = _separated_clusters(rng, n, dcol, k)
     sums, counts = kmeans_update_stats(jnp.asarray(pts), jnp.asarray(cents),
                                        block_n=8192, tie_policy=tie_policy)
-    # numpy oracle: single-assignment Lloyd's stats (separated clusters
-    # have no ties, so all tie policies must agree with it)
-    d2 = ((pts[:, None, :] - cents[None, :, :]) ** 2).sum(-1)
-    assign = d2.argmin(1)
-    want_counts = np.bincount(assign, minlength=k).astype(np.float64)
-    want_sums = np.zeros((k, dcol))
-    np.add.at(want_sums, assign, pts)
+    _, _, want_sums, want_counts = _lloyd_oracle(pts, cents)
     # counts are the exact-parity guard: any flipped assignment shows up
     # as a whole unit.  sums pass through one default-precision MXU dot
     # (inputs truncated to bf16, ~2^-8 relative), so their tolerance is
     # bf16-scaled: a genuine misassignment would move a sum by >= the
-    # 20-unit cluster separation, far past it.
+    # 16-unit cluster separation, far past it.
     np.testing.assert_allclose(np.asarray(counts, np.float64), want_counts,
                                atol=1e-3)
     np.testing.assert_allclose(np.asarray(sums, np.float64), want_sums,
                                rtol=2e-3, atol=0.5)
+
+
+@_KMEANS_SHAPES
+def test_kmeans_workset_kernel_parity(tpu, rng, n, dcol, k):
+    """kmeans_workset_update (fused Hamerly scoring + stats) vs the numpy
+    oracle and its registered XLA twin: half the points active, the rest
+    keeping a cached assignment, a tail of masked padding rows."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.kernels.registry import lookup
+    from flink_ml_tpu.ops.kmeans_pallas import pick_block_n_workset
+
+    pts, cents = _separated_clusters(rng, n, dcol, k)
+    prev = rng.integers(0, k, size=n).astype(np.int32)
+    active = (rng.random(n) < 0.5).astype(np.float32)
+    pad_mask = np.ones(n, np.float32)
+    pad_mask[-9:] = 0.0
+    args = [jnp.asarray(a) for a in (pts, cents, prev, active, pad_mask)]
+    # full width: the block the fit plans (4096 — at 8192 the compiler
+    # wants 20.05 MB of its 16 MB scoped VMEM).  The small case takes a
+    # 1024-row block only to keep this tier short: Mosaic's compile time
+    # for this kernel grows with the block (measured PR 21: 63 s at 4096,
+    # 220 s at 8192 — its per-point vectors are 1-D blocks).
+    block_n = pick_block_n_workset(None, dcol, k) if k == 256 else 1024
+    got = lookup("kmeans_workset_update", backend="pallas").fn(
+        *args, block_n=block_n)
+    twin = lookup("kmeans_workset_update", backend="xla").fn(
+        DistanceMeasure.get_instance("euclidean"), k, *args)
+    assign, d_best, d_second, sums, counts = (np.asarray(a) for a in got)
+
+    fresh, d2, _, _ = _lloyd_oracle(pts, cents)
+    want_assign = np.where(active > 0, fresh, prev)
+    np.testing.assert_array_equal(assign, want_assign)
+    np.testing.assert_array_equal(assign, np.asarray(twin[0]))
+    # the distances are |p|^2 - 2 p.c + |c|^2 with p.c from a default-
+    # precision MXU pass (operands truncated to bf16): the SQUARED distance
+    # carries an absolute error of ~2^-8 (|p|^2 + |c|^2) however near the
+    # centroid is, so that — not a relative bound on the root — is what a
+    # correct kernel can be held to.  A wrong centroid is >= 256 away.
+    want = np.sort(d2, axis=1)[:, :2]
+    p2c2 = (pts.astype(np.float64) ** 2).sum(1) + (cents ** 2).sum(1).max()
+    for got_root, col in ((d_best, 0), (d_second, 1)):
+        err = np.abs(got_root.astype(np.float64) ** 2 - want[:, col])
+        assert (err <= 2.0 ** -6 * p2c2).all(), (col, err.max(), p2c2.max())
+    want_counts = np.bincount(want_assign, weights=pad_mask, minlength=k)
+    want_sums = np.zeros((k, dcol))
+    np.add.at(want_sums, want_assign, pts * pad_mask[:, None])
+    np.testing.assert_allclose(counts, want_counts, atol=1e-3)
+    # cached assignments put points on FAR centers, so a sum holds terms
+    # up to 16 * sqrt(dcol) from its mean: the same bf16-scaled bound
+    np.testing.assert_allclose(sums, want_sums, rtol=2e-3, atol=0.5)
+    np.testing.assert_allclose(sums, np.asarray(twin[3]), rtol=2e-3,
+                               atol=0.5)
 
 
 def test_ell_fused_gather_kernel_parity(tpu, rng):
@@ -253,22 +380,268 @@ def test_als_sorted_neq_on_device(tpu, rng):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_gbt_mxu_hist_on_device(tpu, rng):
-    """MXU double-one-hot histograms vs segment_sum on the chip."""
+@pytest.mark.parametrize("precision", [
+    pytest.param("default", marks=pytest.mark.xfail(
+        strict=True, raises=AssertionError,
+        reason="PARKED (models/common/gbt.py): at default MXU precision "
+               "every gradient/hessian is truncated to bf16 before it is "
+               "summed — measured on the chip (PR 21): max |diff| 0.0079 "
+               "on sums of ~3, where segment_sum is exact f32")),
+    "highest"])
+def test_gbt_mxu_hist_on_device(tpu, rng, precision):
+    """gbt_level_histograms/mxu (double one-hot matmuls, forced lookup
+    only) vs the segment_sum twin "auto" plans.  At the precision it runs
+    with today it must keep failing — the day it matches, un-park it; at
+    "highest" (what ROADMAP S3 would have to time) it matches."""
+    import jax
     import jax.numpy as jnp
 
+    from flink_ml_tpu.kernels.registry import lookup
     from flink_ml_tpu.models.common import gbt
 
+    assert gbt.resolve_hist_impl("auto") == "segsum"
     n, d, bins, n_nodes = 256, 4, 16, 4
     binned = jnp.asarray(rng.integers(0, bins, size=(n, d)), jnp.int32)
     ids = jnp.asarray(rng.integers(-1, n_nodes, size=n), jnp.int32)
     g = jnp.asarray(rng.normal(size=n), jnp.float32)
     h = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
-    gs, hs = gbt._level_histograms_segsum(binned, ids, g, h, n_nodes, d,
-                                          bins)
-    gm, hm = gbt._level_histograms_mxu(binned, ids, g, h, n_nodes, d,
-                                       bins)
+    gs, hs = lookup("gbt_level_histograms").fn(binned, ids, g, h, n_nodes,
+                                               d, bins)
+    with jax.default_matmul_precision(precision):
+        gm, hm = lookup("gbt_level_histograms", backend="mxu").fn(
+            binned, ids, g, h, n_nodes, d, bins)
     np.testing.assert_allclose(np.asarray(gm), np.asarray(gs),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(hm), np.asarray(hs),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("emb", [8, 64])
+@pytest.mark.parametrize("fold_passes", [1, 2, 3, 4])
+def test_fold_runs_fused_parity(tpu, rng, emb, fold_passes):
+    """routed_table_grad/pallas: the fused segmented fold (shifts by 1, 2,
+    4, 8 sublanes inside a VMEM tile, halo from the next block) against
+    the XLA fold it mirrors pass for pass — adds only, so bit-exact.
+
+    PARKED (ops/emb_grad_pallas.py::_register): Mosaic refuses the kernel,
+    so today this xfails on the refusal.  The day it compiles it must
+    match, and then it fails loudly: un-park the entry."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.ops.emb_grad import _folded_ext
+    from flink_ml_tpu.ops.emb_grad_pallas import fold_runs_fused
+
+    slots, block_n = 1024, 256
+    # runs up to 2^fold_passes long, some crossing a block boundary
+    ids = np.sort(rng.integers(0, slots >> fold_passes, size=slots))
+    g = rng.normal(size=(slots, emb)).astype(np.float32)
+    try:
+        got = np.asarray(fold_runs_fused(
+            jnp.asarray(g), jnp.asarray(ids, jnp.int32),
+            fold_passes=fold_passes, block_n=block_n))
+    except Exception as exc:   # noqa: BLE001 — the refusal is the point
+        assert "Mosaic failed to compile TPU kernel" in str(exc), exc
+        pytest.xfail(str(exc).splitlines()[0][:200])
+    want, _ = _folded_ext(jnp.asarray(g), jnp.arange(slots),
+                          jnp.asarray(ids, jnp.int32), fold_passes)
+    np.testing.assert_array_equal(got, np.asarray(want)[:-1])
+    pytest.fail("fold_runs_fused compiles and matches on this chip now: "
+                "un-park routed_table_grad/pallas")
+
+
+def test_routed_table_grad_plans_the_xla_fold(tpu, rng):
+    """With the Pallas fold parked, a gather route with a heavy run plans
+    the XLA fold on the chip, and the routed gradient it computes matches
+    the scatter-add oracle."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.kernels.registry import lookup
+    from flink_ml_tpu.ops.emb_grad import emb_grad_route
+
+    vocab, emb = 4096, 8
+    cat = rng.integers(0, vocab, size=(1, 64, 4)).astype(np.int64)
+    cat[0, :40, 0] = 5                    # heavy run -> fold_passes > 0
+    route = emb_grad_route(cat, vocab, placement="gather")
+    assert route.fold_passes > 0
+    entry = lookup("routed_table_grad", sig=route.kernel_sig())
+    assert entry.backend == "xla", entry.backend
+    g = rng.normal(size=(256, emb)).astype(np.float32)
+    got = np.asarray(entry.fn(route, jnp.asarray(g), *route.step_slice(0)))
+    want = np.zeros((vocab, emb), np.float64)
+    np.add.at(want, cat[0].reshape(-1), g)
+    np.testing.assert_allclose(got, want.astype(np.float32), rtol=1e-4,
+                               atol=1e-4)
+
+
+def _small_int_vectors(rng, n, dim):
+    """Vectors of small integers: exact in bf16, so every distance is the
+    same exact integer at any MXU precision and on any backend."""
+    return rng.integers(-8, 9, size=(n, dim)).astype(np.float32)
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+def test_retrieve_flat_kernel_parity(tpu, rng, rows):
+    """retrieve/pallas, flat scan: the registry plans it on the chip, and
+    at nprobe == nlist (every list scanned, so the probe order cannot
+    matter) its distances equal the exact ones bit for bit and every id it
+    returns sits at the distance it reports."""
+    from flink_ml_tpu.retrieval import IVFIndex
+
+    # dim and block at the kernel's DMA alignment (multiples of 128)
+    X = _small_int_vectors(rng, 600, 128)
+    index = IVFIndex.build(X, nlist=8, k=10, nprobe=8, seed=1, block=256)
+    assert index.search_plan().backend == "pallas"
+    q = _small_int_vectors(rng, rows, 128)
+    nbrs, dists = index.search(q)
+
+    exact = ((q[:, None, :].astype(np.float64) - X[None]) ** 2).sum(-1)
+    np.testing.assert_array_equal(dists, np.sort(exact, axis=1)[:, :10])
+    assert (nbrs >= 0).all()
+    np.testing.assert_array_equal(
+        np.take_along_axis(exact, nbrs, axis=1), dists)
+    for row in nbrs:
+        assert len(set(row.tolist())) == 10, row
+
+
+def test_retrieve_flat_matches_xla_stage_on_real_data(tpu, rng):
+    """retrieve/pallas on real-valued vectors at nprobe < nlist — what
+    serving runs: the MXU passes round their operands to bf16 and the
+    probed lists decide the answer (eight natural clusters split over 32
+    lists; one probe finds under half the neighbours, eight find all).
+    Held against the registered XLA stage on the same index and against
+    the exact float64 scan, 64 queries = eight grid steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.kernels.registry import lookup
+    from flink_ml_tpu.retrieval import IVFIndex
+    from flink_ml_tpu.retrieval.ivf import _DIST_STAGE, _NN_STAGE
+
+    n, dim, k = 4096, 128, 10
+    centers = (2.0 * rng.normal(size=(8, dim))).astype(np.float32)
+    X = (centers[rng.integers(0, 8, size=n)]
+         + rng.normal(size=(n, dim))).astype(np.float32)
+    q = (X[rng.choice(n, size=64, replace=False)]
+         + 0.25 * rng.normal(size=(64, dim))).astype(np.float32)
+    index = IVFIndex.build(X, nlist=32, k=k, nprobe=8, seed=1, block=256)
+    assert index.search_plan().backend == "pallas"
+    nbrs, dists = index.search(q)
+
+    static, sig = index._static(), index.sig()
+    twin = jax.jit(lambda params, cols: lookup(
+        "retrieve", sig=sig, backend="xla").fn(static, params, cols))(
+        {name: jnp.asarray(v) for name, v in index.params.items()},
+        {index.query_col: jnp.asarray(q)})
+    twin_nbrs = np.asarray(twin[_NN_STAGE])
+
+    ids, stored = index.stored_vectors()
+    assert np.array_equal(ids, np.arange(n))
+    q64, x64 = q.astype(np.float64), stored.astype(np.float64)
+    exact = ((q64[:, None, :] - x64[None]) ** 2).sum(-1)
+    # q^2 + x^2 - 2 q.x with q.x from one bf16 pass: both operands carry
+    # up to 2^-8 relative error, so the squared distance is off by at most
+    # 2^-6 |q||x| <= 2^-7 (|q|^2 + |x|^2); twice that is allowed
+    bound = 2.0 ** -6 * ((q64 ** 2).sum(1)[:, None]
+                         + (x64 ** 2).sum(1)[None])
+    for who, got_n, got_d in (("pallas", nbrs, dists),
+                              ("xla", twin_nbrs,
+                               np.asarray(twin[_DIST_STAGE]))):
+        assert (got_n >= 0).all(), who
+        assert all(len(set(row.tolist())) == k for row in got_n), who
+        assert (np.diff(got_d, axis=1) >= 0).all(), who
+        err = np.abs(got_d - np.take_along_axis(exact, got_n, axis=1))
+        assert (err <= np.take_along_axis(bound, got_n, axis=1)).all(), (
+            who, float(err.max()))
+
+    def overlap(a, b):
+        return np.array([len(set(x.tolist()) & set(y.tolist())) / k
+                         for x, y in zip(a, b)])
+
+    both = overlap(nbrs, twin_nbrs)
+    assert both.mean() >= 0.9 and both.min() >= 0.8, (both.mean(),
+                                                      both.min())
+    truth = np.argsort(exact, axis=1)[:, :k]
+    recall, twin_recall = (overlap(nbrs, truth).mean(),
+                           overlap(twin_nbrs, truth).mean())
+    assert recall >= 0.9 and recall >= twin_recall - 0.05, (recall,
+                                                           twin_recall)
+    print(f"retrieve/pallas vs xla: mean overlap {both.mean():.4f}, min "
+          f"{both.min():.1f}; recall@{k} {recall:.4f} vs {twin_recall:.4f}")
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+def test_retrieve_pq_serves_through_xla(tpu, rng, rows):
+    """The Pallas PQ scan does not lower for TPU and is forced-lookup only
+    (retrieve/pallas-pq, ops/retrieve_pallas.py::_register): a PQ index
+    must plan the XLA stage on the chip, warm up as a servable and answer
+    requests."""
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.retrieval import IVFIndex, PQConfig
+    from flink_ml_tpu.serving import make_servable
+
+    X = rng.normal(size=(600, 32)).astype(np.float32)
+    index = IVFIndex.build(X, nlist=8, k=10, nprobe=4, seed=1,
+                           pq=PQConfig(m=8, ksub=16))
+    assert index.search_plan().backend == "xla"
+    queries = Table({"query": rng.normal(size=(8, 32)).astype(np.float32)})
+    servable = make_servable(index, queries.take(2), max_batch_rows=8)
+    servable.warm_up()
+    served = servable.predict(queries.take(rows))
+    offline = index.transform(queries)[0]
+    np.testing.assert_array_equal(served["neighbors"],
+                                  offline["neighbors"][:rows])
+    assert np.isfinite(np.asarray(served["distances"])).all()
+
+
+def _int8_cases(rng):
+    """(op, static, f32 params, cols, agreement(got, ref)) for the three
+    forced-lookup int8 entries — the fixtures of tests/test_kernels.py's
+    accuracy-envelope harnesses."""
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.models.recommendation.widedeep import (
+        _field_offsets,
+        init_params,
+    )
+
+    X = rng.normal(size=(512, 16)).astype(np.float32)
+    yield ("linear_margins", ("f", "m"),
+           {"w": rng.normal(size=(16,)).astype(np.float32),
+            "b": np.float32(0.1)},
+           {"f": X}, "m", lambda got, ref: np.mean((got > 0) == (ref > 0)))
+    yield ("kmeans_assign",
+           ("f", "a", DistanceMeasure.get_instance("euclidean")),
+           {"centroids": rng.normal(size=(7, 16)).astype(np.float32)},
+           {"f": X}, "a", lambda got, ref: np.mean(got == ref))
+    vocab = (17, 23)
+    net = init_params(rng, 4, vocab, 8, (16,))
+    for name in ("wide_cat", "wide_dense"):
+        net[name] = (rng.normal(size=net[name].shape) * 0.1
+                     ).astype(np.float32)
+    cat = np.stack([rng.integers(0, v, size=512) for v in vocab],
+                   axis=1).astype(np.int32)
+    yield ("widedeep_scores", ("d", "c", "s"),
+           {"net": net, "offsets": _field_offsets(vocab)},
+           {"d": X[:, :4], "c": cat}, "s",
+           lambda got, ref: np.mean((got > 0.5) == (ref > 0.5)))
+
+
+def test_int8_entries_on_device(tpu, rng):
+    """The three "int8" registry entries (weight-only quantized serving
+    kernels, forced lookup only) through the shared DONATING dispatch
+    surface: decisions agree with the f32 entry within the 99% envelope
+    the CPU parity matrix holds them to."""
+    from flink_ml_tpu.kernels.quantize import quantize_stage_params
+    from flink_ml_tpu.kernels.registry import dispatch, lookup
+
+    for op, static, params, cols, out_col, agreement in _int8_cases(rng):
+        outs = {}
+        for backend in ("xla", "int8"):
+            p = (quantize_stage_params(op, params) if backend == "int8"
+                 else params)
+            plan = ((lookup(op, backend=backend).fn, static),)
+            # a fresh column dict per call: the plan jit donates it
+            outs[backend] = np.asarray(dispatch(
+                plan, (p,), {k: np.array(v) for k, v in cols.items()},
+                op=f"{op}-{backend}")[out_col])
+        agree = float(agreement(outs["int8"], outs["xla"]))
+        assert agree >= 0.99, f"{op}: int8 agreement {agree} vs f32"
