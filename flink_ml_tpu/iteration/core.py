@@ -49,6 +49,7 @@ from .body import (
     active_fraction,
     normalize_body_result,
 )
+from ..obs.trace import tracer
 from .checkpoint import CheckpointConfig, CheckpointManager
 
 __all__ = ["iterate", "IterationResult"]
@@ -427,6 +428,32 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
                    config: IterationConfig, *,
                    frac_fn: Optional[Callable[[Any], Any]] = None
                    ) -> IterationResult:
+    # ``iterate.dispatch``: what the host does to get the fused program
+    # running (probe, trace, lower, the compile-cache request, the
+    # enqueue); it ends when the jitted call returns, not when the device
+    # has finished.  ``fit.fetch``: the host blocked on the device.
+    with tracer.span("iterate.dispatch", "fit"):
+        final_state, outputs, num_epochs, trace = _dispatch_fused(
+            body, initial_state, provider, config, frac_fn)
+    if num_epochs is None:
+        return IterationResult(final_state, outputs, config.max_epochs, {})
+    # on a process-spanning mesh the loop counter comes back as a
+    # non-fully-addressable replicated scalar; read this host's replica
+    from ..parallel.mesh import fetch_replicated
+
+    with tracer.span("fit.fetch", "fit"):
+        n_run = int(np.asarray(fetch_replicated(num_epochs)))
+        side = {"epoch_trace": trace.fetch(
+            get=lambda v: np.asarray(fetch_replicated(v)))}
+    return IterationResult(final_state, outputs, n_run, side)
+
+
+def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
+                    config: IterationConfig,
+                    frac_fn: Optional[Callable[[Any], Any]]) -> tuple:
+    """Build the fused program and enqueue it, nothing fetched:
+    ``(final_state, outputs, num_epochs, epoch_trace)``, the last two
+    ``None`` where no criteria ask for them."""
     if not provider.is_static:
         raise ValueError("fused mode requires device-resident (static) data")
     if config.max_epochs is None:
@@ -453,8 +480,7 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
             return jax.lax.scan(scan_step, state,
                                 jnp.arange(max_epochs, dtype=jnp.int32))
 
-        final_state, outputs = run(initial_state, data)
-        return IterationResult(final_state, outputs, max_epochs, {})
+        return (*run(initial_state, data), None, None)
 
     # Criteria-driven: lax.while_loop; keeps only the last outputs.
     if probe.outputs is not None:
@@ -465,7 +491,7 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
             "LAST epoch's outputs (a while_loop cannot stack a dynamic "
             "number of them); use mode='hosted' (or carry a fixed-size "
             "buffer in state) to keep the full per-epoch output log",
-            stacklevel=3)
+            stacklevel=4)
     zero_out = jax.tree_util.tree_map(
         lambda s: jnp.zeros(s.shape, s.dtype), probe.outputs)
 
@@ -504,14 +530,7 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
                          jnp.asarray(True), trace0))
 
     final_state, outputs, num_epochs, _, trace = run(initial_state, data)
-    # on a process-spanning mesh the loop counter comes back as a
-    # non-fully-addressable replicated scalar; read this host's replica
-    from ..parallel.mesh import fetch_replicated
-
-    n_run = int(np.asarray(fetch_replicated(num_epochs)))
-    side = {"epoch_trace": trace.fetch(
-        get=lambda v: np.asarray(fetch_replicated(v)))}
-    return IterationResult(final_state, outputs, n_run, side)
+    return final_state, outputs, num_epochs, trace
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ from ...iteration import (
     iterate,
 )
 from ...linalg import stack_vectors
+from ...obs.trace import tracer
 from ...params.param import (
     BoolParam,
     IntParam,
@@ -155,19 +156,19 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
         return self.set(KMeansParams.INIT_MODE, value)
 
 
-def _prepare_points(points: np.ndarray, mesh, row_multiple: int = 1,
-                    fill: str = "first_row",
-                    cross_host_checked: bool = False) -> tuple:
-    """Host -> device: pad rows to a multiple of the data-axis size (and of
-    ``row_multiple`` per shard; mask marks real rows), shard the batch dim.
+def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
+                fill: str = "first_row",
+                cross_host_checked: bool = False) -> tuple:
+    """The host half of host -> device: pad rows to a multiple of the
+    data-axis size (and of ``row_multiple`` per shard; mask marks real
+    rows).  The caller shards the batch dim of both
+    (``put_sharded(.., P("data"))``).
 
     On a process-spanning mesh ``points`` is THIS process's shard; each
     host pads to its local device multiple and the global array assembles
     over processes.  Equal padded counts are required — validated here
     unless the caller already allgathered row counts
     (``cross_host_checked``)."""
-    from jax.sharding import PartitionSpec as P
-
     multiple = local_axis_multiple(mesh, row_multiple=row_multiple)
     padded, mask = pad_rows_with_mask(points, multiple, fill=fill)
     if mesh_process_count(mesh) > 1 and not cross_host_checked:
@@ -179,8 +180,7 @@ def _prepare_points(points: np.ndarray, mesh, row_multiple: int = 1,
             raise ValueError(
                 "multi-host KMeans requires equal padded row counts per "
                 f"process; got {rows.tolist()}")
-    return (put_sharded(padded, mesh, P("data")),
-            put_sharded(mask, mesh, P("data")))
+    return padded, mask
 
 
 @partial(jax.jit, static_argnums=0)
@@ -681,6 +681,16 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
 
     def fit(self, *inputs) -> "KMeansModel":
         (table,) = inputs
+        with tracer.fit_span(type(self).__name__):
+            return self._fit(table)
+
+    def _fit(self, table: Table) -> "KMeansModel":
+        """``fit`` under its root span.  The phase spans (``fit.gather``,
+        ``fit.arrange``, ``fit.upload``, then ``iterate.dispatch`` inside
+        ``iterate``, ``fit.fetch``) follow each other without a gap and
+        add no fence: each covers what the host does in it."""
+        from jax.sharding import PartitionSpec as P
+
         # report describes THIS fit only — a reused estimator must not
         # serve a stale report from an earlier workset fit
         self.last_workset_report = None
@@ -688,57 +698,67 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
         k = self.get_k()
         measure = DistanceMeasure.get_instance(self.get_distance_measure())
 
-        host_points = stack_vectors(table[self.get_features_col()]).astype(
-            np.float32)
-        n_for_plan = host_points.shape[0]
-        multi_host = mesh_process_count(mesh) > 1
-        if multi_host:
-            # Every process passed its own shard.  ONE allgather of the
-            # raw row counts runs before any other collective so every
-            # host takes identical branches from identical facts: the
-            # impl plan uses the GLOBAL row count (per-host planning
-            # straddling the Pallas threshold would compile mismatched
-            # collective programs -> deadlock), the host-0-shard-too-small
-            # error raises on ALL hosts (raising on one strands the rest
-            # in the init broadcast), and padded-count equality is
-            # validated here rather than re-gathered downstream.
-            from jax.experimental import multihost_utils
+        with tracer.span("fit.gather", "fit"):
+            with tracer.span("fit.gather.stack", "fit"):
+                host_points = stack_vectors(table[self.get_features_col()])
+            with tracer.span("fit.gather.cast", "fit"):
+                host_points = host_points.astype(np.float32)
+        with tracer.span("fit.arrange", "fit"):
+            n_for_plan = host_points.shape[0]
+            multi_host = mesh_process_count(mesh) > 1
+            if multi_host:
+                # Every process passed its own shard.  ONE allgather of the
+                # raw row counts runs before any other collective so every
+                # host takes identical branches from identical facts: the
+                # impl plan uses the GLOBAL row count (per-host planning
+                # straddling the Pallas threshold would compile mismatched
+                # collective programs -> deadlock), the
+                # host-0-shard-too-small error raises on ALL hosts (raising
+                # on one strands the rest in the init broadcast), and
+                # padded-count equality is validated here rather than
+                # re-gathered downstream.
+                from jax.experimental import multihost_utils
 
-            rows = np.asarray(multihost_utils.process_allgather(
-                np.asarray([host_points.shape[0]], np.int64))).reshape(-1)
-            n_for_plan = int(rows.sum())
-            if rows[0] < k:
-                raise ValueError(
-                    f"multi-host KMeans selects initial centroids from "
-                    f"host 0's shard, which holds {int(rows[0])} rows "
-                    f"< k={k}; give host 0 at least k rows")
+                rows = np.asarray(multihost_utils.process_allgather(
+                    np.asarray([host_points.shape[0]], np.int64))).reshape(-1)
+                n_for_plan = int(rows.sum())
+                if rows[0] < k:
+                    raise ValueError(
+                        f"multi-host KMeans selects initial centroids from "
+                        f"host 0's shard, which holds {int(rows[0])} rows "
+                        f"< k={k}; give host 0 at least k rows")
 
-        workset_mode = self.get_workset()
-        plan = _fit_plan(n_for_plan, host_points.shape[1], k, measure, mesh,
-                         workset=workset_mode)
-        impl, block_n = plan.impl, plan.block_n
-        row_multiple, fill = plan.row_multiple, plan.fill
-        select_init = _INIT_MODES[self.get_init_mode()]
-        if multi_host:
-            from ...parallel.distributed import broadcast_from_host0
+            workset_mode = self.get_workset()
+            plan = _fit_plan(n_for_plan, host_points.shape[1], k, measure,
+                             mesh, workset=workset_mode)
+            impl, block_n = plan.impl, plan.block_n
+            select_init = _INIT_MODES[self.get_init_mode()]
+            with tracer.span("fit.arrange.init", "fit"):
+                if multi_host:
+                    from ...parallel.distributed import broadcast_from_host0
 
-            multiple = plan.local_multiple(mesh)
-            padded_rows = -(-rows // multiple) * multiple
-            if not np.all(padded_rows == padded_rows[0]):
-                raise ValueError(
-                    "multi-host KMeans requires equal padded row counts "
-                    f"per process; got {padded_rows.tolist()}")
-            init = (select_init(host_points, k, self.get_seed())
-                    if jax.process_index() == 0
-                    else np.zeros((k, host_points.shape[1]), np.float32))
-            init = np.asarray(broadcast_from_host0(init))
-        else:
-            init = select_init(host_points, k, self.get_seed())
-
-        points, mask = _prepare_points(host_points, mesh,
-                                       row_multiple=row_multiple, fill=fill,
-                                       cross_host_checked=True)
-        init_dev = replicate(init, mesh)
+                    multiple = plan.local_multiple(mesh)
+                    padded_rows = -(-rows // multiple) * multiple
+                    if not np.all(padded_rows == padded_rows[0]):
+                        raise ValueError(
+                            "multi-host KMeans requires equal padded row "
+                            f"counts per process; got {padded_rows.tolist()}")
+                    init = (select_init(host_points, k, self.get_seed())
+                            if jax.process_index() == 0
+                            else np.zeros((k, host_points.shape[1]),
+                                          np.float32))
+                    init = np.asarray(broadcast_from_host0(init))
+                else:
+                    init = select_init(host_points, k, self.get_seed())
+            with tracer.span("fit.arrange.pad", "fit"):
+                padded, mask = _pad_points(
+                    host_points, mesh, row_multiple=plan.row_multiple,
+                    fill=plan.fill, cross_host_checked=True)
+        with tracer.span("fit.upload", "fit"):
+            points = put_sharded(padded, mesh, P("data"))
+            mask = put_sharded(mask, mesh, P("data"))
+            init_dev = replicate(init, mesh)
+        del padded  # the runtime holds it for as long as the transfer needs
 
         if workset_mode:
             result = iterate(
@@ -764,7 +784,8 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                 max_epochs=self.get_max_iter(),
                 config=IterationConfig(mode="fused"),
             )
-        centroids = np.asarray(fetch_replicated(result.state))
+        with tracer.span("fit.fetch", "fit"):
+            centroids = np.asarray(fetch_replicated(result.state))
 
         model = KMeansModel()
         model.copy_params_from(self)

@@ -12,6 +12,7 @@ import numpy as np
 from ...api.stage import Estimator, Model
 from ...data.table import Table
 from ...linalg import SparseVector, stack_sparse_vectors, stack_vectors
+from ...obs.trace import tracer
 from ...params.shared import (
     HasElasticNet,
     HasFeaturesCol,
@@ -326,11 +327,18 @@ class LinearEstimatorBase(LinearEstimatorParams, Estimator):
 
     def fit(self, *inputs):
         (table,) = inputs
-        kind, feats = resolve_features(table, self.get_features_col())
-        y = self._labels(table)
-        weight_col = self.get_weight_col()
-        weights = (np.asarray(table[weight_col], np.float64)
-                   if weight_col else None)
+        with tracer.fit_span(type(self).__name__):
+            return self._fit(table)
+
+    def _fit(self, table: Table):
+        """``fit`` under its root span; the phase spans are in the
+        trainers (``sgd_fit_mixed`` has all of them)."""
+        with tracer.span("fit.gather", "fit"):
+            kind, feats = resolve_features(table, self.get_features_col())
+            y = self._labels(table)
+            weight_col = self.get_weight_col()
+            weights = (np.asarray(table[weight_col], np.float64)
+                       if weight_col else None)
 
         if kind == "sparse":
             idx, vals, dim = feats

@@ -307,10 +307,11 @@ def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
         return IterationBodyResult(
             feedback=(params, epoch_loss, probe), termination=termination)
 
-    init_state = (replicate(init_params, mesh) if place_params
-                  else init_params,
-                  jnp.asarray(jnp.inf, jnp.float32),
-                  StepProbe.create(("loss",), config.max_epochs))
+    with tracer.span("fit.upload", "fit"):
+        init_state = (replicate(init_params, mesh) if place_params
+                      else init_params,
+                      jnp.asarray(jnp.inf, jnp.float32),
+                      StepProbe.create(("loss",), config.max_epochs))
 
     result = iterate(
         epoch_body, init_state, data,
@@ -318,9 +319,10 @@ def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
         config=IterationConfig(mode="fused"),
     )
     params, _final_loss, probe = result.state
-    params = _fetch_replicated(params)
-    loss_log = list(probe.fetch(
-        get=lambda v: _fetch_replicated(v))["loss"][:result.num_epochs])
+    with tracer.span("fit.fetch", "fit"):
+        params = _fetch_replicated(params)
+        loss_log = list(probe.fetch(
+            get=lambda v: _fetch_replicated(v))["loss"][:result.num_epochs])
     return params, loss_log
 
 
@@ -1144,98 +1146,109 @@ def sgd_fit_mixed(loss_fn: LossFn, dense_features: np.ndarray,
     :func:`sgd_fit` / :func:`sgd_fit_sparse`."""
     from .linear import check_sparse_indices
 
-    check_sparse_indices(cat_indices, num_features)
-    n_dense = dense_features.shape[1]
-    if n_dense > num_features:
-        raise ValueError(f"n_dense={n_dense} exceeds "
-                         f"num_features={num_features}")
     mesh = mesh or default_mesh()
     n = dense_features.shape[0]
-    steps, batch, perm = _plan_epoch_layout_for_mesh(
-        n, resolve_global_batch_size(config, n, num_features), mesh,
-        config.seed)
+    with tracer.span("fit.gather", "fit"):
+        check_sparse_indices(cat_indices, num_features)
+        n_dense = dense_features.shape[1]
+        if n_dense > num_features:
+            raise ValueError(f"n_dense={n_dense} exceeds "
+                             f"num_features={num_features}")
 
-    dense = prepare_epoch_tensor(dense_features.astype(np.float32), perm,
-                                 steps, batch)
-    cat = prepare_epoch_tensor(cat_indices.astype(np.int32), perm, steps,
-                               batch)
-    y = prepare_epoch_tensor(labels.astype(np.float32), perm, steps, batch)
-    w_host = (weights.astype(np.float32) if weights is not None
-              else np.ones((n,), np.float32))
-    w = prepare_epoch_tensor(w_host, perm, steps, batch, pad_value=0.0)
+    with tracer.span("fit.arrange", "fit"):
+        steps, batch, perm = _plan_epoch_layout_for_mesh(
+            n, resolve_global_batch_size(config, n, num_features), mesh,
+            config.seed)
+        # cast then permute, a tensor at a time: a cast copy is freed as
+        # soon as its permuted copy exists
+        with tracer.span("fit.arrange.permute", "fit"):
+            dense = prepare_epoch_tensor(dense_features.astype(np.float32),
+                                         perm, steps, batch)
+            cat = prepare_epoch_tensor(cat_indices.astype(np.int32), perm,
+                                       steps, batch)
+            y = prepare_epoch_tensor(labels.astype(np.float32), perm, steps,
+                                     batch)
+            w_host = (weights.astype(np.float32) if weights is not None
+                      else np.ones((n,), np.float32))
+            w = prepare_epoch_tensor(w_host, perm, steps, batch,
+                                     pad_value=0.0)
 
-    model_sharded = int(mesh.shape.get("model", 1)) > 1
-    impl = ("sharded" if model_sharded
-            else plan_mixed_impl(num_features, mesh, steps,
-                                 allow_sharded=True))
-    n_dev_data = int(mesh.shape.get("data", 1))
-    ell_sharded = impl == "ell" and n_dev_data > 1
-    place_params = True
-    init_params = {"w": jnp.zeros((num_features,), jnp.float32),
-                   "b": jnp.zeros((), jnp.float32)}
-    if ell_sharded:
-        # per-device shard layouts (VERDICT r3 task 4): slot sources are
-        # numbered inside each device's local (batch/n_dev)-row shard, and
-        # the stacks gain a device dim sharded over 'data'
-        from ...ops.ell_scatter import ell_layout
+        model_sharded = int(mesh.shape.get("model", 1)) > 1
+        impl = ("sharded" if model_sharded
+                else plan_mixed_impl(num_features, mesh, steps,
+                                     allow_sharded=True))
+        n_dev_data = int(mesh.shape.get("data", 1))
+        ell_sharded = impl == "ell" and n_dev_data > 1
+        place_params = True
+        init_params = {"w": jnp.zeros((num_features,), jnp.float32),
+                       "b": jnp.zeros((), jnp.float32)}
+        if ell_sharded:
+            # per-device shard layouts (VERDICT r3 task 4): slot sources
+            # are numbered inside each device's local (batch/n_dev)-row
+            # shard, and the stacks gain a device dim sharded over 'data'
+            from ...ops.ell_scatter import ell_layout
 
-        local = batch // n_dev_data
-        lay = ell_layout(
-            cat.reshape(steps * n_dev_data, local, cat.shape[-1]),
-            num_features)
+            local = batch // n_dev_data
+            with tracer.span("fit.arrange.ell_layout", "fit"):
+                lay = ell_layout(
+                    cat.reshape(steps * n_dev_data, local, cat.shape[-1]),
+                    num_features)
 
-        def dev_stack(a):
-            return a.reshape((steps, n_dev_data) + a.shape[1:])
+            def dev_stack(a):
+                return a.reshape((steps, n_dev_data) + a.shape[1:])
 
-        extra = tuple(dev_stack(a) for a in (
-            lay.src, lay.pos, lay.mask, lay.ovf_idx, lay.ovf_src,
-            lay.heavy_idx, lay.heavy_cnt))
-        update = _mixed_update_ell_sharded(
-            loss_fn, config, mesh, num_features)
-    elif impl == "ell":
-        # one-time static routing of every step's categorical slots
-        # (amortised over max_epochs replays of the same epoch tensor)
-        from ...ops.ell_scatter import ell_layout
+            extra = tuple(dev_stack(a) for a in (
+                lay.src, lay.pos, lay.mask, lay.ovf_idx, lay.ovf_src,
+                lay.heavy_idx, lay.heavy_cnt))
+            update = _mixed_update_ell_sharded(
+                loss_fn, config, mesh, num_features)
+        elif impl == "ell":
+            # one-time static routing of every step's categorical slots
+            # (amortised over max_epochs replays of the same epoch tensor)
+            from ...ops.ell_scatter import ell_layout
 
-        layout = ell_layout(cat, num_features)
-        extra = (layout.src, layout.pos, layout.mask,
-                 layout.ovf_idx, layout.ovf_src,
-                 layout.heavy_idx, layout.heavy_cnt)
-        update = _mixed_update_ell(loss_fn, config)
-    elif impl == "sharded":
-        # weight sharded over the model axis (2^24+ hash spaces never
-        # replicate); see _mixed_update_sharded
-        extra = ()
-        update = _mixed_update_sharded(loss_fn, config, mesh, num_features,
-                                       n_dense)
-        init_params = {
-            "w": _place_zeros((num_features,), mesh, P("model")),
-            "b": _place_zeros((), mesh, P()),
-        }
-        place_params = False
-    else:
-        extra = ()
-        update = _mixed_update(loss_fn, config)
+            with tracer.span("fit.arrange.ell_layout", "fit"):
+                layout = ell_layout(cat, num_features)
+            extra = (layout.src, layout.pos, layout.mask,
+                     layout.ovf_idx, layout.ovf_src,
+                     layout.heavy_idx, layout.heavy_cnt)
+            update = _mixed_update_ell(loss_fn, config)
+        elif impl == "sharded":
+            # weight sharded over the model axis (2^24+ hash spaces never
+            # replicate); see _mixed_update_sharded
+            extra = ()
+            update = _mixed_update_sharded(loss_fn, config, mesh,
+                                           num_features, n_dense)
+            init_params = {
+                "w": _place_zeros((num_features,), mesh, P("model")),
+                "b": _place_zeros((), mesh, P()),
+            }
+            place_params = False
+        else:
+            extra = ()
+            update = _mixed_update(loss_fn, config)
 
-    dense = _put_epoch_tensor(dense, mesh, P(None, "data", None))
-    y = _put_epoch_tensor(y, mesh, P(None, "data"))
-    w = _put_epoch_tensor(w, mesh, P(None, "data"))
-    if ell_sharded:
-        specs = ([P(None, "data", None, None)] * 3
-                 + [P(None, "data", None)] * 3
-                 + [P(None, "data", None, None)])
-        extra = tuple(_put_epoch_tensor(a, mesh, s)
-                      for a, s in zip(extra, specs))
-    elif impl == "ell":
-        extra = tuple(jax.device_put(a) for a in extra)  # single-device
-    if impl in ("ell",):
-        # the ELL updates never read the raw index tensor — margins and
-        # scatters both ride the layout — so the (steps, batch, nnz)
-        # epoch tensor stays host-side (~steps*batch*nnz*4 B of HBM)
-        epoch_args = (dense,) + extra + (y, w)
-    else:
-        cat = _put_epoch_tensor(cat, mesh, P(None, "data", None))
-        epoch_args = (dense, cat) + extra + (y, w)
+    with tracer.span("fit.upload", "fit"):
+        dense = _put_epoch_tensor(dense, mesh, P(None, "data", None))
+        y = _put_epoch_tensor(y, mesh, P(None, "data"))
+        w = _put_epoch_tensor(w, mesh, P(None, "data"))
+        if ell_sharded:
+            specs = ([P(None, "data", None, None)] * 3
+                     + [P(None, "data", None)] * 3
+                     + [P(None, "data", None, None)])
+            extra = tuple(_put_epoch_tensor(a, mesh, s)
+                          for a, s in zip(extra, specs))
+        elif impl == "ell":
+            extra = tuple(jax.device_put(a) for a in extra)  # single-device
+        if impl in ("ell",):
+            # the ELL updates never read the raw index tensor — margins
+            # and scatters both ride the layout — so the (steps, batch,
+            # nnz) epoch tensor stays host-side (~steps*batch*nnz*4 B of
+            # HBM)
+            epoch_args = (dense,) + extra + (y, w)
+        else:
+            cat = _put_epoch_tensor(cat, mesh, P(None, "data", None))
+            epoch_args = (dense, cat) + extra + (y, w)
 
     params, loss_log = _run_minibatch_epochs(
         update, epoch_args, init_params, steps, config,

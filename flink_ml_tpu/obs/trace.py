@@ -11,19 +11,30 @@ as nested/adjacent events on a shared timeline.
 
 Design stance:
 
-- **Off by default, near-free when off.**  Every instrumentation site
-  goes through :meth:`SpanTracer.span` (or guards on
-  :attr:`SpanTracer.enabled`); disabled, ``span()`` returns one shared
-  no-op context manager — no allocation, no lock, no clock read.  The
-  serving/bench A/B (``bench.py::bench_obs``) holds the enabled-path
-  overhead under 5% of p99 with ZERO new XLA lowerings (tracing is
-  pure host bookkeeping — it never touches a traced program).
+- **One clock with the device.**  Every :meth:`SpanTracer.span` is
+  also a ``jax.profiler.TraceAnnotation``: inside a profiler session
+  (``jax.profiler.start_trace``) the span lands on the ``/host:CPU``
+  plane of the same ``.xplane.pb`` as the device's operations, on the
+  device's clock, with its ids as the event's stats — so a span can
+  explain a device gap.  No switch turns this on; with no session the
+  annotation is inert (about a microsecond, nothing kept).
+- **Ring off by default, near-free when off.**  Every instrumentation
+  site goes through :meth:`SpanTracer.span` (or guards on
+  :attr:`SpanTracer.enabled`); with the ring off ``span()`` is the bare
+  annotation — one small object, no lock, no clock read, nothing
+  recorded (``tracer.count`` stays 0).  ``bench.py::bench_obs`` compares
+  ring ON with ring off within one run (the enabled path under 5% of
+  p99, ZERO new XLA lowerings: tracing is pure host bookkeeping and
+  never touches a traced program); it cannot see what the off path
+  itself costs.  That was compared once, when the off path stopped
+  being a shared no-op object: the same serving sweep, ring off, before
+  and after (PERF.md section 6, PR 27: no difference that shows).
 - **Bounded memory.**  Completed spans land in a preallocated ring
   (default 64 Ki spans); the lock is held only for the slot bump +
   assignment — never across a clock read or an export.
 - **Correlation ids, not parent pointers.**  Spans carry a small dict
   of well-known keys (``request_id``, ``generation``, ``step``,
-  ``window``, ``epoch``, ``op``, ``bucket`` — the contract
+  ``window``, ``epoch``, ``op``, ``bucket``, ``fit`` — the contract
   ARCHITECTURE.md "Observability" documents); viewers nest by
   (tid, time) containment, and cross-thread causality rides the shared
   ids (a publish's ``generation`` is the served request's
@@ -44,12 +55,17 @@ graftlint atomic-writes durable set).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import itertools
 import json
 import os
 import threading
 import time
 
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "SpanTracer", "tracer", "CORRELATION_KEYS"]
 
@@ -62,9 +78,18 @@ __all__ = ["Span", "SpanTracer", "tracer", "CORRELATION_KEYS"]
 #: window index; ``epoch``/``op``/``bucket`` label loops and dispatch;
 #: ``tenant`` = the multi-tenant scheduler's tenant name (ISSUE 14) —
 #: queue-wait/serve/shed spans carry it, so one trace shows
-#: cross-tenant interleaving on the shared device.
+#: cross-tenant interleaving on the shared device.  ``fit`` = one
+#: ``fit()`` call (a process-unique integer from
+#: :meth:`SpanTracer.fit_span`, shared by every span opened inside it).
 CORRELATION_KEYS = ("request_id", "generation", "step", "window",
-                    "epoch", "op", "bucket", "tenant")
+                    "epoch", "op", "bucket", "tenant", "fit")
+
+_fit_ids = itertools.count(1)
+#: the ``fit`` id of the ``fit()`` call this context is inside, if any:
+#: how the id reaches ``iterate`` and the registry's dispatch spans
+#: without a parameter
+_current_fit: contextvars.ContextVar = contextvars.ContextVar(
+    "flink_ml_tpu_fit", default=None)
 
 
 class Span:
@@ -91,34 +116,29 @@ class Span:
         return out
 
 
-class _NullSpan:
-    """The shared disabled-path context manager: every method is a no-op
-    and ``note`` chains, so instrumentation sites never branch."""
+class _ProfilerSpan(TraceAnnotation):
+    """The ring-off span: the profiler's annotation and nothing else.
+    ``note(**ids)`` chains and adds stats to the event, so
+    instrumentation sites never branch."""
 
     __slots__ = ()
 
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    def note(self, **ids) -> "_NullSpan":
+    def note(self, **ids) -> "_ProfilerSpan":
+        self.set_metadata(**ids)
         return self
 
 
-_NULL = _NullSpan()
-
-
-class _LiveSpan:
-    """One in-flight span; ``note(**ids)`` attaches correlation ids
-    discovered mid-span (e.g. the generation captured after the batch
-    formed)."""
+class _LiveSpan(_ProfilerSpan):
+    """One in-flight span with the ring on: the same annotation, and a
+    commit to the ring at exit.  ``note(**ids)`` attaches correlation
+    ids discovered mid-span (e.g. the generation captured after the
+    batch formed)."""
 
     __slots__ = ("_tracer", "name", "cat", "ids", "_t0")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  ids: Dict[str, Any]):
+        super().__init__(name, **ids)
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -126,17 +146,19 @@ class _LiveSpan:
         self._t0 = 0.0
 
     def __enter__(self) -> "_LiveSpan":
+        super().__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._tracer.add(self.name, self._t0, time.perf_counter(),
                          cat=self.cat, **self.ids)
+        super().__exit__(*exc)
         return False
 
     def note(self, **ids) -> "_LiveSpan":
         self.ids.update(ids)
-        return self
+        return super().note(**ids)
 
 
 class SpanTracer:
@@ -183,19 +205,37 @@ class SpanTracer:
 
     # -- recording ----------------------------------------------------------
     def span(self, name: str, cat: str = "host", **ids):
-        """Context manager timing a code region.  Disabled -> the shared
-        no-op (no allocation); enabled -> a live span committed to the
-        ring at exit."""
+        """Context manager timing a code region.  Always a profiler
+        annotation (inert outside a profiler session); with the ring on
+        also a live span committed to the ring at exit.  Inside a
+        :meth:`fit_span` the span carries that call's ``fit`` id."""
+        fit = _current_fit.get()
+        if fit is not None:
+            ids.setdefault("fit", fit)
         if not self.enabled:
-            return _NULL
+            return _ProfilerSpan(name, **ids)
         return _LiveSpan(self, name, cat, ids)
+
+    @contextlib.contextmanager
+    def fit_span(self, op: str):
+        """The root span ``fit`` of one ``fit()`` call of the estimator
+        ``op``: draws the call's ``fit`` id and makes it current, so every
+        span opened inside (here, in ``iterate``, in the registry) shares
+        it."""
+        token = _current_fit.set(next(_fit_ids))
+        try:
+            with self.span("fit", "fit", op=op) as span:
+                yield span
+        finally:
+            _current_fit.reset(token)
 
     def add(self, name: str, t0: float, t1: float, *, cat: str = "host",
             tid: Optional[int] = None, **ids) -> None:
         """Commit a RETROACTIVE span measured by the caller (``t0``/``t1``
         on the ``perf_counter`` timebase) — how queue-wait is recorded:
         the serve loop stamps it from the request's submit timestamp
-        once the batch forms, no tracer work on the submit path."""
+        once the batch forms, no tracer work on the submit path.
+        Ring-only: a profiler annotation cannot be back-dated."""
         if not self.enabled:
             return
         self._commit(Span(name, cat, t0, max(t1 - t0, 0.0),
@@ -203,7 +243,8 @@ class SpanTracer:
                           threading.get_ident(), "X", ids))
 
     def instant(self, name: str, cat: str = "host", **ids) -> None:
-        """Zero-duration marker event (e.g. a shed, a rollback)."""
+        """Zero-duration marker event (e.g. a shed, a rollback).
+        Ring-only, like :meth:`add`."""
         if not self.enabled:
             return
         self._commit(Span(name, cat, time.perf_counter(), 0.0,

@@ -1,37 +1,22 @@
-"""Profiler hooks — the TPU answer to the reference's latency tracking.
+"""Device-fenced wall timing — the TPU answer to the reference's latency
+tracking.
 
 The reference's only tracing is Flink LatencyMarker stats in the per-round
-wrapper (SURVEY §5).  Here: thin wrappers over ``jax.profiler`` producing
-Perfetto/XPlane traces of the jitted epoch steps, plus named trace
-annotations for host-side phases.
+wrapper (SURVEY §5).  Here a wall-clock timing of device work ends on a
+fetch of its result (:class:`StepTimer`, :func:`fenced_call`).  To put a
+named host phase on the profiler's timeline use ``obs.tracer.span``:
+every span is a ``jax.profiler.TraceAnnotation``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 
-from typing import Any, Callable, Iterator, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 
-__all__ = ["trace", "annotate", "StepTimer", "fenced_call"]
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture a device+host profile into ``log_dir`` (view with Perfetto /
-    tensorboard).  Usage: ``with profiler.trace("/tmp/prof"): fit()``."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named host annotation that shows up on the trace timeline."""
-    return jax.profiler.TraceAnnotation(name)
+__all__ = ["StepTimer", "fenced_call"]
 
 
 class StepTimer:

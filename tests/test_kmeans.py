@@ -176,16 +176,19 @@ def test_pallas_epoch_step_sharded_matches(cpu_mesh_8):
     import jax
     import jax.numpy as jnp
 
+    from jax.sharding import PartitionSpec as P
+
     from flink_ml_tpu.models.clustering.kmeans import (
-        _prepare_points,
+        _pad_points,
         kmeans_epoch_step_pallas,
     )
-    from flink_ml_tpu.parallel.mesh import replicate
+    from flink_ml_tpu.parallel.mesh import put_sharded, replicate
 
     rng = np.random.default_rng(4)
     pts = rng.normal(size=(1000, 4)).astype(np.float32)
-    points, mask = _prepare_points(pts, cpu_mesh_8, row_multiple=128,
-                                   fill="zero")
+    points, mask = (put_sharded(a, cpu_mesh_8, P("data"))
+                    for a in _pad_points(pts, cpu_mesh_8, row_multiple=128,
+                                         fill="zero"))
     assert points.shape[0] == 1024
     cents = replicate(pts[:5].copy(), cpu_mesh_8)
 

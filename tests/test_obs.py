@@ -3,7 +3,8 @@
 Four blocks:
 
 1. **Tracer mechanics** — ring wraparound, disabled-path no-op,
-   retroactive spans, Chrome-trace/JSONL export round trips.
+   retroactive spans, Chrome-trace/JSONL export round trips; a span is
+   also a profiler annotation, and ``fit()`` carries its phase spans.
 2. **One metrics tree** — every surface merges into one snapshot, the
    Prometheus exposition parses line by line, the never-published
    staleness gauge exports ABSENT (the ``-1`` sentinel regression),
@@ -53,12 +54,148 @@ def _quiet_global_tracer():
 
 def test_tracer_disabled_records_nothing():
     t = SpanTracer(capacity=8)
-    assert t.span("a") is t.span("b")          # one shared no-op object
-    with t.span("a", op="x"):
-        pass
+    # ring off: a span is the profiler's annotation alone (inert with no
+    # session); ``note`` still chains, nothing is recorded
+    with t.span("a", op="x") as span:
+        assert span.note(request_id=1) is span
     t.instant("b")
     t.add("c", 0.0, 1.0)
     assert t.spans() == [] and t.count == 0
+
+
+# the phase spans of one fit(), in the order they must appear
+FIT_PHASES = ("fit.gather", "fit.arrange", "fit.upload",
+              "iterate.dispatch", "fit.fetch")
+
+
+def _fit_case(name):
+    """``(make_estimator, table, arrays_of_model)`` for a small fit."""
+    rng = np.random.default_rng(11)
+    if name == "kmeans":
+        from flink_ml_tpu.models.clustering.kmeans import KMeans
+
+        table = Table({"features": rng.normal(size=(300, 5))})
+        return (lambda: KMeans().set_k(3).set_max_iter(3).set_seed(7),
+                table,
+                lambda m: [np.asarray(m.get_model_data()[0]["centroids"])])
+    from flink_ml_tpu.models.classification.logisticregression import (
+        LogisticRegression,
+    )
+
+    n, n_dense, n_cat, d = 256, 4, 3, 128
+    dense = rng.normal(size=(n, n_dense)).astype(np.float32)
+    cat = rng.integers(n_dense, d, size=(n, n_cat)).astype(np.int32)
+    table = Table({"features_dense": dense, "features_indices": cat,
+                   "label": (dense[:, 0] > 0).astype(np.float64)})
+    return (lambda: (LogisticRegression().set_num_features(d)
+                     .set_max_iter(2).set_tol(0).set_seed(5)
+                     .set_global_batch_size(64)),
+            table,
+            lambda m: [m._state.coefficients,
+                       np.asarray(m._state.intercept),
+                       np.asarray(m._loss_log)])
+
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """``run(fn)`` calls ``fn`` inside a ``jax.profiler`` session and
+    returns ``(fn's result, the program's spans read back from the
+    .xplane.pb)``: ``(name, start_ns, end_ns, stats)`` of the host
+    events named ``fit*`` / ``iterate.dispatch``, in time order."""
+    import glob
+
+    import jax
+
+    def run(fn):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            result = fn()
+        finally:
+            jax.profiler.stop_trace()
+        (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        spans = []
+        for plane in jax.profiler.ProfileData.from_file(pb).planes:
+            if plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name == "fit" or e.name.startswith(
+                            ("fit.", "iterate.dispatch")):
+                        spans.append((e.name, e.start_ns,
+                                      e.start_ns + e.duration_ns,
+                                      dict(e.stats)))
+        return result, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+    return run
+
+
+@pytest.mark.parametrize("case", ["kmeans", "lr_mixed"])
+def test_fit_spans_land_in_the_profiler_trace_with_the_ring_off(
+        case, profiler_session):
+    make, table, _ = _fit_case(case)
+    assert not trace_mod.tracer.enabled
+    _, spans = profiler_session(
+        lambda: [make().fit(table), make().fit(table)])
+    assert trace_mod.tracer.count == 0
+    roots = [s for s in spans if s[0] == "fit"]
+    assert len(roots) == 2
+    assert roots[0][2] <= roots[1][1]
+    fit_ids = [r[3]["fit"] for r in roots]
+    assert fit_ids[0] != fit_ids[1]
+    for _, lo, hi, stats in roots:
+        assert stats["op"] == type(make()).__name__
+        inside = [s for s in spans if s[0] != "fit" and lo <= s[1] < hi]
+        assert all(s[2] <= hi for s in inside)
+        assert {s[3]["fit"] for s in inside} == {stats["fit"]}
+        phases = [s for s in inside if s[0] in FIT_PHASES]
+        # each phase is there, in this order (a phase may come in two
+        # adjacent pieces, one per function that does part of it) ...
+        order = [s[0] for i, s in enumerate(phases)
+                 if i == 0 or phases[i - 1][0] != s[0]]
+        assert tuple(order) == FIT_PHASES
+        # ... and no two overlap
+        assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+        # a child span lies inside a span of its parent's name
+        for name, c_lo, c_hi, _ in inside:
+            if name not in FIT_PHASES:
+                parent = name.rsplit(".", 1)[0]
+                assert any(p[0] == parent and p[1] <= c_lo and c_hi <= p[2]
+                           for p in phases), name
+
+
+@pytest.mark.parametrize("case", ["kmeans", "lr_mixed"])
+def test_fit_spans_land_in_the_ring_when_it_is_on(case):
+    make, table, _ = _fit_case(case)
+    tracer = trace_mod.tracer
+    tracer.enable()
+    make().fit(table)
+    make().fit(table)
+    tracer.disable()
+    roots = list(tracer.find("fit"))
+    assert len(roots) == 2
+    assert roots[0].ids["fit"] != roots[1].ids["fit"]
+    for root in roots:
+        names = {s.name for s in tracer.find(fit=root.ids["fit"])}
+        assert set(FIT_PHASES) <= names and "fit" in names
+        for s in tracer.find(fit=root.ids["fit"]):
+            assert root.t0 <= s.t0 and s.t0 + s.dur <= root.t0 + root.dur
+
+
+@pytest.mark.parametrize("case", ["kmeans", "lr_mixed"])
+def test_fit_is_the_same_model_traced_or_not_and_records_nothing_when_off(
+        case, profiler_session):
+    make, table, arrays = _fit_case(case)
+    tracer = trace_mod.tracer
+    plain = arrays(make().fit(table))
+    assert tracer.count == 0 and tracer.spans() == []
+    traced, spans = profiler_session(lambda: arrays(make().fit(table)))
+    assert any(s[0] == "fit" for s in spans)
+    assert len(plain) == len(traced)
+    for a, b in zip(plain, traced):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
 def test_tracer_ring_wraparound_keeps_newest():
