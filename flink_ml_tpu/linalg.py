@@ -165,3 +165,27 @@ def stack_vectors(column: Iterable[Any]) -> np.ndarray:
     if not rows:
         return np.zeros((0, 0), dtype=np.float64)
     return np.stack(rows)
+
+
+def float32_rows(column: Iterable[Any]) -> np.ndarray:
+    """A features column as one C-contiguous float32 ``(rows, dim)`` array,
+    with the values of ``stack_vectors(column).astype(np.float32)`` and the
+    fewest copies the column allows.  The route follows what the column is:
+
+    - a float32 ndarray that is C-contiguous: the column ITSELF (a 1-D one
+      as the ``(n, 1)`` view ``stack_vectors`` promotes it to), no byte
+      copied.  The caller must not write into the result;
+    - a float16 / float64 ndarray, one of integers no wider than 32 bits or
+      of booleans, or a float32 one that is not C-contiguous: ONE
+      conversion.  float64 holds each of these exactly, so a stop there
+      would round the same way;
+    - anything else (wider integers, which float64 would round first;
+      an object column of ``DenseVector`` s or lists): through
+      ``stack_vectors``' float64, as before."""
+    if not isinstance(column, np.ndarray) or column.dtype == object:
+        return stack_vectors(column).astype(np.float32)
+    kind, size = column.dtype.kind, column.dtype.itemsize
+    if not ((kind == "f" and size <= 8) or (kind in "iub" and size <= 4)):
+        column = np.asarray(column, np.float64)
+    arr = np.ascontiguousarray(column, dtype=np.float32)
+    return arr.reshape(-1, 1) if arr.ndim == 1 else arr

@@ -23,7 +23,7 @@ inside the iteration body.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, Optional
 
 import jax
@@ -39,7 +39,7 @@ from ...iteration import (
     Workset,
     iterate,
 )
-from ...linalg import stack_vectors
+from ...linalg import float32_rows, stack_vectors
 from ...obs.trace import tracer
 from ...params.param import (
     BoolParam,
@@ -159,10 +159,14 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
 def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
                 fill: str = "first_row",
                 cross_host_checked: bool = False) -> tuple:
-    """The host half of host -> device: pad rows to a multiple of the
-    data-axis size (and of ``row_multiple`` per shard; mask marks real
-    rows).  The caller shards the batch dim of both
-    (``put_sharded(.., P("data"))``).
+    """The host half of host -> device on every mesh that SHARDS the rows
+    (more than one device on the ``data`` axis, or more than one process):
+    pad rows to a multiple of the data-axis size (and of ``row_multiple``
+    per shard; mask marks real rows).  The caller shards the batch dim of
+    both (``put_sharded(.., P("data"))``).  With a remainder this is a
+    whole copy of the points (``pad_rows_with_mask``), without one it is
+    the array it was given; a mesh whose ``data`` axis is one device of
+    one process pads on the device instead (:func:`_rows_on_device`).
 
     On a process-spanning mesh ``points`` is THIS process's shard; each
     host pads to its local device multiple and the global array assembles
@@ -181,6 +185,49 @@ def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
                 "multi-host KMeans requires equal padded row counts per "
                 f"process; got {rows.tolist()}")
     return padded, mask
+
+
+#: Rows ``_rows_on_device`` gives their layout at a time: a reshape to
+#: rows of ``d`` < 128 floats is lane-padded to 512 B a row on the chip, so
+#: done whole it would reserve 10 GB beside 20 M rows; done this many at a
+#: time the padded piece stays out of HBM (compiled for a v5e: no
+#: temporary at all).
+_RELAYOUT_ROWS = 1 << 16
+
+
+@lru_cache(maxsize=None)
+def _rows_on_device(shape: tuple, pad: int, fill: str, sharding):
+    """The jitted program that makes ``pad_rows_with_mask``'s two arrays
+    where the rows already are.  Its argument is the C-order buffer of
+    the ``shape`` = (n, d) rows as it was put: one dimension, so the
+    runtime transposes nothing on the host on the way (the chip keeps
+    narrow rows column-major, and a put of the 2-D array makes that
+    layout tile by tile on the host's threads).  It gives the rows in
+    that layout, ``_RELAYOUT_ROWS`` at a time (the last piece overlaps
+    the one before it, so that every piece has one shape), with ``pad``
+    rows of ``fill`` after the last one, and the float32 mask of the real
+    rows, both under ``sharding``.  One program per shapes, kept for the
+    process (as ``_predict``'s jit keeps its own): a refit neither traces
+    nor compiles."""
+    n, d = shape
+    rows = min(n, _RELAYOUT_ROWS)
+
+    def place(flat, i, points):
+        start = jnp.minimum(i * rows, n - rows)
+        piece = jax.lax.dynamic_slice(flat, (start * d,), (rows * d,))
+        return jax.lax.dynamic_update_slice(
+            points, piece.reshape(rows, d), (start, 0))
+
+    def rows_and_mask(flat):
+        points = jax.lax.fori_loop(
+            0, -(-n // rows) if n else 0, partial(place, flat),
+            jnp.zeros((n + pad, d), flat.dtype))
+        if pad and fill == "first_row":
+            points = jax.lax.dynamic_update_slice(
+                points, jnp.broadcast_to(flat[:d], (pad, d)), (n, 0))
+        return points, (jnp.arange(n + pad) < n).astype(jnp.float32)
+
+    return jax.jit(rows_and_mask, out_shardings=(sharding, sharding))
 
 
 @partial(jax.jit, static_argnums=0)
@@ -688,8 +735,24 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
         """``fit`` under its root span.  The phase spans (``fit.gather``,
         ``fit.arrange``, ``fit.upload``, then ``iterate.dispatch`` inside
         ``iterate``, ``fit.fetch``) follow each other without a gap and
-        add no fence: each covers what the host does in it."""
-        from jax.sharding import PartitionSpec as P
+        add no fence: each covers what the host does in it.
+
+        How the points reach the device follows what the column and the
+        mesh are; no parameter chooses.  The column takes
+        :func:`~flink_ml_tpu.linalg.float32_rows`' route: a C-contiguous
+        float32 array is read IN PLACE (``fit`` never writes into it, and
+        the table must not be mutated from another thread while ``fit``
+        runs, which was never allowed), any other numeric array is
+        converted once, an object column of vectors is stacked first.  On
+        a mesh of one process whose ``data`` axis is one device the rows
+        are put as they are (their buffer, flat), BEFORE the start is
+        drawn, so that the transfer runs under the start's permutation,
+        and the device gives them their layout, the mask, and the fill
+        rows of a remainder against the plan's row multiple
+        (:func:`_rows_on_device`; no remainder, no row added).  Any other
+        mesh shards the rows, so they are padded on the host first
+        (:func:`_pad_points`) and put after the start, as before."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         # report describes THIS fit only — a reused estimator must not
         # serve a stale report from an earlier workset fit
@@ -699,10 +762,12 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
         measure = DistanceMeasure.get_instance(self.get_distance_measure())
 
         with tracer.span("fit.gather", "fit"):
+            column = table[self.get_features_col()]
             with tracer.span("fit.gather.stack", "fit"):
-                host_points = stack_vectors(table[self.get_features_col()])
+                if column.dtype == object:
+                    column = stack_vectors(column)
             with tracer.span("fit.gather.cast", "fit"):
-                host_points = host_points.astype(np.float32)
+                host_points = float32_rows(column)
         with tracer.span("fit.arrange", "fit"):
             n_for_plan = host_points.shape[0]
             multi_host = mesh_process_count(mesh) > 1
@@ -733,32 +798,51 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                              mesh, workset=workset_mode)
             impl, block_n = plan.impl, plan.block_n
             select_init = _INIT_MODES[self.get_init_mode()]
-            with tracer.span("fit.arrange.init", "fit"):
-                if multi_host:
-                    from ...parallel.distributed import broadcast_from_host0
 
-                    multiple = plan.local_multiple(mesh)
-                    padded_rows = -(-rows // multiple) * multiple
-                    if not np.all(padded_rows == padded_rows[0]):
-                        raise ValueError(
-                            "multi-host KMeans requires equal padded row "
-                            f"counts per process; got {padded_rows.tolist()}")
-                    init = (select_init(host_points, k, self.get_seed())
-                            if jax.process_index() == 0
-                            else np.zeros((k, host_points.shape[1]),
-                                          np.float32))
-                    init = np.asarray(broadcast_from_host0(init))
-                else:
-                    init = select_init(host_points, k, self.get_seed())
-            with tracer.span("fit.arrange.pad", "fit"):
-                padded, mask = _pad_points(
-                    host_points, mesh, row_multiple=plan.row_multiple,
-                    fill=plan.fill, cross_host_checked=True)
+        def draw_start():
+            with tracer.span("fit.arrange.init", "fit"):
+                if not multi_host:
+                    return select_init(host_points, k, self.get_seed())
+                from ...parallel.distributed import broadcast_from_host0
+
+                multiple = plan.local_multiple(mesh)
+                padded_rows = -(-rows // multiple) * multiple
+                if not np.all(padded_rows == padded_rows[0]):
+                    raise ValueError(
+                        "multi-host KMeans requires equal padded row "
+                        f"counts per process; got {padded_rows.tolist()}")
+                init = (select_init(host_points, k, self.get_seed())
+                        if jax.process_index() == 0
+                        else np.zeros((k, host_points.shape[1]), np.float32))
+                return np.asarray(broadcast_from_host0(init))
+
+        spec = P("data")
+        if not multi_host and int(mesh.shape["data"]) == 1:
+            with tracer.span("fit.upload", "fit"):
+                # asynchronous: the transfer runs while the host draws
+                # the start below
+                flat = put_sharded(host_points.reshape(-1), mesh, spec)
+            with tracer.span("fit.arrange", "fit"):
+                with tracer.span("fit.arrange.pad", "fit"):
+                    points, mask = _rows_on_device(
+                        host_points.shape,
+                        -host_points.shape[0] % plan.local_multiple(mesh),
+                        plan.fill, NamedSharding(mesh, spec))(flat)
+                    del flat
+                init = draw_start()
+        else:
+            with tracer.span("fit.arrange", "fit"):
+                init = draw_start()
+                with tracer.span("fit.arrange.pad", "fit"):
+                    padded, mask = _pad_points(
+                        host_points, mesh, row_multiple=plan.row_multiple,
+                        fill=plan.fill, cross_host_checked=True)
+            with tracer.span("fit.upload", "fit"):
+                points = put_sharded(padded, mesh, spec)
+                mask = put_sharded(mask, mesh, spec)
+            del padded  # the runtime holds it while the transfer needs it
         with tracer.span("fit.upload", "fit"):
-            points = put_sharded(padded, mesh, P("data"))
-            mask = put_sharded(mask, mesh, P("data"))
             init_dev = replicate(init, mesh)
-        del padded  # the runtime holds it for as long as the transfer needs
 
         if workset_mode:
             result = iterate(

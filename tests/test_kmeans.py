@@ -517,3 +517,207 @@ def test_fit_plan_workset_initializer_settles_padding():
     assert np.all(np.asarray(ws.bounds["lower"]) == -np.inf)
     np.testing.assert_array_equal(np.asarray(ws.bounds["assign"]),
                                   np.zeros(5))
+
+
+# -- ISSUE 28: the points reach the device from the table's own buffer ------
+
+def _column_of(kind: str, n: int, d: int = 5):
+    """A features column of each kind ``KMeans.fit`` tells apart."""
+    from flink_ml_tpu.linalg import DenseVector
+
+    rng = np.random.default_rng(28)
+    base = (100.0 * rng.normal(size=(n, d))).astype(np.float32)
+    if kind == "f32_c":
+        return base
+    if kind == "f32_1d":
+        return np.ascontiguousarray(base[:, 0])
+    if kind == "f64":
+        return 100.0 * rng.normal(size=(n, d))     # rounds on the way down
+    if kind == "f32_fortran":
+        return np.asfortranarray(base)
+    if kind == "i32":
+        return (base * 1e5).astype(np.int32)       # beyond float32's 2^24
+    if kind == "i64":
+        return (base.astype(np.float64) * 1e14).astype(np.int64)
+    assert kind == "object"
+    col = np.empty((n,), object)
+    for i, row in enumerate(base.astype(np.float64)):
+        col[i] = DenseVector(row)
+    return col
+
+
+_COLUMN_KINDS = ["f32_c", "f32_1d", "f64", "f32_fortran", "i32", "object"]
+
+
+@pytest.fixture
+def small_relayout_pieces(monkeypatch):
+    """``_rows_on_device`` at 64 rows a piece, so that a few hundred rows
+    take the loop the chip takes at 20 M (its programs are kept per
+    shapes, so they are dropped on the way in and out)."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    km._rows_on_device.cache_clear()
+    monkeypatch.setattr(km, "_RELAYOUT_ROWS", 64)
+    yield
+    km._rows_on_device.cache_clear()
+
+
+def _parent_fit(column, mesh, *, k, seed, max_iter, row_multiple, fill):
+    """The centroids as the parent commit's ``_fit`` made them: its three
+    host copies, kept here as the expectation, then the same program."""
+    from jax.sharding import PartitionSpec as P
+
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.iteration import IterationConfig, iterate
+    from flink_ml_tpu.linalg import stack_vectors
+    from flink_ml_tpu.models.clustering.kmeans import kmeans_epoch_step
+    from flink_ml_tpu.parallel.mesh import (
+        fetch_replicated, local_axis_multiple, put_sharded, replicate)
+    from flink_ml_tpu.utils.padding import pad_rows_with_mask
+
+    host_points = stack_vectors(column)
+    host_points = host_points.astype(np.float32)
+    padded, mask = pad_rows_with_mask(
+        host_points, local_axis_multiple(mesh, row_multiple=row_multiple),
+        fill=fill)
+
+    init = select_random_centroids(host_points, k, seed)
+    result = iterate(
+        kmeans_epoch_step(DistanceMeasure.get_instance("euclidean"), k),
+        replicate(init, mesh),
+        (put_sharded(padded, mesh, P("data")),
+         put_sharded(mask, mesh, P("data"))),
+        max_epochs=max_iter, config=IterationConfig(mode="fused"))
+    return np.asarray(fetch_replicated(result.state))
+
+
+def _fit_recording_puts(monkeypatch, column, mesh, *, row_multiple, fill):
+    """``KMeans.fit`` on ``mesh`` under a plan that pads to
+    ``row_multiple`` (off the chip the XLA plan asks for no multiple, so
+    the test steers it); returns the centroids, the parent's, and the host
+    arrays handed to ``put_sharded``."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel.mesh import use_mesh
+
+    monkeypatch.setattr(
+        km, "_fit_plan",
+        lambda n, d, k, measure, mesh, workset=False:
+        km.FitPlan("xla", None, row_multiple, fill, k, d))
+    puts = []
+    real_put = km.put_sharded
+    monkeypatch.setattr(
+        km, "put_sharded",
+        lambda arr, *a: (puts.append(arr), real_put(arr, *a))[1])
+    args = dict(k=4, seed=7, max_iter=4)
+    with use_mesh(mesh):
+        model = (KMeans().set_k(args["k"]).set_seed(args["seed"])
+                 .set_max_iter(args["max_iter"])
+                 .fit(Table({"features": column})))
+        expected = _parent_fit(column, mesh, row_multiple=row_multiple,
+                               fill=fill, **args)
+    (data,) = model.get_model_data()
+    return np.asarray(data["centroids"][0]), expected, puts
+
+
+def _column_bytes(column) -> bytes:
+    if column.dtype == object:
+        return np.stack([v.values for v in column]).tobytes()
+    return column.tobytes()
+
+
+@pytest.mark.parametrize("n", [256, 250])    # a multiple of 64, and not one
+@pytest.mark.parametrize("kind", _COLUMN_KINDS)
+def test_fit_reads_the_column_in_place_bitexact_vs_parent(
+        monkeypatch, small_relayout_pieces, kind, n):
+    """One process, one device on the ``data`` axis (the benchmark's
+    mesh): the rows go up as they are, flat, and the device gives them
+    their layout, the mask and the fill rows.  Centroids equal the parent's path bit for bit; a float32
+    C-contiguous column is handed to the put WITHOUT a copy; no route
+    writes into the user's table (on the CPU backend a put may alias host
+    memory, so this also holds ``iterate`` to it)."""
+    import jax
+
+    from flink_ml_tpu.parallel.mesh import device_mesh
+
+    column = _column_of(kind, n)
+    before = _column_bytes(column)
+    got, expected, puts = _fit_recording_puts(
+        monkeypatch, column, device_mesh(devices=jax.devices()[:1]),
+        row_multiple=64, fill="zero")
+
+    assert got.dtype == np.float32 and got.shape == (4, 1 if kind == "f32_1d"
+                                                     else 5)
+    assert got.tobytes() == expected.tobytes()
+    points_put = puts[0]
+    assert points_put.dtype == np.float32 and points_put.flags.c_contiguous
+    assert points_put.shape == (n * got.shape[1],)   # flat and unpadded
+    assert len(puts) == 1                    # pad and mask: the device's
+    in_place = kind in ("f32_c", "f32_1d")
+    assert (column.dtype != object
+            and np.shares_memory(points_put, column)) == in_place
+    assert _column_bytes(column) == before
+
+
+@pytest.mark.parametrize("n", [256, 250])
+def test_fit_on_a_sharding_mesh_pads_on_the_host_bitexact_vs_parent(
+        monkeypatch, cpu_mesh_8, n):
+    """Eight devices on the ``data`` axis: the rows are sharded, so
+    ``_pad_points`` still pads them on the host (8 x 16 = 128 a
+    multiple); with no remainder that is the column itself."""
+    column = _column_of("f32_c", n)
+    before = column.tobytes()
+    got, expected, puts = _fit_recording_puts(
+        monkeypatch, column, cpu_mesh_8, row_multiple=16, fill="zero")
+    assert got.tobytes() == expected.tobytes()
+    assert [a.shape for a in puts] == [(256, 5), (256,)]
+    assert np.shares_memory(puts[0], column) == (n == 256)
+    assert column.tobytes() == before
+
+
+@pytest.mark.parametrize("fill", ["zero", "first_row"])
+@pytest.mark.parametrize("shape", [(250, 5), (250, 1), (256, 5), (40, 5)])
+def test_rows_on_device_equals_the_host_pad(small_relayout_pieces, shape,
+                                            fill):
+    """One piece (40 rows), whole pieces (256 = 4 x 64), and a last piece
+    that overlaps the one before it (250)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.models.clustering.kmeans import _rows_on_device
+    from flink_ml_tpu.parallel.mesh import device_mesh, put_sharded
+    from flink_ml_tpu.utils.backend import count_compiles
+    from flink_ml_tpu.utils.padding import pad_rows_with_mask
+
+    mesh = device_mesh(devices=jax.devices()[:1])
+    pts = np.random.default_rng(5).normal(size=shape).astype(np.float32)
+    want = pad_rows_with_mask(pts, 64, fill=fill)
+
+    def on_device(rows):
+        return _rows_on_device(
+            shape, -shape[0] % 64, fill, NamedSharding(mesh, P("data")))(
+                put_sharded(rows.reshape(-1), mesh, P("data")))
+
+    for got, expected in zip(on_device(pts), want):
+        assert got.sharding == put_sharded(expected, mesh, P("data")).sharding
+        assert got.dtype == expected.dtype and got.shape == expected.shape
+        assert np.asarray(got).tobytes() == expected.tobytes()
+    # one program per shapes, kept: the same shapes compile once
+    with count_compiles() as compiles:
+        on_device(pts + 1)
+    assert compiles() == 0
+
+
+@pytest.mark.parametrize("kind", _COLUMN_KINDS + ["i64"])
+def test_float32_rows_values_and_copies(kind):
+    """``float32_rows`` gives ``stack_vectors(column).astype(float32)``'s
+    values on every route, and the column itself where it can."""
+    from flink_ml_tpu.linalg import float32_rows, stack_vectors
+
+    column = _column_of(kind, 37)
+    got = float32_rows(column)
+    expected = stack_vectors(column).astype(np.float32)
+    assert got.dtype == np.float32 and got.flags.c_contiguous
+    assert got.shape == expected.shape
+    assert got.tobytes() == expected.tobytes()
+    assert (column.dtype != object and np.shares_memory(got, column)) == (
+        kind in ("f32_c", "f32_1d"))
