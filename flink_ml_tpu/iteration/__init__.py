@@ -14,4 +14,10 @@ from .checkpoint import (  # noqa: F401
     load_pytree,
     save_pytree,
 )
-from .core import IterationResult, PerEpoch, Replayed, iterate  # noqa: F401
+from .core import (  # noqa: F401
+    HandedOver,
+    IterationResult,
+    PerEpoch,
+    Replayed,
+    iterate,
+)

@@ -89,6 +89,18 @@ def _vote_continue(vote: Any) -> bool:
     return bool(jax.device_get(vote))
 
 
+class HandedOver:
+    """Marks an initial state whose buffers the caller gives up: a
+    donating loop consumes them as they are, without the private copy
+    that otherwise protects arrays the caller still holds.  For a state
+    that fills a large share of the device (a table with its optimizer
+    state), where a second copy would not fit beside the first.  The
+    caller must not touch the arrays again."""
+
+    def __init__(self, state: Any):
+        self.state = state
+
+
 class Replayed:
     """Marks a bounded input replayed identically every epoch (the analog of
     ``ReplayableDataStreamList.replay(...)``).  On TPU a replayed input is
@@ -316,7 +328,14 @@ def iterate(
     chunk, so the returned state is the voting epoch's feedback exactly
     as in the per-epoch loop.  Ignored (with per-epoch stepping) for
     per-epoch data sources, unjitted bodies, and PER_ROUND lifecycles.
+
+    ``initial_state`` wrapped in :class:`HandedOver` is donated as it
+    is: the caller gives its buffers up, and the loop makes no private
+    copy of them first.
     """
+    handed_over = isinstance(initial_state, HandedOver)
+    if handed_over:
+        initial_state = initial_state.state
     config = config or IterationConfig()
     if max_epochs is not None:
         config = dataclasses.replace(config, max_epochs=max_epochs)
@@ -407,12 +426,12 @@ def iterate(
 
     if mode == "fused":
         result = _iterate_fused(body, initial_state, provider, config,
-                                frac_fn=frac_fn)
+                                frac_fn=frac_fn, handed_over=handed_over)
     else:
         result = _iterate_hosted(body, initial_state, provider, config,
                                  listeners, per_round_lifecycle,
                                  per_round_init, checkpoint, resume,
-                                 frac_fn=frac_fn)
+                                 frac_fn=frac_fn, handed_over=handed_over)
     if workset is not None:
         final_state, final_ws = result.state
         result = dataclasses.replace(result, state=final_state,
@@ -426,15 +445,15 @@ def iterate(
 
 def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
                    config: IterationConfig, *,
-                   frac_fn: Optional[Callable[[Any], Any]] = None
-                   ) -> IterationResult:
+                   frac_fn: Optional[Callable[[Any], Any]] = None,
+                   handed_over: bool = False) -> IterationResult:
     # ``iterate.dispatch``: what the host does to get the fused program
     # running (probe, trace, lower, the compile-cache request, the
     # enqueue); it ends when the jitted call returns, not when the device
     # has finished.  ``fit.fetch``: the host blocked on the device.
     with tracer.span("iterate.dispatch", "fit"):
         final_state, outputs, num_epochs, trace = _dispatch_fused(
-            body, initial_state, provider, config, frac_fn)
+            body, initial_state, provider, config, frac_fn, handed_over)
     if num_epochs is None:
         return IterationResult(final_state, outputs, config.max_epochs, {})
     # on a process-spanning mesh the loop counter comes back as a
@@ -450,7 +469,8 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
 
 def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
                     config: IterationConfig,
-                    frac_fn: Optional[Callable[[Any], Any]]) -> tuple:
+                    frac_fn: Optional[Callable[[Any], Any]],
+                    handed_over: bool = False) -> tuple:
     """Build the fused program and enqueue it, nothing fetched:
     ``(final_state, outputs, num_epochs, epoch_trace)``, the last two
     ``None`` where no criteria ask for them."""
@@ -458,7 +478,7 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
         raise ValueError("fused mode requires device-resident (static) data")
     if config.max_epochs is None:
         raise ValueError("fused mode requires max_epochs")
-    if config.donate_state:
+    if config.donate_state and not handed_over:
         initial_state = _private_copy(initial_state)
     data = provider(0)
     max_epochs = config.max_epochs
@@ -542,8 +562,8 @@ def _iterate_hosted(body: BodyFn, initial_state, provider: _DataProvider,
                     listeners: Sequence[IterationListener],
                     per_round_lifecycle: bool, per_round_init,
                     checkpoint, resume: bool, *,
-                    frac_fn: Optional[Callable[[Any], Any]] = None
-                    ) -> IterationResult:
+                    frac_fn: Optional[Callable[[Any], Any]] = None,
+                    handed_over: bool = False) -> IterationResult:
     donating = (config.jit and config.donate_state
                 and not per_round_lifecycle)
     if config.jit:
@@ -601,7 +621,8 @@ def _iterate_hosted(body: BodyFn, initial_state, provider: _DataProvider,
         is not IterationListener.on_checkpoint_saved
         for lst in listeners)
 
-    state = _private_copy(initial_state) if donating else initial_state
+    state = (_private_copy(initial_state) if donating and not handed_over
+             else initial_state)
     start_epoch = 0
     resumed_terminated = False
     if manager is not None and resume:

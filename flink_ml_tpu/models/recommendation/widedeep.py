@@ -18,6 +18,7 @@ TPU-native design:
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -28,7 +29,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...api.stage import Estimator, Model
 from ...data.table import Table
-from ...iteration import IterationBodyResult, IterationConfig, iterate
+from ...iteration import (
+    HandedOver,
+    IterationBodyResult,
+    IterationConfig,
+    iterate,
+)
+from ...linalg import float32_rows
+from ...obs.trace import tracer
 from ...params.param import (
     BoolParam,
     FloatParam,
@@ -112,13 +120,54 @@ class WideDeepParams(HasLabelCol, HasPredictionCol, HasRawPredictionCol,
     def set_vocab_sizes(self, v):
         return self.set(WideDeepParams.VOCAB_SIZES, v)
 
+    def set_embedding_dim(self, v: int):
+        return self.set(WideDeepParams.EMBEDDING_DIM, v)
+
+    def set_hidden_units(self, v):
+        return self.set(WideDeepParams.HIDDEN_UNITS, v)
+
+    def set_learning_rate(self, v: float):
+        return self.set(WideDeepParams.LEARNING_RATE, v)
+
 
 def _field_offsets(vocab_sizes) -> np.ndarray:
     return np.concatenate([[0], np.cumsum(vocab_sizes)[:-1]]).astype(np.int32)
 
 
+#: half the width of the embedding table's uniform start: a standard
+#: deviation of 0.05
+_EMB_INIT_HALF_WIDTH = np.float32(0.05 * np.sqrt(3.0))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _init_tables(word, total_vocab: int, emb_dim: int):
+    bits = jax.random.bits(jax.random.key(word), (total_vocab, emb_dim),
+                           jnp.uint32)
+    steps = (bits >> 8).astype(jnp.int32) - (1 << 23)
+    emb = steps.astype(jnp.float32) * (_EMB_INIT_HALF_WIDTH / (1 << 23))
+    return jnp.zeros((total_vocab,), jnp.float32), emb
+
+
 def init_params(rng: np.random.Generator, d_dense: int, vocab_sizes,
                 emb_dim: int, hidden) -> Dict[str, Any]:
+    """THE start of a Wide&Deep fit, one rule for every size and every
+    caller (``fit``, ``fit_outofcore``, the step builders, the tests and
+    the benchmark's plain reference, which writes it out again):
+
+    - the towers, on the host, from ``rng`` layer by layer: ``w`` =
+      ``rng.normal(size=(fan_in, h)) * sqrt(2 / fan_in)`` as float32, ``b``
+      zeros; the wide part (``wide_dense``, ``wide_b``, ``wide_cat``) zeros;
+    - then ONE word from the same stream, ``rng.integers(0, 2**32)``,
+      keys the embedding table, which is drawn where it is used, on the
+      device: ``bits = jax.random.bits(jax.random.key(word), (total_vocab,
+      emb_dim), uint32)``, ``emb = ((bits >> 8) - 2**23) * (a / 2**23)``
+      with ``a = float32(0.05 * sqrt(3))``: uniform on ``[-a, a)``, standard
+      deviation 0.05.  An integer below 2**24 times one float32 constant
+      is a single correctly rounded product, so the table is the same
+      bit for bit on every backend, and no host copy of it exists.
+
+    The tables (``emb``, ``wide_cat``) come back as device arrays, the
+    rest as host arrays."""
     total_vocab = int(np.sum(vocab_sizes))
     n_fields = len(vocab_sizes)
     deep_in = d_dense + n_fields * emb_dim
@@ -131,12 +180,13 @@ def init_params(rng: np.random.Generator, d_dense: int, vocab_sizes,
             "b": np.zeros((h,), np.float32),
         })
         fan_in = h
+    word = np.uint32(rng.integers(0, 1 << 32))
+    wide_cat, emb = _init_tables(word, total_vocab, int(emb_dim))
     return {
-        "wide_cat": np.zeros((total_vocab,), np.float32),
+        "wide_cat": wide_cat,
         "wide_dense": np.zeros((d_dense,), np.float32),
         "wide_b": np.zeros((), np.float32),
-        "emb": (rng.normal(size=(total_vocab, emb_dim)) * 0.05
-                ).astype(np.float32),
+        "emb": emb,
         "mlp": layers,
     }
 
@@ -206,62 +256,86 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
 
     def fit(self, *inputs) -> "WideDeepModel":
         (table,) = inputs
+        with tracer.fit_span(type(self).__name__):
+            return self._fit(table)
+
+    def _fit(self, table: Table) -> "WideDeepModel":
+        """``fit`` under its root span.  The phase spans (``fit.gather``,
+        ``fit.arrange`` with its parts ``.layout``, ``.route`` and
+        ``.params``, ``fit.upload``, then ``iterate.dispatch`` inside
+        ``iterate``, ``fit.fetch``) follow each other without a gap and
+        add no fence: each covers what the host does in it.
+
+        The tables and their optimizer state are made on the device
+        (:func:`init_params`) and handed over to the loop, which donates
+        them as they are: at a vocabulary that fills the chip there is no
+        room for a second copy, and no host copy exists on the way in."""
         vocab_sizes = self.get_vocab_sizes()
         if vocab_sizes is None:
             raise ValueError("WideDeep requires vocabSizes to be set")
         mesh = default_mesh()
         n_dev = int(mesh.shape["data"])
 
-        dense = np.asarray(table[self.DENSE_FEATURES_COL],
-                           np.float32)
-        cat = np.asarray(table[self.CAT_FEATURES_COL], np.int32)
-        labels = np.asarray(table[self.get_label_col()], np.float32)
-        cat = _validate_cat_ids(cat, vocab_sizes)
-
-        n = dense.shape[0]
-        steps, batch, perm = plan_epoch_layout(
-            n, self.get_global_batch_size() or DEFAULT_GLOBAL_BATCH, n_dev,
-            self.get_seed())
-
-        def layout(arr):
-            return prepare_epoch_tensor(arr, perm, steps, batch)
-
-        mask = layout(np.ones((n,), np.float32))
-        X = layout(dense)
-        C = layout(cat)
-        y = layout(labels)
+        with tracer.span("fit.gather", "fit"):
+            # a column that already has the type is passed on as it is
+            dense = float32_rows(table[self.DENSE_FEATURES_COL])
+            cat = np.asarray(table[self.CAT_FEATURES_COL], np.int32)
+            labels = np.asarray(table[self.get_label_col()], np.float32)
+            cat = _validate_cat_ids(cat, vocab_sizes)
 
         lazy = bool(self.LAZY_EMB_OPT)
         routed_mode = self.get(WideDeepParams.ROUTED_EMB_GRAD)
         route = None
-        if routed_mode == "on" or (routed_mode == "auto" and not lazy):
-            from ...ops.emb_grad import emb_grad_route
+        with tracer.span("fit.arrange", "fit"):
+            with tracer.span("fit.arrange.layout", "fit"):
+                n = dense.shape[0]
+                steps, batch, perm = plan_epoch_layout(
+                    n, self.get_global_batch_size() or DEFAULT_GLOBAL_BATCH,
+                    n_dev, self.get_seed())
 
-            # the epoch tensor C is replayed every epoch, so the
-            # slot->row sort is static — built once here, host-side
-            # (device=False: replicate() below does the one device_put;
-            # placement="auto": gather until the inverse map outgrows
-            # its budget at large vocab x many steps, then scatter)
-            route = emb_grad_route(C, int(np.sum(vocab_sizes)),
-                                   device=False, placement="auto")
+                def layout(arr):
+                    return prepare_epoch_tensor(arr, perm, steps, batch)
 
-        bsh = NamedSharding(mesh, P(None, "data"))
-        X = jax.device_put(X, NamedSharding(mesh, P(None, "data", None)))
-        C = jax.device_put(C, NamedSharding(mesh, P(None, "data", None)))
-        y, mask = jax.device_put(y, bsh), jax.device_put(mask, bsh)
-        route_data = ()
-        if route is not None:
-            route_data = tuple(replicate(a, mesh)
-                               for a in route.stacked_arrays())
+                mask = layout(np.ones((n,), np.float32))
+                X = layout(dense)
+                C = layout(cat)
+                y = layout(labels)
+            if routed_mode == "on" or (routed_mode == "auto" and not lazy):
+                from ...ops.emb_grad import emb_grad_route
 
-        rng = np.random.default_rng(self.get_seed() + 1)  # init-draw stream
-        params = replicate(
-            init_params(rng, dense.shape[1], vocab_sizes,
-                        self.EMBEDDING_DIM,
-                        self.HIDDEN_UNITS), mesh)
-        step_fn, opt_state = _make_train_ops(
-            params, self.LEARNING_RATE, lazy, route=route)
-        opt_state = replicate(opt_state, mesh)
+                # the epoch tensor C is replayed every epoch, so the
+                # slot->row sort is static — built once here, host-side
+                # (device=False: replicate() below does the one device_put;
+                # placement="auto": gather until the inverse map outgrows
+                # its budget at large vocab x many steps, then scatter)
+                with tracer.span("fit.arrange.route", "fit") as span:
+                    route = emb_grad_route(C, int(np.sum(vocab_sizes)),
+                                           device=False, placement="auto")
+                    span.note(
+                        placement=route.placement,
+                        fold_passes=route.fold_passes,
+                        unique_max=int(route.unique_per_step.max()),
+                        unique_mean=float(route.unique_per_step.mean()),
+                        route_bytes=sum(int(a.nbytes)
+                                        for a in route.stacked_arrays()))
+            with tracer.span("fit.arrange.params", "fit"):
+                rng = np.random.default_rng(self.get_seed() + 1)  # init draws
+                params = replicate(
+                    init_params(rng, dense.shape[1], vocab_sizes,
+                                self.EMBEDDING_DIM, self.HIDDEN_UNITS), mesh)
+                step_fn, opt_state = _make_train_ops(
+                    params, self.LEARNING_RATE, lazy, route=route)
+                opt_state = replicate(opt_state, mesh)
+
+        with tracer.span("fit.upload", "fit"):
+            bsh = NamedSharding(mesh, P(None, "data"))
+            X = jax.device_put(X, NamedSharding(mesh, P(None, "data", None)))
+            C = jax.device_put(C, NamedSharding(mesh, P(None, "data", None)))
+            y, mask = jax.device_put(y, bsh), jax.device_put(mask, bsh)
+            route_data = ()
+            if route is not None:
+                route_data = tuple(replicate(a, mesh)
+                                   for a in route.stacked_arrays())
 
         def epoch_body(state, epoch, data):
             Xd, Cd, yd, md = data[:4]
@@ -282,8 +356,10 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
             return IterationBodyResult((params, opt_state, loss_log))
 
         max_epochs = self.get_max_iter()
-        init_state = (params, opt_state,
-                      jnp.full((max_epochs,), jnp.nan, jnp.float32))
+        init_state = HandedOver((
+            params, opt_state,
+            jnp.full((max_epochs,), jnp.nan, jnp.float32)))
+        del params, opt_state
         result = iterate(epoch_body, init_state, (X, C, y, mask) + route_data,
                          max_epochs=max_epochs,
                          config=IterationConfig(mode="fused"))
@@ -291,9 +367,11 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
 
         model = WideDeepModel()
         model.copy_params_from(self)
-        model._params = jax.device_get(fitted)
+        with tracer.span("fit.fetch", "fit"):
+            model._params = jax.device_get(fitted)
+            model._loss_log = list(np.asarray(jax.device_get(loss_buf)))
         model._vocab_sizes = tuple(int(v) for v in vocab_sizes)
-        model._loss_log = list(np.asarray(jax.device_get(loss_buf)))
+        model.route_placement = None if route is None else route.placement
         return model
 
     def fit_outofcore(self, make_reader, *, mesh=None,
@@ -633,6 +711,10 @@ class WideDeepModel(WideDeepParams, Model):
         self._params: Optional[Dict[str, Any]] = None
         self._vocab_sizes: Optional[Tuple[int, ...]] = None
         self._loss_log: List[float] = []
+        #: how the fit's static route placed the table gradients
+        #: (``ops/emb_grad.py``: "gather" or "scatter"); None where the
+        #: fit took no route, or the model was not fitted here
+        self.route_placement: Optional[str] = None
 
     @property
     def loss_log(self) -> List[float]:
@@ -714,35 +796,64 @@ class WideDeepModel(WideDeepParams, Model):
                               (scores > 0.5).astype(np.int64))
         return [out]
 
+    # -- model data ---------------------------------------------------------
+    def _flat_arrays(self) -> Dict[str, np.ndarray]:
+        flat = {k: np.asarray(self._params[k])
+                for k in ("emb", "wide_cat", "wide_dense", "wide_b")}
+        for i, layer in enumerate(self._params["mlp"]):
+            flat[f"mlp_{i}_w"] = np.asarray(layer["w"])
+            flat[f"mlp_{i}_b"] = np.asarray(layer["b"])
+        return flat
+
+    def _set_flat_arrays(self, flat, vocab_sizes) -> None:
+        vocab_sizes = tuple(int(v) for v in vocab_sizes)
+        n_layers = sum(1 for k in flat if k.startswith("mlp_")
+                       and k.endswith("_w"))
+        params = {k: np.asarray(flat[k], np.float32)
+                  for k in ("emb", "wide_cat", "wide_dense", "wide_b")}
+        params["mlp"] = [{"w": np.asarray(flat[f"mlp_{i}_w"], np.float32),
+                          "b": np.asarray(flat[f"mlp_{i}_b"], np.float32)}
+                         for i in range(n_layers)]
+        if params["emb"].shape[0] != sum(vocab_sizes) \
+                or params["wide_cat"].shape != params["emb"].shape[:1]:
+            raise ValueError(
+                f"model data holds {params['emb'].shape[0]} embedding and "
+                f"{params['wide_cat'].shape[0]} wide rows, vocabSizes sum "
+                f"to {sum(vocab_sizes)}")
+        self._params = params
+        self._vocab_sizes = vocab_sizes
+
+    def set_model_data(self, *inputs) -> "WideDeepModel":
+        """One row: ``emb``, ``wide_cat``, ``wide_dense``, ``wide_b`` and
+        the towers' ``mlp_<i>_w`` / ``mlp_<i>_b`` by layer.  The
+        vocabulary is the model's ``vocabSizes`` param."""
+        (table,) = inputs
+        vocab_sizes = self.get_vocab_sizes()
+        if vocab_sizes is None:
+            raise ValueError("WideDeepModel requires vocabSizes to be set")
+        self._set_flat_arrays(
+            {name: table[name][0] for name in table.column_names},
+            vocab_sizes)
+        return self
+
+    def get_model_data(self) -> List[Table]:
+        self._require_model()
+        return [Table({name: arr[None]
+                       for name, arr in self._flat_arrays().items()})]
+
     # -- persistence --------------------------------------------------------
     def save(self, path: str) -> None:
         self._require_model()
         persist.save_metadata(
             self, path, {"vocabSizes": list(self._vocab_sizes)})
-        flat = {"wide_cat": self._params["wide_cat"],
-                "wide_dense": self._params["wide_dense"],
-                "wide_b": self._params["wide_b"],
-                "emb": self._params["emb"]}
-        for i, layer in enumerate(self._params["mlp"]):
-            flat[f"mlp_{i}_w"] = layer["w"]
-            flat[f"mlp_{i}_b"] = layer["b"]
-        persist.save_model_arrays(path, "model", flat)
+        persist.save_model_arrays(path, "model", self._flat_arrays())
 
     @classmethod
     def load(cls, path: str) -> "WideDeepModel":
         model = persist.load_stage_param(path)
         meta = persist.load_metadata(path)
-        data = persist.load_model_arrays(path, "model")
-        n_layers = sum(1 for k in data if k.endswith("_w"))
-        model._params = {
-            "wide_cat": data["wide_cat"],
-            "wide_dense": data["wide_dense"],
-            "wide_b": data["wide_b"],
-            "emb": data["emb"],
-            "mlp": [{"w": data[f"mlp_{i}_w"], "b": data[f"mlp_{i}_b"]}
-                    for i in range(n_layers)],
-        }
-        model._vocab_sizes = tuple(meta["vocabSizes"])
+        model._set_flat_arrays(persist.load_model_arrays(path, "model"),
+                               meta["vocabSizes"])
         return model
 
 
@@ -759,12 +870,19 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
     reference oracle semantics).
 
     ``route`` (an ``ops.emb_grad.EmbGradRoute``, dense-Adam only): the
-    returned step takes four extra per-step route arrays
-    (``order, sorted_ids, out_pos, out_ids`` — one step's slice) and
-    computes the embedding/wide-table gradients with the statically-
-    routed scatter instead of autodiff's random-RMW scatter-add; all
-    other gradients and the Adam update are identical.  See the
-    ``routedEmbeddingGrad`` param doc.
+    returned step takes the route's per-step arrays after the batch, one
+    step's slice of ``route.stacked_arrays()`` — three under the
+    ``gather`` placement (``order, sorted_ids, pos_map``), four under
+    ``scatter`` (``order, sorted_ids, out_pos, out_ids``) — and computes
+    the embedding/wide-table gradients with the statically-routed
+    placement instead of autodiff's random-RMW scatter-add; all other
+    gradients and the Adam update are identical.  See the
+    ``routedEmbeddingGrad`` param doc.  The step's device operations
+    carry ``jax.named_scope`` s by what they are for: ``widedeep.lookup``
+    (the row gathers), ``widedeep.towers`` (forward and backward of the
+    wide sum and the deep tower), ``widedeep.table_grad`` (permutation
+    gather, fold and placement, for both tables) and
+    ``widedeep.optimizer`` (Adam over every parameter).
 
     ``lazy=True`` (LazyAdam, ``lazyEmbeddingOptimizer``): dense Adam
     touches every row of the ``(total_vocab, emb_dim)`` embedding and
@@ -821,26 +939,31 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
         def batch_step(params, opt_state, dense, cat_ids, labels, mask,
                        *route_arrays):
             _, rest = split(params)
-            emb_rows = params["emb"][cat_ids]
-            wide_rows = params["wide_cat"][cat_ids]
+            with jax.named_scope("widedeep.lookup"):
+                emb_rows = params["emb"][cat_ids]
+                wide_rows = params["wide_cat"][cat_ids]
 
             def loss_rows(rest, emb_rows, wide_rows):
                 return logistic_loss(
                     forward_from_rows(rest, dense, wide_rows, emb_rows),
                     labels, mask)
 
-            loss, (g_rest, g_emb, g_wide) = jax.value_and_grad(
-                loss_rows, argnums=(0, 1, 2))(rest, emb_rows, wide_rows)
+            with jax.named_scope("widedeep.towers"):
+                loss, (g_rest, g_emb, g_wide) = jax.value_and_grad(
+                    loss_rows, argnums=(0, 1, 2))(rest, emb_rows, wide_rows)
             emb_dim = emb_rows.shape[-1]
-            grads = {
-                **g_rest,
-                "emb": route_apply(g_emb.reshape(-1, emb_dim),
-                                   *route_arrays),
-                "wide_cat": route_apply(g_wide.reshape(-1),
-                                        *route_arrays),
-            }
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss
+            with jax.named_scope("widedeep.table_grad"):
+                grads = {
+                    **g_rest,
+                    "emb": route_apply(g_emb.reshape(-1, emb_dim),
+                                       *route_arrays),
+                    "wide_cat": route_apply(g_wide.reshape(-1),
+                                            *route_arrays),
+                }
+            with jax.named_scope("widedeep.optimizer"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            return params, opt_state, loss
 
         return batch_step, opt.init(params)
     if not lazy:
@@ -907,8 +1030,9 @@ def build_reference_train_step(d_dense: int, vocab_sizes, emb_dim: int,
     it); asserted by tests/test_widedeep.py and __graft_entry__'s multichip
     dryrun.  ``lazy_embeddings`` swaps in the LazyAdam table update;
     ``route`` swaps in the statically-routed table gradients (see
-    :func:`_make_train_ops` — the step then takes four extra per-step
-    route arrays)."""
+    :func:`_make_train_ops` — the step then takes the route's per-step
+    arrays as well).  The parameters are :func:`init_params`' rule on the
+    stream ``default_rng(0)``."""
     params = jax.tree_util.tree_map(
         jnp.asarray,
         init_params(np.random.default_rng(0), d_dense, vocab_sizes, emb_dim,

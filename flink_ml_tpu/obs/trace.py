@@ -81,6 +81,10 @@ __all__ = ["Span", "SpanTracer", "tracer", "CORRELATION_KEYS"]
 #: cross-tenant interleaving on the shared device.  ``fit`` = one
 #: ``fit()`` call (a process-unique integer from
 #: :meth:`SpanTracer.fit_span`, shared by every span opened inside it).
+#: Beside its ids a span may carry, through ``note()``, counters of the
+#: work it did, named where they are noted (``fit.arrange.route`` of
+#: ``WideDeep.fit``: ``placement``, ``fold_passes``, ``unique_max``,
+#: ``unique_mean``, ``route_bytes``); nobody joins spans on those.
 CORRELATION_KEYS = ("request_id", "generation", "step", "window",
                     "epoch", "op", "bucket", "tenant", "fit")
 

@@ -100,6 +100,9 @@ class EmbGradRoute:
     out_ids: Optional[jnp.ndarray] = None  # (steps, U) i32: unique ids
                              #   per run, ascending; pad = num_rows +
                              #   rank (unique, out of range -> dropped)
+    unique_per_step: Optional[np.ndarray] = None  # (steps,) host i64:
+                             #   table rows each step touches (what the
+                             #   fit's route span reports)
 
     @property
     def steps(self) -> int:
@@ -209,7 +212,8 @@ def emb_grad_route(cat_steps: np.ndarray, num_rows: int,
     # the u_cap contract holds for BOTH placements (a caller-forced cap
     # must never be silently ignored); gather just has no U-shaped
     # arrays to size with it
-    need_u = max(p.size for p, _ in starts_list)
+    unique_per_step = np.asarray([p.size for p, _ in starts_list], np.int64)
+    need_u = int(unique_per_step.max())
     if u_cap is not None and need_u > u_cap:
         raise ValueError(
             f"route needs {need_u} unique ids in some step > forced "
@@ -222,7 +226,8 @@ def emb_grad_route(cat_steps: np.ndarray, num_rows: int,
         return EmbGradRoute(
             order=wrap(orders), sorted_ids=wrap(sids),
             pos_map=wrap(pos_map), fold_passes=fold_passes,
-            num_rows=num_rows, placement="gather")
+            num_rows=num_rows, placement="gather",
+            unique_per_step=unique_per_step)
     U = u_cap if u_cap is not None else need_u
     out_pos = np.full((steps, U), S, np.int32)
     # pad ids: ascending out-of-range sentinels — unique (the scatter's
@@ -235,7 +240,8 @@ def emb_grad_route(cat_steps: np.ndarray, num_rows: int,
     return EmbGradRoute(
         order=wrap(orders), sorted_ids=wrap(sids),
         out_pos=wrap(out_pos), out_ids=wrap(out_ids),
-        fold_passes=fold_passes, num_rows=num_rows, placement="scatter")
+        fold_passes=fold_passes, num_rows=num_rows, placement="scatter",
+        unique_per_step=unique_per_step)
 
 
 def _folded_ext(g_flat, order, sorted_ids, fold_passes):

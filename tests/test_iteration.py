@@ -203,6 +203,38 @@ def test_donation_preserves_caller_state():
     np.testing.assert_array_equal(np.asarray(r1.state), np.asarray(r2.state))
 
 
+@pytest.mark.parametrize("mode", ["fused", "hosted"])
+def test_handed_over_state_is_donated_as_it_is(mode):
+    # HandedOver: the caller gives its buffers up, so the loop makes no
+    # private copy; on the CPU a donation is not carried out, so what can
+    # be shown here is that the loop works on the caller's own arrays
+    # (a copy would be another object) and gives the same result.
+    from flink_ml_tpu.iteration import HandedOver
+    from flink_ml_tpu.iteration import core
+
+    copied = []
+    sound_copy = core._private_copy
+
+    def spy(state):
+        copied.append(state)
+        return sound_copy(state)
+
+    core._private_copy = spy
+    try:
+        kept = iterate(lambda x, e: x + 1, jnp.arange(4, dtype=jnp.float32),
+                       max_epochs=3, config=IterationConfig(mode=mode))
+        assert len(copied) == 1
+        given = iterate(lambda x, e: x + 1,
+                        HandedOver(jnp.arange(4, dtype=jnp.float32)),
+                        max_epochs=3, config=IterationConfig(mode=mode))
+        assert len(copied) == 1
+    finally:
+        core._private_copy = sound_copy
+    np.testing.assert_array_equal(np.asarray(given.state),
+                                  np.asarray(kept.state))
+    assert given.num_epochs == kept.num_epochs == 3
+
+
 def test_auto_mode_with_criteria_keeps_all_outputs():
     # auto must not pick fused (last-output-only) when a vote exists
     def body(x, epoch):

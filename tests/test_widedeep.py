@@ -454,3 +454,197 @@ def test_routed_fit_exact_with_padding_rows():
         np.testing.assert_allclose(np.asarray(m_r._params[k]),
                                    np.asarray(m_d._params[k]),
                                    rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------------- the plain reference, the one start
+
+
+def _benchmark_module(kind, name):
+    """``benchmarks/<kind>/<name>.py``: the benchmark's plain reference
+    and generator import nothing of the program."""
+    import importlib
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return importlib.import_module(f"{kind}.{name}")
+
+
+# skewed ids (Zipf 1.05 by rank within a field, as the benchmark's cell
+# draws them), 5 steps of 128 rows the last of which is partial
+_REF_CONFIG = {
+    "rows": 600, "n_dense": 5, "vocab_sizes": [3, 40, 700, 2000],
+    "embedding_dim": 8, "hidden_units": [32, 16],
+    "zipf_exponent": 1.05, "label_bias": -1.0,
+    "label_dense_coefficients": [0.3, -0.3, 0.3, -0.3, 0.3],
+    "label_fields": [[0, 0.5], [1, 0.5]],
+    "reference_params": {"batch": 128, "epochs": 4, "learning_rate": 0.001,
+                         "matmul_precision": "default",
+                         "control_state_dtype": "bfloat16"},
+}
+_REF_SEED = 11
+# float32 on both sides, summed in another order (the routed fold against
+# XLA's scatter-add, eight devices against one), 20 Adam steps.  Each gap
+# is taken over the distance the training covered (1 = a state left
+# unchanged).  Read over seeds 11..16 on the CPU: loss_gap 5.6e-8..9.0e-8,
+# table_err 2.2e-7..9.7e-6, tower_err 4.1e-7..1.6e-6; the bfloat16 control
+# 4.7e-3..1.5e-2, infinity (its idle rows are the start rounded, not the
+# start) and 0.49..0.66.  Each limit is twenty or more times a sound
+# reading and far under the control.  (At the benchmark's 160 steps on the
+# chip the same gaps read 0.04..0.2: Adam's trajectory parts with the
+# steps, PERF.md section 2.)
+_REF_LIMITS = {"loss_gap": 2e-5, "table_err": 2e-4, "tower_err": 1e-4}
+
+
+def _reference_fixture():
+    reference = _benchmark_module("references", "widedeep_adam")
+    data = _benchmark_module("generators", "criteo_fields").generate(
+        _REF_CONFIG, _REF_SEED)
+    ref = _REF_CONFIG["reference_params"]
+    est = (WideDeep().set_vocab_sizes(_REF_CONFIG["vocab_sizes"])
+           .set_embedding_dim(_REF_CONFIG["embedding_dim"])
+           .set_hidden_units(_REF_CONFIG["hidden_units"])
+           .set_learning_rate(ref["learning_rate"])
+           .set_global_batch_size(ref["batch"])
+           .set_max_iter(ref["epochs"]).set_seed(_REF_SEED))
+    return reference, data, est
+
+
+def _answer(model):
+    (table,) = model.get_model_data()
+    answer = {name: table[name][0] for name in table.column_names}
+    answer["loss_log"] = np.asarray(model.loss_log, np.float64)
+    return answer
+
+
+def test_fit_matches_the_plain_reference_and_the_bf16_control_does_not():
+    """``WideDeep.fit`` against ``benchmarks/references/widedeep_adam.py``
+    (plain jax.numpy, Adam written out, the epoch order and the start
+    drawn by the reference itself): the loss log, every row of both
+    tables, every tower layer."""
+    reference, data, est = _reference_fixture()
+    model = est.fit(Table(data))
+    assert model.route_placement == "gather"
+    numbers = reference.compare(_REF_CONFIG, data, _answer(model), _REF_SEED)
+    assert {k: numbers[k] <= _REF_LIMITS[k] for k in _REF_LIMITS} == {
+        k: True for k in _REF_LIMITS}, numbers
+    # a row no batch touched is its start, bit for bit (table_err reads
+    # infinity otherwise); the fixture has such rows
+    ids = data["catFeatures"] + np.concatenate(
+        [[0], np.cumsum(_REF_CONFIG["vocab_sizes"])[:-1]])
+    untouched = np.setdiff1d(np.arange(sum(_REF_CONFIG["vocab_sizes"])), ids)
+    assert untouched.size > 1000
+    control = reference.control(_REF_CONFIG, data, _REF_SEED)
+    numbers = reference.compare(_REF_CONFIG, data, control, _REF_SEED)
+    assert all(numbers[k] > _REF_LIMITS[k] for k in _REF_LIMITS), numbers
+
+
+def test_fit_past_the_route_budget_scatters_and_equals_the_gather_fit(
+        monkeypatch):
+    """``placement="auto"`` leaves the scatter-free gather once its inverse
+    map outgrows the budget; both placements put the same folded sums in
+    the same rows, so the two fits differ by no more than two compiled
+    programs round (1e-8 on the CPU; every number of the answer is
+    held to 1e-5 of its size)."""
+    from flink_ml_tpu.ops import emb_grad
+
+    _, data, est = _reference_fixture()
+    table = Table(data)
+    gather = est.fit(table)
+    monkeypatch.setattr(emb_grad, "_POS_MAP_BUDGET_BYTES", 0)
+    scatter = est.fit(table)
+    assert (gather.route_placement, scatter.route_placement) == (
+        "gather", "scatter")
+    for name, value in _answer(gather).items():
+        np.testing.assert_allclose(_answer(scatter)[name], value,
+                                   rtol=1e-5, atol=1e-7, err_msg=name)
+
+
+def test_model_data_round_trip_and_save_load(tmp_path):
+    t = _ctr_table(n=128)
+    model = (WideDeep().set_vocab_sizes([10, 7]).set_hidden_units([16, 8])
+             .set_max_iter(3).fit(t))
+    (data,) = model.get_model_data()
+    assert data.num_rows == 1 and data.column_names == [
+        "emb", "wide_cat", "wide_dense", "wide_b", "mlp_0_w", "mlp_0_b",
+        "mlp_1_w", "mlp_1_b", "mlp_2_w", "mlp_2_b"]
+    assert data["emb"].shape == (1, 17, 8)
+    fresh = WideDeepModel()
+    fresh.copy_params_from(model)
+    fresh.set_model_data(data)
+    expected = model.transform(t)[0]["rawPrediction"]
+    np.testing.assert_array_equal(fresh.transform(t)[0]["rawPrediction"],
+                                  expected)
+    # save / load go through the same columns
+    path = str(tmp_path / "wd")
+    fresh.save(path)
+    loaded = WideDeepModel.load(path)
+    (again,) = loaded.get_model_data()
+    for name in data.column_names:
+        np.testing.assert_array_equal(again[name], data[name])
+    np.testing.assert_array_equal(loaded.transform(t)[0]["rawPrediction"],
+                                  expected)
+    # a table for another vocabulary is refused
+    with pytest.raises(ValueError, match="vocabSizes"):
+        WideDeepModel().set_vocab_sizes([10, 8]).set_model_data(data)
+    with pytest.raises(ValueError, match="vocabSizes"):
+        WideDeepModel().set_model_data(data)
+
+
+@pytest.mark.parametrize("through", ["fit", "fit_outofcore",
+                                     "build_reference_train_step",
+                                     "plain_reference"])
+def test_the_start_is_one_rule(through):
+    """``init_params`` on the stream ``default_rng(seed + 1)`` is THE
+    start: a row of the embedding table that no batch touches comes back
+    from ``fit`` and ``fit_outofcore`` as that rule drew it (dense Adam
+    leaves a row with a zero gradient where it is), the step builders
+    start from it, and the benchmark's plain reference, which writes the
+    rule out again, draws the same bits."""
+    from flink_ml_tpu.models.recommendation.widedeep import (
+        build_reference_train_step, init_params)
+
+    vocab, d_dense, emb_dim, hidden, seed = [50, 30], 4, 8, (16, 8), 5
+    start = init_params(np.random.default_rng(seed + 1), d_dense, vocab,
+                        emb_dim, hidden)
+    emb0 = np.asarray(start["emb"])
+    assert emb0.dtype == np.float32 and abs(emb0.std() - 0.05) < 0.005
+    assert not isinstance(start["emb"], np.ndarray)   # made on the device
+    if through == "plain_reference":
+        ours = _benchmark_module("references", "widedeep_adam").initial_params(
+            seed, d_dense, vocab, emb_dim, hidden)
+        np.testing.assert_array_equal(np.asarray(ours["emb"]), emb0)
+        for a, b in zip(ours["mlp"], start["mlp"], strict=True):
+            np.testing.assert_array_equal(np.asarray(a["w"]), b["w"])
+        return
+    if through == "build_reference_train_step":
+        _, params, _ = build_reference_train_step(d_dense, vocab, emb_dim,
+                                                  hidden)
+        np.testing.assert_array_equal(
+            np.asarray(params["emb"]),
+            np.asarray(init_params(np.random.default_rng(0), d_dense, vocab,
+                                   emb_dim, hidden)["emb"]))
+        return
+    # ids under 10 only: rows 10.. of field A and 60.. of field B idle
+    rng = np.random.default_rng(0)
+    n = 96
+    cols = {"denseFeatures": rng.normal(size=(n, d_dense)).astype(np.float32),
+            "catFeatures": rng.integers(0, 10, size=(n, 2)).astype(np.int32),
+            "label": rng.integers(0, 2, size=n).astype(np.float32)}
+    est = (WideDeep().set_vocab_sizes(vocab).set_embedding_dim(emb_dim)
+           .set_hidden_units(list(hidden)).set_max_iter(2).set_seed(seed)
+           .set_global_batch_size(32))
+    if through == "fit":
+        model = est.fit(Table(cols))
+    else:
+        def reader():
+            for lo in range(0, n, 32):
+                yield {k: v[lo:lo + 32] for k, v in cols.items()}
+        model = est.fit_outofcore(reader)
+    emb = np.asarray(model._params["emb"])
+    idle = np.r_[10:50, 60:80]
+    np.testing.assert_array_equal(emb[idle], emb0[idle])
+    assert not np.array_equal(emb[:10], emb0[:10])

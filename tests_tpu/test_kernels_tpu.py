@@ -345,6 +345,41 @@ def test_routed_table_grad_both_placements_on_device(tpu, rng):
                                    rtol=1e-4, atol=1e-4, err_msg=placement)
 
 
+def test_routed_scatter_placement_under_heavy_skew_on_device(tpu, rng):
+    """The scatter placement as ``widedeep_criteo.fit`` runs it (PR 29:
+    past the inverse map's budget ``auto`` takes it, and it is the fit's
+    primary path there): a table of 2^22 rows, 2^16 slots a step of
+    which one id fills more than half (15 fold passes), ids of two steps
+    so that the per-step slices differ.  Both tables' payloads, against
+    a float64 sum on the host."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.ops.emb_grad import emb_grad_route
+
+    vocab, slots, emb = 1 << 22, 1 << 16, 16
+    cat = rng.integers(0, vocab, size=(2, slots // 4, 4)).astype(np.int64)
+    cat[:, :, 0] = np.where(rng.random((2, slots // 4)) < 0.6, 3,
+                            cat[:, :, 0])
+    cat[:, ::7, 1] = vocab - 1
+    route = emb_grad_route(cat, vocab, placement="scatter")
+    assert route.fold_passes >= 13 and route.placement == "scatter"
+    for step in (0, 1):
+        for shape in ((slots, emb), (slots,)):
+            g = rng.normal(size=shape).astype(np.float32)
+            want = np.zeros((vocab,) + shape[1:], np.float64)
+            np.add.at(want, cat[step].reshape(-1), g)
+            got = np.asarray(route.apply(
+                jnp.asarray(g), *(jnp.asarray(np.asarray(a))
+                                  for a in route.step_slice(step))))
+            assert got.shape == want.shape
+            touched = np.unique(cat[step])
+            np.testing.assert_allclose(got[touched], want[touched],
+                                       rtol=1e-5, atol=2e-4)
+            idle = np.ones(vocab, bool)
+            idle[touched] = False
+            assert not got[idle].any()
+
+
 def test_als_sorted_neq_on_device(tpu, rng):
     """Sorted MXU normal equations vs the scatter form on the chip
     (dynamic-slice band accumulation + one-hot dot_general under
