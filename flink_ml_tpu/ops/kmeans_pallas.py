@@ -2,25 +2,82 @@
 
 The XLA expansion of one Lloyd's iteration (pairwise matmul -> argmin ->
 one_hot -> einsum, ``models/clustering/kmeans.py``) materialises two
-``(n, k)`` intermediates in HBM (scores and the one-hot matrix): for the
-headline shape (n=1M, d=64, k=256, f32) that is ~3 GB of HBM traffic per
-iteration, which makes the step memory-bound (~3.3 ms/iter, ~300 iter/s on
-one v5e chip).  These kernels tile the points over a sequential TPU grid and
-keep the score/one-hot tiles in VMEM, so HBM traffic drops to reading the
-points once (~256 MB) plus the (k, d) outputs:
+``(n, k)`` intermediates in HBM (scores and the one-hot matrix).  These
+kernels tile the points over a sequential TPU grid and keep the
+score/one-hot tiles in VMEM, so an iteration reads the points from HBM
+once and writes the ``(k, d)`` / ``(k,)`` results.
 
-    XLA fused path                          : ~300 iter/s   (3.3 ms/it)
-    kmeans_update_stats  tie_policy="split" : ~730 iter/s   (1.4 ms/it)
-    kmeans_update_stats  tie_policy="fast"  : ~1070 iter/s  (0.93 ms/it)
-    kmeans_update_stats  tie_policy="first" : r3 numbers above; "first"
-        (the r4 fit default) replaces "split"'s division with
-        where/min/compare passes — expected between the two, measured
-        on TPU by tests_tpu + bench each round.
+**The stats kernel's tiles are feature-major** (PR 30): the call takes
+``points.T``, shape ``(d, n)``, in blocks ``(d, block_n)``: ``d`` and ``k``
+lie on sublanes, the block's rows on lanes.
 
-(one v5e chip, r3/r4 rounds of 2026-07, before PR 1 and not re-measured
-since; 480-iteration fused scans so per-dispatch overhead is amortised;
-bf16 dots measure within noise of f32 — the MXU is not the bottleneck at
-d=64, the VPU passes over the (block_n, k) tile are.)
+- The chip keeps a narrow ``(n, d)`` array column-major
+  (``f32[n,d]{0,1:T(8,128)}``: ``d`` on sublanes in groups of 8, 128 rows a
+  lane tile), which IS ``(d, n)`` row-major: the transposition compiles
+  to a ``bitcast`` (d 20 and d 64, compiled for a described v5e), where
+  the row-major kernel before it made XLA copy the points into rows of
+  128 lanes, 512 B each: 10.24 GB reserved beside 20 M rows of d 20.
+- ``scores (k, bn) = -2 C (k, d) @ Xt (d, bn) + c2 (k, 1)``; the minimum
+  over the ``k`` axis, ``is_min``, the first-index rule and the one-hot are
+  sublane-axis work on ceil(k/8) vregs per 128 rows, every lane a row
+  (row-major, each of these was a ``(bn, k)`` tile with k of 128 lanes in
+  use: 8 vregs per 128 rows at k 10 where 2 hold the data);
+  ``sums (k, d) += onehot (k, bn) . Xt (d, bn)`` contracts both operands
+  over their last axis, the MXU's transposed-operand form, so no tile is
+  transposed in VMEM.
+- Both contractions are one default-precision MXU pass (operands rounded
+  to bfloat16, float32 accumulation) unless ``compute_dtype`` says
+  otherwise: the arithmetic of the row-major kernel, in another order of
+  accumulation over a block.
+
+Measured on one v5e, the kernel alone in a jitted scan that feeds the
+centroids back, best of five calls, ms an iteration (my chip runs, PR 30,
+``TPU v5 lite``; "row-major" is the kernel this one replaced, run from the
+parent commit in the same process on the same points):
+
+    n 20,021,248 = 611 x 32768, d 20, k 10 (HiBench's; seed 2147488001),
+    1.6 GB of points (1.92 GB as laid out: 20 features on 24 sublanes)
+      feature-major  first  block 32768   2.692   (595 GB/s of the 1.6 GB)
+                            block 16384   2.696
+                            block  8192   2.802
+                            block  4096   3.437
+                     fast   block 32768   2.674
+                     split  block 32768   2.679
+      row-major      first  block  8192 115.86    (its lane-padded copy of
+                                                   the points, once a call,
+                                                   shared by 5 iterations)
+    n 2^20, d 64, k 256 (chip_smoke.py's; seeds 2147488002 / 3000000303)
+      feature-major  first  block  8192   1.056 / 1.052
+                            block  4096   1.009 / 1.009
+                            block 16384   1.091 / 1.085
+                     fast   block  8192   0.943 / 0.938
+                     split  block  8192   1.043 / 1.036
+      row-major      first  block  8192   1.527 / 1.528
+                            block  4096   1.362 / 1.362
+                     fast   block  8192   0.883 / 0.879
+                     split  block  8192   1.219 / 1.227
+
+At k 256 both layouts give the MXU the same work (by their shapes, 512
+streamed rows per 128 points) and differ in the VPU/XLU passes around
+it: feature-major is 31% faster under
+``first`` (the fit's policy) and 15% under ``split``, 7% slower under
+``fast``, whose chain has no pass that a lane reduction made dear.  One
+kernel serves every shape.  Counts agree to the unit and sums to 2.3e-6
+relative between the layouts on the same points; against float64 sums on
+separated clusters (seed 3000000301) both are 5e-5 from the sums of the
+points rounded to bfloat16 and 10.9 from the sums of the points as they
+are: one bfloat16 MXU pass, as stated above.
+
+The VMEM model (:func:`_stats_tile_bytes`) was held against the compiler
+for a described v5e (no chip): over (d, k) in {(1, 1), (3, 2), (8, 4),
+(20, 10), (33, 65), (64, 256), (100, 100), (128, 16), (256, 256),
+(512, 8), (784, 256), (16, 2048), (64, 1024), (20, 1000)} the block it
+picks compiles under all three tie policies, and the first power of two
+the compiler refuses (16 MiB of scoped VMEM; looked for at nine of them)
+is two to eight times the pick; at d 20, k 10 it refuses 65536 at
+16.45 MiB.  At k 256, d 64 it
+still compiles 16384 (points twice 8 MiB, a float32 score tile 16 MiB),
+so it never holds a whole score-shaped tile: hence the half.
 
 Design notes:
 
@@ -32,7 +89,7 @@ Design notes:
   lands on the centroid nearest the origin and contributes nothing to
   ``sums``; the caller subtracts the padding count from that one cluster
   (:func:`pad_correction`) — an exact fix that saves one HBM read + one
-  (block_n, k) VPU pass over keeping a mask.  (The workset kernel below
+  (k, block_n) VPU pass over keeping a mask.  (The workset kernel below
   instead uses the MASKED contract: it needs the pad mask anyway to
   merge cached assignments, see :func:`kmeans_workset_update`.)
 - **tie_policy="fast"** assigns a point to *every* centroid at exactly the
@@ -40,11 +97,10 @@ Design notes:
   are measure-zero; the known benign case is duplicated centroids, which
   receive identical (double-counted) updates and therefore stay identical —
   the same fixed point Lloyd's has.  **"split"** divides tied points
-  fractionally among the minimisers (exact expected-assignment semantics)
-  at ~30% throughput cost.  **"first"** (the fit default since r4) keeps
-  the reference's exact first-index-argmin semantics: the smallest tied
-  column index via where/row-min/compare over an iota tile — no argmin
-  loop, no division.
+  fractionally among the minimisers (exact expected-assignment semantics).
+  **"first"** (the fit default since r4) keeps the reference's exact
+  first-index-argmin semantics: the smallest tied centroid index via
+  where/min/compare over an iota tile — no argmin loop, no division.
 - a true ``argmin`` inside a Mosaic kernel lowers to a slow
   index-tracking loop (~6 ms/it measured), so the fit kernels compute
   assignment one-hots directly (see the policies above) rather than
@@ -90,15 +146,24 @@ __all__ = [
 _VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom below the ~16 MB/core VMEM
 
 
+def _up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
 def _stats_tile_bytes(d: int, k: int, block_n: int) -> int:
-    """THE per-tile VMEM model of the stats kernels: one (block_n, k) f32
-    score tile + a (block_n, d) points tile + the (k, d)/(k,)
-    accumulators.  One score-sized tile is the right model: Mosaic
-    reuses the buffer across the compare/one-hot chain (empirically
-    block_n=8192, k=256, d=64 compiles and runs on v5e).  Every
-    supported()/pick_block_n variant in this module derives from this
-    ONE formula."""
-    return block_n * k * 4 + block_n * d * 4 + k * d * 4 + k * 4
+    """THE per-tile VMEM model of the stats kernel's feature-major
+    layout, padding counted: the points block ``(d, block_n)`` lies on
+    ceil(d/8)*8 sublanes and the pipeline holds two of it; HALF a
+    score-shaped ``(k, block_n)`` float32 tile on ceil(k/8)*8 sublanes
+    for the compare/one-hot chain (the compiler works through it by lane
+    columns and never holds a whole one: see the module docstring for
+    the probe that says so); and the resident ``(k, d)`` / ``(k, 1)``
+    blocks (centroids, sums, c2, counts), lane-padded to 128, two
+    buffers each.  Every supported()/pick_block_n variant of the stats
+    kernel derives from this ONE formula."""
+    d8, k8 = _up(d, 8), _up(k, 8)
+    return ((2 * d8 * 4 + k8 * 2) * block_n
+            + 4 * k8 * (_up(d, 128) + 128) * 4)
 
 
 def supported(d: int, k: int, block_n: int = 8192) -> bool:
@@ -107,39 +172,51 @@ def supported(d: int, k: int, block_n: int = 8192) -> bool:
     return _stats_tile_bytes(d, k, block_n) <= _VMEM_BUDGET
 
 
-def _pick_block(n: Optional[int], fits) -> Optional[int]:
-    """Largest power-of-two block (<= 8192, >= 128) satisfying ``fits``
-    and — when ``n`` is given — dividing ``n``; None if nothing works
-    (caller falls back to XLA)."""
-    bn = 8192
-    while bn >= 128:
+# Largest block each kernel is offered.  The stats kernel's blocks are
+# lane counts of a feature-major tile (about 256 B a row at d 20, k 10);
+# the workset kernel's are rows of a row-major one.
+_MAX_STATS_BLOCK = 65536
+_MAX_WORKSET_BLOCK = 8192
+
+
+def _blocks_down(largest: int) -> list:
+    """The power-of-two blocks ``largest``, ``largest / 2``, ..., 128."""
+    return [largest >> s for s in range(largest.bit_length() - 7)]
+
+
+def _pick_block(n: Optional[int], fits, largest: int) -> Optional[int]:
+    """Largest power-of-two block (<= ``largest``, >= 128) satisfying
+    ``fits`` and — when ``n`` is given — dividing ``n``; None if nothing
+    works (caller falls back to XLA)."""
+    for bn in _blocks_down(largest):
         if (n is None or n % bn == 0) and fits(bn):
             return bn
-        bn //= 2
     return None
 
 
 def pick_block_n(n: Optional[int], d: int, k: int) -> Optional[int]:
     """Largest viable stats-kernel block.  Pass ``n=None`` when the
     caller zero-pads to the block anyway (the estimator does)."""
-    return _pick_block(n, lambda bn: supported(d, k, bn))
+    return _pick_block(n, lambda bn: supported(d, k, bn), _MAX_STATS_BLOCK)
 
 
-def _viable_blocks(fits) -> list:
-    """Every power-of-two block (8192 down to 128) passing ``fits`` —
-    the candidate set the measured search ranks (the analytic descent
-    only ever took the largest)."""
-    return [bn for bn in (8192, 4096, 2048, 1024, 512, 256, 128)
-            if fits(bn)]
+def _viable_blocks(fits, largest: int) -> list:
+    """Every power-of-two block (``largest`` down to 128) passing
+    ``fits`` — the candidate set the measured search ranks (the analytic
+    descent only ever took the largest)."""
+    return [bn for bn in _blocks_down(largest) if fits(bn)]
 
 
 def _measured_block(op: str, d: int, k: int, candidates: list,
-                    runner_factory, *, analytic: int) -> int:
+                    runner_factory, *, analytic: int,
+                    layout: tuple = ()) -> int:
     """Resolve a block size by measurement through the registry
     autotuner (``kernels/autotune.py``): ``choose`` honors a recorded
-    decision for ``(op, ("block_n", d, k))`` without running anything;
-    a first encounter times every candidate on a synthetic probe of the
-    kernel's real entry point and persists the winner.  With autotuning
+    decision for ``(op, ("block_n", *layout, d, k))`` without running
+    anything; a first encounter times every candidate on a synthetic
+    probe of the kernel's real entry point and persists the winner.
+    ``layout`` tags the key of a kernel whose tiles changed, so that no
+    decision measured on the old ones is reused.  With autotuning
     disabled (no cache root) the analytic pick stands — exactly the
     pre-autotune behavior."""
     from ..kernels import autotune
@@ -147,7 +224,7 @@ def _measured_block(op: str, d: int, k: int, candidates: list,
     if len(candidates) == 1 or not autotune.enabled():
         return analytic
     choice, _ = autotune.choose(
-        op, ("block_n", d, k),
+        op, ("block_n", *layout, d, k),
         {str(bn): runner_factory(bn) for bn in candidates},
         kind="block", probe=f"synthetic n={max(candidates)} d={d} k={k}")
     return int(choice)
@@ -174,7 +251,8 @@ def pick_block_n_measured(d: int, k: int, *, interpret: bool = False,
     autotuning is disabled; returns None exactly when the analytic
     descent would (no viable block -> XLA fallback)."""
     cands = (candidates if candidates is not None
-             else _viable_blocks(lambda bn: supported(d, k, bn)))
+             else _viable_blocks(lambda bn: supported(d, k, bn),
+                                 _MAX_STATS_BLOCK))
     if not cands:
         return None
     # probe operands are lazy AND shared across candidates: a recorded
@@ -190,8 +268,10 @@ def pick_block_n_measured(d: int, k: int, *, interpret: bool = False,
                                        interpret=interpret)
         return thunk
 
+    # decisions recorded under ("block_n", d, k) were measured on the
+    # row-major kernel this one replaced
     return _measured_block("kmeans_update_stats", d, k, cands, runner,
-                           analytic=max(cands))
+                           analytic=max(cands), layout=("feature_major",))
 
 
 def pick_block_n_workset_measured(d: int, k: int, *,
@@ -203,7 +283,8 @@ def pick_block_n_workset_measured(d: int, k: int, *,
     kernels have different VPU/VMEM profiles, so one winner must never
     be assumed to transfer to the other)."""
     cands = (candidates if candidates is not None
-             else _viable_blocks(lambda bn: workset_supported(d, k, bn)))
+             else _viable_blocks(lambda bn: workset_supported(d, k, bn),
+                                 _MAX_WORKSET_BLOCK))
     if not cands:
         return None
     probe: list = []
@@ -226,7 +307,12 @@ def pick_block_n_workset_measured(d: int, k: int, *,
 
 
 def _stats_kernel(tie_policy: str, compute_dtype):
-    def kern(points_ref, cent_ref, c2_ref, sums_ref, counts_ref):
+    # contract both operands over their LAST axis (the block's rows, on
+    # lanes): the MXU's transposed-operand form, nothing is transposed in
+    # VMEM
+    over_rows = (((1,), (1,)), ((), ()))
+
+    def kern(xt_ref, cent_ref, c2_ref, sums_ref, counts_ref):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
@@ -234,32 +320,32 @@ def _stats_kernel(tie_policy: str, compute_dtype):
             sums_ref[:] = jnp.zeros_like(sums_ref)
             counts_ref[:] = jnp.zeros_like(counts_ref)
 
-        pts = points_ref[:]
-        scores = (-2.0 * jnp.dot(pts.astype(compute_dtype),
-                                 cent_ref[:].astype(compute_dtype).T,
+        xt = xt_ref[:].astype(compute_dtype)                      # (d, bn)
+        scores = (-2.0 * jnp.dot(cent_ref[:].astype(compute_dtype), xt,
                                  preferred_element_type=jnp.float32)
-                  + c2_ref[:])                                    # (bn, k)
-        mins = jnp.min(scores, axis=1, keepdims=True)
+                  + c2_ref[:])                                    # (k, bn)
+        mins = jnp.min(scores, axis=0, keepdims=True)
         is_min = scores <= mins
         if tie_policy == "first":
             # exact first-index-argmin semantics WITHOUT an argmin loop
             # (which lowers to a ~6 ms index-tracking scan in Mosaic):
-            # the first minimiser is the smallest column index among the
-            # tied minima — one where + row-min + compare, all cheap VPU
-            # passes (no division like "split").
-            k = scores.shape[1]
-            iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            first = jnp.min(jnp.where(is_min, iota, k), axis=1,
+            # the first minimiser is the smallest centroid index among
+            # the tied minima -- one where + min over the k axis +
+            # compare, all sublane-axis VPU passes (no division like
+            # "split").
+            k = scores.shape[0]
+            iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            first = jnp.min(jnp.where(is_min, iota, k), axis=0,
                             keepdims=True)
             onehot = (iota == first).astype(jnp.float32)
         else:
             onehot = is_min.astype(jnp.float32)
             if tie_policy == "split":
-                onehot = onehot / jnp.sum(onehot, axis=1, keepdims=True)
-        sums_ref[:] += jnp.dot(onehot.T.astype(compute_dtype),
-                               pts.astype(compute_dtype),
-                               preferred_element_type=jnp.float32)
-        counts_ref[:] += jnp.sum(onehot, axis=0)
+                onehot = onehot / jnp.sum(onehot, axis=0, keepdims=True)
+        sums_ref[:] += jax.lax.dot_general(
+            onehot.astype(compute_dtype), xt, over_rows,
+            preferred_element_type=jnp.float32)                   # (k, d)
+        counts_ref[:] += jnp.sum(onehot, axis=1, keepdims=True)   # (k, 1)
 
     return kern
 
@@ -306,7 +392,9 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     (sums (k, d) f32, counts (k,) f32)``.
 
     ``n`` must be a multiple of ``block_n``; pad with all-zero rows and
-    correct the counts with :func:`pad_correction`.
+    correct the counts with :func:`pad_correction`.  A block is
+    ``block_n`` rows on lanes: a multiple of 128 for the chip (the
+    interpreter takes any).
     """
     if tie_policy not in ("first", "fast", "split"):
         raise ValueError(f"tie_policy must be 'first', 'fast' or 'split', "
@@ -314,27 +402,30 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     n, d = points.shape
     k = centroids.shape[0]
     _check_block(n, block_n)
-    c2 = jnp.sum(centroids * centroids, axis=1)[None, :]
+    c2 = jnp.sum(centroids * centroids, axis=1, keepdims=True)
 
-    return pl.pallas_call(
+    # (n, d) -> (d, n): the chip keeps narrow rows column-major, so for
+    # them this is the array it already holds under another name
+    sums, counts = pl.pallas_call(
         _stats_kernel(tie_policy, compute_dtype),
         grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0),
+            pl.BlockSpec((d, block_n), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k,), lambda i: (0,), memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
+            jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(points, centroids, c2)
+    )(points.T, centroids, c2)
+    return sums, counts[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -460,7 +551,8 @@ def workset_supported(d: int, k: int, block_n: int = 8192) -> bool:
 def pick_block_n_workset(n: Optional[int], d: int, k: int) -> Optional[int]:
     """Largest viable workset-kernel block (``n=None`` when the caller
     pads to the block — the estimator does)."""
-    return _pick_block(n, lambda bn: workset_supported(d, k, bn))
+    return _pick_block(n, lambda bn: workset_supported(d, k, bn),
+                       _MAX_WORKSET_BLOCK)
 
 
 def _workset_kernel(k: int):

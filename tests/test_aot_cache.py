@@ -423,7 +423,7 @@ def test_kmeans_block_pick_measured_and_persisted(cache):
     bn = kp.pick_block_n_measured(8, 4, interpret=True,
                                   candidates=[128, 256])
     assert bn in (128, 256)
-    key = "kmeans_update_stats|('block_n', 8, 4)"
+    key = "kmeans_update_stats|('block_n', 'feature_major', 8, 4)"
     assert kernel_stats.tuned_ops[key]["source"] == "measured"
     assert set(kernel_stats.tuned_ops[key]["timings_ms"]) == \
         {"128", "256"}

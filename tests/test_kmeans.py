@@ -223,6 +223,33 @@ def test_plan_fit_impl_gates():
     assert km._plan_fit_impl(1 << 20, 64, 256, cosine, mesh)[0] == "xla"
 
 
+@pytest.mark.parametrize("n,d,k,data_devs,block_n", [
+    (20_000_000, 20, 10, 1, 32768),   # HiBench: the largest the tile admits
+    (1 << 20, 64, 256, 1, 8192),      # chip_smoke.py's fit
+    (1 << 20, 20, 10, 8, 16384),      # a shard of 2^17 rows: eight blocks
+    (65536, 20, 10, 1, 8192),         # the smallest fit the kernel takes
+    (70_000, 8, 4, 1, 8192),          # 8750 rows a block at most
+])
+def test_plan_leaves_a_shard_eight_blocks(monkeypatch, n, d, k, data_devs,
+                                          block_n):
+    """On a TPU the plan takes the largest block the VMEM model admits,
+    halved until a shard holds eight of them: its fill rows stay under an
+    eighth of it."""
+    import jax
+
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel.mesh import device_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = device_mesh({"data": data_devs},
+                       devices=jax.devices()[:data_devs])
+    plan = km._fit_plan(n, d, k, DistanceMeasure.get_instance("euclidean"),
+                        mesh)
+    assert (plan.impl, plan.block_n, plan.row_multiple, plan.fill) == (
+        "pallas", block_n, block_n, "zero")
+
+
 def test_pallas_step_fractional_split_counts_divide_exactly():
     # A cluster whose total "split" count is fractional (< 1) must divide by
     # the fractional count, not a clamp-to-1 (regression: centroid scaled by

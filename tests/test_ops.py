@@ -1,6 +1,8 @@
 """Pallas kernel tests — run in interpret mode on the CPU mesh (the kernels
 compile natively on TPU; interpret mode is the portable correctness oracle)."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,14 +45,60 @@ def _corrected_stats(pts, cents, n_pad, **kw):
     return sums, counts
 
 
-def test_update_stats_matches_oracle():
-    pts, cents, n_pad = _problem()
+def _hibench_problem(n_blocks, block_n, n_pad, seed=0, d=20, k=10):
+    """HiBench's shape class: d 20, k 10, ``n_blocks`` blocks of rows of
+    which the last ``n_pad`` are zero fill.  Clusters as ``tests_tpu``
+    separates them (centres the bit codes of their index times 16, unit
+    noise), so no margin is within reach of a rounding."""
+    rng = np.random.default_rng(seed)
+    n = n_blocks * block_n
+    bits = (np.arange(k)[:, None] >> np.arange(d)[None, :]) & 1
+    true_c = (16.0 * bits).astype(np.float32)
+    pts = (true_c[rng.integers(0, k, size=n)]
+           + rng.normal(size=(n, d))).astype(np.float32)
+    pts[n - n_pad:] = 0.0
+    cents = (true_c + 0.5 * rng.normal(size=(k, d))).astype(np.float32)
+    return jnp.asarray(pts), jnp.asarray(cents), n_pad
+
+
+#: (problem, block_n): the module's small problem in one-lane-tile
+#: blocks, HiBench's shape class over several blocks of two sizes
+_PARITY_CASES = {
+    "d16-k8": (_problem, 128),
+    "hibench-5x128": (partial(_hibench_problem, 5, 128, 37), 128),
+    "hibench-3x512": (partial(_hibench_problem, 3, 512, 300, seed=1), 512),
+}
+
+
+@pytest.mark.parametrize("tie_policy", ["first", "fast", "split"])
+@pytest.mark.parametrize("case", list(_PARITY_CASES))
+def test_update_stats_matches_oracle(case, tie_policy):
+    problem, block_n = _PARITY_CASES[case]
+    pts, cents, n_pad = problem()
     _, exp_sums, exp_counts = _oracle(pts, cents, n_pad)
-    for tie_policy in ("fast", "split"):
-        sums, counts = _corrected_stats(pts, cents, n_pad, block_n=128,
-                                        tie_policy=tie_policy)
-        np.testing.assert_allclose(np.asarray(sums), exp_sums, atol=1e-3)
-        np.testing.assert_allclose(np.asarray(counts), exp_counts, atol=1e-5)
+    sums, counts = kmeans_update_stats(pts, cents, block_n=block_n,
+                                       tie_policy=tie_policy, interpret=True)
+    counts = pad_correction(counts, cents, n_pad, tie_policy=tie_policy)
+    np.testing.assert_allclose(np.asarray(sums), exp_sums, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(counts), exp_counts, atol=1e-5)
+
+
+def test_update_stats_first_takes_exact_ties_to_the_first_centroid():
+    """Centroids 3 and 7 are the same row, bit for bit, so every point of
+    their cluster ties exactly: ``first`` counts all of them on 3, as the
+    oracle's argmin does, and 7 gets nothing.  Counts to the unit, sums
+    to the bf16-scaled tolerance ``tests_tpu`` uses on the chip."""
+    pts, cents, n_pad = _hibench_problem(4, 256, 100, seed=2)
+    cents = cents.at[7].set(cents[3])
+    _, exp_sums, exp_counts = _oracle(pts, cents, n_pad)
+    assert exp_counts[3] > 0 and exp_counts[7] == 0
+    sums, counts = kmeans_update_stats(pts, cents, block_n=256,
+                                       tie_policy="first", interpret=True)
+    counts = pad_correction(counts, cents, n_pad, tie_policy="first")
+    np.testing.assert_array_equal(np.asarray(counts), exp_counts)
+    np.testing.assert_allclose(np.asarray(sums), exp_sums, rtol=2e-3,
+                               atol=0.5)
+    assert not np.asarray(sums)[7].any()
 
 
 def test_update_stats_bf16_dots_conserve_mass():
@@ -171,6 +219,20 @@ def test_supported_budget_and_block_pick():
     assert pick_block_n(1_048_576, 64, 256) == 8192
     assert pick_block_n(640, 16, 8) == 128
     assert pick_block_n(100, 16, 8) is None
+
+
+@pytest.mark.parametrize("d,k,block_n", [
+    (20, 10, 32768),    # HiBench: 24 sublanes of points twice, 16 of scores
+    (64, 256, 8192),    # chip_smoke.py's fit
+    (784, 256, 1024),   # MNIST's width: the points block alone is 6.1 MiB
+    (8, 4, 65536),      # the largest block on offer
+])
+def test_block_pick_follows_the_feature_major_tile(d, k, block_n):
+    """The picks the compiler was asked about for a described v5e (PR 30:
+    each compiles under all three tie policies, and at d 20, k 10 the
+    next power of two is refused at 16.45 MiB of the 16 it may take)."""
+    assert pick_block_n(None, d, k) == block_n
+    assert block_n == 65536 or not supported(d, k, 2 * block_n)
 
 
 def test_pad_correction_exact_under_min_norm_ties_first():

@@ -9,7 +9,10 @@ here at its smallest supported shape and at the full width
 
 Lowering is not compiling: Mosaic inside libtpu can still refuse what
 lowers (VMEM limit, unaligned slices).  That is ``tests_tpu``'s job, on
-the chip.
+the chip.  The last tests of this file go one step further for the
+KMeans fit, the benchmark's kernel: they COMPILE it for a described v5e
+(the TPU's compiler is installed here; nothing runs), which is where a
+block over the VMEM limit and a lane-padded copy of the points show.
 """
 
 from functools import partial
@@ -72,8 +75,11 @@ def _lr_step(d, batch, n_dense):
 
 
 def _kmeans_stats(n, d, k, block_n, tie_policy):
-    from flink_ml_tpu.ops.kmeans_pallas import kmeans_update_stats
+    """``block_n`` None: the block the fit's plan picks at (d, k)."""
+    from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
+                                                pick_block_n)
 
+    block_n = block_n or pick_block_n(n, d, k)
     return (partial(kmeans_update_stats, block_n=block_n,
                     tie_policy=tie_policy),
             (Shape((n, d), F32), Shape((k, d), F32)))
@@ -105,9 +111,11 @@ def _retrieve(b, dim, nlist, block, m=0, ksub=16, nprobe=4, k=10):
 # (op, backend) -> {case id: thunk returning (fn, abstract args)}.  The
 # smallest ELL table is 128 rows; full width is the Criteo step
 # (d = 2^20, batch 32768) and the KMeans fit (n = 2^20, d = 64, k = 256)
-# of chip_smoke.py.  (routed_table_grad/pallas lowers too, but Mosaic
-# refuses it on the chip; gbt_level_histograms/mxu is plain XLA and
-# misses its twin's numbers there.  Both are forced-lookup only.)
+# of chip_smoke.py; "hibench" is the benchmark cell's d 20, k 10 at the
+# block of 32768 lanes its plan picks, rows contracted on lanes.
+# (routed_table_grad/pallas lowers too, but Mosaic refuses it on the
+# chip; gbt_level_histograms/mxu is plain XLA and misses its twin's
+# numbers there.  Both are forced-lookup only.)
 CASES = {
     ("ell_margin", "pallas"): {
         "smallest": lambda: _ell_margin(128, 64, False),
@@ -131,6 +139,7 @@ CASES = {
         **{f"smallest-{tie}": partial(_kmeans_stats, 128, 8, 4, 128, tie)
            for tie in ("first", "fast", "split")},
         "full-width": lambda: _kmeans_stats(1 << 20, 64, 256, 8192, "first"),
+        "hibench": lambda: _kmeans_stats(1 << 20, 20, 10, None, "first"),
     },
     ("kmeans_workset_update", "pallas"): {
         "smallest": lambda: _kmeans_workset(128, 8, 4, 128),
@@ -220,3 +229,64 @@ def test_sharded_lr_step_lowers_for_tpu():
     reason=registry.lookup("retrieve", backend="pallas-pq").forced_only)
 def test_parked_kernel_still_does_not_lower(case):
     _lower_for_tpu(case)
+
+
+# -- compiled for a described v5e (no chip; nothing runs) -------------------
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A single-device sharding on a described v5e, or a skip where none
+    can be described.  Made in a fixture: only the worker that runs this
+    file may load the TPU's library."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("d,k", [(20, 10), (64, 256), (784, 256)],
+                         ids=["hibench", "chip-smoke", "mnist-width"])
+def test_kmeans_stats_compiles_at_the_block_the_model_picks(one_v5e, d, k):
+    """``_stats_tile_bytes`` against the compiler: the largest block it
+    admits fits the 16 MiB of scoped VMEM Mosaic may take."""
+    from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
+                                                pick_block_n)
+
+    block_n = pick_block_n(None, d, k)
+    jax.jit(partial(kmeans_update_stats, block_n=block_n,
+                    tie_policy="first")).lower(
+        Shape((2 * block_n, d), F32, sharding=one_v5e),
+        Shape((k, d), F32, sharding=one_v5e)).compile()
+
+
+def test_kmeans_fit_program_keeps_no_copy_of_the_points(one_v5e):
+    """The fused program of a fit at 2^22 x 20, k 10 (``iterate``'s scan
+    over the Pallas step): the kernel takes the rows as the chip lays
+    them out (column-major: ``points.T`` is a bitcast), so the program's
+    temporaries stay under the points' own bytes.  A kernel that wants
+    rows on sublanes makes XLA copy them into rows of 128 lanes, 6.4
+    times the points (``tests_tpu`` asserts the same on the chip)."""
+    from flink_ml_tpu.models.clustering.kmeans import kmeans_epoch_step_pallas
+    from flink_ml_tpu.ops.kmeans_pallas import pick_block_n
+
+    n, d, k = 1 << 22, 20, 10
+    body = kmeans_epoch_step_pallas(k, block_n=pick_block_n(n, d, k))
+
+    def run(centroids, data):
+        return jax.lax.scan(
+            lambda c, epoch: (body(c, epoch, data).feedback, None),
+            centroids, jnp.arange(5, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(run).lower(
+        Shape((k, d), F32, sharding=one_v5e),
+        (Shape((n, d), F32, sharding=one_v5e),
+         Shape((n,), F32, sharding=one_v5e))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4

@@ -174,23 +174,40 @@ def _lloyd_oracle(pts, cents):
 
 
 # one block_n tile at the smallest shape; two 8192-row tiles at the
-# k = 256, d = 64 shape chip_smoke.py fits (the VMEM-budget case)
+# k = 256, d = 64 shape chip_smoke.py fits
 _KMEANS_SHAPES = pytest.mark.parametrize(
     "n,dcol,k", [(8192, 8, 4), (16384, 64, 256)],
     ids=["smallest", "full-width"])
 
 
-@_KMEANS_SHAPES
+# the same two at blocks of 8192 lanes, and the benchmark cell's d 20,
+# k 10 at the block its plan picks (32768 lanes): 16 x 8192 rows and a
+# remainder, zero rows up to the block
+_KMEANS_STATS_SHAPES = pytest.mark.parametrize(
+    "n,dcol,k,block_n",
+    [(8192, 8, 4, 8192), (16384, 64, 256, 8192),
+     (16 * 8192 + 1000, 20, 10, None)],
+    ids=["smallest", "full-width", "hibench"])
+
+
+@_KMEANS_STATS_SHAPES
 @pytest.mark.parametrize("tie_policy", ["first", "split", "fast"])
-def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k):
+def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k, block_n):
     """kmeans_update_stats (the fused Lloyd's kernel) vs a numpy oracle."""
     import jax.numpy as jnp
 
-    from flink_ml_tpu.ops.kmeans_pallas import kmeans_update_stats
+    from flink_ml_tpu.ops.kmeans_pallas import (
+        kmeans_update_stats, pad_correction, pick_block_n)
+    from flink_ml_tpu.utils.padding import pad_rows_to_block
 
+    block_n = block_n or pick_block_n(None, dcol, k)
     pts, cents = _separated_clusters(rng, n, dcol, k)
-    sums, counts = kmeans_update_stats(jnp.asarray(pts), jnp.asarray(cents),
-                                       block_n=8192, tie_policy=tie_policy)
+    (padded,), _ = pad_rows_to_block((pts,), block_n)
+    sums, counts = kmeans_update_stats(jnp.asarray(padded),
+                                       jnp.asarray(cents), block_n=block_n,
+                                       tie_policy=tie_policy)
+    counts = pad_correction(counts, jnp.asarray(cents),
+                            padded.shape[0] - n, tie_policy=tie_policy)
     _, _, want_sums, want_counts = _lloyd_oracle(pts, cents)
     # counts are the exact-parity guard: any flipped assignment shows up
     # as a whole unit.  sums pass through one default-precision MXU dot

@@ -74,6 +74,39 @@ def test_fit_pads_on_device_bitexact_vs_host_pad_and_refit_compiles_nothing(
     assert column.tobytes() == before
 
 
+def test_fit_program_keeps_no_copy_of_the_points(tpu):
+    """The fused program of a fit at 2^22 x 20, k 10 (the scan ``iterate``
+    builds over the Pallas step), compiled from shapes: the kernel takes
+    the rows as the chip lays them out, so the program's temporaries stay
+    under the points' own bytes (a lane-padded copy would be 6.4 times
+    them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.models.clustering.kmeans import (
+        _fit_plan, kmeans_epoch_step_pallas)
+    from flink_ml_tpu.parallel.mesh import device_mesh
+
+    n, d, k = 1 << 22, 20, 10
+    plan = _fit_plan(n, d, k, DistanceMeasure.get_instance("euclidean"),
+                     device_mesh(devices=[tpu]))
+    assert plan.impl == "pallas" and n % plan.block_n == 0
+    body = kmeans_epoch_step_pallas(k, block_n=plan.block_n)
+
+    def run(centroids, data):
+        return jax.lax.scan(
+            lambda c, epoch: (body(c, epoch, data).feedback, None),
+            centroids, jnp.arange(5, dtype=jnp.int32))[0]
+
+    f32 = jnp.float32
+    compiled = jax.jit(run).lower(
+        jax.ShapeDtypeStruct((k, d), f32),
+        (jax.ShapeDtypeStruct((n, d), f32),
+         jax.ShapeDtypeStruct((n,), f32))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
+
+
 @pytest.mark.parametrize("fill", ["zero", "first_row"])
 def test_rows_on_device_equals_the_host_pad_on_chip(tpu, rng, fill):
     """The layout program at 2^20 + 5 rows (seventeen pieces, the last
