@@ -55,7 +55,7 @@ REHEARSAL = {"fit": (1 << 14, 1 << 12), "stream": (1 << 13, 1 << 10),
              "kmeans": 1 << 16}
 
 # One representative signature per registry op for the op -> backend
-# table: the shapes this script runs where it runs the op, the bench
+# table: the shapes this script runs where it runs the op, the Criteo
 # shape elsewhere.  An op missing here is looked up with no signature.
 OP_SIGNATURES = {
     "ell_margin": [("", (NUM_FEATURES // 128,))],

@@ -160,7 +160,7 @@ class ChainConfig:
 
 
 # --------------------------------------------------------------------------
-# enable/disable switch (tests and the bench A/B baseline)
+# enable/disable switch (the tests' stagewise oracle)
 # --------------------------------------------------------------------------
 
 _STATE = threading.local()
@@ -171,8 +171,8 @@ def _enabled() -> bool:
 
 
 class chain_disabled:
-    """Context manager forcing the stagewise path — the bench A/B baseline
-    and the bit-exactness oracle in tests."""
+    """Context manager forcing the stagewise path — the bit-exactness
+    oracle in tests."""
 
     def __enter__(self):
         self._prev = _enabled()
@@ -405,27 +405,6 @@ class CompiledSegment:
     def num_stages(self) -> int:
         return len(self.stages)
 
-    def transfer_bytes(self, num_rows: int) -> Tuple[int, int]:
-        """(host->device, device->host) bytes this segment moves for a
-        ``num_rows`` batch — exact shape math for the bench accounting."""
-        itemsize = np.dtype(self.config.dtype).itemsize
-
-        def _nbytes(names, schema):
-            total = 0
-            for n in names:
-                shape, dt = schema.get(n, ((), np.dtype(self.config.dtype)))
-                width = int(np.prod(shape)) if shape else 1
-                size = itemsize if dt.kind == "f" else 4
-                total += num_rows * width * size
-            return total
-
-        return (_nbytes(self.entry_cols, self._entry_schema),
-                _nbytes(self.fetch_cols, self._out_schema))
-
-    def bind_schemas(self, entry_schema: dict, out_schema: dict) -> None:
-        self._entry_schema = dict(entry_schema)
-        self._out_schema = dict(out_schema)
-
     def run(self, table: Table) -> Table:
         cfg = self.config
         try:
@@ -489,10 +468,6 @@ class CompiledPipeline:
         return [i for i in self.items if isinstance(i, CompiledSegment)]
 
     @property
-    def num_fused_stages(self) -> int:
-        return sum(s.num_stages for s in self.segments)
-
-    @property
     def worthwhile(self) -> bool:
         """Fusing pays once any segment merges >= 2 stages; a plan of
         singletons is the stagewise path with extra bookkeeping."""
@@ -531,7 +506,6 @@ def compile_pipeline(pipeline_model, example: Table, *,
     current = example
     run_stages: List = []
     run_kernels: List[StageKernel] = []
-    run_entry: Table = example
     produced_in_run: set = set()
 
     def flush(out_table: Table) -> None:
@@ -540,7 +514,6 @@ def compile_pipeline(pipeline_model, example: Table, *,
             return
         seg = CompiledSegment(run_stages, run_kernels,
                               out_table.column_names, config)
-        seg.bind_schemas(run_entry.schema(), out_table.schema())
         items.append(seg)
         run_stages, run_kernels, produced_in_run = [], [], set()
 
@@ -562,8 +535,6 @@ def compile_pipeline(pipeline_model, example: Table, *,
             flush(current)
         next_table = stage.transform(current)[0]
         if kernel is not None:
-            if not run_stages:
-                run_entry = current
             run_stages.append(stage)
             run_kernels.append(kernel)
             produced_in_run.update(kernel.produces)

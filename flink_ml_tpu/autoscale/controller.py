@@ -34,7 +34,7 @@ clock in tests advances all of them coherently, and MTTR-style
 accounting never divides one clock's delta by another's.
 
 Like :class:`~flink_ml_tpu.obs.tree.ObsSampler`, the controller can
-run tick-on-demand (tests, bench replay loops) or as a background
+run tick-on-demand (tests, replay loops) or as a background
 daemon thread (``start()``/``stop()``); the thread's cadence uses the
 wall sleep of ``threading.Event.wait`` but every *measurement* stays on
 the injected clock.

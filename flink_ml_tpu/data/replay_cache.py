@@ -6,9 +6,8 @@ every later round re-reads the cache instead of re-running the upstream
 pipeline (``iteration/operator/ReplayOperator.java:62-311``).  On TPU the
 expensive upstream work is not the read — it is the host *decode* that
 turns raw cached rows into device-ready arrays (pad + dtype casts + the
-ELL routing build, ``ops/ell_scatter.py``).  r4 measurement: at the bench
-shape the decode costs ~4 s/epoch while the device step costs ~25 ms —
-the out-of-core epoch rate is decode-bound, not math-bound.
+ELL routing build, ``ops/ell_scatter.py``): the out-of-core epoch rate
+is decode-bound, not math-bound.
 
 :class:`DecodedReplayCache` is the TPU-native analog, one level higher
 than the reference's, and serves two access patterns:

@@ -29,13 +29,10 @@ entries are truncated on snapshot once they fall behind the
 ``keep_snapshots`` most recent cuts (every kept cut must still be able to
 restore).
 
-Durability cost (measured r4, single-core bench host, 256-row f32
-windows): ~1100 windows/s with the per-window file+dir fsync pair
-(~2.6 ms/window overhead; ~2700 w/s with fsync stubbed out).  Online
-windows arrive at device-step rate — orders of magnitude below that — so
-the per-window fsync stays; batching the dirfsync would only matter past
-~1k windows/s.  bench.py re-measures this each round
-(``notes.wal_windows_per_sec``).
+Durability cost: every window pays a file fsync and a directory fsync.
+Online windows arrive at device-step rate, far below what that pair
+sustains on a local disk, so the per-window fsync stays; the rate itself
+is not measured by the benchmark.
 """
 
 from __future__ import annotations
@@ -209,7 +206,7 @@ class WindowBatchReader:
     NOT claim ``total_rows``: the stream is unbounded, so the decoded
     replay cache must never engage.
 
-    ``max_windows`` bounds the run (benches/tests); the bound is an
+    ``max_windows`` bounds the run (tests); the bound is an
     ABSOLUTE window index, so a resumed reader still stops at the same
     stream position.
     """

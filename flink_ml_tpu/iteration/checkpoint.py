@@ -300,7 +300,7 @@ class CheckpointManager:
         os.makedirs(config.directory, exist_ok=True)
         self._pending: Optional["threading.Thread"] = None
         self._pending_error: Optional[BaseException] = None
-        #: set by :meth:`latest` — the supervisor/bench read these to
+        #: set by :meth:`latest` — the supervisor reads these to
         #: compute MTTR (detect -> restore complete) and steps replayed
         self.last_restore_at: Optional[float] = None
         self.last_restored_step: Optional[int] = None

@@ -87,9 +87,8 @@ def measure(candidates: Dict[str, Callable[[], object]], *,
             iters: int = 3, repeats: int = 2) -> Dict[str, float]:
     """Wall-time each candidate thunk: one untimed warm-up call
     (compile + transfer costs stay out of the ranking), then
-    best-of-``repeats`` averages over ``iters`` synced calls — the
-    ``bench.py::timed`` discipline, so a one-off GC pause cannot crown
-    the wrong winner.  Returns ``{name: best_ms_per_call}``."""
+    best-of-``repeats`` averages over ``iters`` synced calls, so a
+    one-off GC pause cannot crown the wrong winner.  Returns ``{name: best_ms_per_call}``."""
     import jax
 
     timings: Dict[str, float] = {}

@@ -34,9 +34,8 @@ entry points always did.
 
 Observability: compile-count / cache-hit / dispatch-latency gauges live
 on :data:`kernel_stats` and publish into any ``MetricGroup`` (serving
-endpoints re-export them per batch; ``bench.py::bench_kernels`` reports
-them), so cross-consumer compile reuse — CV folds, hot-swap
-generations, fused serving — is a measured number.
+endpoints re-export them per batch), so cross-consumer compile reuse —
+CV folds, hot-swap generations, fused serving — is a counted number.
 """
 
 from __future__ import annotations
@@ -96,8 +95,8 @@ class KernelEntry:
     ``supports(sig)`` is the shape/schema contract (e.g. the fused ELL
     kernels need ``rows % 8 == 0``); ``available()`` is the backend
     gate (Pallas entries default to TPU-only).  A *forced* backend
-    lookup bypasses ``available`` — tests and bench A/B legs run Pallas
-    kernels in interpret mode on CPU — but never ``supports``: a shape
+    lookup bypasses ``available`` — tests run Pallas kernels in
+    interpret mode on CPU — but never ``supports``: a shape
     the kernel cannot express must fail loudly, not fall back silently.
 
     ``forced_only`` is THE way an entry stays out of automatic
@@ -189,8 +188,8 @@ def lookup(op: str, sig: tuple = (), *,
            backend: Optional[str] = None) -> KernelEntry:
     """Resolve ``(op, schema-signature)`` to the best registered entry.
 
-    ``backend`` forces a specific implementation (the bench A/B legs and
-    the tests' XLA oracles): availability is bypassed — the caller owns
+    ``backend`` forces a specific implementation (the tests' XLA
+    oracles): availability is bypassed — the caller owns
     running e.g. a Pallas kernel in interpret mode — but a PROVIDED
     ``sig`` still gates through ``supports``, so a shape outside the
     kernel's contract raises instead of silently computing the wrong
@@ -400,7 +399,7 @@ class KernelStats:
     def publish(self, group) -> None:
         """Refresh gauges on ``group`` (the ``PrefetchStats.publish``
         idiom): serving endpoints re-export the registry's counters into
-        their own metric subtree, ``bench.py`` into its report.  The
+        their own metric subtree.  The
         cache-source gauges make cold-start composition a measured
         number: ``aot_load_ms`` vs ``compile_ms`` is literally 'what the
         persistent cache saved this process'."""
@@ -563,6 +562,5 @@ def dispatch(plan: tuple, params_seq: tuple, cols: Dict[str, Any], *,
 
 
 def dispatch_count() -> int:
-    """Shared-jit invocations so far (one per segment/kernel run) — the
-    bench_pipeline A/B evidence, previously ``api.chain.dispatch_count``."""
+    """Shared-jit invocations so far (one per segment/kernel run)."""
     return _DISPATCHES[0]

@@ -86,15 +86,15 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
     - ``"split"``: fractional assignment across the tied minimisers
       (exact expected-assignment semantics: total cluster mass always
       sums to n).
-    - ``"fast"`` (opt-in via ``setTiePolicy``; bench.py times whatever
-      ``fit`` plans, i.e. the "first" default): a tied point
+    - ``"fast"`` (opt-in via ``setTiePolicy``; ``kmeans_hibench.fit``
+      measures what ``fit`` plans, i.e. the "first" default): a tied point
       counts toward EVERY minimizing centroid — its mass is
       double-counted, biasing the tied centroids' means toward it.  On
       continuous features exact f32 ties are measure-zero, so this is
       free; on DISCRETE/quantized features (integer grids, one-hot),
       distinct equidistant centroids are common and "fast" measurably
-      changes the fit.  ~45% faster per iteration than "split" on v5e
-      (r3 numbers; "first" re-measured r4).
+      changes the fit.  Its time against "split" and "first" is not
+      measured on the chip.
 
     The XLA fallback path (non-TPU, small n, non-euclidean) always uses
     first-index argmin and ignores this param."""
@@ -120,7 +120,7 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
         "bounds through the fused fit loop and exit the while_loop at "
         "Lloyd's fixed point instead of always running maxIter rounds.  "
         "Settled points keep cached assignments, shrinking the points "
-        "SCORED per round (the report/bench accounting; the fused "
+        "SCORED per round (the fit report's accounting; the fused "
         "program still evaluates dense shapes, so the wall-clock win "
         "today is the early exit).  Off TPU the body is XLA — final "
         "centroids bit-identical to the XLA BSP fit (first-index "
@@ -353,9 +353,8 @@ def workset_points_scored(active_fraction, n_real: int,
     """Points scored per round, derived from the POST-round
     active-fraction trace: round 0 rescored every real point (BSP round
     0), round ``e`` scores round ``e-1``'s survivors (the fraction is
-    over padded rows).  THE one copy of this convention — the fit report
-    and the bench leg's FLOPs accounting both read it, so a trace
-    semantics change cannot skew one silently."""
+    over padded rows).  THE one copy of this convention: the fit report
+    reads it."""
     frac = np.asarray(active_fraction, np.float64)
     if not frac.size:
         return np.zeros((0,))
@@ -416,7 +415,7 @@ def kmeans_workset_epoch_step(measure: DistanceMeasure, k: int, *,
     the same einsum over all n points — identical assignments, identical
     f32 summation order).  What shrinks is the LOGICAL scoring work: the
     number of points whose (n, k) distance rows a round must re-score
-    (``points_scored`` in the fit report / bench leg) — the fused
+    (``points_scored`` in the fit report) — the fused
     fixed-shape program still evaluates densely, so that count is what a
     compacting backend banks, while the early exit below is the physical
     saving available today.

@@ -122,8 +122,8 @@ def apply_bins_device(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
 #: segment_sum everywhere today, see the registrations at the end of
 #: this module), "segsum" (force the XLA scatter-adds, the r1-r4 path)
 #: or "mxu" (force the double one-hot matmul: exact up to f32 summation
-#: order off TPU, bf16-truncated addends on the MXU).  Module-level so
-#: the bench can measure both and a chip verdict can pin the default.
+#: order off TPU, bf16-truncated addends on the MXU).  Neither forced
+#: value is timed on the chip (ROADMAP S12).
 HIST_IMPL = "auto"
 
 

@@ -202,8 +202,7 @@ class LinearModelBase(LinearModelParams, Model):
     @property
     def planned_impl(self) -> Optional[str]:
         """Which update implementation the fit planned ("ell" / "xla" /
-        "sharded" / "dense" / "*-stream") — what bench.py tags as
-        ``lr_impl``, surfaced on the product path (VERDICT r3 task 3).
+        "sharded" / "dense" / "*-stream"), surfaced on the product path.
         None when the model was loaded rather than trained."""
         return self._state.planned_impl if self._state is not None else None
 

@@ -60,7 +60,7 @@ class SGDConfig:
     elastic_net: float = 0.0    # l1 mixing (0 = pure l2)
     #: None/0 = auto: 32 for dense fits; mixed/sparse hashed layouts grow
     #: the batch until the ELL routing layout fits its HBM budget, so the
-    #: default product path plans the same kernel the bench times
+    #: default product path plans the ELL kernels
     #: (:func:`resolve_global_batch_size`).
     global_batch_size: Optional[int] = None
     max_epochs: int = 20
@@ -68,14 +68,12 @@ class SGDConfig:
     seed: int = 0
     fit_intercept: bool = True
     #: MXU precision of the fused ELL kernels' in-kernel one-hot
-    #: contractions.  "default" (one bf16 pass) measured 4.39 ms/step at
-    #: bench shape vs 10.49 for "highest" (multi-pass f32) and 11.0 for
-    #: the XLA oracle (r4 chip run, 2026-07-31), and passes the bench's
-    #: epoch-level parity gate (rtol=1e-3): the contracted residuals are
-    #: batch-normalized, so their ~2^-8 relative truncation lands below
-    #: the f32 summation-order noise every ELL path already carries.
-    #: "highest" restores bit-comparable-to-XLA gathers at ~2.4x the
-    #: step cost.
+    #: contractions.  "default" is one bf16 pass and holds epoch-level
+    #: parity with the XLA oracle (rtol=1e-3): the contracted residuals
+    #: are batch-normalized, so their ~2^-8 relative truncation lands
+    #: below the f32 summation-order noise every ELL path already
+    #: carries.  "highest" (multi-pass f32) restores
+    #: bit-comparable-to-XLA gathers; neither is timed on the chip.
     ell_precision: str = "default"
     #: How the data-parallel gradient sum is performed
     #: (:class:`~flink_ml_tpu.parallel.grad_reduce.GradReduceConfig`).
@@ -93,8 +91,8 @@ class SGDConfig:
 #: Classic minibatch default when nothing layout-aware applies.
 DEFAULT_GLOBAL_BATCH = 32
 
-#: Auto-sizing never grows the batch past the bench-headline scale: a
-#: bigger batch changes optimization dynamics more than it buys steps.
+#: Auto-sizing never grows the batch past this: a bigger batch changes
+#: optimization dynamics more than it buys steps.
 _AUTO_BATCH_CAP = 1 << 15
 
 
@@ -105,10 +103,9 @@ def resolve_global_batch_size(config: "SGDConfig", n: int,
     through untouched.  Auto (None/0) resolves to 32 for dense fits; for
     the hashed mixed/sparse layouts it grows the batch (fewer steps) until
     the per-step ELL routing layout stack fits ``_ELL_LAYOUT_BUDGET_BYTES``
-    — at the r2 default of 32, a 1M-row fit needs 32k steps of layout
-    (~400 GB at 2^20 features) and :func:`plan_mixed_impl` silently fell
-    back to XLA, so the product path and the bench ran different code
-    (VERDICT r3 weak #2).  Deterministic in (n, num_features) only — the
+    — at a batch of 32, a 1M-row fit needs 32k steps of layout
+    (~400 GB at 2^20 features) and :func:`plan_mixed_impl` would fall
+    back to XLA in silence.  Deterministic in (n, num_features) only — the
     same fit plans the same batch on any backend."""
     if config.global_batch_size:
         return config.global_batch_size
@@ -126,7 +123,7 @@ class LinearState:
     intercept: float
     #: which update implementation the fit planned ("ell" / "xla" /
     #: "sharded" / "dense" / streaming variants) — surfaced so product
-    #: callers can see what bench.py tags as lr_impl (VERDICT r3 task 3).
+    #: callers can see it; ``lr_criteo.fit`` checks it as its plan.
     #: Not part of persisted model data.
     planned_impl: Optional[str] = None
 
@@ -646,9 +643,8 @@ def _ell_margin(backend, precision, w, batch, src, pos, mask, ovf_idx,
                 ovf_src, heavy_idx, heavy_cnt, val_ell=None, ovf_val=None):
     """Per-sample categorical margin ``sum_j v_j * w[idx_j]`` computed
     over the SAME ELL routing the scatter uses — the forward half of the
-    r4 kernel plan (the ``w[cat]`` gather measured ~3.4 ms of the 7.79 ms
-    bench-shape step; the Mosaic margin kernel replaces it with one-hot
-    MXU contractions).  The in-grid implementation resolves from the
+    kernel plan (the Mosaic margin kernel replaces the ``w[cat]`` gather
+    with one-hot MXU contractions).  The in-grid implementation resolves from the
     kernel registry (op ``ell_margin``: the fused Mosaic kernel on TPU
     grids divisible into 8-row blocks, the XLA twin otherwise;
     ``backend`` forces one — tests pass ``"xla"`` for the oracle).
@@ -700,9 +696,7 @@ def _mixed_update_ell(loss_fn: LossFn, config: SGDConfig,
     regularization algebra, but BOTH halves of the categorical work —
     the forward margin gather and the backward scatter — go through the
     static ELL routing's fused Mosaic kernels (``ops/ell_scatter.py``)
-    instead of XLA's per-element gather/scatter: measured 1.02 ms/step
-    vs the 10.86 ms XLA oracle at bench shape, same run, v5e
-    (r4 chip run, 2026-07-31).  The extra batch arguments (src, pos,
+    instead of XLA's per-element gather/scatter.  The extra batch arguments (src, pos,
     mask, ovf_idx, ovf_src, heavy_idx, heavy_cnt) are the per-step
     layout stacks produced by ``ell_layout`` at fit time — the raw
     ``cat`` tensor itself is not an input; results differ from the XLA
@@ -1502,8 +1496,7 @@ def sgd_fit_outofcore(loss_fn: LossFn, make_reader: Callable, *,
     writes while passing through, later rounds re-read instead of
     re-running the upstream (``iteration/operator/ReplayOperator.java:62-311``)
     — lifted from raw records to *decoded* batches because on this host
-    the decode, not the read, dominates (r4 bench: ~4 s decode vs ~25 ms
-    compute per epoch).  ``cache_decoded="auto"`` (default) engages only
+    the decode, not the read, dominates.  ``cache_decoded="auto"`` (default) engages only
     when the reader speaks the cursor protocol (``seek``/``batch_rows``/
     ``total_rows``), and every replay epoch re-reads the FIRST raw batch
     and compares its digest against the recorded epoch's — a reader that

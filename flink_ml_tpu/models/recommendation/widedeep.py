@@ -887,8 +887,8 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
     ``lazy=True`` (LazyAdam, ``lazyEmbeddingOptimizer``): dense Adam
     touches every row of the ``(total_vocab, emb_dim)`` embedding and
     ``(total_vocab,)`` wide tables each step — m/v/param read+write
-    streams over rows whose gradient is exactly zero (~1.6 GB/step at
-    the 2^20-vocab bench shape).  The lazy step instead:
+    streams over rows whose gradient is exactly zero.  The lazy step
+    instead:
 
     1. takes the standard dense-shaped gradient (XLA's scatter-add from
        the gather's transpose — one zero-init + 213k-row scatter, the

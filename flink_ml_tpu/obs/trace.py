@@ -22,13 +22,9 @@ Design stance:
   site goes through :meth:`SpanTracer.span` (or guards on
   :attr:`SpanTracer.enabled`); with the ring off ``span()`` is the bare
   annotation — one small object, no lock, no clock read, nothing
-  recorded (``tracer.count`` stays 0).  ``bench.py::bench_obs`` compares
-  ring ON with ring off within one run (the enabled path under 5% of
-  p99, ZERO new XLA lowerings: tracing is pure host bookkeeping and
-  never touches a traced program); it cannot see what the off path
-  itself costs.  That was compared once, when the off path stopped
-  being a shared no-op object: the same serving sweep, ring off, before
-  and after (PERF.md section 6, PR 27: no difference that shows).
+  recorded (``tracer.count`` stays 0).  Ring on or off, tracing makes
+  ZERO new XLA lowerings: it is pure host bookkeeping and never touches
+  a traced program.
 - **Bounded memory.**  Completed spans land in a preallocated ring
   (default 64 Ki spans); the lock is held only for the slot bump +
   assignment — never across a clock read or an export.
@@ -167,8 +163,8 @@ class _LiveSpan(_ProfilerSpan):
 
 class SpanTracer:
     """Ring-buffered host span recorder (module doc).  One process-wide
-    instance lives at :data:`tracer`; tests and benches may construct
-    private ones."""
+    instance lives at :data:`tracer`; tests may construct private
+    ones."""
 
     def __init__(self, capacity: int = 1 << 16):
         if capacity <= 0:

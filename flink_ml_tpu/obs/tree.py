@@ -254,7 +254,7 @@ class ObsSampler:
     missing newline) and drops — the same tail-truncation stance as the
     WAL (``data/wal.py``).  ``start()`` spawns a daemon thread;
     ``sample()`` is also callable directly for tick-on-demand use
-    (tests, bench legs)."""
+    (tests)."""
 
     def __init__(self, tree: MetricsTree, path: str, *,
                  interval_s: float = 1.0,
@@ -271,7 +271,7 @@ class ObsSampler:
 
     def sample(self) -> Dict[str, Any]:
         """Take one snapshot and append it durably; returns the line's
-        dict (handy for tests/benches)."""
+        dict (handy for tests)."""
         record = {"t": self._clock()}
         record.update(self._tree.snapshot())
         line = json.dumps(record) + "\n"

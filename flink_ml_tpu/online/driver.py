@@ -186,7 +186,7 @@ class ContinuousLearner:
             resume: bool = True, report: Optional[Any] = None):
         """Train-and-serve until the source ends (or ``max_windows``).
         Returns ``(LinearState, loss_log)`` from the underlying fit —
-        unbounded sources never return; bounded runs (benches, tests)
+        unbounded sources never return; bounded runs (tests)
         do.  ``resume=True`` (default) continues from the newest valid
         checkpoint + WAL cursor, which is also what every crash restart
         does."""

@@ -37,8 +37,8 @@ __all__ = ["StalenessPolicy", "PublishStats"]
 
 @dataclass
 class PublishStats:
-    """Rolling publish accounting the policy consults (and the driver /
-    bench read back)."""
+    """Rolling publish accounting the policy consults (and the driver
+    reads back)."""
     publishes: int = 0
     deltas: int = 0
     fulls: int = 0

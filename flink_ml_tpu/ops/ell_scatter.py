@@ -690,9 +690,9 @@ def ell_scatter_apply_xla(w: jnp.ndarray, upd: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Forward (margin) path over the SAME layout: the r4 TPU ablation showed
-# the ``w[cat]`` forward gather costs ~3.4 ms/step at bench shape — the
-# other transaction-bound half of the mixed step.  Every slot's table
+# Forward (margin) path over the SAME layout: the ``w[cat]`` forward
+# gather is the other transaction-bound half of the mixed step.  Every
+# slot's table
 # position is already encoded in pos/mask (slots sorted by lane within a
 # row; ``pos[l]`` = last slot with lane <= l, mask = lane non-empty), so
 # the margin contribution of the in-grid slots is computable with zero

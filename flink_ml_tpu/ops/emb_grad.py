@@ -3,11 +3,9 @@ hot path.
 
 Problem: the Wide&Deep backward must form the dense gradient of the
 stacked ``(total_vocab, emb_dim)`` embedding table from per-slot gradient
-rows: ``g_table[cat[b, f]] += g_rows[b, f]`` for ~213k slots per batch at
-the bench shape.  Autodiff lowers this to XLA's general scatter-add —
-one random HBM read-modify-write per slot with conflict handling, which
-the r4 TPU measurement (2026-07-31) put at ~9.4 of the 18.8 ms step (the
-backward's dominant cost).
+rows: ``g_table[cat[b, f]] += g_rows[b, f]``, 26 slots a row of the
+batch.  Autodiff lowers this to XLA's general scatter-add — one random
+HBM read-modify-write per slot with conflict handling.
 
 But bounded fits replay the SAME epoch tensor every epoch
 (``models/common/sgd.py`` builds it once), so — exactly as with the LR

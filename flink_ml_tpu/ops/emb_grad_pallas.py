@@ -8,14 +8,12 @@ scatter-free, but its segmented suffix-fold materialises the full
 ``(S, E)`` sorted-gradient array in HBM once per fold pass —
 ``fold_passes`` is ``ceil(log2(max_run))``, and one heavy-hitter id
 appearing in most of an 8192-row batch drives it to ~13, i.e. ~13
-read+write round trips of the 213k x 16 f32 slot array (~220 MB of HBM
-traffic per step at bench shape) for what is arithmetically a handful
-of masked adds per element.
+read+write round trips of the ``(S, E)`` f32 slot array for what is
+arithmetically a handful of masked adds per element.
 
 This kernel runs ALL fold passes on a VMEM tile: HBM traffic drops to
-one read + one write of ``(S, E)`` regardless of ``fold_passes``
-(~2/13ths of the unfused fold's traffic at the bench shape — the
-analytic accounting ``bench.py::bench_kernels`` reports).  Correctness
+one read + one write of ``(S, E)`` regardless of ``fold_passes``.
+Correctness
 across tile boundaries uses a halo: the fold only propagates values
 from HIGHER to LOWER sorted positions over distances < ``2^fold_passes``,
 so with ``block_n >= 2^fold_passes`` a tile's fully-folded rows depend

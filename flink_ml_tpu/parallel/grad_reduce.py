@@ -1222,8 +1222,8 @@ def payload_bytes(grads_like: Any, config: GradReduceConfig, *,
 
 def bucket_report(grads_like: Any, config: GradReduceConfig,
                   rungs=None) -> dict:
-    """The analytic bucket plan the bench publishes even when timing legs
-    are skipped (pure shape math, device-independent): bucket count,
+    """The analytic bucket plan (pure shape math, device-independent,
+    so it is there where nothing can be timed): bucket count,
     dense bytes per bucket, each bucket's resolved rung payload, and the
     per-leaf chosen density (``rungs`` = realized per-leaf rung indices
     from reducer state; ``None`` = the initial rung)."""

@@ -189,9 +189,7 @@ def _retrieve_stage_xla(static, params, cols):
     ``dynamic_slice`` slab reads rather than one batched gather:
     XLA:CPU scalarizes a (b, nprobe) gather of (block, d) row slabs to
     per-element loads and then re-streams the materialized candidate
-    tensor through each fused consumer, which on the bench corpus is
-    an order of magnitude slower than the flat matmul it is supposed
-    to beat.  A dynamic-slice of a contiguous row block is a plain
+    tensor through each fused consumer.  A dynamic-slice of a contiguous row block is a plain
     copy, and the whole per-query scan (norms, dot, mask, top-k) stays
     resident in cache.  The distance math is identical expression for
     expression, so the per-row bits — and Pallas parity — are
@@ -393,7 +391,7 @@ class IVFIndex:
     def with_options(self, *, nprobe: Optional[int] = None,
                      k: Optional[int] = None) -> "IVFIndex":
         """A view of the same index at a different operating point (new
-        plan schema, same posting lists) — the bench's nprobe sweep."""
+        plan schema, same posting lists): what an nprobe sweep walks."""
         clone = dataclasses.replace if False else None  # noqa: F841
         out = IVFIndex.__new__(IVFIndex)
         out.__dict__.update(self.__dict__)

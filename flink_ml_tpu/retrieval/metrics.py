@@ -1,6 +1,6 @@
 """Retrieval quality instrumentation: recall harness + sampled probes.
 
-``recall_at_k`` is the offline harness (tests, bench, parity envelopes);
+``recall_at_k`` is the offline harness (tests, parity envelopes);
 ``RecallProbe`` is the online form — a deterministic sample of live
 queries re-scored against an EXACT float64 scan of the index's stored
 vectors, published as the per-tenant ``recall_probe`` gauge through the

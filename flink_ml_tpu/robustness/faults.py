@@ -159,8 +159,8 @@ def corrupt_file(path: str, mode: str = "flip", seed: int = 0) -> None:
     region — container formats like zip tolerate flips in their header/
     directory slack, which would make the corruption a no-op), ``"torn"``
     truncates to a seeded fraction (a torn write's committed prefix).
-    The standalone helper tests and bench use to corrupt
-    *already-committed* artifacts."""
+    The standalone helper tests use to corrupt *already-committed*
+    artifacts."""
     size = os.path.getsize(path)
     if size == 0:
         raise ValueError(f"cannot corrupt empty file {path!r}")
@@ -199,7 +199,7 @@ class FaultPlan:
     block see it too), or pass the plan explicitly where an API takes
     one (``plan.wrap_source``).  ``fires`` records every fault that
     actually fired as ``(scope, index, kind)`` — the audit log recovery
-    tests and the bench's steps-replayed accounting read."""
+    tests read."""
 
     seed: int = 0
     _specs: List[_FaultSpec] = field(default_factory=list)

@@ -22,8 +22,7 @@ the fallback path.
 
 The per-restart :class:`RecoveryEvent` records MTTR (detect -> restore
 complete, which is where training resumes) measured against the
-manager's restore timestamp — the number ``bench.py::bench_recovery``
-reports.
+manager's restore timestamp.
 """
 
 from __future__ import annotations
@@ -57,8 +56,7 @@ class RecoveryEvent:
     I/O failure, worker death) or ``"resize"`` (planned elasticity: a
     membership change detected at a chunk boundary); both ride the same
     restore-and-continue transition, so ``mttr_s`` doubles as the
-    resize-pause wall (detect -> restore complete) the elastic bench
-    leg reports."""
+    resize-pause wall (detect -> restore complete)."""
     error: str
     detected_at: float
     backoff_s: float = 0.0

@@ -112,8 +112,8 @@ class MicroBatcher:
         #: making one slot free means at worst one spurious shed at the
         #:  saturation boundary — admission control's documented
         #: semantics either way.  The authoritative check under the
-        #: lock still guards every admit.  (Toggle exists for the
-        #: bench_multitenant A/B.)
+        #: lock still guards every admit.  (A test turns it off to hold
+        #: the locked path.)
         self.fast_shed = True
 
     def _shed_error(self) -> ServingOverloadedError:

@@ -33,8 +33,8 @@ host table at construction (publish-time calibration — ``rebind``'s
 fresh cache re-calibrates each generation).  The codes pool plus the
 scales pool cost ~(1 + 4/row_dim)/4 of the f32 pool at the same
 ``capacity_blocks`` — so at a FIXED device byte budget an int8 cache
-holds ~2x the resident rows (the models-per-chip multiplier
-``bench_int8`` measures).  A lookup gathers codes and scales and
+holds more resident rows (the models-per-chip multiplier).  A lookup
+gathers codes and scales and
 dequantizes the gathered rows in-program (one exact cast + one f32
 multiply; the f32 table never materializes); the oversized-batch bypass
 dequantizes the SAME codes host-side, so cached and bypassed batches
@@ -290,14 +290,6 @@ class EmbeddingRowCache:
         return sum(int(np.prod(p.shape)) * p.dtype.itemsize
                    for p in itertools.chain(self._pools.values(),
                                             self._scale_pools.values()))
-
-    def reset_counters(self) -> None:
-        """Zero the hit/miss ledger (bench legs separate warm-up from
-        the measured window); the resident set is untouched."""
-        self.hits = self.misses = 0
-        self.block_faults = self.evictions = self.lookups = 0
-        self.bypasses = 0
-        self._fault_s = 0.0
 
     def snapshot(self) -> Dict[str, Any]:
         return {

@@ -55,7 +55,7 @@ analog, built from the same parts:
   params-only HBM cost: a replicated tenant keeps a surviving chip
   through any single loss and its failover window is ONE dispatch (no
   re-admission, no warm), while an unreplicated tenant pays the
-  re-warm window.  The A/B is measured in ``bench.py::bench_failover``.
+  re-warm window.
 
 Observability: fleet-health gauges under the ``failover`` metric group
 (``chips_live``/``chips_down``/``brownout_level``/counters), and
@@ -325,7 +325,7 @@ class FleetHealth:
 @dataclass(frozen=True)
 class FailoverReport:
     """One failover, detection to recovery — the audit record chaos
-    tests and ``bench_failover`` read.  ``moved`` tenants lost every
+    tests read.  ``moved`` tenants lost every
     chip and paid the re-admission (re-warm) window; ``replicated``
     tenants kept a surviving replica, so their window was one dispatch.
     ``generation`` is the placement generation the re-placement

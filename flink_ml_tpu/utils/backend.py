@@ -57,7 +57,7 @@ def force_virtual_cpu(n_devices: int, *, verify: bool = True) -> None:
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compile cache and return its directory.
 
-    Entry points (``chip_smoke.py``, ``bench.py``, ``tests_tpu``) call
+    Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``tests_tpu``) call
     this before their first compile.  Where ``JAX_COMPILATION_CACHE_DIR``
     is set JAX already reads the directory from it and nothing is set
     here; otherwise the cache lives at ``<checkout>/.jax_cache`` — a

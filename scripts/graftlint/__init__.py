@@ -26,8 +26,6 @@ Passes (see ``scripts/graftlint/passes/``):
 - ``lock-discipline``         no Lock held across a blocking call
 - ``collective-consistency``  collectives inside manual regions stay
                               well-formed across branches
-- ``bench-schema``            bench.py <-> BENCH_SCHEMA.md drift (non-AST,
-                              delegates to check_bench_schema)
 
 Wired into tier-1 via ``tests/test_graftlint.py``.
 """

@@ -2,7 +2,6 @@
 ``# graftlint: disable=<id>`` and the baseline file key on."""
 
 from .atomic_writes import AtomicWritesPass
-from .bench_schema import BenchSchemaPass
 from .collectives import CollectiveConsistencyPass
 from .donation import DonationSafetyPass
 from .host_sync import HostSyncPass
@@ -18,10 +17,8 @@ ALL_PASSES = (
     CollectiveConsistencyPass,
     KernelRegistryPass,
     UnfencedTimingPass,
-    BenchSchemaPass,
 )
 
-__all__ = ["ALL_PASSES", "AtomicWritesPass", "BenchSchemaPass",
-           "CollectiveConsistencyPass", "DonationSafetyPass",
-           "HostSyncPass", "KernelRegistryPass", "LockDisciplinePass",
-           "UnfencedTimingPass"]
+__all__ = ["ALL_PASSES", "AtomicWritesPass", "CollectiveConsistencyPass",
+           "DonationSafetyPass", "HostSyncPass", "KernelRegistryPass",
+           "LockDisciplinePass", "UnfencedTimingPass"]

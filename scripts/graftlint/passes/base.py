@@ -1,6 +1,5 @@
 """Pass protocol: a pass declares an ``id``, the repo-relative ``roots``
-it scans, and implements ``check_module``.  Whole-repo (non-AST) passes
-override ``run`` instead."""
+it scans, and implements ``check_module``."""
 
 from __future__ import annotations
 
@@ -18,8 +17,6 @@ class LintPass:
     describes: str = ""
     #: repo-relative directories/files scanned by default
     roots: Sequence[str] = ()
-    #: True = findings can never be grandfathered via the baseline file
-    baseline_exempt: bool = False
     #: True = ``roots`` define WHERE THE CONVENTION APPLIES (the
     #: durable layer, the step trees) and explicit paths can only
     #: narrow them; False = ``roots`` are just the default scan surface

@@ -5,10 +5,8 @@ JAX dispatch is asynchronous: ``t0 = perf_counter(); jitted(...);
 perf_counter() - t0`` measures the *enqueue*, not the work.  The repo's
 one idiom is a fence between the jitted call and the clock read: a
 ``device_get``/``np.asarray`` of a probe value, or ``block_until_ready``
-(``utils/profiler.StepTimer`` / ``fenced_call``).  bench.py hand-rolled
-that idiom in half a dozen
-places before ISSUE 13 consolidated them onto ``fenced_call``; this
-pass keeps the hand-rolled-without-the-fence form from coming back.
+(``utils/profiler.StepTimer``).  This pass keeps the hand-rolled form
+without the fence out of the code that times.
 
 Detection (per function, events in source order):
 
@@ -17,8 +15,7 @@ Detection (per function, events in source order):
   module (assignment or decorator, ``partial(jax.jit, ...)``
   included), or a direct ``jax.jit(...)(...)`` invocation;
 - **fence** — ``np.asarray`` / ``jax.device_get`` /
-  ``.block_until_ready()`` / ``.item()`` / ``StepTimer.stop`` /
-  ``fenced_call`` (which fences internally);
+  ``.block_until_ready()`` / ``.item()`` / ``StepTimer.stop``;
 - **read** — any other ``time.perf_counter()`` call (the
   ``perf_counter() - t0`` form).
 
@@ -27,7 +24,7 @@ has no fence after it is a finding.  Heuristic by design (the
 host-sync stance): timing code in this repo is straight-line
 start/call/fence/read, so positional order is the control flow that
 matters.  Scope-fixed to the trees that TIME device work as their
-product — ``bench.py`` and ``flink_ml_tpu/obs`` — where an unfenced
+product — ``benchmarks/`` and ``flink_ml_tpu/obs`` — where an unfenced
 number would be published as a measurement.
 """
 
@@ -43,8 +40,7 @@ from .base import LintPass
 _PARTIAL = {"functools.partial", "partial"}
 
 #: call qualnames / attribute names that fence the dispatch stream
-_FENCE_QUALS = {"numpy.asarray", "jax.device_get", "device_get",
-                "fenced_call", "flink_ml_tpu.utils.profiler.fenced_call"}
+_FENCE_QUALS = {"numpy.asarray", "jax.device_get", "device_get"}
 _FENCE_ATTRS = {"block_until_ready", "item", "stop", "fetch"}
 
 _PERF_QUALS = {"time.perf_counter", "perf_counter"}
@@ -124,13 +120,13 @@ def _event(mod: ModuleInfo, node: ast.AST, jitted: Set[str]
 class UnfencedTimingPass(LintPass):
     id = "unfenced-timing"
     describes = ("perf_counter timing that brackets a jitted call needs "
-                 "a device fence (device_get/np.asarray/fenced_call) "
+                 "a device fence (device_get/np.asarray/block_until_ready) "
                  "before the clock is read")
-    roots = ("bench.py", "flink_ml_tpu/obs")
+    roots = ("benchmarks", "flink_ml_tpu/obs")
     scope_fixed = True      # the convention applies to the timing trees
-    hint = ("route the timing through utils/profiler.fenced_call (or "
-            "fetch a probe of the result with np.asarray/jax.device_get "
-            "before reading the clock)")
+    hint = ("fetch a probe of the result with np.asarray/jax.device_get "
+            "(or stop a utils/profiler.StepTimer on it) before reading "
+            "the clock")
 
     def check_module(self, mod: ModuleInfo,
                      project: Project) -> List:

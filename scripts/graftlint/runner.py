@@ -117,9 +117,8 @@ def run(repo: str = REPO, passes: Optional[Sequence] = None,
         baseline_path: str = BASELINE,
         enforce_suppressions: Optional[bool] = None) -> Report:
     """Run ``passes`` (default: all) over ``repo``; apply suppressions
-    and the baseline.  ``paths`` narrows AST passes to explicit files or
-    directories (whole-repo passes like bench-schema skip themselves
-    when a narrowing is active — see ``BenchSchemaPass.run``)."""
+    and the baseline.  ``paths`` narrows the passes to explicit files or
+    directories."""
     project = Project(repo=repo)
     chosen = list(passes) if passes is not None else all_passes()
     if enforce_suppressions is None:
@@ -134,7 +133,6 @@ def run(repo: str = REPO, passes: Optional[Sequence] = None,
     raw: List[Finding] = []
     for p in chosen:
         raw += p.run(project, paths=paths)
-    no_baseline = {p.id for p in chosen if p.baseline_exempt}
 
     report = Report(files_scanned=len(project.scanned))
     by_rel = {m.rel: m for m in project._cache.values()}
@@ -153,8 +151,7 @@ def run(repo: str = REPO, passes: Optional[Sequence] = None,
     by_fp = {e.fingerprint: e for e in entries}
     kept = []
     for f in report.findings:
-        entry = None if f.pass_id in no_baseline \
-            else by_fp.get(f.fingerprint)
+        entry = by_fp.get(f.fingerprint)
         if entry is not None:
             entry.hits += 1
             report.baselined.append(f)

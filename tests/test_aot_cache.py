@@ -330,8 +330,7 @@ def test_second_process_warms_with_zero_compiles(cache, tmp_path):
                for b in warm["warmup"]["buckets"].values())
     assert all(b["source"] == "compile"
                for b in cold["warmup"]["buckets"].values())
-    # and the measured number the bench leg headlines: warm-up wall
-    # collapses when compiles become deserializes
+    # warm-up wall collapses when compiles become deserializes
     assert warm["warmup"]["wall_s"] < cold["warmup"]["wall_s"]
 
 

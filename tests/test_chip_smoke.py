@@ -1,10 +1,10 @@
 """The entry points refuse to look fine without the chip.
 
-``chip_smoke.py`` and ``bench.py`` take the backend JAX gives them and
-fail when it is not a TPU — before any model code runs, with no CPU
-fallback — and the compile-cache helper puts the cache where it can be
-found again.  (The legs themselves only mean something on the chip; the
-driver and ``chiprun`` run them there.)
+``chip_smoke.py`` and ``benchmarks/run.py`` take the backend JAX gives
+them and fail when it is not a TPU — before any model code runs, with no
+CPU fallback — and the compile-cache helper puts the cache where it can
+be found again.  (The legs and the cells themselves only mean something on
+the chip; the driver and ``chiprun`` run them there.)
 """
 
 import os
@@ -36,71 +36,51 @@ def test_chip_smoke_without_tpu_fails_before_any_model_code(tmp_path):
     assert not (tmp_path / "chiprun_out").exists()
 
 
-def test_bench_without_tpu_needs_an_explicit_cpu(tmp_path):
-    """JAX falls back to the CPU by itself when it finds no accelerator;
-    the bench must not follow it unless JAX_PLATFORMS says cpu."""
-    import bench
+@pytest.fixture
+def benchmark_run(monkeypatch):
+    """``benchmarks/run.py`` as a module of this process, whose JAX the
+    suite holds to the CPU: a machine that has no TPU."""
+    import importlib.util
 
-    fake_cpu = {"platform": "cpu", "kind": "cpu", "count": 1}
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(bench, "_device_line", lambda: fake_cpu)
-        mp.delenv("JAX_PLATFORMS", raising=False)
-        assert bench.main() == 1
-        mp.setenv("JAX_PLATFORMS", "")
-        assert bench.main() == 1
-
-
-def _stub_legs(mp, bench, raising=()):
-    def leg(name):
-        def run(results):
-            if name in raising:
-                raise RuntimeError(f"{name} broke")
-            results.setdefault("logreg_epochs_per_sec", 1.0)
-            results.setdefault("vs_baseline", 1.0)
-        run.__name__ = name
-        return run
-
-    for name in [n for n in vars(bench) if n.startswith("bench_")]:
-        mp.setattr(bench, name, leg(name))
-    mp.setenv("JAX_PLATFORMS", "cpu")
-
-
-def test_a_raising_bench_leg_makes_the_exit_code_nonzero(capsys):
-    import json
-
-    import bench
     from flink_ml_tpu.utils import backend
 
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(backend, "enable_compile_cache", lambda: "unused")
-        _stub_legs(mp, bench)
-        assert bench.main() == 0
-        ok_summary = json.loads(capsys.readouterr().out.splitlines()[-1])
-        assert ok_summary["failed_legs"] == []
-        assert ok_summary["device"]["platform"] == "cpu"
-
-        _stub_legs(mp, bench, raising={"bench_kmeans"})
-        assert bench.main() == 1
-        lines = capsys.readouterr().out.splitlines()
-        assert json.loads(lines[-1])["failed_legs"] == ["bench_kmeans"]
-        # the other legs still ran and the full line still parses
-        full = json.loads(lines[-2])
-        assert "bench_kmeans_error" in full["notes"]
-        assert full["value"] == 1.0
-        assert "cpu_rehearsal" in full["notes"]
+    monkeypatch.syspath_prepend(os.path.join(_REPO, "benchmarks"))
+    monkeypatch.setattr(backend, "enable_compile_cache", lambda: "unused")
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_run", os.path.join(_REPO, "benchmarks", "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    return run
 
 
-def test_mfu_only_for_a_chip_in_the_peaks_table(monkeypatch):
-    import bench
+@pytest.mark.parametrize("platforms", [None, ""])
+def test_benchmark_without_tpu_does_not_fall_back(
+        benchmark_run, platforms, monkeypatch, capsys):
+    """JAX falls back to the CPU by itself when it finds no accelerator;
+    the benchmark must not follow it unless JAX_PLATFORMS says cpu."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(SystemExit) as exit_:
+        benchmark_run.main(["--workload", "kmeans_hibench.fit",
+                            "--seed", "1", "--seconds", "1"])
+    assert exit_.value.code == 1
+    out, err = capsys.readouterr()
+    assert out == ""                                 # no result line
+    assert "does not fall back" in err
+    assert "cpu-rehearsal" not in err
 
-    line = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
-    monkeypatch.setattr(bench, "_device_line", lambda: line)
-    assert bench._mfu(19.7e12, 4) == 0.1
-    line["kind"] = "TPU v9 imaginary"
-    with pytest.raises(KeyError, match="no published peak"):
-        bench._mfu(1e12, 4)
-    line.update(platform="cpu", kind="cpu")
-    assert bench._mfu(1e12, 4) is None
+
+def test_benchmark_refuses_an_unknown_chip_kind(benchmark_run):
+    """A roofline share is a share of a published peak: a chip the table
+    lacks is an error, never a default."""
+    from harness import files
+
+    assert files.peaks("TPU v5 lite") == {
+        "flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    with pytest.raises(SystemExit, match="no peaks for device kind"):
+        files.peaks("TPU v9 imaginary")
 
 
 def test_compile_cache_helper_env_wins_else_fixed_checkout_path(monkeypatch):

@@ -384,33 +384,25 @@ def test_prefetch_chunks_reject_put_fn():
 
 @pytest.mark.slow
 def test_chunk_sweep_amortization(tmp_path):
-    """The INGEST_SCALING.md amortization table's generator: epoch time
-    and dispatch count over W in (1, 2, 4, 8, 16) on the CPU smoke
-    shape.  Asserts the >= 4x dispatch-count reduction at W=8 the bench
-    acceptance requires, and bit-exactness across the whole sweep."""
+    """Over W in (1, 2, 4, 8, 16): W=1 makes one dispatch a batch, W=8 at
+    most a quarter of that, and the coefficients are bit-equal across the
+    whole sweep."""
     cache = _lr_cache(tmp_path, "sweep", n=1 << 14, d=16, seed=9)
     cfg = SGDConfig(learning_rate=0.5, max_epochs=3, tol=0.0)
     n_batches = (1 << 14) // 512    # 32
 
-    rows = []
+    by_w = {}
     ref = None
     for W in (1, 2, 4, 8, 16):
         info = {}
-        t0 = time.perf_counter()
         state, _ = sgd_fit_outofcore(
             logistic_loss, lambda: DataCacheReader(cache, batch_rows=512),
             num_features=16, config=cfg, steps_per_dispatch=W,
             cache_decoded=False, stream_info=info)
-        epoch_ms = (time.perf_counter() - t0) / cfg.max_epochs * 1000
-        dispatches = info["dispatches_per_epoch"][-1]
-        rows.append((W, dispatches, round(epoch_ms, 1)))
+        by_w[W] = info["dispatches_per_epoch"][-1]
         if ref is None:
             ref = state.coefficients
         else:
             np.testing.assert_array_equal(state.coefficients, ref)
-    print("\nW  dispatches/epoch  epoch_ms")
-    for W, disp, ms in rows:
-        print(f"{W:<3}{disp:<18}{ms}")
-    by_w = {w: d for w, d, _ in rows}
     assert by_w[1] == n_batches
     assert by_w[1] / by_w[8] >= 4.0

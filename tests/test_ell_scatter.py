@@ -5,8 +5,8 @@ Tier-1 (CPU) coverage: layout construction (host + device builders must
 agree, overflow and heavy-hitter routing must be exact), the csum/pick
 math against a plain numpy scatter, and the full `_mixed_update_ell`
 step against the `_mixed_update` oracle.  The Mosaic kernel itself is
-compiled and parity-checked on real TPU by bench.py before anything is
-timed (same stance as the KMeans kernel, bench.py)."""
+compiled and parity-checked on the chip by `tests_tpu/` and
+`chip_smoke.py` (same stance as the KMeans kernel)."""
 
 import numpy as np
 import pytest

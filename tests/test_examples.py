@@ -16,7 +16,7 @@ import pytest
 
 _EXAMPLES_DIR = os.path.join(os.path.dirname(__file__), "..", "examples")
 
-# measured on the 1-core bench host (CPU mesh): fast <= ~12s each
+# on the CPU mesh: fast <= ~12s each
 _FAST = [
     "kmeans_example.py",
     "pipeline_example.py",

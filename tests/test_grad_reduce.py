@@ -1,7 +1,7 @@
 """Gradient-reduction subsystem tests (parallel/grad_reduce.py): every
 mode against a numpy single-program oracle on the 8-device CPU mesh, the
 EF residual recursion, the hierarchical ICI x DCN composition, and the
-bytes-on-wire accounting the bench comm leg reports."""
+bytes-on-wire accounting."""
 
 import jax
 import jax.numpy as jnp
@@ -477,7 +477,11 @@ def test_outofcore_reduced_chunked_bit_exact_vs_w1(tmp_path):
     s8, log8 = sgd_fit_outofcore(logistic_loss, reader, num_features=8,
                                  config=cfg, steps_per_dispatch=8)
     np.testing.assert_array_equal(s1.coefficients, s8.coefficients)
-    np.testing.assert_array_equal(log1, log8)
+    # a step's loss is a float32 reduction over its batch, and XLA:CPU
+    # (jax 0.9.0) does not order it the same in the program for a chunk
+    # of 1 as in the one for a chunk of 8 (found: 6.5e-8 relative), so the
+    # loss log is held to 1e-6; the parameters are bit-equal
+    np.testing.assert_allclose(log1, log8, rtol=1e-6, atol=0)
 
 
 def test_outofcore_rejects_compressed_sparse_layouts(tmp_path):

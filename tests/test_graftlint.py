@@ -32,7 +32,6 @@ if REPO not in sys.path:
 from scripts.graftlint import runner  # noqa: E402
 from scripts.graftlint.core import (  # noqa: E402
     EXCLUDE_DIRS,
-    Finding,
     ModuleInfo,
     Project,
     iter_py_files,
@@ -126,25 +125,6 @@ def test_json_dash_emits_parseable_stdout():
     assert "graftlint clean" in proc.stderr
 
 
-def test_bench_schema_findings_cannot_be_baselined(tmp_path):
-    """Review regression: schema drift is never grandfathered — a
-    baseline entry naming a bench-schema finding must not silence it."""
-    from scripts.graftlint.passes.bench_schema import BenchSchemaPass
-
-    assert BenchSchemaPass.baseline_exempt
-    drifting = BenchSchemaPass()
-    fake = Finding(pass_id="bench-schema", path="bench.py", line=0,
-                   message="drift", symbol="<schema>")
-    drifting.run = lambda project, paths=None: [fake]
-    drifting.baseline_exempt = True
-    baseline = tmp_path / "baseline.txt"
-    baseline.write_text("bench-schema bench.py::<schema>  # nope\n")
-    report = runner.run(passes=[drifting], baseline_path=str(baseline),
-                        enforce_suppressions=False)
-    assert [f.pass_id for f in report.findings] == ["bench-schema"]
-    assert report.baselined == []
-
-
 def test_nonexistent_explicit_path_fails_loudly(tmp_path):
     """Review regression: a typo'd CI path must never pass by checking
     zero files — the runner raises (legacy-checker parity) and the CLI
@@ -199,7 +179,7 @@ def test_pass_catalog_covers_the_contract():
     ids = {cls.id for cls in ALL_PASSES}
     assert ids == {"host-sync", "atomic-writes", "donation-safety",
                    "lock-discipline", "collective-consistency",
-                   "kernel-registry", "unfenced-timing", "bench-schema"}
+                   "kernel-registry", "unfenced-timing"}
 
 
 # ---------------------------------------------------------------------------
@@ -1054,8 +1034,8 @@ def test_kernel_registry_scope_is_models_and_retrieval_trees():
 
 def test_unfenced_timing_flags_bare_bracketing(tmp_path):
     """The can't-fail seeded fixture: perf_counter brackets a jitted
-    call with no fence — the dispatch-enqueue-not-the-work bug bench.py
-    hand-dodged per leg before fenced_call."""
+    call with no fence — it times the dispatch's enqueue, not the
+    work."""
     from scripts.graftlint.passes.unfenced_timing import UnfencedTimingPass
 
     problems = _check(UnfencedTimingPass(), tmp_path, """\
@@ -1075,7 +1055,7 @@ def test_unfenced_timing_flags_bare_bracketing(tmp_path):
 
 
 def test_unfenced_timing_accepts_fenced_forms(tmp_path):
-    """np.asarray probe fetch, jax.device_get, and fenced_call all
+    """np.asarray probe fetch, jax.device_get, and StepTimer.stop all
     satisfy the fence; host-only timing (no jitted call inside the
     bracket) is never flagged."""
     from scripts.graftlint.passes.unfenced_timing import UnfencedTimingPass
@@ -1085,7 +1065,7 @@ def test_unfenced_timing_accepts_fenced_forms(tmp_path):
         import jax
         import numpy as np
 
-        from flink_ml_tpu.utils.profiler import fenced_call
+        from flink_ml_tpu.utils.profiler import StepTimer
 
         run = jax.jit(lambda x: x * 2)
 
@@ -1101,9 +1081,11 @@ def test_unfenced_timing_accepts_fenced_forms(tmp_path):
             jax.device_get(y)
             return time.perf_counter() - t0
 
-        def measure_fenced(x):
+        def measure_timer(x):
             t0 = time.perf_counter()
-            y, s = fenced_call(run, x)
+            timer = StepTimer().start()
+            y = run(x)
+            timer.stop(y)
             return time.perf_counter() - t0
 
         def measure_host_only(rows):
@@ -1187,21 +1169,20 @@ def test_unfenced_timing_nested_defs_are_their_own_scope(tmp_path):
 
 
 def test_unfenced_timing_scope_and_repo_clean():
-    """Scope-fixed to the trees that publish measurements (bench.py +
-    obs/), and both are clean — the consolidation satellite actually
-    routed the hand-rolled copies through fenced_call."""
+    """Scope-fixed to the trees that publish measurements (benchmarks/
+    + obs/), and both are clean."""
     from scripts.graftlint.passes.unfenced_timing import UnfencedTimingPass
 
     p = UnfencedTimingPass()
     assert p.scope_fixed
-    assert set(p.roots) == {"bench.py", "flink_ml_tpu/obs"}
+    assert set(p.roots) == {"benchmarks", "flink_ml_tpu/obs"}
     project = Project(repo=REPO)
     assert [f.render() for f in p.run(project)] == []
     # the walk genuinely visited both roots
     scanned = {os.path.relpath(s, REPO) for s in project.scanned}
-    assert "bench.py" in scanned
-    assert any(s.startswith(os.path.join("flink_ml_tpu", "obs"))
-               for s in scanned)
+    for root in (os.path.join("benchmarks", "runners"),
+                 os.path.join("flink_ml_tpu", "obs")):
+        assert any(s.startswith(root) for s in scanned)
 
 
 def test_elastic_module_visited_by_lock_and_host_sync_passes():

@@ -603,7 +603,7 @@ def test_microbatcher_fast_shed_never_touches_the_lock():
     batcher._cond = _PoisonedLock()             # saturation reached
     with pytest.raises(ServingOverloadedError, match="queue full"):
         batcher.submit(t.take(1))               # lock-free shed
-    batcher.fast_shed = False                   # the bench A/B toggle
+    batcher.fast_shed = False                   # the locked path
     with pytest.raises(AssertionError, match="fast path"):
         batcher.submit(t.take(1))               # legacy path locks
 

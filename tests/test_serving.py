@@ -459,8 +459,8 @@ def test_concurrent_clients_coalesce_and_stay_exact():
 
 @pytest.mark.slow
 def test_serving_concurrency_sweep():
-    """The bench.py serving sweep shape (1/8/64 clients), asserted for
-    correctness and shed-free completion at ample capacity."""
+    """1, 8 and 64 concurrent clients: every answer equals the offline
+    transform and nothing is shed at ample capacity."""
     model = _fit_lr()
     feats = _lr_table(n=512, seed=13).drop("label")
     endpoint = serve_model(model, feats.take(1), max_batch_rows=256,

@@ -5,7 +5,7 @@ lowering rules without a chip, which is where the block-shape class of
 error lives (a ``(1, d)`` block, a batched dot, a 3-D gather).  Every
 ``pallas`` entry the registry selects by itself on a TPU lowers
 here at its smallest supported shape and at the full width
-``chip_smoke.py`` runs (or, off the smoke's path, the bench shape).
+``chip_smoke.py`` runs (or, off the smoke's path, the Criteo shape).
 
 Lowering is not compiling: Mosaic inside libtpu can still refuse what
 lowers (VMEM limit, unaligned slices).  That is ``tests_tpu``'s job, on
