@@ -63,6 +63,9 @@ OP_SIGNATURES = {
     "kmeans_update_stats": [("", (1 << 20, 64, 256, "euclidean"))],
     "kmeans_workset_update": [("", (1 << 20, 64, 256, "euclidean", 1))],
     "routed_table_grad": [("", ("gather", 13, 8192 * 26))],
+    # (rows, width) at Criteo's cardinalities; width 0: the wide table
+    "routed_adam_update": [("emb", (33_762_577, 16)),
+                           ("wide", (33_762_577, 0))],
     # (nprobe, k, dim, m, ksub, nlist, block)
     "retrieve": [("flat", (16, 10, 128, 0, 0, 1024, 1024)),
                  ("pq", (16, 10, 128, 16, 16, 1024, 1024))],

@@ -7,6 +7,7 @@ is the kernel's own business, an op's planning policy the model's):
 
 - ``ops/ell_scatter.py``      — ``ell_margin``, ``ell_scatter_apply``
 - ``ops/emb_grad.py`` / ``ops/emb_grad_pallas.py`` — ``routed_table_grad``
+- ``ops/adam_table_pallas.py`` — ``routed_adam_update``
 - ``models/common/gbt.py``    — ``gbt_level_histograms``
 - ``models/common/linear.py`` — ``linear_margins`` (stage convention)
 - ``models/clustering/kmeans.py`` — ``kmeans_assign`` (stage),
@@ -24,7 +25,7 @@ lookup), never at ``flink_ml_tpu.kernels`` import — that keeps the
 registry itself dependency-free and cycle-safe.
 """
 
-from .. import ops  # noqa: F401  (ell + kmeans + emb_grad + retrieve)
+from .. import ops  # noqa: F401  (ell + kmeans + emb_grad + adam + retrieve)
 from ..models.clustering import kmeans  # noqa: F401
 from ..models.common import gbt, linear  # noqa: F401
 from ..models.recommendation import widedeep  # noqa: F401

@@ -313,6 +313,9 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
                                            device=False, placement="auto")
                     span.note(
                         placement=route.placement,
+                        table_update=_table_update_name(
+                            _routed_adam_entries(route, lazy, n_dev == 1,
+                                                 self.EMBEDDING_DIM)),
                         fold_passes=route.fold_passes,
                         unique_max=int(route.unique_per_step.max()),
                         unique_mean=float(route.unique_per_step.mean()),
@@ -372,6 +375,7 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
             model._loss_log = list(np.asarray(jax.device_get(loss_buf)))
         model._vocab_sizes = tuple(int(v) for v in vocab_sizes)
         model.route_placement = None if route is None else route.placement
+        model.table_update = getattr(step_fn, "table_update", None)
         return model
 
     def fit_outofcore(self, make_reader, *, mesh=None,
@@ -715,6 +719,11 @@ class WideDeepModel(WideDeepParams, Model):
         #: (``ops/emb_grad.py``: "gather" or "scatter"); None where the
         #: fit took no route, or the model was not fitted here
         self.route_placement: Optional[str] = None
+        #: how the fit's routed step updated the tables: ``"fused"`` (the
+        #: optimizer's pass placed the touched rows' gradient itself) or
+        #: ``"dense_grad"`` (a table-shaped gradient was formed first);
+        #: None as ``route_placement`` is
+        self.table_update: Optional[str] = None
 
     @property
     def loss_log(self) -> List[float]:
@@ -862,6 +871,42 @@ class WideDeepModel(WideDeepParams, Model):
 _LAZY_TABLE_KEYS = ("emb", "wide_cat")
 
 
+def _on_one_device(x) -> bool:
+    """True for a concrete array that lies on one device (not for a
+    tracer, nor for an array replicated or sharded over a mesh)."""
+    sharding = getattr(x, "sharding", None)
+    return sharding is not None and len(sharding.device_set) == 1
+
+
+def _routed_adam_entries(route, lazy: bool, one_device: bool, emb_dim: int):
+    """The registry entries of op ``routed_adam_update`` that a routed
+    step updates its two tables with, by table, or None where it forms
+    the table-shaped gradients and hands them to optax with the towers'.
+    The op takes the ``scatter`` placement's run sums, is dense Adam, and
+    is one program on one device: the ``gather`` placement (small
+    vocabularies), LazyAdam and tables replicated over a mesh keep the
+    other path."""
+    if (route is None or lazy or route.placement != "scatter"
+            or not one_device):
+        return None
+    from ...kernels.registry import lookup
+
+    return {"emb": lookup("routed_adam_update",
+                          sig=(route.num_rows, emb_dim)),
+            "wide_cat": lookup("routed_adam_update",
+                               sig=(route.num_rows, 0))}
+
+
+def _table_update_name(adam_entries) -> str:
+    """``"fused"``: the optimizer's pass over the embedding table places
+    the touched rows' gradient itself (the ``pallas`` backend of
+    ``routed_adam_update``); ``"dense_grad"``: a table-shaped gradient is
+    formed first."""
+    fused = (adam_entries is not None
+             and adam_entries["emb"].backend == "pallas")
+    return "fused" if fused else "dense_grad"
+
+
 def _make_train_ops(params, lr: float, lazy: bool, route=None,
                     b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8):
     """Build ``(batch_step, opt_state0)`` for the Wide&Deep training loop.
@@ -883,6 +928,25 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
     wide sum and the deep tower), ``widedeep.table_grad`` (permutation
     gather, fold and placement, for both tables) and
     ``widedeep.optimizer`` (Adam over every parameter).
+
+    How the two tables are updated follows from what the builder sees,
+    not from a param (:func:`_routed_adam_entries`): under the
+    ``scatter`` placement with the tables on ONE device, ``table_grad``
+    ends at the run sums (``ops.emb_grad.routed_run_sums``: the touched
+    rows' folded gradient, ``(U, E)``) and the optimizer's scope updates
+    each table through registry op ``routed_adam_update``
+    (``ops/adam_table_pallas.py``), optax keeping the towers and the
+    step count both halves share.  On a TPU the embedding table takes the
+    op's fused pass, which places the run sums while ``p``, ``m`` and
+    ``v`` stream through VMEM: no table-shaped gradient exists
+    (``table_update == "fused"``, on the returned step, the model and the
+    ``fit.arrange.route`` span).  The wide table's vector of scalars and
+    every table off the TPU take the op's XLA composition (zeros,
+    sorted unique scatter-set, Adam in optax's expressions).  The
+    ``gather`` placement and tables replicated over a mesh form the
+    dense gradient and hand it to optax with the rest, as before
+    (``"dense_grad"``).  Either way it is dense Adam in float32 on every
+    row, every step.
 
     ``lazy=True`` (LazyAdam, ``lazyEmbeddingOptimizer``): dense Adam
     touches every row of the ``(total_vocab, emb_dim)`` embedding and
@@ -931,10 +995,41 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
             raise ValueError(
                 "routed table gradients are a dense-Adam path; disable "
                 "lazyEmbeddingOptimizer or set routedEmbeddingGrad='off'")
-        # registry op ``routed_table_grad``, resolved ONCE at step-build:
-        # the fused Mosaic fold (ops/emb_grad_pallas.py) on TPU, the XLA
-        # routed path elsewhere — the step body never branches on backend
-        route_apply = route.resolve_apply()
+        adam_entries = _routed_adam_entries(
+            route, lazy, _on_one_device(params["emb"]),
+            params["emb"].shape[1])
+        if adam_entries is None:
+            # registry op ``routed_table_grad``, resolved ONCE at
+            # step-build: the step body never branches on backend
+            table_grad = route.resolve_apply()
+
+            def update(params, opt_state, g_rest, g_tables, out_ids):
+                updates, opt_state = opt.update({**g_rest, **g_tables},
+                                                opt_state, params)
+                return optax.apply_updates(params, updates), opt_state
+        else:
+            from ...ops.emb_grad import routed_run_sums
+
+            def table_grad(g_flat, order, sorted_ids, out_pos, out_ids):
+                return routed_run_sums(g_flat, order, sorted_ids, out_pos,
+                                       fold_passes=route.fold_passes)
+
+            def update(params, opt_state, g_rest, run_sums, out_ids):
+                # optax for the towers; op ``routed_adam_update`` for the
+                # tables, on the step count the towers' update returns
+                tables, rest = split(params)
+                adam, *tail = opt_state
+                (mu_t, mu_r), (nu_t, nu_r) = split(adam.mu), split(adam.nu)
+                updates, (adam, *tail) = opt.update(
+                    g_rest, (adam._replace(mu=mu_r, nu=nu_r), *tail), rest)
+                rest = optax.apply_updates(rest, updates)
+                for k in _LAZY_TABLE_KEYS:
+                    tables[k], mu_t[k], nu_t[k] = adam_entries[k].fn(
+                        tables[k], mu_t[k], nu_t[k], run_sums[k], out_ids,
+                        adam.count, lr=lr, b1=b1, b2=b2, eps=eps)
+                adam = adam._replace(mu={**adam.mu, **mu_t},
+                                     nu={**adam.nu, **nu_t})
+                return {**rest, **tables}, (adam, *tail)
 
         def batch_step(params, opt_state, dense, cat_ids, labels, mask,
                        *route_arrays):
@@ -953,18 +1048,18 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
                     loss_rows, argnums=(0, 1, 2))(rest, emb_rows, wide_rows)
             emb_dim = emb_rows.shape[-1]
             with jax.named_scope("widedeep.table_grad"):
-                grads = {
-                    **g_rest,
-                    "emb": route_apply(g_emb.reshape(-1, emb_dim),
-                                       *route_arrays),
-                    "wide_cat": route_apply(g_wide.reshape(-1),
-                                            *route_arrays),
+                g_tables = {
+                    "emb": table_grad(g_emb.reshape(-1, emb_dim),
+                                      *route_arrays),
+                    "wide_cat": table_grad(g_wide.reshape(-1),
+                                           *route_arrays),
                 }
             with jax.named_scope("widedeep.optimizer"):
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                params, opt_state = update(params, opt_state, g_rest,
+                                           g_tables, route_arrays[-1])
             return params, opt_state, loss
 
+        batch_step.table_update = _table_update_name(adam_entries)
         return batch_step, opt.init(params)
     if not lazy:
         def batch_step(params, opt_state, dense, cat_ids, labels, mask):

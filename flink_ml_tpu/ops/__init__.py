@@ -1,6 +1,8 @@
+from . import adam_table_pallas  # noqa: F401  (registers routed_adam_update)
 from .emb_grad import (  # noqa: F401
     EmbGradRoute,
     emb_grad_route,
+    routed_run_sums,
     routed_table_grad,
     routed_table_grad_gather,
 )

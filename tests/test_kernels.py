@@ -18,6 +18,8 @@ What these tests pin down:
   new backend without a parity harness fails this file.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -103,7 +105,7 @@ def test_catalog_registers_every_documented_op():
     for op in ("ell_margin", "ell_scatter_apply", "gbt_level_histograms",
                "kmeans_assign", "kmeans_update_stats",
                "kmeans_workset_update", "linear_margins", "retrieve",
-               "routed_table_grad", "widedeep_scores"):
+               "routed_adam_update", "routed_table_grad", "widedeep_scores"):
         assert op in ops, f"catalog lost op {op}"
     # every op has the automatic non-TPU fallback registered
     for op in ops:
@@ -405,6 +407,140 @@ def _parity_routed_table_grad(backends):
         np.testing.assert_array_equal(got, ref, err_msg=b)
 
 
+def _adam_case(n, e, block_n, *, touched=40, full_block=None,
+               empty_block=None, pads=24, seed=9):
+    """A table of ``n`` rows (``e`` 0: the scalar wide table) with Adam
+    state from an earlier step, and the run sums of one step: ``touched``
+    random rows, every row of block ``full_block``, none of block
+    ``empty_block``, then ``pads`` padded entries (ids ``n + rank``, zero
+    sums) as ``emb_grad_route`` writes them."""
+    rng = np.random.default_rng(seed)
+    shape = (n, e) if e else (n,)
+    block = np.arange(n) // block_n
+    ids = rng.choice(np.flatnonzero(block != empty_block), touched,
+                     replace=False)
+    if full_block is not None:
+        ids = np.union1d(ids, np.flatnonzero(block == full_block))
+    ids = np.sort(ids)
+    out_ids = np.concatenate([ids, n + np.arange(pads)]).astype(np.int32)
+    sums = rng.normal(size=(out_ids.size,) + shape[1:]).astype(np.float32)
+    sums[ids.size:] = 0.0
+    p = rng.normal(size=shape).astype(np.float32)
+    m, v = np.zeros(shape, np.float32), np.zeros(shape, np.float32)
+    hist = rng.choice(n, n // 3, replace=False)   # rows with a history
+    m[hist] = 0.1 * rng.normal(size=(hist.size,) + shape[1:])
+    v[hist] = np.square(m[hist])
+    return ids, tuple(map(jnp.asarray, (p, m, v))), jnp.asarray(sums), \
+        jnp.asarray(out_ids)
+
+
+_ADAM = dict(lr=1e-2, b1=0.9, b2=0.999, eps=1e-8)
+
+
+def _adam_backends(block_n):
+    return {"xla": lookup("routed_adam_update", backend="xla").fn,
+            "pallas": partial(
+                lookup("routed_adam_update", backend="pallas").fn,
+                block_n=block_n, interpret=True)}
+
+
+def _parity_routed_adam_update(backends):
+    assert sorted(backends) == ["pallas", "xla"]
+    _, state, sums, out_ids = _adam_case(700, 16, 128, full_block=1,
+                                         empty_block=3)
+    outs = {b: fn(*state, sums, out_ids, jnp.int32(3), **_ADAM)
+            for b, fn in _adam_backends(128).items()}
+    for got, ref, name in zip(outs["pallas"], outs["xla"], "pmv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-7, err_msg=name)
+
+
+# Each case names what its table and its step's ids hold: a last block of
+# 60 of 128 rows, a block no id falls in, a block with an id in every row,
+# padded ids behind the real ones, a table of one block, both payload
+# widths (``e`` 0 is the wide table's vector of scalars).
+@pytest.mark.parametrize("n,e,block_n,kw", [
+    pytest.param(700, 16, 128, {}, id="ragged-last-block"),
+    pytest.param(700, 16, 128, {"empty_block": 2}, id="untouched-block"),
+    pytest.param(700, 16, 128, {"full_block": 4},
+                 id="block-touched-in-every-row"),
+    pytest.param(700, 16, 128, {"full_block": 5},
+                 id="ragged-block-touched-in-every-row"),
+    pytest.param(640, 16, 128, {"pads": 0}, id="whole-blocks-no-padding"),
+    pytest.param(700, 16, 128, {"touched": 1, "pads": 300},
+                 id="one-row-many-pads"),
+    pytest.param(100, 16, 128, {"touched": 30}, id="table-of-one-block"),
+    pytest.param(3000, 16, 256,
+                 {"touched": 1500, "full_block": 0, "empty_block": 7},
+                 id="segments-pass-a-window"),
+    pytest.param(700, 8, 128, {"full_block": 1}, id="width-8"),
+    pytest.param(700, 0, 128, {"full_block": 1, "empty_block": 3},
+                 id="wide-scalars"),
+    pytest.param(3000, 0, 1024, {"touched": 900},
+                 id="wide-scalars-large-block"),
+])
+def test_routed_adam_update_fused_equals_its_xla_backend(n, e, block_n, kw):
+    """Two consecutive steps of op ``routed_adam_update``, the fused pass
+    in interpret mode against the XLA composition, p, m and v each.  Step
+    2 touches nothing, so a row step 1 touched keeps its momentum tail
+    and moves again.  A row with no history that neither step touches is
+    its start, bit for bit, on both backends."""
+    ids, state, sums, out_ids = _adam_case(n, e, block_n, **kw)
+    start = [np.asarray(x) for x in state]
+    outs = {}
+    for b, fn in _adam_backends(block_n).items():
+        s1 = fn(*state, sums, out_ids, jnp.int32(1), **_ADAM)
+        s2 = fn(*s1, jnp.zeros_like(sums), out_ids, jnp.int32(2), **_ADAM)
+        outs[b] = [np.asarray(x) for x in s1 + s2]
+    for got, ref, name in zip(outs["pallas"], outs["xla"],
+                              ("p1", "m1", "v1", "p2", "m2", "v2")):
+        np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-7,
+                                   err_msg=name)
+    p0, m0, v0 = start
+    idle = np.setdiff1d(np.flatnonzero(
+        (m0.reshape(n, -1) == 0).all(1) & (v0.reshape(n, -1) == 0).all(1)),
+        ids)
+    assert idle.size
+    fresh = np.setdiff1d(ids, np.flatnonzero((m0.reshape(n, -1) != 0).any(1)))
+    for b, (p1, m1, v1, p2, m2, v2) in outs.items():
+        for got, was in ((p1, p0), (m1, m0), (v1, v0), (p2, p0), (m2, m0),
+                         (v2, v0)):
+            np.testing.assert_array_equal(got[idle], was[idle], err_msg=b)
+        # the momentum tail: touched in step 1, idle in step 2, moved twice
+        assert np.all(m2[fresh] == np.float32(0.9) * m1[fresh]), b
+        assert np.all(p2[fresh] != p1[fresh]), b
+        assert np.all(p1[ids] != p0[ids]), b
+
+
+def test_routed_adam_update_is_planned_for_narrow_tables_only():
+    """The fused pass takes the widths whose transposed view is the array
+    the chip holds; the wide table's scalars and a table as wide as a
+    lane tile stay with the XLA composition."""
+    fused = lookup("routed_adam_update", backend="pallas")
+    assert [fused.supports_sig((1000, e)) for e in (0, 4, 8, 16, 64, 128)] \
+        == [False, False, True, True, True, False]
+    assert lookup("routed_adam_update", sig=(1000, 0)).backend == "xla"
+
+
+def test_routed_adam_update_xla_is_optax_adam_on_the_scattered_gradient():
+    """The oracle's own oracle: the ``xla`` backend against ``optax.adam``
+    handed the table-shaped gradient, same step count."""
+    import optax
+
+    ids, (p, m, v), sums, out_ids = _adam_case(700, 16, 128, full_block=1)
+    g = jnp.zeros_like(p).at[out_ids].set(sums, mode="drop")
+    opt = optax.adam(_ADAM["lr"])
+    state = opt.init(p)
+    state = (state[0]._replace(count=jnp.int32(4), mu=m, nu=v), *state[1:])
+    updates, state = opt.update(g, state, p)
+    got = lookup("routed_adam_update", backend="xla").fn(
+        p, m, v, sums, out_ids, state[0].count, **_ADAM)
+    for a, b, name in zip(got, (optax.apply_updates(p, updates),
+                                state[0].mu, state[0].nu), "pmv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-8, err_msg=name)
+
+
 # -- accuracy-envelope harnesses (int8 backends, ISSUE 18) ------------------
 # Int8 entries are weight-only quantized: bitwise equality with f32 is
 # NOT the contract — rank-order/decision agreement within the envelope
@@ -663,6 +799,7 @@ _PARITY = {
     "kmeans_workset_update": _parity_kmeans_workset_update,
     "linear_margins": _parity_linear_margins,
     "retrieve": _parity_retrieve,
+    "routed_adam_update": _parity_routed_adam_update,
     "routed_table_grad": _parity_routed_table_grad,
     "widedeep_scores": _parity_widedeep_scores,
 }

@@ -108,6 +108,22 @@ def _retrieve(b, dim, nlist, block, m=0, ksub=16, nprobe=4, k=10):
              Shape((m, ksub, dim // m), I8), Shape((m, ksub), F32)))
 
 
+def _routed_adam(n, u, e, block_n=None):
+    """``e`` 0: the wide table's vector of scalars."""
+    from flink_ml_tpu.ops.adam_table_pallas import routed_adam_update_fused
+
+    table = Shape((n, e) if e else (n,), F32)
+    return (partial(routed_adam_update_fused, lr=1e-3, b1=0.9, b2=0.999,
+                    eps=1e-8, block_n=block_n),
+            (table, table, table, Shape((u, e) if e else (u,), F32),
+             Shape((u,), I32), Shape((), I32)))
+
+
+# Criteo's 26 cardinalities in all, and the most table rows one batch of
+# 32768 touches there (benchmarks/configs/widedeep_criteo.json; PERF.md)
+_CRITEO_ROWS, _CRITEO_UNIQUE = 33_762_577, 126_629
+
+
 # (op, backend) -> {case id: thunk returning (fn, abstract args)}.  The
 # smallest ELL table is 128 rows; full width is the Criteo step
 # (d = 2^20, batch 32768) and the KMeans fit (n = 2^20, d = 64, k = 256)
@@ -144,6 +160,11 @@ CASES = {
     ("kmeans_workset_update", "pallas"): {
         "smallest": lambda: _kmeans_workset(128, 8, 4, 128),
         "full-width": lambda: _kmeans_workset(1 << 20, 64, 256, 4096),
+    },
+    ("routed_adam_update", "pallas"): {
+        "smallest": lambda: _routed_adam(100, 8, 16),
+        "smallest-scalars": lambda: _routed_adam(100, 8, 0),
+        "criteo": lambda: _routed_adam(_CRITEO_ROWS, _CRITEO_UNIQUE, 16),
     },
     ("retrieve", "pallas"): {
         **{f"flat-rows{b}": partial(_retrieve, b, 128, 16, 128)
@@ -290,3 +311,93 @@ def test_kmeans_fit_program_keeps_no_copy_of_the_points(one_v5e):
         (Shape((n, d), F32, sharding=one_v5e),
          Shape((n,), F32, sharding=one_v5e))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
+
+
+@pytest.mark.parametrize("n,e", [(_CRITEO_ROWS, 16), ((1 << 22) + 1, 64),
+                                 (5300, 16)],
+                         ids=["criteo", "width-64", "table-of-one-block"])
+def test_routed_adam_update_compiles_in_place(one_v5e, n, e):
+    """The fused Adam pass at the block its VMEM model picks (8192 and
+    2048 rows, and the 5376 of a table smaller than a block, whose id
+    windows still have to be multiples of 1024): inside the 16 MiB of
+    scoped VMEM, ``p``, ``m`` and ``v`` aliased in -> out, no temporary
+    to speak of: the transposed views are the arrays the chip holds.  (Not so at width 128, which the chip keeps
+    row-major, nor for the scalar table's ``(1, N)`` view: the same
+    compile leaves table-sized temporaries there, which is why the
+    registry's ``supports`` keeps those on the XLA backend.)"""
+    fn, args = _routed_adam(n, _CRITEO_UNIQUE, e)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2)).lower(*(
+        Shape(a.shape, a.dtype, sharding=one_v5e) for a in args)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * n * e * 4
+    assert mem.temp_size_in_bytes < (64 << 20)
+
+
+def test_widedeep_fit_program_forms_no_table_shaped_gradient(
+        one_v5e, monkeypatch):
+    """The fused program of ``WideDeep.fit`` at the benchmark cell's
+    shapes (5 epochs of 32 routed steps, ``scatter`` placement, the
+    tables on one device) compiled for a described v5e: the step updates
+    embedding table through the fused ``routed_adam_update``, so the
+    program keeps no temporary of that table's size (with the dense
+    gradient, 2.16 GB, it kept 2.75 GB, PERF.md section 4; without it
+    1.10 GB, half a table: the folds' slot arrays and the wide table's
+    gradient), donates p, m and v into the loop, and neither copies nor
+    transposes an array of the table's shape."""
+    import re
+
+    import numpy as np
+    import optax
+
+    from flink_ml_tpu.models.recommendation import widedeep
+    from flink_ml_tpu.ops.emb_grad import EmbGradRoute
+
+    n, u, e, batch, fields, steps, epochs = (
+        _CRITEO_ROWS, _CRITEO_UNIQUE, 16, 32768, 26, 32, 5)
+    vocab = [n - fields + 1] + [1] * (fields - 1)
+    slots = batch * fields
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: Shape(a.shape, a.dtype, sharding=one_v5e), tree)
+
+    route = EmbGradRoute(
+        order=Shape((steps, slots), I32),
+        sorted_ids=Shape((steps, slots), I32),
+        out_pos=Shape((steps, u), I32), out_ids=Shape((steps, u), I32),
+        fold_passes=15, num_rows=n, placement="scatter")
+    # the step is built as on the chip: the registry's own pick there
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    small = widedeep.init_params(np.random.default_rng(0), 13, [3, 2], e,
+                                 (1024, 512, 256))
+    step, _ = widedeep._make_train_ops(small, 1e-3, False, route=route)
+    assert step.table_update == "fused"
+    monkeypatch.undo()
+
+    params = jax.eval_shape(lambda: widedeep.init_params(
+        np.random.default_rng(0), 13, vocab, e, (1024, 512, 256)))
+    opt_state = jax.eval_shape(optax.adam(1e-3).init, params)
+
+    def run(state, data):
+        def epoch(state, _):
+            def batch_step(carry, i):
+                *carry, loss = step(*carry, *(a[i] for a in data))
+                return tuple(carry), loss
+            return jax.lax.scan(batch_step, state,
+                                jnp.arange(steps, dtype=jnp.int32))
+        return jax.lax.scan(epoch, state, None, length=epochs)
+
+    compiled = jax.jit(run, donate_argnums=0).lower(
+        on_chip((params, opt_state)),
+        on_chip((Shape((steps, batch, 13), F32),
+                 Shape((steps, batch, fields), I32),
+                 Shape((steps, batch), F32), Shape((steps, batch), F32))
+                + route.stacked_arrays())).compile()
+    mem = compiled.memory_analysis()
+    table = n * e * 4
+    assert mem.temp_size_in_bytes < 0.6 * table, mem
+    assert mem.alias_size_in_bytes >= 3 * (table + n * 4), mem
+    moved = [line for line in compiled.as_text().splitlines()
+             if re.search(r"= f32\[(%d,%d|%d,%d)\]\S* (copy|transpose)\("
+                          % (n, e, e, n), line)]
+    assert not moved, moved[:3]

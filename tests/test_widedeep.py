@@ -1,6 +1,9 @@
 """Wide&Deep tests: fit/predict on a synthetic CTR-like task, save/load,
 sharded multichip train step, broadcast utils."""
 
+from functools import partial
+
+import jax
 import numpy as np
 import pytest
 
@@ -561,6 +564,96 @@ def test_fit_past_the_route_budget_scatters_and_equals_the_gather_fit(
     for name, value in _answer(gather).items():
         np.testing.assert_allclose(_answer(scatter)[name], value,
                                    rtol=1e-5, atol=1e-7, err_msg=name)
+
+
+@pytest.fixture
+def _one_device_scatter_fit(monkeypatch):
+    """``fit`` of the reference fixture on a one-device ``data`` mesh with
+    the route past its gather budget: where ``_make_train_ops`` updates
+    the tables through op ``routed_adam_update``."""
+    from flink_ml_tpu.obs.trace import tracer
+    from flink_ml_tpu.ops import emb_grad
+    from flink_ml_tpu.parallel.mesh import device_mesh, use_mesh
+
+    _, data, est = _reference_fixture()
+    monkeypatch.setattr(emb_grad, "_POS_MAP_BUDGET_BYTES", 0)
+
+    def fit(est=est):
+        tracer.enable()
+        try:
+            with use_mesh(device_mesh({"data": 1},
+                                      devices=jax.devices()[:1])):
+                model = est.fit(Table(data))
+            spans = list(tracer.find("fit.arrange.route"))
+        finally:
+            tracer.disable()
+            tracer.clear()
+        return model, spans[0].ids if spans else None
+
+    return fit
+
+
+@pytest.mark.parametrize("backend,table_update", [("xla", "dense_grad"),
+                                                  ("pallas", "fused")])
+def test_one_device_scatter_fit_equals_the_dense_adam_oracle(
+        _one_device_scatter_fit, backend, table_update):
+    """The tables updated by op ``routed_adam_update``, forced to each
+    backend, against autodiff's scatter-add under ``optax.adam`` over
+    every parameter (``routedEmbeddingGrad='off'``) on the same mesh: the
+    same dense Adam up to the order the duplicates' gradients are summed
+    in.  ``pallas`` is the registry as a TPU sees it: the fused pass (in
+    interpret mode here) for the embedding table, the XLA composition for
+    the wide table's scalars.  The model and the route span say which
+    update ran."""
+    import dataclasses
+
+    from flink_ml_tpu.kernels import registry as kreg
+
+    kreg.ops()                                   # the catalog is loaded
+    table = kreg._REGISTRY["routed_adam_update"]
+    saved = dict(table)
+    if backend == "pallas":
+        fused = saved["pallas"]
+        table["pallas"] = dataclasses.replace(
+            fused, fn=partial(fused.fn, interpret=True), available=None)
+    else:
+        del table["pallas"]
+    try:
+        model, notes = _one_device_scatter_fit()
+    finally:
+        table.clear()
+        table.update(saved)
+    assert (model.route_placement, model.table_update) == (
+        "scatter", table_update)
+    assert (notes["placement"], notes["table_update"]) == (
+        "scatter", table_update)
+    est = _reference_fixture()[2]
+    oracle, notes = _one_device_scatter_fit(
+        est.set(WideDeep.ROUTED_EMB_GRAD, "off"))
+    assert (oracle.route_placement, oracle.table_update, notes) == (
+        None, None, None)
+    for name, value in _answer(oracle).items():
+        np.testing.assert_allclose(_answer(model)[name], value, rtol=2e-4,
+                                   atol=2e-6, err_msg=name)
+
+
+def test_gather_placement_and_a_mesh_keep_the_dense_gradient(
+        _one_device_scatter_fit, monkeypatch):
+    """What the op does not cover keeps its path and says so: the
+    ``gather`` placement on one device, and the ``scatter`` placement
+    with the tables replicated over the suite's eight devices."""
+    from flink_ml_tpu.ops import emb_grad
+
+    _, data, est = _reference_fixture()
+    replicated = est.fit(Table(data))            # scatter (the fixture's
+    assert (replicated.route_placement,          # budget), eight devices
+            replicated.table_update) == ("scatter", "dense_grad")
+    monkeypatch.setattr(emb_grad, "_POS_MAP_BUDGET_BYTES", 512 << 20)
+    model, notes = _one_device_scatter_fit()
+    assert (model.route_placement, model.table_update) == (
+        "gather", "dense_grad")
+    assert (notes["placement"], notes["table_update"]) == (
+        "gather", "dense_grad")
 
 
 def test_model_data_round_trip_and_save_load(tmp_path):

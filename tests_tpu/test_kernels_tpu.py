@@ -7,6 +7,7 @@ break, never an OOM or capacity artifact; the
 ``full-width`` cases repeat the kernels of the Criteo LR step and the
 KMeans fit at the block sizes ``chip_smoke.py`` runs them at."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -23,6 +24,8 @@ COVERED = {
     ("kmeans_update_stats", "pallas"): "test_kmeans_kernel_parity",
     ("kmeans_workset_update", "pallas"): "test_kmeans_workset_kernel_parity",
     ("retrieve", "pallas"): "test_retrieve_flat_kernel_parity",
+    ("routed_adam_update", "pallas"):
+        "test_routed_adam_update_under_heavy_skew_on_device",
 }
 
 
@@ -395,6 +398,126 @@ def test_routed_scatter_placement_under_heavy_skew_on_device(tpu, rng):
             idle = np.ones(vocab, bool)
             idle[touched] = False
             assert not got[idle].any()
+
+
+def test_routed_adam_update_under_heavy_skew_on_device(tpu, rng):
+    """The fused Adam pass the registry plans for an embedding table on
+    the chip, fed by the scatter placement's run sums under the skew of
+    the test above (a table of 2^22 rows and one more, so that the last
+    block is ragged; 2^16 slots a step, one id in more than half of
+    them), two steps from a state with history, against a float64 Adam
+    step on the host.  A row with no history that neither step touches
+    is its start, bit for bit."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.kernels.registry import lookup
+    from flink_ml_tpu.ops.adam_table_pallas import _bias_corrections
+    from flink_ml_tpu.ops.emb_grad import emb_grad_route, routed_run_sums
+
+    vocab, slots, emb = (1 << 22) + 1, 1 << 16, 16
+    lr, b1, b2, eps = 1e-3, 0.9, 0.999, 1e-8
+    cat = rng.integers(0, vocab, size=(2, slots // 4, 4)).astype(np.int64)
+    cat[:, :, 0] = np.where(rng.random((2, slots // 4)) < 0.6, 3,
+                            cat[:, :, 0])
+    cat[:, ::7, 1] = vocab - 1
+    cat[1, :, 2] = np.arange(slots // 4) + 8192  # blocks touched in every row
+    route = emb_grad_route(cat, vocab, placement="scatter")
+    p0 = (0.05 * rng.normal(size=(vocab, emb))).astype(np.float32)
+    m0 = np.zeros_like(p0)
+    hist = rng.choice(vocab, vocab // 8, replace=False)
+    m0[hist] = (1e-3 * rng.normal(size=(hist.size, emb))).astype(np.float32)
+    v0 = np.square(m0)
+    entry = lookup("routed_adam_update", sig=(vocab, emb))
+    assert entry.backend == "pallas", entry.backend
+    state = tuple(map(jnp.asarray, (p0, m0, v0)))
+    want = tuple(x.astype(np.float64) for x in (p0, m0, v0))
+    corrections = jax.jit(partial(_bias_corrections, b1=b1, b2=b2))
+    for step in (0, 1):
+        g = (1e-2 * rng.normal(size=(slots, emb))).astype(np.float32)
+        order, sorted_ids, out_pos, out_ids = (
+            jnp.asarray(np.asarray(a)) for a in route.step_slice(step))
+        sums = routed_run_sums(jnp.asarray(g), order, sorted_ids, out_pos,
+                               fold_passes=route.fold_passes)
+        state = entry.fn(*state, sums, out_ids, jnp.int32(step + 1), lr=lr,
+                         b1=b1, b2=b2, eps=eps)
+        dense = np.zeros((vocab, emb), np.float64)
+        np.add.at(dense, cat[step].reshape(-1), g)
+        p, m, v = want
+        m = (1 - b1) * dense + b1 * m
+        v = (1 - b2) * dense * dense + b2 * v
+        # the bias corrections as the device computes them: its float32
+        # ``b ** count`` is off in the seventh digit, which 1 - 0.999 ** t
+        # turns into the fourth (optax's own expression, on either backend)
+        c1, c2 = (float(c) for c in corrections(jnp.int32(step + 1)))
+        p = p - lr * (m / c1) / (np.sqrt(v / c2) + eps)
+        want = (p, m, v)
+    got = [np.asarray(x) for x in state]
+    touched = np.unique(cat)
+    # the heaviest id's run sum is near 2 and carries the fold's float32
+    # rounding (2e-4 in the test above): m holds a tenth of it
+    for a, b, tol, name in zip(got, want, (3e-7, 2e-5, 1e-6), "pmv"):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=tol, err_msg=name)
+    assert not np.array_equal(got[0][touched], p0[touched])
+    idle = np.ones(vocab, bool)
+    idle[touched] = False
+    idle[hist] = False
+    assert idle.sum() > vocab // 2
+    for a, start in zip(got, (p0, m0, v0)):
+        np.testing.assert_array_equal(a[idle], start[idle])
+
+
+def test_widedeep_scatter_fit_takes_the_fused_update_on_device(
+        tpu, rng, monkeypatch):
+    """``WideDeep.fit`` past the gather budget on the chip's one-device
+    mesh: the route span and the model say ``fused``, a table smaller
+    than the kernel's block (5300 rows: one ragged block, id windows
+    rounded up to 1024) compiles under Mosaic, and the fit equals the
+    autodiff dense-Adam fit up to the order of summation; under the
+    ``gather`` placement the same fit says ``dense_grad``."""
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.recommendation.widedeep import WideDeep
+    from flink_ml_tpu.obs.trace import tracer
+    from flink_ml_tpu.ops import emb_grad
+
+    n, vocab = 4096, [5000, 300]
+    table = Table({
+        "denseFeatures": rng.normal(size=(n, 4)).astype(np.float32),
+        "catFeatures": np.stack([rng.integers(0, v, n) for v in vocab],
+                                1).astype(np.int32),
+        "label": rng.integers(0, 2, n).astype(np.float32)})
+
+    def fit(mode="auto"):
+        est = (WideDeep().set_vocab_sizes(vocab).set_embedding_dim(16)
+               .set_hidden_units([32, 16]).set_max_iter(2)
+               .set_global_batch_size(1024).set_seed(3)
+               .set(WideDeep.ROUTED_EMB_GRAD, mode))
+        tracer.enable()
+        try:
+            model = est.fit(table)
+            notes = [s.ids for s in tracer.find("fit.arrange.route")]
+        finally:
+            tracer.disable()
+            tracer.clear()
+        return model, notes
+
+    gather, notes = fit()
+    assert (gather.route_placement, gather.table_update,
+            notes[0]["table_update"]) == ("gather", "dense_grad",
+                                          "dense_grad")
+    monkeypatch.setattr(emb_grad, "_POS_MAP_BUDGET_BYTES", 0)
+    fused, notes = fit()
+    assert (fused.route_placement, fused.table_update,
+            notes[0]["table_update"]) == ("scatter", "fused", "fused")
+    oracle, _ = fit("off")
+    for name in ("emb", "wide_cat", "mlp"):
+        for a, b in zip(jax.tree_util.tree_leaves(fused._params[name]),
+                        jax.tree_util.tree_leaves(oracle._params[name])):
+            np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5,
+                                       err_msg=name)
+    np.testing.assert_allclose(fused.loss_log, oracle.loss_log, rtol=1e-4)
 
 
 def test_als_sorted_neq_on_device(tpu, rng):
