@@ -10,6 +10,8 @@ weights start at zero, data comes from a seed):
 - ``serve``   serve_model on that model; requests of 1, 37 and 256 rows
 - ``stream``  fit_outofcore over a DataCacheWriter cache of 2^18 rows
 - ``kmeans``  KMeans(k=256).fit on 2^20 x 64 points, then transform
+- ``als``     ALS(rank 32).fit on 2^20 skewed ratings, the grouped normal
+              equations by blocks, against a float64 solve of a sample
 - ``mesh4``   the fit leg on a four-device data mesh (only with >= 4 chips)
 
 Each leg checks what came out: finite values of the expected shape, the
@@ -50,9 +52,9 @@ NATIVE_LIBS = ("ell_layout", "datacache", "criteo")
 # (rows, batch) per leg — rows only for kmeans: the chip run, and the
 # --cpu-rehearsal cut
 FULL = {"fit": (1 << 20, 1 << 15), "stream": (1 << 18, 1 << 13),
-        "kmeans": 1 << 20}
+        "kmeans": 1 << 20, "als": 1 << 20}
 REHEARSAL = {"fit": (1 << 14, 1 << 12), "stream": (1 << 13, 1 << 10),
-             "kmeans": 1 << 16}
+             "kmeans": 1 << 16, "als": 1 << 14}
 
 # One representative signature per registry op for the op -> backend
 # table: the shapes this script runs where it runs the op, the Criteo
@@ -402,6 +404,71 @@ def leg_kmeans(ctx) -> dict:
             "take_k_mean_sq_distance": round(start_cost, 2)}
 
 
+def leg_als(ctx) -> dict:
+    """Explicit ALS-WR through ``ALS.fit`` with the grouped normal
+    equations (``normalEquationsImpl='sorted'``): skewed users and items,
+    one iteration from the seed's start, then the users of a sample
+    (heaviest, lightest, a spread between) re-solved in float64 NumPy
+    against the START's item factors, which is what the first half-epoch
+    saw."""
+    import numpy as np
+
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.recommendation.als import ALS
+    from flink_ml_tpu.parallel.mesh import use_mesh
+
+    rows = ctx["sizes"]["als"]
+    users, items, rank, reg, seed = rows // 32, rows // 256, 32, 0.05, 3
+    rng = np.random.default_rng(4)
+    # Zipf-like popularity on both sides, distinct pairs
+    u = np.minimum((users * rng.random(rows) ** 2.5).astype(np.int64),
+                   users - 1)
+    i = np.minimum((items * rng.random(rows) ** 2.5).astype(np.int64),
+                   items - 1)
+    pairs = np.unique(u * items + i)
+    u, i = pairs // items, pairs % items
+    r = rng.integers(1, 6, size=len(pairs)).astype(np.float32)
+    with use_mesh(ctx["mesh1"]):
+        model = (ALS().set_rank(rank).set_reg_param(reg).set_max_iter(1)
+                 .set_seed(seed).set(ALS.NEQ_IMPL, "sorted")
+                 .fit(Table({"user": u, "item": i, "rating": r})))
+    check(model.neq_plan == "grouped",
+          f"als: the fit planned {model.neq_plan!r}, expected 'grouped'")
+    (data,) = model.get_model_data()
+    user_ids = np.asarray(data["userIds"][0])
+    item_ids = np.asarray(data["itemIds"][0])
+    fitted = np.asarray(data["userFactors"][0], np.float64)
+    check(fitted.shape == (len(user_ids), rank)
+          and np.isfinite(fitted).all()
+          and np.isfinite(np.asarray(data["itemFactors"][0])).all(),
+          f"als: factors {fitted.shape}, not all finite")
+    # the start, by the estimator's documented rule
+    start = np.random.default_rng(seed)
+    start.normal(size=(len(user_ids), rank))
+    v0 = (start.normal(size=(len(item_ids), rank)) / np.sqrt(rank)).astype(
+        np.float32).astype(np.float64)
+    u_pos, i_pos = np.searchsorted(user_ids, u), np.searchsorted(item_ids, i)
+    counts = np.bincount(u_pos, minlength=len(user_ids))
+    # the heaviest user, the lightest, and a spread between
+    sample = np.unique(np.concatenate([
+        [counts.argmax(), counts.argmin()],
+        np.argsort(counts)[::max(1, len(counts) // 64)]]))
+    worst = 0.0
+    for g in sample:
+        mine = u_pos == g
+        y = v0[i_pos[mine]]
+        a = y.T @ y + reg * max(mine.sum(), 1) * np.eye(rank)
+        x = np.linalg.solve(a, y.T @ r[mine].astype(np.float64))
+        worst = max(worst, float(np.abs(fitted[g] - x).max()
+                                 / max(np.abs(x).max(), 1e-12)))
+    check(worst < 1e-3,
+          f"als: a user's factors are {worst:.2e} off a float64 solve")
+    return {"neq_plan": model.neq_plan, "ratings": int(len(pairs)),
+            "users": int(len(user_ids)), "items": int(len(item_ids)),
+            "heaviest_user": int(counts.max()),
+            "worst_relative_gap": float(f"{worst:.3g}")}
+
+
 def leg_mesh4(ctx) -> dict:
     import jax
     import numpy as np
@@ -480,7 +547,7 @@ def backend_table() -> list:
 
 
 LEGS = {"fit": leg_fit, "serve": leg_serve, "stream": leg_stream,
-        "kmeans": leg_kmeans, "mesh4": leg_mesh4}
+        "kmeans": leg_kmeans, "als": leg_als, "mesh4": leg_mesh4}
 
 
 def cache_entries(path: str) -> int:

@@ -7,19 +7,23 @@ feedback (ALS-WR: per-row regularization scaled by the row's rating count)
 and implicit feedback (Hu/Koren confidence weighting,
 ``c = 1 + alpha * |r|``).
 
-TPU-native shape of one half-epoch (solve all users against fixed item
-factors):
+One half-epoch solves all users against fixed item factors (then the
+items against the new users).  Its normal equations come in two forms
+(``normalEquationsImpl``):
 
-- gather   — ``y = V[item_idx]`` for every rating, chunked by ``lax.scan``
-             so the (chunk, rank, rank) outer products stay bounded in HBM
-             regardless of nnz
-- reduce   — normal equations accumulated with ``.at[].add`` scatter-adds
-             into dense ``(n_users, rank, rank)`` / ``(n_users, rank)``
-             operands (the reference's analog would be a keyed shuffle +
-             per-key reduce)
-- solve    — ONE batched Cholesky solve over all users at once
-             (``jax.scipy.linalg.cho_solve``) — a big batched MXU op instead
-             of the per-user host loops of CPU implementations
+- ``grouped`` (``'sorted'``, and ``'auto'`` wherever one side's dense
+  ``(n_groups, rank, rank)`` would outgrow a block): one host plan a side
+  (:class:`GroupedPlan`) lays every group's ratings out at a padded
+  length, groups of one length together, and the epoch body forms AND
+  solves the equations a block of groups at a time under ``lax.scan``:
+  gather the other side's rows, ``A_g = Y_g^T diag(w) Y_g`` and ``b_g`` as
+  batched contractions over the group's own slots, Cholesky with the
+  groups on the lanes, write the block's rows.  The state on the chip is
+  one block's ``A``, never the side's.
+- ``scatter``: the ratings in their own order, ``.at[group].add`` of one
+  outer product a rating into dense ``(n_groups, rank, rank)`` operands,
+  then ONE batched Cholesky over all groups.  Small ranks and few groups;
+  the workset fit stays on it (it needs the per-rating ids).
 
 Both half-epochs make one epoch, driven by the ``iterate`` runtime in fused
 mode: the whole ``max_iter`` loop compiles to a single XLA program, factors
@@ -33,8 +37,9 @@ would be singular).
 
 from __future__ import annotations
 
-from functools import partial
-from typing import List, Optional
+import os
+from concurrent.futures import ThreadPoolExecutor
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +60,7 @@ from ...params.param import (
     ParamValidators,
     StringParam,
 )
+from ...obs.trace import tracer
 from ...params.shared import HasMaxIter, HasPredictionCol, HasSeed
 from ...utils import persist
 
@@ -62,141 +68,395 @@ __all__ = ["ALS", "ALSModel", "ALSParams", "ALSModelParams"]
 
 _CHUNK = 65536  # ratings per scan step: (chunk, rank^2) is the HBM high-water
 
-#: sorted-path chunk: (chunk, rank^2) outer-product transient per scan
-#: step (134 MB at rank 64) — smaller than _CHUNK because the sorted
-#: path materializes the outers for its MXU contraction
-_SORTED_CHUNK = 8192
+#: a group's padded length is a multiple of one sublane tile: a block's
+#: gathered rows ``(groups * length, rank)`` then reshape to ``(groups,
+#: length, rank)`` without moving a word
+_MIN_LENGTH = 8
 
-#: 'auto' picks the sorted path only while every chunk's group band
-#: stays this narrow: per-chunk MXU work scales with span, so long-tail
-#: data (most groups with 1-2 ratings — the common recommendation
-#: shape) can drive span toward the chunk size and make the one-hot
-#: contraction orders of magnitude more work than the scatter it
-#: replaces.  Span is known at host plan-build time, so the fallback is
-#: free to decide.
-_NEQ_AUTO_SPAN_CAP = 256
-
-
-def _neq_plan_span(group_idx: np.ndarray, chunk: int = _SORTED_CHUNK) -> int:
-    """The chunk-band span :class:`NeqPlan` would compute for
-    ``group_idx``, WITHOUT the plan's O(nnz log nnz) argsort or its
-    O(nnz) local-rank arrays: within a sorted chunk the band maximum
-    sits at the chunk's last slot, so span needs only the sorted group
-    value at each chunk boundary — and the sorted sequence is fully
-    determined by ``np.bincount`` (each group id repeated by its
-    count).  O(nnz + n_groups) time, O(n_groups) memory.  'auto' mode
-    consults this BEFORE building a plan, so long-tail datasets — the
-    common recommendation shape, which falls back to scatter — skip
-    both argsorts entirely."""
-    group_idx = np.asarray(group_idx)
-    nnz = group_idx.shape[0]
-    if nnz == 0:
-        return 1
-    chunk = int(min(chunk, nnz))
-    cum = np.cumsum(np.bincount(group_idx))
-    n_chunks = -(-nnz // chunk)
-    starts = np.arange(n_chunks) * chunk
-    # the plan pads the tail chunk by repeating the last sorted group,
-    # so its band ends at sorted position nnz - 1
-    ends = np.minimum(starts + chunk - 1, nnz - 1)
-    lo = np.searchsorted(cum, starts, side="right")
-    hi = np.searchsorted(cum, ends, side="right")
-    return int((hi - lo).max()) + 1
+#: the shares of the device's memory that one block's normal equations
+#: ``A`` and one block's gathered rows may take, as the chip lays them
+#: out (a float32 ``(rank, rank)`` tile padded to 8 sublanes x 128 lanes;
+#: a gathered row padded to 128 lanes).  The factorisation works on two
+#: more arrays of ``A``'s size, so a block peaks near half of the chip.
+_BLOCK_A_SHARE = 1 / 8
+_BLOCK_ROWS_SHARE = 1 / 12
 
 
-class NeqPlan:
-    """Static routing for :func:`_normal_equations_sorted` — one host
-    sort per fit side (the ratings are fixed for the whole fit, the
-    same replay insight as the LR/WDL static routes).
+#: host threads of a fit's index and plan: the sorts, searches and
+#: scatters over all the ratings release the GIL
+_HOST_THREADS = min(8, os.cpu_count() or 1)
 
-    Sorting by group makes each scan chunk's groups a NARROW CONTIGUOUS
-    band ``[g_lo, g_lo + span)`` (``span`` = static max band over
-    chunks), so the normal-equation accumulation becomes one small MXU
-    contraction + one dynamic-slice add per chunk instead of per-rating
-    scatter-adds.  A group whose run crosses a chunk boundary simply
-    keeps accumulating into the same rows from the next chunk — heavy
-    groups need no special path.
-    """
 
-    def __init__(self, group_idx: np.ndarray, chunk: int = _SORTED_CHUNK):
+def _index_labels(labels: np.ndarray) -> tuple:
+    """``np.unique(labels, return_inverse=True)``, a part of the labels a
+    thread: each part's distinct labels, the distinct of those, and every
+    label's place among them by binary search."""
+    if len(labels) < (1 << 20) or _HOST_THREADS == 1:
+        return np.unique(labels, return_inverse=True)
+    parts = np.array_split(labels, _HOST_THREADS)
+    with ThreadPoolExecutor(_HOST_THREADS) as pool:
+        ids = np.unique(np.concatenate(list(pool.map(np.unique, parts))))
+        index = np.concatenate(list(pool.map(
+            lambda part: np.searchsorted(ids, part), parts)))
+    return ids, index
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def _block_sizes(rank: int) -> tuple:
+    """``(groups, slots)`` a block may hold, from the rank and the first
+    device's memory (16 GiB where the backend does not say)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    memory = int(stats.get("bytes_limit", 16 << 30))
+    row = 4 * _round_up(rank, 128)           # bytes of one padded row
+    groups = int(memory * _BLOCK_A_SHARE) // (_round_up(rank, 8) * row)
+    slots = int(memory * _BLOCK_ROWS_SHARE) // row
+    # whole powers of two: the same blocks on every device of one size
+    return (1 << max(groups.bit_length() - 1, 0),
+            1 << max(slots.bit_length() - 1, 3))
+
+
+def _stable_group_order(group_idx: np.ndarray, n_groups: int) -> np.ndarray:
+    """``np.argsort(group_idx, kind="stable")`` in passes of 16 bits: a
+    stable sort of 16-bit keys is NumPy's radix sort, linear in the
+    ratings where the comparison sort of int32 keys is not."""
+    order = np.argsort((group_idx & 0xFFFF).astype(np.uint16), kind="stable")
+    if n_groups > 1 << 16:
+        high = (group_idx >> 16).astype(np.uint16)[order]
+        order = order[np.argsort(high, kind="stable")]
+    return order
+
+
+def _padded_lengths(counts: np.ndarray) -> np.ndarray:
+    """The slots a group of ``counts`` ratings gets: the next of 8, 16,
+    24, 32, 48, 64, 96, ... (two lengths an octave, every one a multiple
+    of 8), so that about a sixth of the slots is padding where whole
+    powers of two would make it a third."""
+    power = 1 << np.ceil(np.log2(np.maximum(counts, _MIN_LENGTH))).astype(
+        np.int64)
+    three_quarters = 3 * power // 4
+    return np.where((counts <= three_quarters) & (power >= 32),
+                    three_quarters, power)
+
+
+class GroupedClass(NamedTuple):
+    """The groups of one padded ``length``: every block of the plan holds
+    ``groups`` of them; ``rows`` ``(blocks, groups)`` names them
+    (``n_groups`` fills the last block's tail: its slots have weight 0 and
+    its row is dropped); ``offset`` is the class's first flat slot."""
+
+    length: int
+    groups: int
+    rows: np.ndarray
+    offset: int
+
+
+class GroupedPlan:
+    """Static layout of one side's ratings for the grouped normal
+    equations: one host pass a fit (the ratings are fixed for the whole
+    fit, the same replay insight as the LR/WDL static routes).
+
+    A group of ``n`` ratings gets :func:`_padded_lengths` slots; the
+    groups of one length make a class, and every one of the plan's
+    ``blocks`` holds the same number of groups of each class, so that all
+    blocks have one shape: at most ``block_groups`` groups, and of no
+    class more than ``block_slots`` slots.  A group with more ratings than
+    ``block_slots`` is split instead into parts of that many slots, one
+    part a scan step, whose partial sums the scan carries (``split_rows``
+    ``(parts, 1)``, ``first`` / ``last`` ``(parts,)``).  A group with no
+    rating is nowhere (it keeps its factors).  ``slot`` sends every rating
+    to its place in the flat slots (class after class, then the parts);
+    the slots no rating fills are padding, of weight 0."""
+
+    def __init__(self, group_idx: np.ndarray, n_groups: int, rank: int,
+                 block_groups: Optional[int] = None,
+                 block_slots: Optional[int] = None):
         group_idx = np.asarray(group_idx)
-        nnz = group_idx.shape[0]
-        self.chunk = int(min(chunk, max(nnz, 1)))
-        self.order = np.argsort(group_idx, kind="stable").astype(np.int64)
-        sg = group_idx[self.order].astype(np.int32)
-        pad = (-nnz) % self.chunk
-        if pad:
-            sg = np.concatenate([sg, np.full(pad, sg[-1] if nnz else 0,
-                                             np.int32)])
-        self.nnz, self.pad = nnz, pad
-        n_chunks = sg.shape[0] // self.chunk
-        self.g_lo = sg[np.arange(n_chunks) * self.chunk].astype(np.int32)
-        local = sg - np.repeat(self.g_lo, self.chunk)
-        self.span = int(local.max(initial=0)) + 1
-        self.local_rank = local.astype(np.int32)
+        group_idx = np.asarray(group_idx)
+        counts = np.bincount(group_idx, minlength=n_groups)
+        slot0 = self._lay_out(counts, rank, block_groups, block_slots)
+        # in the stable order by group the k-th rating goes to its group's
+        # first slot plus its rank in the group: k, shifted group by group
+        shift = slot0 - (np.cumsum(counts) - counts)
+        self.slot = np.empty(self.nnz, np.int64)
+        self.slot[_stable_group_order(group_idx, n_groups)] = (
+            np.arange(self.nnz) + np.repeat(shift, counts))
 
-    def sort_pad(self, a: np.ndarray, fill=0) -> np.ndarray:
-        """``a`` reordered by the plan's sort, padded to the chunk
-        multiple with ``fill`` (pad weights MUST be 0 — every
-        accumulator term is weight-scaled, which is what makes the pad
-        slots inert)."""
-        out = np.asarray(a)[self.order]
-        if self.pad:
-            out = np.concatenate(
-                [out, np.full((self.pad,) + out.shape[1:], fill,
-                              out.dtype)])
-        return out
+    @classmethod
+    def of_counts(cls, counts: np.ndarray, rank: int,
+                  block_groups: Optional[int] = None,
+                  block_slots: Optional[int] = None) -> "GroupedPlan":
+        """The plan's classes for groups of ``counts`` ratings, without a
+        place for any rating: the shapes of the epoch body's program."""
+        plan = cls.__new__(cls)
+        plan._lay_out(np.asarray(counts), rank, block_groups, block_slots)
+        plan.slot = None
+        return plan
+
+    def _lay_out(self, counts, rank, block_groups, block_slots):
+        """Sets the classes and the split parts; returns every group's
+        first slot."""
+        sized = _block_sizes(rank)
+        block_groups = int(block_groups or sized[0])
+        block_slots = int(block_slots or sized[1])
+        if block_slots % _MIN_LENGTH:
+            raise ValueError("block_slots must be a multiple of "
+                             f"{_MIN_LENGTH}")
+        n_groups = len(counts)
+        self.n_groups, self.nnz = n_groups, int(counts.sum())
+        self.counts = counts
+        length = _padded_lengths(counts)
+        whole = (counts > 0) & (length <= block_slots)
+        sizes, members = np.unique(length[whole], return_counts=True)
+        self.blocks = int(max(
+            [1, -(-int(whole.sum()) // block_groups)]
+            + [-(-int(n) // (block_slots // int(size)))
+               for size, n in zip(sizes, members)]))
+        slot0 = np.zeros(n_groups, np.int64)     # a group's first slot
+        self.classes, offset = [], 0
+        for size in sizes:
+            ids = np.flatnonzero(whole & (length == size))
+            groups = -(-len(ids) // self.blocks)
+            rows = np.full(self.blocks * groups, n_groups, np.int32)
+            rows[:len(ids)] = ids
+            slot0[ids] = offset + np.arange(len(ids)) * int(size)
+            self.classes.append(GroupedClass(
+                int(size), groups, rows.reshape(self.blocks, groups),
+                offset))
+            offset += self.blocks * groups * int(size)
+        self.split_offset, self.split_length = offset, block_slots
+        long_ = np.flatnonzero(counts > block_slots)
+        parts = -(-counts[long_] // block_slots)
+        ends = np.cumsum(parts)
+        self.parts = int(parts.sum())
+        slot0[long_] = offset + (ends - parts) * block_slots
+        self.split_rows = np.repeat(long_, parts).astype(np.int32)[:, None]
+        self.first = np.zeros(self.parts, bool)
+        self.last = np.zeros(self.parts, bool)
+        self.first[ends - parts], self.last[ends - 1] = True, True
+        self.slots = int(offset + self.parts * block_slots)
+        return slot0
+
+    @property
+    def block_groups(self) -> int:
+        """Groups a block holds, fill included."""
+        return sum(c.groups for c in self.classes)
+
+    @property
+    def padded_share(self) -> float:
+        """Pad slots over all slots."""
+        return 1.0 - self.nnz / max(self.slots, 1)
+
+    def arrange(self, values: np.ndarray) -> tuple:
+        """``values`` (one a rating) in the plan's slots, the padding 0:
+        ``(a (blocks, groups * length) array a class, the (parts,
+        block_slots) array of the split groups)``."""
+        flat = np.zeros(self.slots, np.asarray(values).dtype)
+        flat[self.slot] = values
+        return (tuple(flat[c.offset:c.offset + c.rows.size * c.length]
+                      .reshape(self.blocks, -1) for c in self.classes),
+                flat[self.split_offset:].reshape(self.parts, self.split_length))
+
+    def unit_weights(self) -> tuple:
+        """What :meth:`arrange` gives for a weight of 1 on every rating,
+        made from the groups' counts without a pass over the ratings: a
+        group's first ``count`` slots are its ratings."""
+        counts = np.append(self.counts, 0)
+        whole = tuple(
+            (np.arange(c.length) < counts[c.rows][:, :, None]).astype(
+                np.float32).reshape(self.blocks, -1) for c in self.classes)
+        # a part holds what its group has left after the parts before it
+        part = np.arange(self.parts)
+        part -= np.maximum.accumulate(np.where(self.first, part, 0))
+        left = counts[self.split_rows[:, 0]] - part * self.split_length
+        split = (np.arange(self.split_length) < left[:, None]).astype(
+            np.float32)
+        return whole, split
+
+    def arrays(self, other_idx, ratings, weights=None) -> tuple:
+        """What the epoch body scans: ``(whole, split)``.  ``whole`` has
+        ``(other side's index, rating, weight, rows)`` a class, leading
+        axis the blocks; ``split`` is ``(index, rating, weight, rows,
+        first, last)`` over the parts, or ``()`` where no group is split.
+        The padding has weight 0 (every term of the normal equations is
+        scaled by the weight, which is what makes it inert) and points at
+        row 0.  ``weights=None`` is a weight of 1 on every rating."""
+        columns = [(other_idx, np.int32), (ratings, np.float32)]
+        if weights is not None:
+            columns.append((weights, np.float32))
+        with ThreadPoolExecutor(len(columns)) as pool:
+            cols = list(pool.map(
+                lambda c: self.arrange(np.asarray(c[0], c[1])), columns))
+        if weights is None:
+            cols.append(self.unit_weights())
+        whole = tuple(
+            (o, r, w, c.rows) for o, r, w, c in zip(
+                *(col[0] for col in cols), self.classes))
+        split = ()
+        if self.parts:
+            split = tuple(col[1] for col in cols) + (
+                self.split_rows, self.first, self.last)
+        return whole, split
 
 
-def _normal_equations_sorted(factors, other_idx, ratings, weights,
-                             local_rank, g_lo, n_groups: int, span: int,
-                             chunk: int, implicit: bool, alpha: float):
-    """Sorted-path normal equations: inputs are PRE-SORTED by group and
-    padded (see :class:`NeqPlan`).  Equals :func:`_normal_equations` up
-    to f32 summation order, with zero scatters."""
+def _block_normal_equations(factors, other_idx, ratings, weights,
+                            groups: int, implicit: bool, alpha: float):
+    """``A`` ``(groups, rank, rank)``, ``b`` ``(groups, rank)`` and the
+    observed weight ``(groups,)`` of one block: its slots, group after
+    group, each group ``slots / groups`` long."""
     rank = factors.shape[1]
-    n_chunks = other_idx.shape[0] // chunk
-    span_iota = jnp.arange(span, dtype=jnp.int32)
-
-    def scan_step(carry, xs):
-        A, b, cnt = carry
-        o, r, w, lr_, glo = xs
-        y = factors[o]                                   # (chunk, rank)
-        oh = lr_[:, None] == span_iota[None, :]          # (chunk, span)
+    with jax.named_scope("als.gather"):
+        y = factors[other_idx].reshape(groups, -1, rank)
+    with jax.named_scope("als.normal_eq"):
+        r, w = ratings.reshape(groups, -1), weights.reshape(groups, -1)
         if implicit:
-            conf_m1 = alpha * jnp.abs(r) * w             # c - 1, weighted
+            # Hu/Koren: A += (c-1) y y^T per observed pair, b += c p y
+            # with p = 1 (the shared Y^T Y term is added at the solve);
+            # b's weight is w + (c-1) w, NOT (1 + conf_m1) * w, which
+            # would square fractional weights relative to A's
+            conf_m1 = alpha * jnp.abs(r) * w
             aw, bw = conf_m1, w + conf_m1
         else:
             aw, bw = w, w * r
-        outer = (y[:, :, None] * y[:, None, :]).reshape(-1, rank * rank)
-        A_part = jax.lax.dot_general(
-            jnp.where(oh, aw[:, None], 0.0), outer,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(span, rank, rank)
-        b_part = jax.lax.dot_general(
-            jnp.where(oh, bw[:, None], 0.0), y,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (span, rank)
-        cnt_part = jnp.sum(jnp.where(oh, w[:, None], 0.0), axis=0)
-        A = jax.lax.dynamic_update_slice(
-            A, jax.lax.dynamic_slice(
-                A, (glo, 0, 0), (span, rank, rank)) + A_part, (glo, 0, 0))
-        b = jax.lax.dynamic_update_slice(
-            b, jax.lax.dynamic_slice(b, (glo, 0), (span, rank)) + b_part,
-            (glo, 0))
-        cnt = jax.lax.dynamic_update_slice(
-            cnt, jax.lax.dynamic_slice(cnt, (glo,), (span,)) + cnt_part,
-            (glo,))
-        return (A, b, cnt), None
+        A = jnp.einsum("gls,glt->gst", y * aw[:, :, None], y,
+                       preferred_element_type=jnp.float32)
+        b = jnp.einsum("gls,gl->gs", y, bw,
+                       preferred_element_type=jnp.float32)
+        return A, b, jnp.sum(w, axis=1)
 
-    # `span` rows of slack so the last band's slice stays in bounds
-    init = (jnp.zeros((n_groups + span, rank, rank), factors.dtype),
-            jnp.zeros((n_groups + span, rank), factors.dtype),
-            jnp.zeros((n_groups + span,), factors.dtype))
-    xs = tuple(x.reshape(n_chunks, chunk, *x.shape[1:])
-               for x in (other_idx, ratings, weights, local_rank))
-    (A, b, cnt), _ = jax.lax.scan(scan_step, init, xs + (g_lo,))
-    return A[:n_groups], b[:n_groups], cnt[:n_groups]
+
+def _cholesky_solve_lanes(A, b):
+    """``x`` with ``A x = b`` for a batch of symmetric positive definite
+    ``A`` ``(groups, rank, rank)``, ``b`` ``(groups, rank)``: an exact
+    Cholesky factorisation and both triangular solves, written with the
+    GROUPS on the lanes.  ``(rank, rank, groups)`` pads nothing on the
+    chip where ``(groups, rank, rank)`` pads every matrix to ``(8k, 128)``
+    lanes, and a column step is one pass of elementwise work over
+    ``(rank, groups)`` slabs that vectorises over the groups.  A matrix
+    that is not positive definite gives NaN, which the caller catches."""
+    rank = A.shape[-1]
+    At = jnp.transpose(A, (2, 1, 0))         # At[k, i] = A[i, k]: column k
+    index = jnp.arange(rank)[:, None]
+
+    def factor(j, L):
+        # left-looking: column j of A less the columns already made, each
+        # scaled by its entry in row j (columns not made yet are 0).  One
+        # pass over the whole factor a column: static bounds on the
+        # columns and rows a panel needs make XLA copy the slices (1.12 s
+        # an epoch against 0.90 at rank 100, PERF.md section 6)
+        row = jax.lax.dynamic_index_in_dim(L, j, 1, keepdims=False)
+        col = (jax.lax.dynamic_index_in_dim(At, j, 0, keepdims=False)
+               - jnp.sum(L * row[:, None, :], axis=0))
+        pivot = jnp.sqrt(jax.lax.dynamic_index_in_dim(col, j, 0))
+        col = jnp.where(index >= j, col / pivot, 0.0)
+        return jax.lax.dynamic_update_index_in_dim(L, col, j, 0)
+
+    L = jax.lax.fori_loop(0, rank, factor, jnp.zeros_like(At))
+
+    def forward(j, y):                       # L y = b, column by column
+        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
+        yj = (jax.lax.dynamic_index_in_dim(y, j, 0)
+              / jax.lax.dynamic_index_in_dim(col, j, 0))
+        return jnp.where(index > j, y - col * yj,
+                         jnp.where(index == j, yj, y))
+
+    def backward(t, x):                      # L^T x = y, from the last row
+        j = rank - 1 - t
+        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
+        below = jnp.sum(jnp.where(index > j, col * x, 0.0), axis=0,
+                        keepdims=True)
+        xj = ((jax.lax.dynamic_index_in_dim(x, j, 0) - below)
+              / jax.lax.dynamic_index_in_dim(col, j, 0))
+        return jax.lax.dynamic_update_index_in_dim(x, xj, j, 0)
+
+    y = jax.lax.fori_loop(0, rank, forward, b.T)
+    return jax.lax.fori_loop(0, rank, backward, y).T
+
+
+def _regularized(A, cnt, gram, reg: float, implicit: bool):
+    eye = jnp.eye(A.shape[-1], dtype=A.dtype)
+    if implicit:
+        return A + gram[None, :, :] + reg * eye[None, :, :]
+    # ALS-WR: per-row lambda scaled by the row's rating count.
+    return A + (reg * jnp.maximum(cnt, 1.0))[:, None, None] * eye[None, :, :]
+
+
+def _solve_side_grouped(prev, factors, plan: "GroupedPlan", arrays,
+                        reg: float, implicit: bool, alpha: float):
+    """Grouped half-epoch: ``prev``-side factors re-solved against fixed
+    ``factors``: a block of groups a scan step (class by class the
+    gather and the contractions, then one solve for the block), then a
+    split group's parts, one a step."""
+    gram = factors.T @ factors if implicit else None      # shared Y^T Y
+    whole, split = arrays
+
+    def solved_rows(out, A, b, cnt, rows):
+        with jax.named_scope("als.normal_eq"):
+            A = _regularized(A, cnt, gram, reg, implicit)
+        with jax.named_scope("als.solve"):
+            solved = _cholesky_solve_lanes(A, b)
+        # a group without a rating, and a singular system (regParam 0 and
+        # fewer ratings than rank factor to NaN), keep their factors
+        # rather than spreading NaN through the next half-epoch's gathers
+        ok = ((cnt > 0)[:, None]
+              & jnp.all(jnp.isfinite(solved), axis=1, keepdims=True))
+        solved = jnp.where(ok, solved, out.at[rows].get(mode="clip"))
+        return out.at[rows].set(solved, mode="drop")
+
+    def block(out, xs):
+        parts = [_block_normal_equations(factors, o, r, w, c.groups,
+                                         implicit, alpha) + (rows,)
+                 for (o, r, w, rows), c in zip(xs, plan.classes)]
+        A, b, cnt, rows = (jnp.concatenate(p) for p in zip(*parts))
+        return solved_rows(out, A, b, cnt, rows), None
+
+    def part(carry, xs):
+        out, held = carry
+        o, r, w, rows, first, last = xs
+        held = tuple(jnp.where(first, new, new + acc) for new, acc in zip(
+            _block_normal_equations(factors, o, r, w, 1, implicit, alpha),
+            held))
+        rows = jnp.where(last, rows, plan.n_groups)
+        return (solved_rows(out, *held, rows), held), None
+
+    out = prev
+    if plan.classes:
+        out, _ = jax.lax.scan(block, out, whole)
+    if plan.parts:
+        rank = factors.shape[1]
+        held = (jnp.zeros((1, rank, rank), prev.dtype),
+                jnp.zeros((1, rank), prev.dtype), jnp.zeros((1,), prev.dtype))
+        (out, _), _ = jax.lax.scan(part, (out, held), split)
+    return out
+
+
+def _planned_side(group_idx, other_idx, n_groups: int, ratings, rank: int):
+    """One side's plan and what the epoch body scans of it."""
+    plan = GroupedPlan(group_idx, n_groups, rank)
+    return plan, plan.arrays(other_idx, ratings)
+
+
+def grouped_normal_equations(factors, plan: "GroupedPlan", arrays,
+                             implicit: bool = False, alpha: float = 1.0):
+    """The grouped form's ``A``, ``b`` and observed weights for ALL groups
+    as dense arrays, through the epoch body's own
+    :func:`_block_normal_equations`: what the tests hold against the plain
+    per-group sums.  The fit never forms these."""
+    rank = factors.shape[1]
+    dense = (jnp.zeros((plan.n_groups, rank, rank), factors.dtype),
+             jnp.zeros((plan.n_groups, rank), factors.dtype),
+             jnp.zeros((plan.n_groups,), factors.dtype))
+    whole, split = jax.tree_util.tree_map(jnp.asarray, arrays)
+    blocks = [(xs, c.groups) for xs, c in zip(whole, plan.classes)]
+    for xs, groups in blocks + ([(split[:4], 1)] if plan.parts else []):
+        for o, r, w, rows in zip(*xs):
+            new = _block_normal_equations(factors, o, r, w, groups,
+                                          implicit, alpha)
+            dense = tuple(acc.at[rows].add(p, mode="drop")
+                          for acc, p in zip(dense, new))
+    return dense
 
 
 class ALSModelParams(HasPredictionCol):
@@ -229,11 +489,14 @@ class ALSParams(ALSModelParams, HasMaxIter, HasSeed):
                        default=1.0, validator=ParamValidators.gt_eq(0))
     NEQ_IMPL = StringParam(
         "normalEquationsImpl",
-        "Normal-equation accumulation: 'sorted' (default via 'auto') — "
-        "one static host sort per fit turns the per-rating scatter-adds "
-        "into chunked MXU contractions over narrow contiguous group "
-        "bands (the LR/WDL static-routing insight applied to ALS); "
-        "'scatter' keeps the jnp .at[].add form.  Both are exact up to "
+        "Normal equations: 'sorted' — one static host plan per fit side "
+        "lays each group's ratings out at a padded length, and the epoch "
+        "forms and solves the equations a block of groups at a time as "
+        "batched MXU contractions over each group's own slots (the "
+        "grouped form: the chip holds one block's A); 'scatter' keeps the "
+        "jnp .at[].add form over dense (n_groups, rank, rank) operands; "
+        "'auto' (default) takes the grouped form wherever a side's dense "
+        "operand would be larger than one block.  All are exact up to "
         "f32 summation order.",
         default="auto",
         validator=ParamValidators.in_array(("auto", "sorted", "scatter")))
@@ -337,14 +600,8 @@ def _solve_from_neq(prev, factors, A, b, cnt, reg: float, implicit: bool):
     """The solve tail shared by both normal-equation forms: regularize,
     batched Cholesky, keep previous factors for unobserved/singular
     groups."""
-    rank = factors.shape[1]
-    eye = jnp.eye(rank, dtype=factors.dtype)
-    if implicit:
-        gram = factors.T @ factors                         # shared Y^T Y
-        A = A + gram[None, :, :] + reg * eye[None, :, :]
-    else:
-        # ALS-WR: per-row lambda scaled by the row's rating count.
-        A = A + (reg * jnp.maximum(cnt, 1.0))[:, None, None] * eye[None, :, :]
+    gram = factors.T @ factors if implicit else None      # shared Y^T Y
+    A = _regularized(A, cnt, gram, reg, implicit)
     chol = jax.scipy.linalg.cho_factor(A)
     solved = jax.scipy.linalg.cho_solve(chol, b[..., None])[..., 0]
     # A singular system (regParam=0 + fewer ratings than rank) factors to
@@ -365,30 +622,20 @@ def _solve_side(prev, factors, group_idx, other_idx, ratings, weights,
     return _solve_from_neq(prev, factors, A, b, cnt, reg, implicit)
 
 
-def _solve_side_sorted(prev, factors, plan: "NeqPlan", other_idx, ratings,
-                       weights, local_rank, g_lo, n_groups: int,
-                       reg: float, implicit: bool, alpha: float):
-    """Sorted-path half-epoch (arrays pre-sorted by this side's group)."""
-    A, b, cnt = _normal_equations_sorted(
-        factors, other_idx, ratings, weights, local_rank, g_lo,
-        n_groups, plan.span, plan.chunk, implicit, alpha)
-    return _solve_from_neq(prev, factors, A, b, cnt, reg, implicit)
-
-
 def als_epoch_step(n_users: int, n_items: int, reg: float, implicit: bool,
                    alpha: float, plans=None):
     """One ALS epoch (users then items) as an ``iterate`` body.
 
-    ``plans=(plan_u, plan_v)`` (:class:`NeqPlan`) switches to the
-    sorted normal equations — the data tuple is then the pre-sorted
-    per-side arrays (see :meth:`ALS.fit`) instead of the raw
-    ``(u_idx, i_idx, r, w)``."""
+    ``plans=(plan_u, plan_v)`` (:class:`GroupedPlan`) switches to the
+    grouped normal equations — the data is then each side's
+    :meth:`GroupedPlan.arrays` instead of the raw ``(u_idx, i_idx, r,
+    w)``."""
 
     def body(state, epoch, data):
         U, V = state
         # TPU f32 matmuls default to bf16 inputs; the normal equations and
         # triangular solves need true f32 or convergence stalls well short
-        # of the CPU result (rank is tiny, so "highest" costs nothing).
+        # of the CPU result.
         with jax.default_matmul_precision("highest"):
             if plans is None:
                 u_idx, i_idx, r, w = data
@@ -398,12 +645,11 @@ def als_epoch_step(n_users: int, n_items: int, reg: float, implicit: bool,
                                 implicit, alpha)
             else:
                 plan_u, plan_v = plans
-                (ou, ru, wu, lru, glu,
-                 ov, rv, wv, lrv, glv) = data
-                U = _solve_side_sorted(U, V, plan_u, ou, ru, wu, lru, glu,
-                                       n_users, reg, implicit, alpha)
-                V = _solve_side_sorted(V, U, plan_v, ov, rv, wv, lrv, glv,
-                                       n_items, reg, implicit, alpha)
+                by_user, by_item = data
+                U = _solve_side_grouped(U, V, plan_u, by_user, reg,
+                                        implicit, alpha)
+                V = _solve_side_grouped(V, U, plan_v, by_item, reg,
+                                        implicit, alpha)
         return IterationBodyResult(feedback=(U, V))
 
     return body
@@ -429,7 +675,7 @@ def als_workset_epoch_step(n_users: int, n_items: int, reg: float,
     criterion ends the fused while_loop strictly before ``maxIter``.
 
     Uses the raw-index (scatter) data tuple — the movement aggregation
-    needs the per-rating (user, item) ids that the sorted NeqPlan layout
+    needs the per-rating (user, item) ids that the grouped layout
     deliberately discards."""
 
     def body(state, ws, epoch, data):
@@ -472,6 +718,9 @@ class ALSModel(ALSModelParams, Model):
         self._item_ids: Optional[np.ndarray] = None
         self._user_factors: Optional[np.ndarray] = None
         self._item_factors: Optional[np.ndarray] = None
+        #: the normal-equation form the fit that made this model planned
+        #: ("grouped" or "scatter"); None for a model that was loaded
+        self.neq_plan: Optional[str] = None
 
     def set_model_data(self, *inputs) -> "ALSModel":
         (t,) = inputs
@@ -603,91 +852,107 @@ class ALSModel(ALSModelParams, Model):
 class ALS(ALSParams, Estimator[ALSModel]):
     def fit(self, *inputs) -> ALSModel:
         (table,) = inputs
+        with tracer.fit_span(type(self).__name__):
+            return self._fit(table)
+
+    def _fit(self, table: Table) -> ALSModel:
+        """``fit`` under its root span.  The phase spans (``fit.gather``
+        with its part ``.index``, ``fit.arrange`` with ``.plan``,
+        ``fit.upload``, then ``iterate.dispatch`` inside ``iterate``,
+        ``fit.fetch``) follow each other without a gap and add no fence.
+
+        The start is ``default_rng(seed)``: ``U0 = normal(size=(n_users,
+        rank)) / sqrt(rank)`` as float32, then ``V0`` the same, rows in
+        the order of the sorted distinct ids."""
         # report describes THIS fit only — a reused estimator must not
         # serve a stale report from an earlier workset fit
         self.last_workset_report = None
-        users = np.asarray(table[self.get_user_col()])
-        items = np.asarray(table[self.get_item_col()])
-        ratings = np.asarray(table[self.get_rating_col()], np.float32)
-        if len(ratings) == 0:
-            raise ValueError("ALS.fit requires at least one rating")
-        if self.get_implicit_prefs() and np.any(ratings < 0):
-            raise ValueError("implicitPrefs expects non-negative ratings "
-                             "(interaction strengths)")
-
-        user_ids, u_idx = np.unique(users, return_inverse=True)
-        item_ids, i_idx = np.unique(items, return_inverse=True)
+        with tracer.span("fit.gather", "fit"):
+            users = np.asarray(table[self.get_user_col()])
+            items = np.asarray(table[self.get_item_col()])
+            ratings = np.asarray(table[self.get_rating_col()], np.float32)
+            if len(ratings) == 0:
+                raise ValueError("ALS.fit requires at least one rating")
+            if self.get_implicit_prefs() and np.any(ratings < 0):
+                raise ValueError("implicitPrefs expects non-negative "
+                                 "ratings (interaction strengths)")
+            with tracer.span("fit.gather.index", "fit"):
+                user_ids, u_idx = _index_labels(users)
+                item_ids, i_idx = _index_labels(items)
+        n_users, n_items = len(user_ids), len(item_ids)
         rank = self.get_rank()
-        rng = np.random.default_rng(self.get_seed())
-        scale = 1.0 / np.sqrt(rank)
-        U0 = (rng.normal(size=(len(user_ids), rank)) * scale).astype(
-            np.float32)
-        V0 = (rng.normal(size=(len(item_ids), rank)) * scale).astype(
-            np.float32)
 
-        weights = np.ones(len(ratings), np.float32)
-        ws_tol = self.get_workset_tol()
+        with tracer.span("fit.arrange", "fit"):
+            rng = np.random.default_rng(self.get_seed())
+            scale = 1.0 / np.sqrt(rank)
+            U0 = (rng.normal(size=(n_users, rank)) * scale).astype(
+                np.float32)
+            V0 = (rng.normal(size=(n_items, rank)) * scale).astype(
+                np.float32)
+            ws_tol = self.get_workset_tol()
+            neq_mode = self.get(ALSParams.NEQ_IMPL)
+            # 'auto': the grouped form wherever one side's dense (n_groups,
+            # rank, rank) would be larger than the block it works on
+            grouped = ws_tol == 0 and (neq_mode == "sorted" or (
+                neq_mode == "auto"
+                and max(n_users, n_items) > _block_sizes(rank)[0]))
+            with tracer.span("fit.arrange.plan", "fit") as span:
+                plans = None
+                if grouped:
+                    # one static host plan per side (the ratings are fixed
+                    # for the whole fit), the two sides side by side; the
+                    # data ships in the plan's slots, so no per-epoch
+                    # permute exists on device
+                    with ThreadPoolExecutor(2) as pool:
+                        plans, data = zip(*pool.map(
+                            lambda side: _planned_side(*side, ratings, rank),
+                            ((u_idx, i_idx, n_users), (i_idx, u_idx, n_items))))
+                else:
+                    data = (u_idx.astype(np.int32), i_idx.astype(np.int32),
+                            ratings, np.ones(len(ratings), np.float32))
+                span.note(neq_plan="grouped" if grouped else "scatter",
+                          route_bytes=sum(int(a.nbytes) for a in
+                                          jax.tree_util.tree_leaves(data)))
+                if grouped:
+                    slots = sum(p.slots for p in plans)
+                    span.note(blocks=sum(p.blocks + p.parts for p in plans),
+                              slots=slots,
+                              padded_share=1.0 - 2.0 * len(ratings) / slots)
+
         if ws_tol > 0:
-            return self._fit_workset(user_ids, item_ids, u_idx, i_idx,
-                                     ratings, weights, U0, V0, ws_tol)
-        neq_mode = self.get(ALSParams.NEQ_IMPL)
-        plans = None
-        if neq_mode in ("auto", "sorted"):
-            # 'auto' bounds the span from a cheap bincount FIRST: the
-            # long-tail common case falls back to scatter without ever
-            # paying the plan's two O(nnz log nnz) argsorts
-            if (neq_mode == "auto"
-                    and max(_neq_plan_span(u_idx), _neq_plan_span(i_idx))
-                    > _NEQ_AUTO_SPAN_CAP):
-                pass   # long-tail data: scatter wins; no plan is built
-            else:
-                # one static host sort per side (the ratings are fixed
-                # for the whole fit); the data tuple ships pre-sorted,
-                # so no per-epoch permute exists on device
-                plan_u = NeqPlan(u_idx)
-                plan_v = NeqPlan(i_idx)
-                plans = (plan_u, plan_v)
-        if plans is not None:
-            data = tuple(jnp.asarray(a) for a in (
-                plan_u.sort_pad(i_idx.astype(np.int32)),
-                plan_u.sort_pad(ratings),
-                plan_u.sort_pad(weights),
-                plan_u.local_rank, plan_u.g_lo,
-                plan_v.sort_pad(u_idx.astype(np.int32)),
-                plan_v.sort_pad(ratings),
-                plan_v.sort_pad(weights),
-                plan_v.local_rank, plan_v.g_lo))
-        else:
-            plans = None
-            data = (jnp.asarray(u_idx, jnp.int32),
-                    jnp.asarray(i_idx, jnp.int32),
-                    jnp.asarray(ratings), jnp.asarray(weights))
+            return self._fit_workset(user_ids, item_ids, data, U0, V0, ws_tol)
+        with tracer.span("fit.upload", "fit"):
+            # the start goes up flat (a 2-D put of narrow rows costs the
+            # host a transposition) and takes its shape on the device
+            state = tuple(jnp.asarray(x.ravel()).reshape(x.shape)
+                          for x in (U0, V0))
+            data = jax.tree_util.tree_map(jnp.asarray, data)
         result = iterate(
-            als_epoch_step(len(user_ids), len(item_ids),
-                           self.get_reg_param(), self.get_implicit_prefs(),
-                           self.get_alpha(), plans=plans),
-            (jnp.asarray(U0), jnp.asarray(V0)),
-            data,
+            als_epoch_step(n_users, n_items, self.get_reg_param(),
+                           self.get_implicit_prefs(), self.get_alpha(),
+                           plans=plans),
+            state, data,
             max_epochs=self.get_max_iter(),
             config=IterationConfig(mode="fused"),
         )
-        U, V = (np.asarray(jax.device_get(x)) for x in result.state)
+        with tracer.span("fit.fetch", "fit"):
+            U, V = (np.asarray(jax.device_get(x)) for x in result.state)
 
         model = ALSModel()
         model.copy_params_from(self)
         model.set_model_data(Table({
             "userIds": user_ids[None], "itemIds": item_ids[None],
             "userFactors": U[None], "itemFactors": V[None]}))
+        model.neq_plan = "grouped" if grouped else "scatter"
         return model
 
-    def _fit_workset(self, user_ids, item_ids, u_idx, i_idx, ratings,
-                     weights, U0, V0, ws_tol: float) -> ALSModel:
-        """Workset (delta-iteration) fit: raw-index data, both sides
+    def _fit_workset(self, user_ids, item_ids, data, U0, V0,
+                     ws_tol: float) -> ALSModel:
+        """Workset (delta-iteration) fit: raw-index ``data``, both sides
         masked, convergence-driven while_loop exit (see
         :func:`als_workset_epoch_step`)."""
-        data = (jnp.asarray(u_idx, jnp.int32),
-                jnp.asarray(i_idx, jnp.int32),
-                jnp.asarray(ratings), jnp.asarray(weights))
+        with tracer.span("fit.upload", "fit"):
+            data = tuple(jnp.asarray(a) for a in data)
         ws0 = Workset({"users": jnp.ones((len(user_ids),), jnp.float32),
                        "items": jnp.ones((len(item_ids),), jnp.float32)})
         result = iterate(
@@ -709,12 +974,14 @@ class ALS(ALSParams, Estimator[ALSModel]):
                 trace.get("active_fraction", ()), np.float64),
             "n_groups": len(user_ids) + len(item_ids),
         }
-        U, V = (np.asarray(jax.device_get(x)) for x in result.state)
+        with tracer.span("fit.fetch", "fit"):
+            U, V = (np.asarray(jax.device_get(x)) for x in result.state)
         model = ALSModel()
         model.copy_params_from(self)
         model.set_model_data(Table({
             "userIds": user_ids[None], "itemIds": item_ids[None],
             "userFactors": U[None], "itemFactors": V[None]}))
+        model.neq_plan = "scatter"
         return model
 
     def save(self, path: str) -> None:

@@ -1,5 +1,6 @@
 """ALS matrix factorization tests (CPU mesh; fused iterate path)."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -232,109 +233,164 @@ def test_recommend_for_users_topk_and_exclude():
         model.recommend_for_users([0], k=0)
 
 
-def test_sorted_normal_equations_match_scatter():
-    """The sorted MXU normal equations must equal the scatter-add form
-    (f32 summation order aside) for explicit AND implicit modes,
-    including heavy groups whose runs cross chunk boundaries and
-    zero-weight (padding) ratings."""
+def _long_tailed(seed=41, n_groups=40, n_other=30, nnz=3000):
+    """A long-tailed rating set: most groups small, one of 700 ratings
+    (several blocks, or several parts, at the block sizes the tests pass),
+    one of ONE rating, one EMPTY (the last), a tenth of the weights 0."""
+    rng = np.random.default_rng(seed)
+    g = (rng.pareto(0.7, nnz) * 3).astype(np.int64) % (n_groups - 1)
+    g[:700] = 3
+    g = np.concatenate([g[g != 12], [12]])
+    o = rng.integers(0, n_other, len(g)).astype(np.int32)
+    r = rng.normal(size=len(g)).astype(np.float32)
+    w = np.where(rng.random(len(g)) < 0.1, 0.0, 1.0).astype(np.float32)
+    return g, o, r, w
+
+
+def _plain_normal_equations(Y, g, o, r, w, n_groups, implicit, alpha):
+    """The per-group sums, one rating at a time, in float64."""
+    Y = Y.astype(np.float64)
+    rank = Y.shape[1]
+    A = np.zeros((n_groups, rank, rank))
+    b = np.zeros((n_groups, rank))
+    cnt = np.zeros(n_groups)
+    for k in range(len(g)):
+        y = Y[o[k]]
+        if implicit:
+            aw = alpha * abs(r[k]) * w[k]
+            bw = w[k] + aw
+        else:
+            aw, bw = w[k], w[k] * r[k]
+        A[g[k]] += aw * np.outer(y, y)
+        b[g[k]] += bw * y
+        cnt[g[k]] += w[k]
+    return A, b, cnt
+
+
+# (block_groups, block_slots): one block; many blocks and the long group
+# split into parts of 64 slots; blocks of three groups, parts of 256
+_BLOCKS = [(None, None), (4, 64), (3, 256)]
+
+
+@pytest.mark.parametrize("implicit", [False, True])
+@pytest.mark.parametrize("block_groups,block_slots", _BLOCKS)
+def test_grouped_normal_equations_match_plain_sums(block_groups, block_slots,
+                                                   implicit):
+    """The grouped form's ``A``, ``b`` and ``cnt`` against the plain
+    per-group sums in float64, explicit AND implicit, on a long-tailed
+    set with a group of one rating, a group that spans several blocks (or
+    is split into parts) and an empty group, and zero-weight ratings."""
     import jax.numpy as jnp
 
     from flink_ml_tpu.models.recommendation.als import (
-        NeqPlan, _normal_equations, _normal_equations_sorted)
+        GroupedPlan, grouped_normal_equations)
 
-    rng = np.random.default_rng(41)
-    n_groups, n_other, nnz, rank = 12, 9, 700, 5
-    g = rng.integers(0, n_groups, size=nnz)
-    g[:300] = 3                      # heavy group spanning chunks
-    o = rng.integers(0, n_other, size=nnz).astype(np.int32)
-    r = rng.normal(size=nnz).astype(np.float32)
-    w = np.where(rng.random(nnz) < 0.1, 0.0, 1.0).astype(np.float32)
-    factors = rng.normal(size=(n_other, rank)).astype(np.float32)
-
-    for implicit in (False, True):
-        rr = np.abs(r) if implicit else r
-        A0, b0, c0 = _normal_equations(
-            jnp.asarray(factors), jnp.asarray(g, jnp.int32),
-            jnp.asarray(o), jnp.asarray(rr), jnp.asarray(w),
-            n_groups, implicit, 0.7)
-        plan = NeqPlan(g, chunk=128)   # force many chunk crossings
-        A1, b1, c1 = _normal_equations_sorted(
-            jnp.asarray(factors),
-            jnp.asarray(plan.sort_pad(o)),
-            jnp.asarray(plan.sort_pad(rr)),
-            jnp.asarray(plan.sort_pad(w)),
-            jnp.asarray(plan.local_rank), jnp.asarray(plan.g_lo),
-            n_groups, plan.span, plan.chunk, implicit, 0.7)
-        np.testing.assert_allclose(np.asarray(A1), np.asarray(A0),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(b1), np.asarray(b0),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(c1), np.asarray(c0),
-                                   rtol=1e-5, atol=1e-5)
+    n_groups, n_other, rank = 40, 30, 5
+    g, o, r, w = _long_tailed()
+    r = np.abs(r) if implicit else r
+    Y = np.random.default_rng(1).normal(size=(n_other, rank)).astype(
+        np.float32)
+    plan = GroupedPlan(g, n_groups, rank, block_groups, block_slots)
+    if block_slots:
+        assert plan.parts > 1 and plan.blocks > 1
+    A1, b1, c1 = grouped_normal_equations(
+        jnp.asarray(Y), plan, plan.arrays(o, r, w), implicit, 0.7)
+    A0, b0, c0 = _plain_normal_equations(Y, g, o, r, w, n_groups, implicit,
+                                         0.7)
+    assert c0[-1] == 0 and np.count_nonzero(g == 12) == 1
+    np.testing.assert_allclose(np.asarray(A1), A0, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(b1), b0, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(c1), c0, rtol=0, atol=0)
 
 
-def test_sorted_fit_matches_scatter_fit():
-    """End-to-end: the fit() default (sorted) reproduces the scatter
-    fit's factors allclose, explicit and implicit."""
+@pytest.mark.parametrize("implicit", [False, True])
+@pytest.mark.parametrize("block_groups,block_slots", _BLOCKS)
+def test_grouped_half_epoch_matches_scatter(block_groups, block_slots,
+                                            implicit):
+    """One half-epoch: the grouped form's blocks, formed and solved one
+    at a time by the Cholesky with the groups on the lanes, against the
+    scatter form's one batched ``cho_solve``; the empty group keeps its
+    factors in both."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.models.recommendation.als import (
+        GroupedPlan, _solve_side, _solve_side_grouped)
+
+    n_groups, n_other, rank = 40, 30, 5
+    g, o, r, w = _long_tailed(seed=7)
+    r = np.abs(r) if implicit else r
+    rng = np.random.default_rng(2)
+    Y = jnp.asarray(rng.normal(size=(n_other, rank)).astype(np.float32))
+    prev = jnp.asarray(rng.normal(size=(n_groups, rank)).astype(np.float32))
+    plan = GroupedPlan(g, n_groups, rank, block_groups, block_slots)
+    arrays = jax.tree_util.tree_map(jnp.asarray, plan.arrays(o, r, w))
+    with jax.default_matmul_precision("highest"):
+        grouped = _solve_side_grouped(prev, Y, plan, arrays, 0.05, implicit,
+                                      0.7)
+        scatter = _solve_side(prev, Y, jnp.asarray(g, jnp.int32),
+                              jnp.asarray(o), jnp.asarray(r), jnp.asarray(w),
+                              n_groups, 0.05, implicit, 0.7)
+    np.testing.assert_array_equal(np.asarray(grouped)[-1],
+                                  np.asarray(prev)[-1])
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(scatter),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("implicit", [False, True])
+def test_grouped_fit_matches_scatter_fit(implicit):
+    """End-to-end: ``normalEquationsImpl='sorted'`` (the grouped form)
+    reproduces the scatter fit's factors allclose, explicit and
+    implicit, and each model says which form its fit planned."""
     rng = np.random.default_rng(42)
     n = 1500
     users = rng.integers(0, 40, n).astype(np.int64)
     items = rng.integers(0, 25, n).astype(np.int64)
     ratings = (np.sin(users * 0.3) + np.cos(items * 0.5)
                + 0.05 * rng.normal(size=n)).astype(np.float32)
-    t = Table({"user": users, "item": items, "rating": ratings})
+    t = Table({"user": users, "item": items,
+               "rating": np.abs(ratings) if implicit else ratings})
 
-    for implicit in (False, True):
-        r_col = np.abs(ratings) if implicit else ratings
-        ti = Table({"user": users, "item": items, "rating": r_col})
+    def fit(impl):
+        return (ALS().set_rank(6).set_max_iter(4).set_seed(0)
+                .set_implicit_prefs(implicit).set(ALS.NEQ_IMPL, impl)
+                .fit(t))
 
-        def fit(impl):
-            est = (ALS().set_user_col("user").set_item_col("item")
-                   .set_rating_col("rating").set_rank(6).set_max_iter(4)
-                   .set_seed(0).set_implicit_prefs(implicit)
-                   .set(ALS.NEQ_IMPL, impl))
-            return est.fit(ti if implicit else t)
-
-        m_sorted, m_scatter = fit("sorted"), fit("scatter")
-        for a, b in zip(m_sorted.get_model_data(),
-                        m_scatter.get_model_data()):
-            np.testing.assert_allclose(
-                np.asarray(a["userFactors"]), np.asarray(b["userFactors"]),
-                rtol=5e-3, atol=5e-3)
+    m_grouped, m_scatter = fit("sorted"), fit("scatter")
+    assert (m_grouped.neq_plan, m_scatter.neq_plan) == ("grouped", "scatter")
+    (a,), (b,) = m_grouped.get_model_data(), m_scatter.get_model_data()
+    for col in ("userFactors", "itemFactors"):
+        np.testing.assert_allclose(np.asarray(a[col]), np.asarray(b[col]),
+                                   rtol=5e-3, atol=5e-3)
 
 
-def test_auto_falls_back_to_scatter_on_long_tail():
-    """'auto' must not pick the sorted path when the per-chunk group
-    band degenerates (long-tail data: most groups have 1-2 ratings) —
-    span is host-known at plan time, so the fallback is free."""
+def test_auto_takes_the_grouped_form_where_a_side_outgrows_a_block(
+        monkeypatch):
+    """'auto' keeps the scatter form while both sides' dense
+    ``(n_groups, rank, rank)`` fit one block, and takes the grouped form
+    as soon as one does not (long-tail data: every user one rating) — the
+    block is known from the rank and the device before any plan is
+    built."""
     from flink_ml_tpu.models.recommendation import als as als_mod
 
     rng = np.random.default_rng(43)
     n = 600
-    users = np.arange(n).astype(np.int64)       # every user one rating
-    items = rng.integers(0, 20, n).astype(np.int64)
-    ratings = rng.normal(size=n).astype(np.float32)
-    t = Table({"user": users, "item": items, "rating": ratings})
-
-    # span_u == chunk-wide band here; force a tiny cap to trigger
-    old = als_mod._NEQ_AUTO_SPAN_CAP
-    als_mod._NEQ_AUTO_SPAN_CAP = 8
-    try:
-        model = (ALS().set_user_col("user").set_item_col("item")
-                 .set_rating_col("rating").set_rank(4).set_max_iter(2)
-                 .set_seed(0).fit(t))
-    finally:
-        als_mod._NEQ_AUTO_SPAN_CAP = old
-    assert model.get_model_data()  # fit completed on the scatter path
+    t = Table({"user": np.arange(n).astype(np.int64),
+               "item": rng.integers(0, 20, n).astype(np.int64),
+               "rating": rng.normal(size=n).astype(np.float32)})
+    est = ALS().set_rank(4).set_max_iter(2).set_seed(0)
+    small = est.fit(t)
+    assert small.neq_plan == "scatter"
+    monkeypatch.setattr(als_mod, "_block_sizes", lambda rank: (256, 1024))
+    large = est.fit(t)
+    assert large.neq_plan == "grouped"
+    np.testing.assert_allclose(
+        np.asarray(large.get_model_data()[0]["userFactors"]),
+        np.asarray(small.get_model_data()[0]["userFactors"]),
+        rtol=1e-3, atol=1e-4)
 
 
-def test_neq_plan_span_matches_full_plan():
-    """The bincount-based span bound 'auto' consults BEFORE building a
-    NeqPlan must equal the plan's own span exactly — it is the same
-    sorted-sequence arithmetic without the O(nnz log nnz) argsort."""
-    from flink_ml_tpu.models.recommendation.als import (NeqPlan,
-                                                        _neq_plan_span)
-
+def _plan_cases():
     rng = np.random.default_rng(0)
     cases = []
     for _ in range(8):
@@ -345,9 +401,116 @@ def test_neq_plan_span_matches_full_plan():
                      % n_groups)                           # long tail
     cases.append(np.zeros(300, np.int64))                  # single group
     cases.append(np.arange(300))                           # all singletons
-    for g in cases:
-        for chunk in (7, 64, 8192):
-            assert _neq_plan_span(g, chunk) == NeqPlan(g, chunk).span
+    return cases
+
+
+@pytest.mark.parametrize("block_groups,block_slots",
+                         [(7, 64), (64, 512), (8192, 1 << 21)])
+def test_grouped_plan_places_every_rating_once(block_groups, block_slots):
+    """The plan's arithmetic over uniform, long-tailed, single-group and
+    all-singleton sets: every rating has a slot of its own inside its
+    group's run, every block has one shape within the block sizes, a
+    group is whole in one class or split into parts, and what the plan
+    says of its padding is what its slots hold."""
+    from flink_ml_tpu.models.recommendation.als import (GroupedPlan,
+                                                        _padded_lengths)
+
+    for g in _plan_cases():
+        n_groups = int(g.max()) + 2                        # the last empty
+        plan = GroupedPlan(g, n_groups, 4, block_groups, block_slots)
+        counts = np.bincount(g, minlength=n_groups)
+        assert len(np.unique(plan.slot)) == len(g) == plan.nnz
+        assert plan.slot.min() >= 0 and plan.slot.max() < plan.slots
+        # each class rounds its share of a block up
+        assert plan.block_groups < block_groups + len(plan.classes)
+        whole, split = plan.arrays(np.zeros(len(g)), g + 1.0,
+                                   np.ones(len(g)))
+        # a weight of 1 a rating, made from the counts alone, is the same
+        for a, b in zip(jax.tree_util.tree_leaves((whole, split)),
+                        jax.tree_util.tree_leaves(
+                            plan.arrays(np.zeros(len(g)), g + 1.0)),
+                        strict=True):
+            np.testing.assert_array_equal(a, b)
+        seen = np.zeros(n_groups, np.int64)
+        for (o, r, w, rows), c in zip(whole, plan.classes, strict=True):
+            assert c.length % 8 == 0 and c.groups * c.length <= block_slots
+            assert o.shape == (plan.blocks, c.groups * c.length)
+            r = r.reshape(plan.blocks, c.groups, c.length)
+            w = w.reshape(r.shape)
+            real = rows < n_groups
+            # a group's slots hold its own ratings and nothing else
+            assert ((r == 0) | (r == rows[:, :, None] + 1.0)).all()
+            assert (w.sum(axis=2)[real] == counts[rows[real]]).all()
+            assert (w.sum(axis=2)[~real] == 0).all()
+            assert (_padded_lengths(counts[rows[real]]) == c.length).all()
+            np.add.at(seen, rows[real], 1)
+        if plan.parts:
+            o, r, w, rows, first, last = split
+            assert o.shape == (plan.parts, block_slots)
+            assert ((r == 0) | (r == rows + 1.0)).all()
+            assert first.sum() == last.sum() == len(np.unique(rows))
+            assert (counts[rows[:, 0]] > block_slots).all()
+            np.add.at(seen, rows[last, 0], 1)
+        assert (seen == (counts > 0)).all()
+        assert plan.padded_share == pytest.approx(
+            1.0 - len(g) / plan.slots)
+
+
+def test_index_labels_is_np_unique_with_inverse():
+    """The threaded index of a long label column (a part a thread, then
+    binary search) gives ``np.unique(..., return_inverse=True)``'s ids and
+    positions; a short column takes ``np.unique`` itself."""
+    from flink_ml_tpu.models.recommendation.als import _index_labels
+
+    rng = np.random.default_rng(5)
+    pool = rng.integers(1, 1 << 40, size=5000)
+    for n in (1000, (1 << 20) + 77):
+        labels = pool[rng.integers(0, len(pool), size=n)]
+        ids, index = _index_labels(labels)
+        want_ids, want_index = np.unique(labels, return_inverse=True)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(index, want_index)
+
+
+def _benchmark_module(kind, name):
+    """``benchmarks/<kind>/<name>.py``: the benchmark's plain reference
+    and generator import nothing of the program."""
+    import importlib
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return importlib.import_module(f"{kind}.{name}")
+
+
+@pytest.mark.parametrize("impl", ["sorted", "scatter"])
+def test_fit_matches_the_plain_reference_and_the_bf16_control_does_not(impl):
+    """``ALS.fit`` against ``benchmarks/references/als_wr.py`` (the dense
+    masked normal equations, LU solves, its own ids and start) at the
+    benchmark cell's rehearsal sizes, rank 16, within the cell's limits;
+    the reference with its contractions at one bf16 pass is not."""
+    files = _benchmark_module("harness", "files")
+    _, config = files.cell("als_netflix.fit", rehearsal=True)
+    seed = 2147483777
+    data = files.generate(config, seed)
+    reference = _benchmark_module("references", "als_wr")
+    model = (ALS().set_rank(config["rank"])
+             .set_reg_param(config["reg_param"])
+             .set_max_iter(config["max_iter"]).set_seed(seed)
+             .set(ALS.NEQ_IMPL, impl).fit(Table(data)))
+    (table,) = model.get_model_data()
+    answer = {name: np.asarray(table[name][0])
+              for name in table.column_names}
+    numbers = reference.compare(config, data, answer, seed)
+    for name, limit in config["limits"].items():
+        assert numbers[name] <= limit, (name, numbers)
+    control = reference.compare(
+        config, data, reference.control(config, data, seed), seed)
+    assert any(control[name] > limit
+               for name, limit in config["limits"].items()), control
 
 
 # -- workset (delta-iteration) fit, ISSUE 9 ----------------------------------

@@ -401,3 +401,78 @@ def test_widedeep_fit_program_forms_no_table_shaped_gradient(
              if re.search(r"= f32\[(%d,%d|%d,%d)\]\S* (copy|transpose)\("
                           % (n, e, e, n), line)]
     assert not moved, moved[:3]
+
+
+def test_als_fit_program_holds_one_block_of_normal_equations(one_v5e):
+    """The fused program of ``ALS.fit`` at the benchmark cell
+    ``als_netflix.fit``'s shapes (120,047 users x 17,770 items, 24.8 M
+    ratings with the generator's degrees, rank 100, 5 epochs) compiled
+    for a described v5e: the grouped form forms and solves the normal
+    equations a block of groups at a time, so the program fits the chip
+    with room (the users' dense ``(120047, 100, 100)`` alone is 6.4 GB as
+    the chip pads it, and Cholesky wants as much again), every ``A`` it
+    holds is block-shaped, and the factorisation works with the groups
+    on the lanes."""
+    import importlib
+    import json
+    import os
+    import re
+    import sys
+
+    import numpy as np
+
+    from flink_ml_tpu.models.recommendation import als
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    generator = importlib.import_module("generators.netflix_ratings")
+    with open(os.path.join(bench, "configs", "als_netflix.json")) as f:
+        config = json.load(f)
+    params = {**config, **config["generator_params"]}
+    users, items, rank = config["users"], config["items"], config["rank"]
+    rng = np.random.default_rng(0)
+    popularity = np.clip(generator._lognormal(
+        rng, items, params["item_count_median"], params["item_count_mean"]),
+        params["item_count_min"], params["item_count_max"])
+    plans = (
+        als.GroupedPlan.of_counts(generator.user_degrees(rng, params), rank),
+        als.GroupedPlan.of_counts(np.maximum(1, rng.multinomial(
+            config["rows"], popularity / popularity.sum())), rank))
+
+    def on_chip(shape, dtype):
+        return Shape(shape, dtype, sharding=one_v5e)
+
+    def arrays(plan):
+        whole = tuple(
+            (on_chip((plan.blocks, c.groups * c.length), I32),
+             on_chip((plan.blocks, c.groups * c.length), F32),
+             on_chip((plan.blocks, c.groups * c.length), F32),
+             on_chip((plan.blocks, c.groups), I32)) for c in plan.classes)
+        assert plan.parts == 0          # no group outgrows a block here
+        return whole, ()
+
+    body = als.als_epoch_step(users, items, config["reg_param"], False, 1.0,
+                              plans=plans)
+
+    def run(state, data):
+        return jax.lax.scan(
+            lambda s, epoch: (body(s, epoch, data).feedback, None), state,
+            jnp.arange(config["max_iter"], dtype=jnp.int32))[0]
+
+    compiled = jax.jit(run).lower(
+        (on_chip((users, rank), F32), on_chip((items, rank), F32)),
+        (arrays(plans[0]), arrays(plans[1]))).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert (4 << 30) < total < (12 << 30), mem
+    text = compiled.as_text()
+    batches = {int(n) for n in re.findall(
+        r"f32\[(\d+),%d,%d\]" % (rank, rank), text)}
+    on_lanes = {int(n) for n in re.findall(
+        r"f32\[%d,%d,(\d+)\]" % (rank, rank), text)}
+    blocks = {p.block_groups for p in plans}
+    assert blocks <= batches and on_lanes == blocks, (batches, on_lanes)
+    assert max(batches) <= als._block_sizes(rank)[0] + 64 < users

@@ -520,39 +520,66 @@ def test_widedeep_scatter_fit_takes_the_fused_update_on_device(
     np.testing.assert_allclose(fused.loss_log, oracle.loss_log, rtol=1e-4)
 
 
-def test_als_sorted_neq_on_device(tpu, rng):
-    """Sorted MXU normal equations vs the scatter form on the chip
-    (dynamic-slice band accumulation + one-hot dot_general under
-    'highest' precision)."""
+def test_als_grouped_neq_on_device(tpu, rng):
+    """The grouped normal equations of a skewed rating set on the chip
+    (gather, batched contractions over each group's slots at 'highest',
+    blocks of several shapes, one group split into parts) against the
+    per-group sums in float64."""
     import jax
     import jax.numpy as jnp
 
     from flink_ml_tpu.models.recommendation.als import (
-        NeqPlan, _normal_equations, _normal_equations_sorted)
+        GroupedPlan, grouped_normal_equations)
 
-    n_groups, n_other, nnz, rank = 16, 8, 512, 4
-    g = rng.integers(0, n_groups, size=nnz)
+    n_groups, n_other, nnz, rank = 300, 64, 20000, 24
+    g = np.minimum((n_groups * rng.random(nnz) ** 3).astype(np.int64),
+                   n_groups - 2)               # skewed; the last group empty
     o = rng.integers(0, n_other, size=nnz).astype(np.int32)
     r = rng.normal(size=nnz).astype(np.float32)
-    w = np.ones(nnz, np.float32)
+    w = np.where(rng.random(nnz) < 0.1, 0.0, 1.0).astype(np.float32)
     factors = rng.normal(size=(n_other, rank)).astype(np.float32)
-    plan = NeqPlan(g, chunk=128)
+    plan = GroupedPlan(g, n_groups, rank, block_groups=64, block_slots=1024)
+    assert plan.blocks > 1 and plan.parts > 1
     with jax.default_matmul_precision("highest"):
-        A0, b0, c0 = _normal_equations(
-            jnp.asarray(factors), jnp.asarray(g, jnp.int32),
-            jnp.asarray(o), jnp.asarray(r), jnp.asarray(w),
-            n_groups, False, 1.0)
-        A1, b1, c1 = _normal_equations_sorted(
-            jnp.asarray(factors), jnp.asarray(plan.sort_pad(o)),
-            jnp.asarray(plan.sort_pad(r)), jnp.asarray(plan.sort_pad(w)),
-            jnp.asarray(plan.local_rank), jnp.asarray(plan.g_lo),
-            n_groups, plan.span, plan.chunk, False, 1.0)
-    np.testing.assert_allclose(np.asarray(A1), np.asarray(A0),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(b1), np.asarray(b0),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(c1), np.asarray(c0),
-                               rtol=1e-5, atol=1e-5)
+        A1, b1, c1 = grouped_normal_equations(
+            jnp.asarray(factors), plan, plan.arrays(o, r, w))
+    y = factors.astype(np.float64)[o]
+    A0 = np.zeros((n_groups, rank, rank))
+    b0 = np.zeros((n_groups, rank))
+    np.add.at(A0, g, w[:, None, None] * y[:, :, None] * y[:, None, :])
+    np.add.at(b0, g, (w * r)[:, None] * y)
+    scale = np.abs(A0).max()
+    assert np.abs(np.asarray(A1) - A0).max() < 1e-5 * scale
+    assert np.abs(np.asarray(b1) - b0).max() < 1e-5 * np.abs(b0).max()
+    np.testing.assert_array_equal(np.asarray(c1),
+                                  np.bincount(g, w, minlength=n_groups))
+
+
+def test_als_fit_plans_grouped_on_device(tpu, rng):
+    """``ALS.fit`` under ``'sorted'`` on the chip: the model says
+    ``grouped``, and its factors are the scatter fit's (one batched
+    ``cho_solve`` over dense operands) to float32 rounding."""
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.recommendation.als import ALS
+
+    n = 20000
+    users = np.minimum((500 * rng.random(n) ** 2).astype(np.int64), 499)
+    items = rng.integers(0, 120, size=n).astype(np.int64)
+    pairs = np.unique(users * 120 + items)
+    table = Table({"user": pairs // 120, "item": pairs % 120,
+                   "rating": rng.integers(1, 6, size=len(pairs)).astype(
+                       np.float32)})
+
+    def fit(impl):
+        return (ALS().set_rank(16).set_reg_param(0.05).set_max_iter(3)
+                .set_seed(1).set(ALS.NEQ_IMPL, impl).fit(table))
+
+    grouped, scatter = fit("sorted"), fit("scatter")
+    assert (grouped.neq_plan, scatter.neq_plan) == ("grouped", "scatter")
+    (a,), (b,) = grouped.get_model_data(), scatter.get_model_data()
+    for col in ("userFactors", "itemFactors"):
+        np.testing.assert_allclose(np.asarray(a[col]), np.asarray(b[col]),
+                                   rtol=1e-3, atol=1e-4)
 
 
 @pytest.mark.parametrize("precision", [
