@@ -65,6 +65,9 @@ OP_SIGNATURES = {
     "kmeans_update_stats": [("", (1 << 20, 64, 256, "euclidean"))],
     "kmeans_workset_update": [("", (1 << 20, 64, 256, "euclidean", 1))],
     "routed_table_grad": [("", ("gather", 13, 8192 * 26))],
+    # (rank, groups): a users' block of the benchmark's ALS cell, and a
+    # split group's one system a step
+    "als_cholesky_solve": [("block", (100, 30_020)), ("part", (100, 1))],
     # (rows, width) at Criteo's cardinalities; width 0: the wide table
     "routed_adam_update": [("emb", (33_762_577, 16)),
                            ("wide", (33_762_577, 0))],
@@ -410,10 +413,16 @@ def leg_als(ctx) -> dict:
     one iteration from the seed's start, then the users of a sample
     (heaviest, lightest, a spread between) re-solved in float64 NumPy
     against the START's item factors, which is what the first half-epoch
-    saw."""
+    saw.  Then the solve by itself, op ``als_cholesky_solve`` as the
+    registry picks it here (on the chip the kernel that keeps a tile of
+    groups in VMEM), at the benchmark's rank 100 and this leg's 32 with a
+    ragged last tile, against a float64 solve and its XLA twin."""
+    import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from flink_ml_tpu import Table
+    from flink_ml_tpu.kernels.registry import lookup
     from flink_ml_tpu.models.recommendation.als import ALS
     from flink_ml_tpu.parallel.mesh import use_mesh
 
@@ -434,6 +443,10 @@ def leg_als(ctx) -> dict:
                  .fit(Table({"user": u, "item": i, "rating": r})))
     check(model.neq_plan == "grouped",
           f"als: the fit planned {model.neq_plan!r}, expected 'grouped'")
+    solve_plan = "vmem" if ctx["chip"] else "xla"
+    check(model.solve_plan == solve_plan,
+          f"als: the fit's blocks were solved by {model.solve_plan!r}, "
+          f"expected {solve_plan!r}")
     (data,) = model.get_model_data()
     user_ids = np.asarray(data["userIds"][0])
     item_ids = np.asarray(data["itemIds"][0])
@@ -463,7 +476,34 @@ def leg_als(ctx) -> dict:
                                  / max(np.abs(x).max(), 1e-12)))
     check(worst < 1e-3,
           f"als: a user's factors are {worst:.2e} off a float64 solve")
-    return {"neq_plan": model.neq_plan, "ratings": int(len(pairs)),
+    solve_gap = 0.0
+    for solve_rank, groups in (((100, 1100), (32, 4001)) if ctx["chip"]
+                               else ((100, 200), (32, 200))):
+        y = rng.normal(size=(groups, solve_rank + 3, solve_rank)).astype(
+            np.float32)
+        a = np.einsum("gls,glt->gst", y, y) + np.float32(0.5) * np.eye(
+            solve_rank, dtype=np.float32)
+        b = rng.normal(size=(groups, solve_rank)).astype(np.float32)
+        entry = lookup("als_cholesky_solve", sig=(solve_rank, groups))
+        check(entry.backend == ("pallas" if ctx["chip"] else "xla"),
+              f"als: the solve of {groups} groups resolved to "
+              f"{entry.backend!r}")
+        at, bt = jnp.transpose(jnp.asarray(a), (2, 1, 0)), jnp.asarray(b).T
+        got = np.asarray(jax.jit(entry.fn)(at, bt)).T
+        twin = np.asarray(jax.jit(
+            lookup("als_cholesky_solve", backend="xla").fn)(at, bt)).T
+        exact = np.linalg.solve(a.astype(np.float64),
+                                b.astype(np.float64)[..., None])[..., 0]
+        scale = np.abs(exact).max(axis=1, keepdims=True)
+        gaps = (float(np.max(np.abs(got - exact) / scale)),
+                float(np.max(np.abs(got - twin) / scale)))
+        check(max(gaps) < 2e-5,
+              f"als: the solve at rank {solve_rank} is {gaps[0]:.2e} off a "
+              f"float64 solve and {gaps[1]:.2e} off its XLA twin")
+        solve_gap = max(solve_gap, *gaps)
+    return {"neq_plan": model.neq_plan, "solve_plan": model.solve_plan,
+            "solve_gap": float(f"{solve_gap:.3g}"),
+            "ratings": int(len(pairs)),
             "users": int(len(user_ids)), "items": int(len(item_ids)),
             "heaviest_user": int(counts.max()),
             "worst_relative_gap": float(f"{worst:.3g}")}

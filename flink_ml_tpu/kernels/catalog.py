@@ -8,6 +8,7 @@ is the kernel's own business, an op's planning policy the model's):
 - ``ops/ell_scatter.py``      — ``ell_margin``, ``ell_scatter_apply``
 - ``ops/emb_grad.py`` / ``ops/emb_grad_pallas.py`` — ``routed_table_grad``
 - ``ops/adam_table_pallas.py`` — ``routed_adam_update``
+- ``ops/als_solve_pallas.py`` — ``als_cholesky_solve``
 - ``models/common/gbt.py``    — ``gbt_level_histograms``
 - ``models/common/linear.py`` — ``linear_margins`` (stage convention)
 - ``models/clustering/kmeans.py`` — ``kmeans_assign`` (stage),
@@ -25,7 +26,7 @@ lookup), never at ``flink_ml_tpu.kernels`` import — that keeps the
 registry itself dependency-free and cycle-safe.
 """
 
-from .. import ops  # noqa: F401  (ell + kmeans + emb_grad + adam + retrieve)
+from .. import ops  # noqa: F401  (ell + kmeans + emb_grad + adam + als + retrieve)
 from ..models.clustering import kmeans  # noqa: F401
 from ..models.common import gbt, linear  # noqa: F401
 from ..models.recommendation import widedeep  # noqa: F401

@@ -44,6 +44,7 @@ from typing import List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ...api.stage import Estimator, Model
 from ...data.table import Table
@@ -327,52 +328,25 @@ def _block_normal_equations(factors, other_idx, ratings, weights,
         return A, b, jnp.sum(w, axis=1)
 
 
-def _cholesky_solve_lanes(A, b):
-    """``x`` with ``A x = b`` for a batch of symmetric positive definite
-    ``A`` ``(groups, rank, rank)``, ``b`` ``(groups, rank)``: an exact
-    Cholesky factorisation and both triangular solves, written with the
-    GROUPS on the lanes.  ``(rank, rank, groups)`` pads nothing on the
-    chip where ``(groups, rank, rank)`` pads every matrix to ``(8k, 128)``
-    lanes, and a column step is one pass of elementwise work over
-    ``(rank, groups)`` slabs that vectorises over the groups.  A matrix
-    that is not positive definite gives NaN, which the caller catches."""
-    rank = A.shape[-1]
-    At = jnp.transpose(A, (2, 1, 0))         # At[k, i] = A[i, k]: column k
-    index = jnp.arange(rank)[:, None]
+def _block_solve(rank: int, groups: int):
+    """The entry of registry op ``als_cholesky_solve``
+    (``ops/als_solve_pallas.py``) for a block of ``groups`` systems of
+    ``rank`` unknowns: on a TPU a block of a lane tile of groups or more
+    is solved a tile at a time inside VMEM, every other block (the split
+    groups' one system a step among them) and every block off the TPU by
+    the XLA loop over the block's whole factor."""
+    from ...kernels.registry import lookup
 
-    def factor(j, L):
-        # left-looking: column j of A less the columns already made, each
-        # scaled by its entry in row j (columns not made yet are 0).  One
-        # pass over the whole factor a column: static bounds on the
-        # columns and rows a panel needs make XLA copy the slices (1.12 s
-        # an epoch against 0.90 at rank 100, PERF.md section 6)
-        row = jax.lax.dynamic_index_in_dim(L, j, 1, keepdims=False)
-        col = (jax.lax.dynamic_index_in_dim(At, j, 0, keepdims=False)
-               - jnp.sum(L * row[:, None, :], axis=0))
-        pivot = jnp.sqrt(jax.lax.dynamic_index_in_dim(col, j, 0))
-        col = jnp.where(index >= j, col / pivot, 0.0)
-        return jax.lax.dynamic_update_index_in_dim(L, col, j, 0)
+    return lookup("als_cholesky_solve", sig=(rank, groups))
 
-    L = jax.lax.fori_loop(0, rank, factor, jnp.zeros_like(At))
 
-    def forward(j, y):                       # L y = b, column by column
-        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
-        yj = (jax.lax.dynamic_index_in_dim(y, j, 0)
-              / jax.lax.dynamic_index_in_dim(col, j, 0))
-        return jnp.where(index > j, y - col * yj,
-                         jnp.where(index == j, yj, y))
-
-    def backward(t, x):                      # L^T x = y, from the last row
-        j = rank - 1 - t
-        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
-        below = jnp.sum(jnp.where(index > j, col * x, 0.0), axis=0,
-                        keepdims=True)
-        xj = ((jax.lax.dynamic_index_in_dim(x, j, 0) - below)
-              / jax.lax.dynamic_index_in_dim(col, j, 0))
-        return jax.lax.dynamic_update_index_in_dim(x, xj, j, 0)
-
-    y = jax.lax.fori_loop(0, rank, forward, b.T)
-    return jax.lax.fori_loop(0, rank, backward, y).T
+def _solve_plan(plans, rank: int) -> str:
+    """How a fit's blocks are solved, for the record: ``"vmem"`` or
+    ``"xla"`` (the backend of :func:`_block_solve`, by its other name),
+    ``"<users'>/<items'>"`` where the sides differ."""
+    names = ["vmem" if _block_solve(rank, p.block_groups).backend == "pallas"
+             else "xla" for p in plans if p.classes]
+    return "/".join(dict.fromkeys(names)) or "xla"
 
 
 def _regularized(A, cnt, gram, reg: float, implicit: bool):
@@ -396,7 +370,23 @@ def _solve_side_grouped(prev, factors, plan: "GroupedPlan", arrays,
         with jax.named_scope("als.normal_eq"):
             A = _regularized(A, cnt, gram, reg, implicit)
         with jax.named_scope("als.solve"):
-            solved = _cholesky_solve_lanes(A, b)
+            # the groups on the lanes: (rank, rank, groups) pads nothing
+            # on the chip where (groups, rank, rank) pads every matrix to
+            # (8k, 128); At[k, i] = A[i, k], column k
+            solve = _block_solve(A.shape[-1], A.shape[0])
+            if solve.backend == "pallas":
+                # The kernel's operand layout is lane-major.  Left to
+                # itself XLA pushes it back through the transposition and
+                # the concatenation to every class's contraction: a
+                # hundred small transposing copies in place of one, a
+                # program that compiles 12 s longer, and a memory-space
+                # assignment that gathers 5 M of an iteration's rows from
+                # HBM where it had the factors in VMEM (als_gather_ms 157
+                # -> 270, my chip run, PR 34).  Pinned group-major, A is
+                # transposed by one copy in front of the call and the
+                # program around it is the one the XLA backend gets.
+                A = with_layout_constraint(A, Layout(major_to_minor=(0, 1, 2)))
+            solved = solve.fn(jnp.transpose(A, (2, 1, 0)), b.T).T
         # a group without a rating, and a singular system (regParam 0 and
         # fewer ratings than rank factor to NaN), keep their factors
         # rather than spreading NaN through the next half-epoch's gathers
@@ -721,6 +711,11 @@ class ALSModel(ALSModelParams, Model):
         #: the normal-equation form the fit that made this model planned
         #: ("grouped" or "scatter"); None for a model that was loaded
         self.neq_plan: Optional[str] = None
+        #: how that fit solved a block of them: "vmem" (a tile of groups
+        #: at a time inside VMEM, op ``als_cholesky_solve``'s kernel) or
+        #: "xla"; like ``neq_plan`` a record of the fit, not saved with
+        #: the model: None for a model that was loaded
+        self.solve_plan: Optional[str] = None
 
     def set_model_data(self, *inputs) -> "ALSModel":
         (t,) = inputs
@@ -910,7 +905,9 @@ class ALS(ALSParams, Estimator[ALSModel]):
                 else:
                     data = (u_idx.astype(np.int32), i_idx.astype(np.int32),
                             ratings, np.ones(len(ratings), np.float32))
+                solve_plan = _solve_plan(plans, rank) if grouped else "xla"
                 span.note(neq_plan="grouped" if grouped else "scatter",
+                          solve=solve_plan,
                           route_bytes=sum(int(a.nbytes) for a in
                                           jax.tree_util.tree_leaves(data)))
                 if grouped:
@@ -944,6 +941,7 @@ class ALS(ALSParams, Estimator[ALSModel]):
             "userIds": user_ids[None], "itemIds": item_ids[None],
             "userFactors": U[None], "itemFactors": V[None]}))
         model.neq_plan = "grouped" if grouped else "scatter"
+        model.solve_plan = solve_plan
         return model
 
     def _fit_workset(self, user_ids, item_ids, data, U0, V0,
@@ -981,7 +979,7 @@ class ALS(ALSParams, Estimator[ALSModel]):
         model.set_model_data(Table({
             "userIds": user_ids[None], "itemIds": item_ids[None],
             "userFactors": U[None], "itemFactors": V[None]}))
-        model.neq_plan = "scatter"
+        model.neq_plan, model.solve_plan = "scatter", "xla"
         return model
 
     def save(self, path: str) -> None:

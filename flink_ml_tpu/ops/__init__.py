@@ -1,4 +1,5 @@
 from . import adam_table_pallas  # noqa: F401  (registers routed_adam_update)
+from . import als_solve_pallas  # noqa: F401  (registers als_cholesky_solve)
 from .emb_grad import (  # noqa: F401
     EmbGradRoute,
     emb_grad_route,

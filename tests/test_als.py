@@ -1,6 +1,7 @@
 """ALS matrix factorization tests (CPU mesh; fused iterate path)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -362,6 +363,99 @@ def test_grouped_fit_matches_scatter_fit(implicit):
     for col in ("userFactors", "itemFactors"):
         np.testing.assert_allclose(np.asarray(a[col]), np.asarray(b[col]),
                                    rtol=5e-3, atol=5e-3)
+
+
+def _parent_cholesky_solve_lanes(A, b):
+    """PR 33's ``als._cholesky_solve_lanes``, word for word: what the
+    grouped form called before the solve became registry op
+    ``als_cholesky_solve``."""
+    rank = A.shape[-1]
+    At = jnp.transpose(A, (2, 1, 0))         # At[k, i] = A[i, k]: column k
+    index = jnp.arange(rank)[:, None]
+
+    def factor(j, L):
+        row = jax.lax.dynamic_index_in_dim(L, j, 1, keepdims=False)
+        col = (jax.lax.dynamic_index_in_dim(At, j, 0, keepdims=False)
+               - jnp.sum(L * row[:, None, :], axis=0))
+        pivot = jnp.sqrt(jax.lax.dynamic_index_in_dim(col, j, 0))
+        col = jnp.where(index >= j, col / pivot, 0.0)
+        return jax.lax.dynamic_update_index_in_dim(L, col, j, 0)
+
+    L = jax.lax.fori_loop(0, rank, factor, jnp.zeros_like(At))
+
+    def forward(j, y):
+        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
+        yj = (jax.lax.dynamic_index_in_dim(y, j, 0)
+              / jax.lax.dynamic_index_in_dim(col, j, 0))
+        return jnp.where(index > j, y - col * yj,
+                         jnp.where(index == j, yj, y))
+
+    def backward(t, x):
+        j = rank - 1 - t
+        col = jax.lax.dynamic_index_in_dim(L, j, 0, keepdims=False)
+        below = jnp.sum(jnp.where(index > j, col * x, 0.0), axis=0,
+                        keepdims=True)
+        xj = ((jax.lax.dynamic_index_in_dim(x, j, 0) - below)
+              / jax.lax.dynamic_index_in_dim(col, j, 0))
+        return jax.lax.dynamic_update_index_in_dim(x, xj, j, 0)
+
+    y = jax.lax.fori_loop(0, rank, forward, b.T)
+    return jax.lax.fori_loop(0, rank, backward, y).T
+
+
+def test_grouped_fit_solves_with_the_parents_code_off_the_tpu(monkeypatch):
+    """Off the TPU a grouped fit's blocks take op ``als_cholesky_solve``'s
+    ``xla`` backend, which is the parent's solver moved: the span note
+    ``solve`` and ``ALSModel.solve_plan`` say so, and the factors are
+    those of a fit that calls the parent's function, bit for bit.
+    ``solve_plan`` is a record of the fit like ``neq_plan``: a loaded
+    model has none."""
+    import types
+
+    from flink_ml_tpu.models.recommendation import als as als_mod
+    from flink_ml_tpu.obs.trace import tracer
+
+    rng = np.random.default_rng(44)
+    n = 4000
+    t = Table({"user": rng.integers(0, 300, n).astype(np.int64),
+               "item": rng.integers(0, 40, n).astype(np.int64),
+               "rating": rng.normal(size=n).astype(np.float32)})
+    est = (ALS().set_rank(6).set_max_iter(3).set_seed(1)
+           .set(ALS.NEQ_IMPL, "sorted"))
+    tracer.enable()
+    try:
+        model = est.fit(t)
+        (span,) = tracer.find("fit.arrange.plan")
+    finally:
+        tracer.disable()
+        tracer.clear()
+    assert (span.ids["neq_plan"], span.ids["solve"]) == ("grouped", "xla")
+    assert (model.neq_plan, model.solve_plan) == ("grouped", "xla")
+    assert est.set(ALS.NEQ_IMPL, "scatter").fit(t).solve_plan == "xla"
+
+    monkeypatch.setattr(
+        als_mod, "_block_solve", lambda rank, groups: types.SimpleNamespace(
+            backend="xla", fn=lambda At, bt: _parent_cholesky_solve_lanes(
+                jnp.transpose(At, (2, 1, 0)), bt.T).T))
+    parent = est.set(ALS.NEQ_IMPL, "sorted").fit(t)
+    (a,), (b,) = model.get_model_data(), parent.get_model_data()
+    for col in ("userFactors", "itemFactors"):
+        np.testing.assert_array_equal(np.asarray(a[col]), np.asarray(b[col]))
+
+
+def test_solve_plan_is_not_saved_with_the_model(tmp_path):
+    rng = np.random.default_rng(45)
+    t = Table({"user": rng.integers(0, 30, 400).astype(np.int64),
+               "item": rng.integers(0, 20, 400).astype(np.int64),
+               "rating": rng.normal(size=400).astype(np.float32)})
+    model = ALS().set_rank(4).set_max_iter(2).fit(t)
+    assert (model.neq_plan, model.solve_plan) == ("scatter", "xla")
+    model.save(str(tmp_path / "m"))
+    loaded = ALSModel.load(str(tmp_path / "m"))
+    assert (loaded.neq_plan, loaded.solve_plan) == (None, None)
+    np.testing.assert_array_equal(
+        np.asarray(loaded.transform(t)[0]["prediction"]),
+        np.asarray(model.transform(t)[0]["prediction"]))
 
 
 def test_auto_takes_the_grouped_form_where_a_side_outgrows_a_block(

@@ -102,8 +102,8 @@ def test_register_replaces_same_backend():
 
 def test_catalog_registers_every_documented_op():
     ops = kreg.ops()
-    for op in ("ell_margin", "ell_scatter_apply", "gbt_level_histograms",
-               "kmeans_assign", "kmeans_update_stats",
+    for op in ("als_cholesky_solve", "ell_margin", "ell_scatter_apply",
+               "gbt_level_histograms", "kmeans_assign", "kmeans_update_stats",
                "kmeans_workset_update", "linear_margins", "retrieve",
                "routed_adam_update", "routed_table_grad", "widedeep_scores"):
         assert op in ops, f"catalog lost op {op}"
@@ -541,6 +541,90 @@ def test_routed_adam_update_xla_is_optax_adam_on_the_scattered_gradient():
                                    atol=1e-8, err_msg=name)
 
 
+# -- als_cholesky_solve: a tile of groups' systems solved inside VMEM --------
+
+def _als_systems(rank, groups, seed=0):
+    """``groups`` symmetric positive definite systems the way an ALS block
+    holds them: Gram matrices of a few more rows than ``rank`` plus a
+    ridge.  ``(A, b)`` as float32, ``(groups, rank, rank)`` and
+    ``(groups, rank)``."""
+    rng = np.random.default_rng(seed)
+    y = rng.normal(size=(groups, rank + 3, rank)).astype(np.float32)
+    A = np.einsum("gls,glt->gst", y, y) + np.float32(0.5) * np.eye(
+        rank, dtype=np.float32)
+    return A, rng.normal(size=(groups, rank)).astype(np.float32)
+
+
+def _als_solve(backend, A, b, **kw):
+    """Op ``als_cholesky_solve`` on lane-major operands, back as
+    ``(groups, rank)``."""
+    fn = lookup("als_cholesky_solve", backend=backend).fn
+    return np.asarray(fn(jnp.transpose(jnp.asarray(A), (2, 1, 0)),
+                         jnp.asarray(b).T, **kw)).T
+
+
+def _parity_als_cholesky_solve(backends):
+    assert sorted(backends) == ["pallas", "xla"]
+    A, b = _als_systems(12, 200)
+    np.testing.assert_allclose(_als_solve("pallas", A, b, interpret=True),
+                               _als_solve("xla", A, b), rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("groups", [128, 300], ids=["one-tile",
+                                                    "partial-last-tile"])
+@pytest.mark.parametrize("rank", [8, 100])
+def test_als_cholesky_solve_vmem_equals_float64_and_its_xla_backend(
+        rank, groups):
+    """The kernel in interpret mode against ``np.linalg.solve`` in
+    float64 and against the XLA loop: the same recurrence in float32,
+    another order of summation.  300 groups are two whole tiles and 44
+    lanes of a third."""
+    A, b = _als_systems(rank, groups, seed=rank + groups)
+    exact = np.linalg.solve(A.astype(np.float64),
+                            b.astype(np.float64)[..., None])[..., 0]
+    scale = np.abs(exact).max(axis=1, keepdims=True)
+    got = _als_solve("pallas", A, b, interpret=True)
+    twin = _als_solve("xla", A, b)
+    assert got.shape == twin.shape == (groups, rank)
+    assert np.max(np.abs(got - exact) / scale) < 2e-5
+    assert np.max(np.abs(twin - exact) / scale) < 2e-5
+    assert np.max(np.abs(got - twin) / scale) < 2e-5
+
+
+def test_als_cholesky_solve_vmem_keeps_a_failed_factorisation_in_its_lane():
+    """One matrix that is not positive definite among sound ones: NaN in
+    ITS solution on both backends (ALS then keeps that group's factors),
+    its neighbours on the lanes beside it as exact as without it."""
+    A, b = _als_systems(8, 300, seed=5)
+    bad = 170
+    A[bad] = -A[bad]
+    got = _als_solve("pallas", A, b, interpret=True)
+    twin = _als_solve("xla", A, b)
+    sound = np.arange(len(A)) != bad
+    assert np.isnan(got[bad]).all() and np.isnan(twin[bad]).all()
+    exact = np.linalg.solve(A[sound].astype(np.float64),
+                            b[sound].astype(np.float64)[..., None])[..., 0]
+    assert np.max(np.abs(got[sound] - exact)
+                  / np.abs(exact).max(axis=1, keepdims=True)) < 2e-5
+
+
+def test_als_cholesky_solve_is_planned_for_a_lane_tile_of_groups_on_a_tpu(
+        monkeypatch):
+    """Off the TPU every block takes the XLA loop; on one the kernel takes
+    a block of a lane tile of groups or more at a rank whose tile fits
+    VMEM, and the split groups' one system a step stays with XLA."""
+    vmem = lookup("als_cholesky_solve", backend="pallas")
+    assert [vmem.supports_sig(sig) for sig in (
+        (100, 30020), (100, 128), (32, 5000), (8, 128), (256, 128),
+        (100, 127), (100, 1), (264, 4096))] == [True] * 5 + [False] * 3
+    assert lookup("als_cholesky_solve", sig=(100, 30020)).backend == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert lookup("als_cholesky_solve", sig=(100, 30020)).backend == "pallas"
+    assert lookup("als_cholesky_solve", sig=(100, 1)).backend == "xla"
+    with pytest.raises(ValueError, match="does not support"):
+        lookup("als_cholesky_solve", sig=(100, 1), backend="pallas")
+
+
 # -- accuracy-envelope harnesses (int8 backends, ISSUE 18) ------------------
 # Int8 entries are weight-only quantized: bitwise equality with f32 is
 # NOT the contract — rank-order/decision agreement within the envelope
@@ -791,6 +875,7 @@ def test_retrieve_quality_gates(gate, backend):
 
 
 _PARITY = {
+    "als_cholesky_solve": _parity_als_cholesky_solve,
     "ell_margin": _parity_ell_margin,
     "ell_scatter_apply": _parity_ell_scatter_apply,
     "gbt_level_histograms": _parity_gbt_hist,

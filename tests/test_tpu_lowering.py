@@ -119,6 +119,19 @@ def _routed_adam(n, u, e, block_n=None):
              Shape((u,), I32), Shape((), I32)))
 
 
+def _als_solve(rank, groups):
+    from flink_ml_tpu.ops.als_solve_pallas import cholesky_solve_vmem
+
+    return cholesky_solve_vmem, (Shape((rank, rank, groups), F32),
+                                 Shape((rank, groups), F32))
+
+
+# the groups of a users' and of an items' block of ``als_netflix.fit``
+# (four blocks of 120,047 users, three of 17,770 items, each class
+# rounded up; PERF.md section 4), solved at the cell's rank 100
+_NETFLIX_BLOCKS, _NETFLIX_RANK = (30_020, 5_891), 100
+
+
 # Criteo's 26 cardinalities in all, and the most table rows one batch of
 # 32768 touches there (benchmarks/configs/widedeep_criteo.json; PERF.md)
 _CRITEO_ROWS, _CRITEO_UNIQUE = 33_762_577, 126_629
@@ -165,6 +178,13 @@ CASES = {
         "smallest": lambda: _routed_adam(100, 8, 16),
         "smallest-scalars": lambda: _routed_adam(100, 8, 0),
         "criteo": lambda: _routed_adam(_CRITEO_ROWS, _CRITEO_UNIQUE, 16),
+    },
+    ("als_cholesky_solve", "pallas"): {
+        "smallest": lambda: _als_solve(8, 128),
+        "netflix-users": lambda: _als_solve(_NETFLIX_RANK,
+                                            _NETFLIX_BLOCKS[0]),
+        "netflix-items": lambda: _als_solve(_NETFLIX_RANK,
+                                            _NETFLIX_BLOCKS[1]),
     },
     ("retrieve", "pallas"): {
         **{f"flat-rows{b}": partial(_retrieve, b, 128, 16, 128)
@@ -403,7 +423,28 @@ def test_widedeep_fit_program_forms_no_table_shaped_gradient(
     assert not moved, moved[:3]
 
 
-def test_als_fit_program_holds_one_block_of_normal_equations(one_v5e):
+@pytest.mark.parametrize("rank,groups", [
+    (_NETFLIX_RANK, _NETFLIX_BLOCKS[0]), (_NETFLIX_RANK, _NETFLIX_BLOCKS[1]),
+    (32, 4_001), (10, 262_144)],
+    ids=["netflix-users", "netflix-items", "chip-smoke-rank", "default-rank"])
+def test_als_cholesky_solve_compiles_with_its_tile_in_vmem(one_v5e, rank,
+                                                           groups):
+    """The solve kernel at the benchmark cell's two block shapes (a last
+    tile of 68 and of 3 groups), at ``chip_smoke.py``'s rank and at the
+    estimator's default rank with the block of 2^18 groups a v5e gets
+    there: Mosaic takes the dynamic row reads, the ragged last tile and
+    the raised VMEM limit (two buffers of ``At``'s tile and the factor,
+    16 MB at rank 100), and the call keeps no temporary in HBM: the
+    factor is the kernel's own scratch."""
+    fn, args = _als_solve(rank, groups)
+    compiled = jax.jit(fn).lower(*(
+        Shape(a.shape, a.dtype, sharding=one_v5e) for a in args)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_als_fit_program_holds_one_block_of_normal_equations(
+        one_v5e, monkeypatch):
     """The fused program of ``ALS.fit`` at the benchmark cell
     ``als_netflix.fit``'s shapes (120,047 users x 17,770 items, 24.8 M
     ratings with the generator's degrees, rank 100, 5 epochs) compiled
@@ -412,7 +453,10 @@ def test_als_fit_program_holds_one_block_of_normal_equations(one_v5e):
     with room (the users' dense ``(120047, 100, 100)`` alone is 6.4 GB as
     the chip pads it, and Cholesky wants as much again), every ``A`` it
     holds is block-shaped, and the factorisation works with the groups
-    on the lanes."""
+    on the lanes.  Compiled twice: with the registry's pick on a TPU (op
+    ``als_cholesky_solve``'s kernel: a tile's factor in VMEM, no factor
+    in HBM, one transposing copy a block in front of the call) and with
+    the XLA loop the program gets elsewhere."""
     import importlib
     import json
     import os
@@ -456,23 +500,61 @@ def test_als_fit_program_holds_one_block_of_normal_equations(one_v5e):
     body = als.als_epoch_step(users, items, config["reg_param"], False, 1.0,
                               plans=plans)
 
-    def run(state, data):
-        return jax.lax.scan(
-            lambda s, epoch: (body(s, epoch, data).feedback, None), state,
-            jnp.arange(config["max_iter"], dtype=jnp.int32))[0]
-
-    compiled = jax.jit(run).lower(
-        (on_chip((users, rank), F32), on_chip((items, rank), F32)),
-        (arrays(plans[0]), arrays(plans[1]))).compile()
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes)
-    assert (4 << 30) < total < (12 << 30), mem
-    text = compiled.as_text()
-    batches = {int(n) for n in re.findall(
-        r"f32\[(\d+),%d,%d\]" % (rank, rank), text)}
-    on_lanes = {int(n) for n in re.findall(
-        r"f32\[%d,%d,(\d+)\]" % (rank, rank), text)}
+    args = ((on_chip((users, rank), F32), on_chip((items, rank), F32)),
+            (arrays(plans[0]), arrays(plans[1])))
     blocks = {p.block_groups for p in plans}
-    assert blocks <= batches and on_lanes == blocks, (batches, on_lanes)
-    assert max(batches) <= als._block_sizes(rank)[0] + 64 < users
+
+    def compiled_program():
+        def run(state, data):            # traced anew for either backend
+            return jax.lax.scan(
+                lambda s, epoch: (body(s, epoch, data).feedback, None),
+                state, jnp.arange(config["max_iter"], dtype=jnp.int32))[0]
+
+        compiled = jax.jit(run).lower(*args).compile()
+        mem = compiled.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes)
+        assert (2 << 30) < total < (12 << 30), mem
+        text = compiled.as_text()
+        batches = {int(n) for n in re.findall(
+            r"f32\[(\d+),%d,%d\]" % (rank, rank), text)}
+        on_lanes = {int(n) for n in re.findall(
+            r"f32\[%d,%d,(\d+)\]" % (rank, rank), text)}
+        # the kernel takes the ragged last tile of a block as it is: the
+        # lanes are the block's own groups, not rounded to a tile
+        assert blocks <= batches and on_lanes == blocks, (batches, on_lanes)
+        assert max(batches) <= als._block_sizes(rank)[0] + 64 < users
+        return text, mem.temp_size_in_bytes
+
+    # as on the chip: the registry's own pick there, a block's systems
+    # solved a tile at a time inside VMEM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert als._solve_plan(plans, rank) == "vmem"
+    text, temporaries = compiled_program()
+    monkeypatch.undo()
+    assert als._solve_plan(plans, rank) == "xla"
+    twin_text, twin_temporaries = compiled_program()
+
+    def users_block(text, shape):
+        """The distinct arrays of ``shape`` (the users' block) that the
+        program's operations produce."""
+        return {line.split(" = ")[0].strip() for line in text.splitlines()
+                if re.search(r" = f32\[%s\]" % shape, line)}
+
+    lanes = "%d,%d,%d" % (rank, rank, max(blocks))
+    # the twin's column loop carries the block's factor beside At (1.2 GB
+    # at 30,020 groups, and the loop's copy of it); the kernel's factor is
+    # a tile in VMEM, so the program holds At alone
+    assert text.count("tpu_custom_call") >= 2 and (
+        "tpu_custom_call" not in twin_text)
+    assert len(users_block(text, lanes)) < len(users_block(twin_text, lanes))
+    # At is made by ONE transposing copy of the block, as in the twin's
+    # program: the kernel pins its operand group-major, else XLA pushes
+    # the lane-major layout back to every class's contraction (a hundred
+    # small copies, 5 s more of compilation, and factors that the gathers
+    # read from HBM where memory-space assignment had them in VMEM)
+    copied = [int(n) for n in re.findall(
+        r" = f32\[(\d+),%d,%d\]\S* copy\(" % (rank, rank), text)]
+    assert sorted(copied) == sorted(blocks), copied
+    assert temporaries <= 1.02 * twin_temporaries, (
+        temporaries, twin_temporaries)

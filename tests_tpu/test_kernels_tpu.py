@@ -17,6 +17,7 @@ pytestmark = pytest.mark.tpu
 #: that compiles it here; test_every_auto_selected_entry_is_covered keeps
 #: this equal to the registry, so a new Pallas entry cannot land untested
 COVERED = {
+    ("als_cholesky_solve", "pallas"): "test_als_cholesky_solve_on_device",
     ("ell_margin", "pallas"): "test_ell_margin_kernel_parity",
     ("ell_scatter_apply", "pallas"): "test_ell_fused_gather_kernel_parity",
     ("ell_scatter_apply", "pallas-pair"):
@@ -555,10 +556,47 @@ def test_als_grouped_neq_on_device(tpu, rng):
                                   np.bincount(g, w, minlength=n_groups))
 
 
+@pytest.mark.parametrize("rank,groups", [(100, 1100), (32, 4001), (10, 300)],
+                         ids=["netflix-rank", "chip-smoke-rank",
+                              "default-rank"])
+def test_als_cholesky_solve_on_device(tpu, rng, rank, groups):
+    """Op ``als_cholesky_solve`` as the registry picks it on the chip
+    (the kernel that keeps a tile of groups' matrices in VMEM) at a
+    ragged group count, against ``np.linalg.solve`` in float64 and
+    against its XLA twin; one matrix that is not positive definite gives
+    NaN in its own lane and leaves its neighbours alone."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.kernels.registry import lookup
+
+    y = rng.normal(size=(groups, rank + 3, rank)).astype(np.float32)
+    A = np.einsum("gls,glt->gst", y, y) + np.float32(0.5) * np.eye(
+        rank, dtype=np.float32)
+    b = rng.normal(size=(groups, rank)).astype(np.float32)
+    bad = groups - 5                          # in the last, partial tile
+    A[bad] = -A[bad]
+    entry = lookup("als_cholesky_solve", sig=(rank, groups))
+    assert entry.backend == "pallas"
+    At, bt = jnp.transpose(jnp.asarray(A), (2, 1, 0)), jnp.asarray(b).T
+    got = np.asarray(jax.jit(entry.fn)(At, bt)).T
+    twin = np.asarray(jax.jit(
+        lookup("als_cholesky_solve", backend="xla").fn)(At, bt)).T
+    assert got.shape == (groups, rank)
+    assert np.isnan(got[bad]).all() and np.isnan(twin[bad]).all()
+    sound = np.arange(groups) != bad
+    exact = np.linalg.solve(A[sound].astype(np.float64),
+                            b[sound].astype(np.float64)[..., None])[..., 0]
+    scale = np.abs(exact).max(axis=1, keepdims=True)
+    assert np.max(np.abs(got[sound] - exact) / scale) < 2e-5
+    assert np.max(np.abs(got[sound] - twin[sound]) / scale) < 2e-5
+
+
 def test_als_fit_plans_grouped_on_device(tpu, rng):
     """``ALS.fit`` under ``'sorted'`` on the chip: the model says
-    ``grouped``, and its factors are the scatter fit's (one batched
-    ``cho_solve`` over dense operands) to float32 rounding."""
+    ``grouped``, its users' block of 500 groups is solved inside VMEM and
+    its items' 120 by the XLA loop, and its factors are the scatter
+    fit's (one batched ``cho_solve`` over dense operands) to float32
+    rounding."""
     from flink_ml_tpu import Table
     from flink_ml_tpu.models.recommendation.als import ALS
 
@@ -576,6 +614,7 @@ def test_als_fit_plans_grouped_on_device(tpu, rng):
 
     grouped, scatter = fit("sorted"), fit("scatter")
     assert (grouped.neq_plan, scatter.neq_plan) == ("grouped", "scatter")
+    assert (grouped.solve_plan, scatter.solve_plan) == ("vmem/xla", "xla")
     (a,), (b,) = grouped.get_model_data(), scatter.get_model_data()
     for col in ("userFactors", "itemFactors"):
         np.testing.assert_allclose(np.asarray(a[col]), np.asarray(b[col]),
