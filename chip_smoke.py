@@ -9,7 +9,9 @@ weights start at zero, data comes from a seed):
 - ``fit``     LogisticRegression.fit on 2^20 rows, one-device mesh
 - ``serve``   serve_model on that model; requests of 1, 37 and 256 rows
 - ``stream``  fit_outofcore over a DataCacheWriter cache of 2^18 rows
-- ``kmeans``  KMeans(k=256).fit on 2^20 x 64 points, then transform
+- ``kmeans``  KMeans(k=256).fit on 2^20 x 64 points, then transform; the
+              stats kernel tiled over k at 2^17 x 784, k 4096 against
+              float64 sums under the first-index assignment
 - ``als``     ALS(rank 32).fit on 2^20 skewed ratings, the grouped normal
               equations by blocks, against a float64 solve of a sample
 - ``mesh4``   the fit leg on a four-device data mesh (only with >= 4 chips)
@@ -62,7 +64,8 @@ REHEARSAL = {"fit": (1 << 14, 1 << 12), "stream": (1 << 13, 1 << 10),
 OP_SIGNATURES = {
     "ell_margin": [("", (NUM_FEATURES // 128,))],
     "ell_scatter_apply": [("", (NUM_FEATURES // 128,))],
-    "kmeans_update_stats": [("", (1 << 20, 64, 256, "euclidean"))],
+    "kmeans_update_stats": [("", (1 << 20, 64, 256, "euclidean")),
+                            ("mnist8m", (2_025_000, 784, 4096, "euclidean"))],
     "kmeans_workset_update": [("", (1 << 20, 64, 256, "euclidean", 1))],
     "routed_table_grad": [("", ("gather", 13, 8192 * 26))],
     # (rank, groups): a users' block of the benchmark's ALS cell, and a
@@ -404,7 +407,79 @@ def leg_kmeans(ctx) -> dict:
           "points")
     return {"fit_plan": backend, "reference_agreement": round(agreement, 4),
             "mean_sq_distance": round(cost, 2),
-            "take_k_mean_sq_distance": round(start_cost, 2)}
+            "take_k_mean_sq_distance": round(start_cost, 2),
+            "tiled_over_k": kmeans_tiled_over_k(ctx)}
+
+
+def kmeans_tiled_over_k(ctx) -> dict:
+    """The stats kernel tiled over k at ``kmeans_mnist8m``'s width (2^17 x
+    784 whole grey levels, k 4096) against float64 sums of the
+    bfloat16-rounded points under the first-index assignment, and the plan
+    the fit would take there.  The sums of whole grey levels are exact;
+    only the clusters that a row within float32's reach of a tie could
+    touch are let off."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flink_ml_tpu.kernels.registry import lookup
+    from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
+                                                stats_tiles)
+
+    n, d, k = ((1 << 17, 784, 4096) if ctx["chip"] else (1 << 10, 784, 64))
+    rng = np.random.default_rng(3)
+    # more shapes than centroids, or every row lies between near-twins
+    protos = ((rng.random((2 * k, d)) < 0.19)
+              * rng.integers(60, 256, size=(2 * k, d))).astype(np.float32)
+    points = protos[rng.integers(0, 2 * k, size=n)]
+    points = np.clip(np.rint(
+        points * rng.uniform(0.6, 1.0, size=(n, 1))
+        + (points > 0) * rng.integers(-16, 17, size=points.shape)),
+        0, 255).astype(np.float32)
+    cents = points[rng.permutation(n)[:k]].copy()
+    cents[k - 2] = cents[1]           # an exact tie, a whole tile apart
+    if ctx["chip"]:
+        tiles = stats_tiles(d, k)
+        backend = lookup("kmeans_update_stats",
+                         sig=(2_025_000, d, k, "euclidean")).backend
+        check(backend == "pallas" and tiles == (512, 512),
+              f"kmeans: at 2,025,000 x {d}, k {k} the fit plans {backend!r} "
+              f"at tiles {tiles}, expected 'pallas' at (512, 512)")
+    else:
+        tiles = (128, 16)
+    sums, counts = kmeans_update_stats(
+        jnp.asarray(points), jnp.asarray(cents), block_n=tiles[0],
+        k_tile=tiles[1], tie_policy="first", interpret=not ctx["chip"])
+    sums, counts = np.asarray(sums, np.float64), np.asarray(counts)
+    rounded = np.asarray(jnp.asarray(cents).astype(jnp.bfloat16)
+                         .astype(jnp.float32), np.float64)
+    c2 = (cents.astype(np.float64) ** 2).sum(1)
+    assign = np.empty(n, np.int64)
+    loose = np.zeros(k, bool)
+    for lo in range(0, n, 4096):
+        sc = c2[None] - 2.0 * points[lo:lo + 4096].astype(
+            np.float64) @ rounded.T
+        assign[lo:lo + 4096] = sc.argmin(1)
+        two = np.argpartition(sc, 1, axis=1)[:, :2]
+        best, second = (sc[np.arange(len(sc)), two[:, i]] for i in (0, 1))
+        near = (np.abs(best - second) < 8.0) & (best != second)
+        loose[two[near].reshape(-1)] = True
+    want_counts = np.bincount(assign, minlength=k)
+    want_sums = np.zeros((k, d))
+    np.add.at(want_sums, assign, points.astype(np.float64))
+    check(loose.mean() < 0.2,
+          f"kmeans tiled over k: {loose.mean():.3f} of the clusters lie "
+          "within reach of a near-tie; the check would see too little")
+    check(counts.sum() == n and counts[k - 2] == 0,
+          f"kmeans tiled over k: {counts.sum()} rows counted of {n}, "
+          f"{counts[k - 2]} on the second of two equal centroids")
+    check(np.array_equal(counts[~loose], want_counts[~loose])
+          and np.array_equal(sums[~loose], want_sums[~loose]),
+          "kmeans tiled over k: counts or sums differ from the float64 "
+          "sums under the first-index assignment")
+    check(np.array_equal(sums.sum(0), points.sum(0, dtype=np.float64)),
+          "kmeans tiled over k: the sums do not add up to the points")
+    return {"tiles": list(tiles), "rows": n, "k": k,
+            "clusters_near_a_tie": int(loose.sum())}
 
 
 def leg_als(ctx) -> dict:

@@ -200,34 +200,131 @@ def _rows_on_device(shape: tuple, pad: int, fill: str, sharding):
     """The jitted program that makes ``pad_rows_with_mask``'s two arrays
     where the rows already are.  Its argument is the C-order buffer of
     the ``shape`` = (n, d) rows as it was put: one dimension, so the
-    runtime transposes nothing on the host on the way (the chip keeps
-    narrow rows column-major, and a put of the 2-D array makes that
-    layout tile by tile on the host's threads).  It gives the rows in
-    that layout, ``_RELAYOUT_ROWS`` at a time (the last piece overlaps
-    the one before it, so that every piece has one shape), with ``pad``
-    rows of ``fill`` after the last one, and the float32 mask of the real
-    rows, both under ``sharding``.  One program per shapes, kept for the
-    process (as ``_predict``'s jit keeps its own): a refit neither traces
-    nor compiles."""
+    runtime transposes nothing on the host on the way (the chip keeps rows
+    whose width is no multiple of 128 column-major, and a put of the 2-D
+    array makes that layout tile by tile on the host's threads).  It gives
+    the rows in that layout, ``_RELAYOUT_ROWS`` at a time (the last piece
+    overlaps the one before it, so that every piece has one shape), with
+    ``pad`` rows of ``fill`` after the last one, and the float32 mask of
+    the real rows, both under ``sharding``.  One program per shapes, kept
+    for the process (as ``_predict``'s jit keeps its own): a refit neither
+    traces nor compiles."""
     n, d = shape
-    rows = min(n, _RELAYOUT_ROWS)
-
-    def place(flat, i, points):
-        start = jnp.minimum(i * rows, n - rows)
-        piece = jax.lax.dynamic_slice(flat, (start * d,), (rows * d,))
-        return jax.lax.dynamic_update_slice(
-            points, piece.reshape(rows, d), (start, 0))
 
     def rows_and_mask(flat):
-        points = jax.lax.fori_loop(
-            0, -(-n // rows) if n else 0, partial(place, flat),
-            jnp.zeros((n + pad, d), flat.dtype))
-        if pad and fill == "first_row":
-            points = jax.lax.dynamic_update_slice(
-                points, jnp.broadcast_to(flat[:d], (pad, d)), (n, 0))
-        return points, (jnp.arange(n + pad) < n).astype(jnp.float32)
+        points = _placed(jnp.zeros((n + pad, d), flat.dtype), flat, 0, n)
+        return _filled(points, n, pad, fill), _row_mask(n, pad)
 
     return jax.jit(rows_and_mask, out_shardings=(sharding, sharding))
+
+
+def _placed(points, flat, first, rows: int):
+    """``points`` with the ``rows`` rows of the C-order buffer ``flat`` at
+    row ``first``, given their layout ``_RELAYOUT_ROWS`` at a time."""
+    d = points.shape[1]
+    step = min(rows, _RELAYOUT_ROWS)
+
+    def place(i, points):
+        start = jnp.minimum(i * step, rows - step)
+        piece = jax.lax.dynamic_slice(flat, (start * d,), (step * d,))
+        return jax.lax.dynamic_update_slice(
+            points, piece.reshape(step, d), (first + start, 0))
+
+    return jax.lax.fori_loop(0, -(-rows // step) if rows else 0, place,
+                             points)
+
+
+def _filled(points, n: int, pad: int, fill: str):
+    if pad and fill == "first_row":
+        return jax.lax.dynamic_update_slice(
+            points, jnp.broadcast_to(points[:1], (pad, points.shape[1])),
+            (n, 0))
+    return points
+
+
+def _row_mask(n: int, pad: int):
+    return (jnp.arange(n + pad) < n).astype(jnp.float32)
+
+
+#: The most one put hands over.  The runtime moves a buffer of 0.8 to 3.2 GB
+#: at 6.3-6.6 GB/s and one of 6.35 GB at 0.25 (24.5-26.5 s; the same rows
+#: as a 2-D array 33 s); sixteen pieces of 0.4 GB all in flight at once
+#: 1.0 GB/s, four of 1.6 GB 0.7: what it has in flight at its fast pace is
+#: bounded somewhere between 3.2 and 6.35 GB (my chip runs, PR 35, one
+#: v5e).  So a larger table goes up a piece of this size at a time, each
+#: waited for before the next is put.
+_PUT_BYTES = 1 << 31
+
+
+def _put_rows(n: int, d: int) -> int:
+    """Rows of ``(n, d)`` float32 points a put hands over at a time: all
+    of them up to ``_PUT_BYTES``, else whole ``_RELAYOUT_ROWS``."""
+    if 4 * n * d <= _PUT_BYTES:
+        return n
+    return max(1, _PUT_BYTES // (4 * d) // _RELAYOUT_ROWS) * _RELAYOUT_ROWS
+
+
+@lru_cache(maxsize=None)
+def _rows_from_pieces(shape: tuple, pad: int, fill: str, sharding):
+    """:func:`_rows_on_device` for a buffer that was put in pieces, as
+    three jitted programs: ``empty() -> (points, mask)``, ``place(points,
+    flat, first) -> points`` for the piece whose first row is ``first``
+    (``points`` donated: the rows are laid out in place, a piece's buffer
+    is free once it is placed; one program a piece length, so two a
+    table), and ``finish(points)`` for the ``fill`` rows."""
+    n, d = shape
+
+    def empty():
+        return jnp.zeros((n + pad, d), jnp.float32), _row_mask(n, pad)
+
+    def place(points, flat, first):
+        return _placed(points, flat, first, flat.shape[0] // d)
+
+    return (jax.jit(empty, out_shardings=(sharding, sharding)),
+            jax.jit(place, donate_argnums=0, out_shardings=sharding),
+            jax.jit(lambda points: _filled(points, n, pad, fill),
+                    donate_argnums=0, out_shardings=sharding))
+
+
+def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
+    """Host -> device on a mesh of one process whose ``data`` axis is one
+    device: the rows' buffer put flat and laid out, padded and masked on
+    the device, under the spans ``fit.upload`` and ``fit.arrange.pad``.
+    Up to ``_PUT_BYTES`` that is one asynchronous put (the transfer runs
+    while the host draws the start) and :func:`_rows_on_device`; a larger
+    table goes up a piece at a time, each waited for, and the device lays
+    a piece out (:func:`_rows_from_pieces`) while the next one arrives."""
+    from jax.sharding import NamedSharding
+
+    n = len(host_points)
+    put_rows = _put_rows(*host_points.shape)
+    laid_out = (host_points.shape, -n % plan.local_multiple(mesh),
+                plan.fill, NamedSharding(mesh, spec))
+
+    def put(first):
+        return put_sharded(host_points[first:first + put_rows].reshape(-1),
+                           mesh, spec)
+
+    def arranging():
+        return tracer.span("fit.arrange.pad", "fit")
+
+    if put_rows == n:
+        with tracer.span("fit.upload", "fit"):
+            flat = put(0)
+        with tracer.span("fit.arrange", "fit"), arranging():
+            return _rows_on_device(*laid_out)(flat)
+    empty, place, finish = _rows_from_pieces(*laid_out)
+    with tracer.span("fit.arrange", "fit"), arranging():
+        points, mask = empty()
+    for first in range(0, n, put_rows):
+        with tracer.span("fit.upload", "fit"):
+            flat = put(first)
+            flat.block_until_ready()
+        with tracer.span("fit.arrange", "fit"), arranging():
+            points = place(points, flat, first)
+            if first + put_rows >= n:
+                points = finish(points)
+    return points, mask
 
 
 @partial(jax.jit, static_argnums=0)
@@ -341,9 +438,11 @@ def kmeans_epoch_step(measure: DistanceMeasure, k: int):
 
     def body(centroids, epoch, data):
         points, mask = data
-        sums, counts = _assign_stats(measure, k, points, mask, centroids)
-        return IterationBodyResult(
-            feedback=_update_centroids(centroids, sums, counts))
+        with jax.named_scope("kmeans.stats"):
+            sums, counts = _assign_stats(measure, k, points, mask, centroids)
+        with jax.named_scope("kmeans.update"):
+            new_centroids = _update_centroids(centroids, sums, counts)
+        return IterationBodyResult(feedback=new_centroids)
 
     return body
 
@@ -481,11 +580,15 @@ def kmeans_workset_epoch_step(measure: DistanceMeasure, k: int, *,
 
 
 def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
+                             k_tile: Optional[int] = None,
                              tie_policy: str = "first",
                              interpret: bool = False):
     """One Lloyd's iteration on the fused Pallas kernel
     (``ops/kmeans_pallas.py``): score/one-hot tiles stay in VMEM, so the
     points are read from HBM once an iteration and nothing else is.
+    ``(block_n, k_tile)`` are the plan's (``kmeans_pallas.stats_tiles``):
+    ``k_tile`` None is the feature-major kernel with all of k resident,
+    a number the kernel tiled over k.
 
     ``tie_policy="first"`` (the default, what ``KMeans.fit`` plans via
     its ``tiePolicy`` param) keeps the XLA body's exact first-index
@@ -495,28 +598,35 @@ def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
 
     Requires zero-filled padding (``fill="zero"``) with the per-shard row
     count a multiple of ``block_n``; euclidean metric only.  With a
-    multi-device ``mesh``, per-shard partial sums meet in one ICI psum."""
+    multi-device ``mesh``, per-shard partial sums meet in one ICI psum.
+
+    Two ``jax.named_scope`` s say what a device operation is for:
+    ``kmeans.stats`` (points and centroids to sums and counts: the kernel
+    and the XLA around it) and ``kmeans.update`` (the padding's
+    correction, the division, the empty clusters)."""
     from ...ops import kmeans_pallas as kp
 
     sharded = mesh is not None and int(mesh.shape.get("data", 1)) > 1
 
     def body(centroids, epoch, data):
         points, mask = data
-        if sharded:
-            sums, counts = kp.update_stats_sharded(
-                points, centroids, mesh, block_n=block_n,
-                tie_policy=tie_policy, interpret=interpret)
-        else:
-            sums, counts = kp.kmeans_update_stats(
-                points, centroids, block_n=block_n, tie_policy=tie_policy,
-                interpret=interpret)
-        n_pad = points.shape[0] - jnp.sum(mask)
-        counts = kp.pad_correction(counts, centroids, n_pad,
-                                   tie_policy=tie_policy)[:, None]
-        # No clamp-to-1 here: "split" ties legally produce fractional counts
-        # in (0, 1), which must divide as-is.
-        safe = jnp.where(counts > 0, counts, 1.0)
-        new_centroids = jnp.where(counts > 0, sums / safe, centroids)
+        with jax.named_scope("kmeans.stats"):
+            if sharded:
+                sums, counts = kp.update_stats_sharded(
+                    points, centroids, mesh, block_n=block_n, k_tile=k_tile,
+                    tie_policy=tie_policy, interpret=interpret)
+            else:
+                sums, counts = kp.kmeans_update_stats(
+                    points, centroids, block_n=block_n, k_tile=k_tile,
+                    tie_policy=tie_policy, interpret=interpret)
+        with jax.named_scope("kmeans.update"):
+            n_pad = points.shape[0] - jnp.sum(mask)
+            counts = kp.pad_correction(counts, centroids, n_pad,
+                                       tie_policy=tie_policy)[:, None]
+            # No clamp-to-1 here: "split" ties legally produce fractional
+            # counts in (0, 1), which must divide as-is.
+            safe = jnp.where(counts > 0, counts, 1.0)
+            new_centroids = jnp.where(counts > 0, sums / safe, centroids)
         return IterationBodyResult(feedback=new_centroids)
 
     return body
@@ -529,30 +639,39 @@ _MIN_BLOCKS = 8
 
 
 def _plan_fit_impl(n: int, d: int, k: int, measure: DistanceMeasure,
-                   mesh) -> tuple:
-    """Pick (impl, block_n) for the BSP fit loop via registry op
+                   mesh, tie_policy: str = "first") -> tuple:
+    """Pick (impl, block_n, k_tile) for the BSP fit loop via registry op
     ``kmeans_update_stats`` (the Pallas entry's availability gate is the
     TPU backend; its supports predicate is the euclidean metric, the
-    row-count threshold, and a viable VMEM block).  Padding rounds the
-    per-shard row count up to the block, so any supported block size
-    works: the largest that leaves a shard ``_MIN_BLOCKS`` blocks."""
+    row-count threshold, and a viable VMEM tile).  Which of the kernel's
+    two layouts, and its tiles, follow from ``(d, k)`` alone
+    (``kmeans_pallas.stats_tiles``): feature-major with all of k resident
+    (``k_tile`` None) wherever that fits VMEM, else tiled over k.  Padding
+    rounds the per-shard row count up to the block, so any supported block
+    size works: the largest that leaves a shard ``_MIN_BLOCKS`` blocks.
+    The kernel tiled over k assigns by the ``"first"`` policy alone, so
+    another ``tiePolicy`` at its shapes takes the XLA body."""
     from ...kernels.registry import lookup
     from ...ops import kmeans_pallas as kp
 
     entry = lookup("kmeans_update_stats", sig=(n, d, k, measure.name))
     if entry.backend == "pallas":
-        # measured-not-analytic when the autotune cache is configured
-        # (ISSUE 12): the winner is persisted per (d, k, device kind),
-        # so only the fleet's first process pays the search
-        block_n = kp.pick_block_n_measured(d, k)
+        block_n, k_tile = kp.stats_tiles(d, k)
+        if k_tile is not None and tie_policy != "first":
+            return "xla", None, None
+        if k_tile is None:
+            # measured-not-analytic when the autotune cache is configured
+            # (ISSUE 12): the winner is persisted per (d, k, device kind),
+            # so only the fleet's first process pays the search
+            block_n = kp.pick_block_n_measured(d, k)
         # narrow rows admit blocks of 2^15-2^16 rows: keep a shard at
         # _MIN_BLOCKS of them, so that its fill rows stay a small share
         # and the kernel's pipeline has steps to overlap
         shard_rows = -(-n // int(mesh.shape.get("data", 1)))
         while block_n > 128 and block_n * _MIN_BLOCKS > shard_rows:
             block_n //= 2
-        return "pallas", block_n
-    return "xla", None
+        return "pallas", block_n, k_tile
+    return "xla", None, None
 
 
 @dataclass(frozen=True)
@@ -568,6 +687,20 @@ class FitPlan:
     fill: str                  # pad_rows_with_mask fill policy
     k: int
     d: int
+    k_tile: Optional[int] = None   # Pallas stats kernel tiled over k
+
+    def notes(self) -> dict:
+        """What ``fit.arrange`` notes of the plan: which stats kernel the
+        fit took, its tiles, and the share of the MXU passes' operand
+        area that is padding at them."""
+        from ...ops import kmeans_pallas as kp
+
+        if self.impl != "pallas":
+            return {"stats_plan": self.impl}
+        return {"stats_plan": ("k_tiled" if self.k_tile else "feature_major"),
+                "block_n": self.block_n, "k_tile": self.k_tile or self.k,
+                "mxu_padded_share": kp.mxu_padded_share(
+                    self.d, self.k, self.k_tile)}
 
     def local_multiple(self, mesh) -> int:
         """Per-process padded-row multiple on ``mesh`` under this plan."""
@@ -591,7 +724,7 @@ class FitPlan:
 
 
 def _fit_plan(n: int, d: int, k: int, measure: DistanceMeasure, mesh, *,
-              workset: bool = False) -> FitPlan:
+              workset: bool = False, tie_policy: str = "first") -> FitPlan:
     """Build the shared :class:`FitPlan`.  The workset path plans via
     registry op ``kmeans_workset_update``: the fused scoring+stats
     Pallas kernel (PR 10) where available — TPU, euclidean, a viable
@@ -611,10 +744,11 @@ def _fit_plan(n: int, d: int, k: int, measure: DistanceMeasure, mesh, *,
             block_n = kp.pick_block_n_workset_measured(d, k)
             return FitPlan("pallas_ws", block_n, block_n, "first_row", k, d)
         return FitPlan("xla", None, 1, "first_row", k, d)
-    impl, block_n = _plan_fit_impl(n, d, k, measure, mesh)
+    impl, block_n, k_tile = _plan_fit_impl(n, d, k, measure, mesh,
+                                           tie_policy)
     row_multiple, fill = ((block_n, "zero") if impl == "pallas"
                           else (1, "first_row"))
-    return FitPlan(impl, block_n, row_multiple, fill, k, d)
+    return FitPlan(impl, block_n, row_multiple, fill, k, d, k_tile)
 
 
 def kmeans_fit_outofcore(make_reader, k: int, *,
@@ -775,7 +909,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                     column = stack_vectors(column)
             with tracer.span("fit.gather.cast", "fit"):
                 host_points = float32_rows(column)
-        with tracer.span("fit.arrange", "fit"):
+        with tracer.span("fit.arrange", "fit") as arrange:
             n_for_plan = host_points.shape[0]
             multi_host = mesh_process_count(mesh) > 1
             if multi_host:
@@ -802,7 +936,9 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
 
             workset_mode = self.get_workset()
             plan = _fit_plan(n_for_plan, host_points.shape[1], k, measure,
-                             mesh, workset=workset_mode)
+                             mesh, workset=workset_mode,
+                             tie_policy=self.get_tie_policy())
+            arrange.note(**plan.notes())
             impl, block_n = plan.impl, plan.block_n
             select_init = _INIT_MODES[self.get_init_mode()]
 
@@ -825,17 +961,8 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
 
         spec = P("data")
         if not multi_host and int(mesh.shape["data"]) == 1:
-            with tracer.span("fit.upload", "fit"):
-                # asynchronous: the transfer runs while the host draws
-                # the start below
-                flat = put_sharded(host_points.reshape(-1), mesh, spec)
+            points, mask = _put_and_lay_out(host_points, plan, mesh, spec)
             with tracer.span("fit.arrange", "fit"):
-                with tracer.span("fit.arrange.pad", "fit"):
-                    points, mask = _rows_on_device(
-                        host_points.shape,
-                        -host_points.shape[0] % plan.local_multiple(mesh),
-                        plan.fill, NamedSharding(mesh, spec))(flat)
-                    del flat
                 init = draw_start()
         else:
             with tracer.span("fit.arrange", "fit"):
@@ -866,6 +993,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                 result, n_real=n_for_plan, n_padded=int(points.shape[0]))
         else:
             body = (kmeans_epoch_step_pallas(k, mesh, block_n=block_n,
+                                             k_tile=plan.k_tile,
                                              tie_policy=self.get_tie_policy())
                     if impl == "pallas" else kmeans_epoch_step(measure, k))
             result = iterate(
@@ -1033,7 +1161,7 @@ def _pallas_stats_supported(sig: tuple) -> bool:
         return False
     n, d, k, measure_name = sig
     return (measure_name == "euclidean" and n >= _PALLAS_MIN_ROWS
-            and kp.pick_block_n(None, d, k) is not None)
+            and kp.stats_tiles(d, k) is not None)
 
 
 def _pallas_workset_supported(sig: tuple) -> bool:

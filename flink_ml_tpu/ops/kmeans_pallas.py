@@ -79,6 +79,28 @@ is two to eight times the pick; at d 20, k 10 it refuses 65536 at
 still compiles 16384 (points twice 8 MiB, a float32 score tile 16 MiB),
 so it never holds a whole score-shaped tile: hence the half.
 
+**Tiled over k** (PR 35).  All of the above keeps every centroid, its
+sums, ``c2`` and the counts resident and works through a score tile of
+``(k, block_n)``: at k 4096, d 784 the model above says 67 MB of resident
+blocks against 12.  There the same call (``k_tile`` given:
+:func:`stats_tiles` decides from ``(d, k)`` alone) runs
+:func:`_ktiled_stats_kernel`: the centroids (as ``-2 C`` in bfloat16)
+and the float32 sums stay resident under a raised VMEM limit, and a
+block of rows meets them a tile of ``k_tile`` centroids at a time, twice:
+a scoring pass that carries every row's running minimum and the first
+index that reached it across the tiles, then a pass that adds each
+tile's one-hot contraction to that tile's rows of the sums.  Every row is
+scored against every centroid; the points are read from HBM once an
+iteration.  The block's orientation follows the layout the chip keeps the
+points in, which follows from ``d`` (:func:`_update_stats_ktiled`).
+Measured on one v5e, the kernel alone in a jitted scan that feeds the
+centroids back (my chip runs, PR 35): 2^18 x 784 grey-level images, k 4096,
+tiles (512, 512): 20.98 ms an iteration, 80.2 TFLOP/s of the scoring
+contraction's 2 n k d alone and as much again for the sums, with 784 on 896
+lanes 93% of the bf16 peak; counts and sums equal to float64's under the
+first-index rule, to the unit; the XLA expansion of the same step, which
+the compiler fuses without ever holding an ``(n, k)`` array, 25.21 ms.
+
 Design notes:
 
 - **No mask input.**  Padding rows must be exact zeros — the MASKLESS
@@ -139,37 +161,98 @@ __all__ = [
     "pick_block_n_measured",
     "pick_block_n_workset",
     "pick_block_n_workset_measured",
+    "stats_tiles",
+    "mxu_padded_share",
     "supported",
     "workset_supported",
 ]
 
 _VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom below the ~16 MB/core VMEM
+#: the k-tiled layout keeps (k, d) blocks resident and raises Mosaic's
+#: limit to what its model says plus this headroom, inside the chip's
+#: 128 MiB of VMEM
+_KTILED_VMEM_BUDGET = 96 * 1024 * 1024
+_KTILED_VMEM_HEADROOM = 8 * 1024 * 1024
 
 
 def _up(x: int, multiple: int) -> int:
     return -(-x // multiple) * multiple
 
 
-def _stats_tile_bytes(d: int, k: int, block_n: int) -> int:
-    """THE per-tile VMEM model of the stats kernel's feature-major
-    layout, padding counted: the points block ``(d, block_n)`` lies on
-    ceil(d/8)*8 sublanes and the pipeline holds two of it; HALF a
+def _stats_tile_bytes(d: int, k: int, block_n: int,
+                      k_tile: Optional[int] = None) -> int:
+    """THE per-tile VMEM model of the stats kernel, padding counted.
+    Every supported()/pick_block_n variant of the stats kernel derives
+    from this ONE formula.
+
+    Feature-major (``k_tile`` None): the points block ``(d, block_n)``
+    lies on ceil(d/8)*8 sublanes and the pipeline holds two of it; HALF a
     score-shaped ``(k, block_n)`` float32 tile on ceil(k/8)*8 sublanes
     for the compare/one-hot chain (the compiler works through it by lane
     columns and never holds a whole one: see the module docstring for
     the probe that says so); and the resident ``(k, d)`` / ``(k, 1)``
     blocks (centroids, sums, c2, counts), lane-padded to 128, two
-    buffers each.  Every supported()/pick_block_n variant of the stats
-    kernel derives from this ONE formula."""
-    d8, k8 = _up(d, 8), _up(k, 8)
-    return ((2 * d8 * 4 + k8 * 2) * block_n
-            + 4 * k8 * (_up(d, 128) + 128) * 4)
+    buffers each.
+
+    Tiled over k (``k_tile`` centroids a tile): the float32 points block
+    of ``block_n`` rows, reckoned on ceil(d/128)*128 lanes (row-major; the
+    feature-major block pads less), two of it, and its bfloat16 copy;
+    resident for the whole call, two buffers each, the bfloat16 centroids
+    and the float32 sums ``(k, d)`` with k rounded up to the tile, and
+    ``c2`` and the counts ``(k, 1)`` on 128 lanes; a score tile and a
+    one-hot ``(k_tile, block_n)`` and one contraction's result
+    ``(k_tile, d)``, float32.  58 MB at d 784, k 4096 and tiles of 512,
+    which Mosaic compiles under the limit this number sets."""
+    if k_tile is None:
+        d8, k8 = _up(d, 8), _up(k, 8)
+        return ((2 * d8 * 4 + k8 * 2) * block_n
+                + 4 * k8 * (_up(d, 128) + 128) * 4)
+    dp, kp = _up(d, 128), _up(k, k_tile)
+    return ((2 * 4 + 2) * block_n * dp
+            + 2 * kp * (dp * (2 + 4) + 2 * 128 * 4)
+            + k_tile * (2 * block_n + dp) * 4)
 
 
-def supported(d: int, k: int, block_n: int = 8192) -> bool:
+def supported(d: int, k: int, block_n: int = 8192,
+              k_tile: Optional[int] = None) -> bool:
     """True if the stats-kernel tile (:func:`_stats_tile_bytes`) fits the
-    VMEM budget."""
-    return _stats_tile_bytes(d, k, block_n) <= _VMEM_BUDGET
+    VMEM budget of its layout."""
+    budget = _VMEM_BUDGET if k_tile is None else _KTILED_VMEM_BUDGET
+    return _stats_tile_bytes(d, k, block_n, k_tile) <= budget
+
+
+#: ``(block_n, k_tile)`` the k-tiled layout is offered, widest first.  The
+#: tiles hardly matter once both are a few MXU passes wide: at 2^18 x 784,
+#: k 4096 an iteration takes 20.98 ms at (512, 512), 20.68 at (1024, 512),
+#: 20.53 at (512, 1024), 20.47 at (1024, 1024), 20.24 at (2048, 512) and
+#: 21.92 at (256, 512) (my chip runs, PR 35, one v5e: the MXU passes of
+#: both contractions, padding included, at 93% of the bf16 peak), so the
+#: first pick is the one that holds least
+_KTILED_TILES = ((512, 512), (512, 256), (256, 256), (256, 128), (128, 128))
+
+
+def stats_tiles(d: int, k: int) -> Optional[Tuple[int, Optional[int]]]:
+    """``(block_n, k_tile)`` of the stats kernel at ``(d, k)``, from the
+    shapes alone: the feature-major layout with everything of ``k``
+    resident (``k_tile`` None) wherever its tile fits, else the layout
+    tiled over k, else None (the caller falls back to XLA)."""
+    block_n = pick_block_n(None, d, k)
+    if block_n is not None:
+        return block_n, None
+    for block_n, k_tile in _KTILED_TILES:
+        if supported(d, k, block_n, k_tile):
+            return block_n, k_tile
+    return None
+
+
+def mxu_padded_share(d: int, k: int, k_tile: Optional[int]) -> float:
+    """The share of both contractions' centroid-shaped operand that is
+    padding as the kernel lays it out: ``(k, d)`` on ceil(k/8)*8 x
+    ceil(d/8)*8 feature-major, on whole k tiles x ceil(d/128)*128 lanes
+    tiled over k.  The MXU passes over the padding as over data."""
+    kp, dp = ((_up(k, 8), _up(d, 8)) if k_tile is None
+              else (_up(k, k_tile), _up(d, 128)))
+    return 1.0 - (k * d) / (kp * dp)
 
 
 # Largest block each kernel is offered.  The stats kernel's blocks are
@@ -350,6 +433,134 @@ def _stats_kernel(tie_policy: str, compute_dtype):
     return kern
 
 
+def _ktiled_stats_kernel(k_tile: int, n_tiles: int, rows_on_lanes: bool):
+    """The stats of one block of rows against ALL centroids, a tile of
+    ``k_tile`` of them at a time.  The score tile is ``(k_tile, block_n)``
+    with k on sublanes and the block's rows on lanes, as in the kernel
+    with all of k resident; the points block is ``(d, block_n)`` of
+    ``points.T`` (``rows_on_lanes``) or ``(block_n, d)`` of ``points``,
+    whichever the chip holds (:func:`_update_stats_ktiled`), and the two
+    contractions swap their forms with it: ``C_j Xt`` / ``onehot_j . Xt``
+    over the lanes of both, or ``C_j . X`` over the lanes of both /
+    ``onehot_j X``.  Nothing is transposed in VMEM either way.
+
+    Pass 1 carries, for every row, the minimum so far and where it was
+    first seen, as ``(8, block_n)`` vregs: slot ``s`` of a lane holds the
+    best of the centroids ``8 g + s`` and the first group ``g`` that
+    reached it (a strict ``<`` keeps the earlier one, across tile borders
+    too), so a tile costs three VPU passes over its scores and no
+    reduction; the eight slots meet once a block: the minimum over
+    sublanes, then the smallest centroid index among the slots that hold
+    it: the first index of the whole row.  Pass 2 forms each tile's
+    one-hot from that index and adds its contraction with the block to
+    the tile's rows of the resident sums."""
+    over_lanes = (((1,), (1,)), ((), ()))
+    groups = k_tile // 8
+
+    def contract(lhs, rhs, lanes_of_both: bool):
+        if lanes_of_both:
+            return jax.lax.dot_general(lhs, rhs, over_lanes,
+                                       preferred_element_type=jnp.float32)
+        return jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
+
+    def kern(x_ref, cb_ref, c2_ref, sums_ref, counts_ref):
+        block_n = x_ref.shape[1 if rows_on_lanes else 0]
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            sums_ref[:] = jnp.zeros_like(sums_ref)
+            counts_ref[:] = jnp.zeros_like(counts_ref)
+
+        xb = x_ref[:].astype(jnp.bfloat16)
+
+        def tile(j):
+            return pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
+
+        def score(j, carry):
+            best, seen = carry
+            # cb holds -2 C rounded to bfloat16: c2 - 2 C.x, float32
+            scores = (contract(cb_ref[tile(j), :], xb, not rows_on_lanes)
+                      + c2_ref[tile(j), :])               # (k_tile, bn)
+            for g in range(groups):
+                part = scores[8 * g:8 * (g + 1), :]
+                less = part < best
+                best = jnp.where(less, part, best)
+                seen = jnp.where(less, j * groups + g, seen)
+            return best, seen
+
+        best, seen = jax.lax.fori_loop(
+            0, n_tiles, score,
+            (jnp.full((8, block_n), jnp.inf, jnp.float32),
+             jnp.zeros((8, block_n), jnp.int32)))
+        index = 8 * seen + jax.lax.broadcasted_iota(jnp.int32, seen.shape, 0)
+        lowest = jnp.min(best, axis=0, keepdims=True)
+        first = jnp.min(jnp.where(best == lowest, index,
+                                  jnp.iinfo(jnp.int32).max),
+                        axis=0, keepdims=True)            # (1, bn)
+
+        def accumulate(j, carry):
+            ids = j * k_tile + jax.lax.broadcasted_iota(
+                jnp.int32, (k_tile, block_n), 0)
+            onehot = (ids == first).astype(jnp.float32)
+            sums_ref[tile(j), :] += contract(
+                onehot.astype(jnp.bfloat16), xb, rows_on_lanes)
+            counts_ref[tile(j), :] += jnp.sum(onehot, axis=1, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, accumulate, None)
+
+    return kern
+
+
+def _update_stats_ktiled(points, centroids, block_n: int, k_tile: int,
+                         interpret: bool):
+    """The call of :func:`_ktiled_stats_kernel`.  The blocks follow the
+    layout the chip keeps ``points`` in, which follows from ``d``: rows
+    whose width is no multiple of 128 lanes lie column-major (no lane is
+    padded: compiled for a described v5e, an ``f32[n,784]`` parameter is
+    ``{0,1:T(8,128)}``), so the call takes ``points.T``, a bitcast, in
+    blocks ``(d, block_n)``; rows of whole lane tiles lie row-major and go
+    in as they are, ``(block_n, d)``.  The other orientation would make
+    XLA copy the points (7.26 GB beside 6.35 at 2,025,000 x 784).  The
+    centroids go in as ``-2 C`` rounded to bfloat16 on whole tiles (zero
+    rows, whose ``c2`` is infinite, so that no row picks one); the sums
+    come back on the same padded shape and are cut to ``k`` rows."""
+    n, d = points.shape
+    k = centroids.shape[0]
+    k_pad = _up(k, k_tile)
+    rows_on_lanes = d % 128 != 0
+    c2 = jnp.pad(jnp.sum(centroids * centroids, axis=1, keepdims=True),
+                 ((0, k_pad - k), (0, 0)), constant_values=jnp.inf)
+    cb = jnp.pad((-2.0 * centroids).astype(jnp.bfloat16),
+                 ((0, k_pad - k), (0, 0)))
+
+    def resident(shape):
+        return pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+
+    if rows_on_lanes:
+        x, x_spec = points.T, pl.BlockSpec(
+            (d, block_n), lambda i: (0, i), memory_space=pltpu.VMEM)
+    else:
+        x, x_spec = points, pl.BlockSpec(
+            (block_n, d), lambda i: (i, 0), memory_space=pltpu.VMEM)
+    sums, counts = pl.pallas_call(
+        _ktiled_stats_kernel(k_tile, k_pad // k_tile, rows_on_lanes),
+        grid=(n // block_n,),
+        in_specs=[x_spec, resident((k_pad, d)), resident((k_pad, 1))],
+        out_specs=[resident((k_pad, d)), resident((k_pad, 1))],
+        out_shape=[
+            jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
+            jax.ShapeDtypeStruct((k_pad, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(_stats_tile_bytes(d, k, block_n, k_tile)
+                              + _KTILED_VMEM_HEADROOM)),
+        interpret=interpret,
+    )(x, cb, c2)
+    return sums[:k], counts[:k, 0]
+
+
 def _assign_kernel(points_ref, cent_ref, c2_ref,
                    assign_ref, sums_ref, counts_ref):
     i = pl.program_id(0)
@@ -382,10 +593,11 @@ def _check_block(n: int, block_n: int, op: str = "kmeans_pallas") -> None:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_n", "tie_policy", "compute_dtype",
-                                    "interpret"))
+                   static_argnames=("block_n", "k_tile", "tie_policy",
+                                    "compute_dtype", "interpret"))
 def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
-                        block_n: int = 8192, tie_policy: str = "fast",
+                        block_n: int = 8192, k_tile: Optional[int] = None,
+                        tie_policy: str = "fast",
                         compute_dtype=jnp.float32, interpret: bool = False
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fit hot path: ``(points (n, d), centroids (k, d)) ->
@@ -394,7 +606,11 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     ``n`` must be a multiple of ``block_n``; pad with all-zero rows and
     correct the counts with :func:`pad_correction`.  A block is
     ``block_n`` rows on lanes: a multiple of 128 for the chip (the
-    interpreter takes any).
+    interpreter takes any).  ``(block_n, k_tile)`` come from
+    :func:`stats_tiles`: ``k_tile`` None is the feature-major kernel with
+    all of ``k`` resident, a number the kernel tiled over k, which knows
+    the ``"first"`` policy alone and rounds both contractions' operands
+    to bfloat16 itself (``compute_dtype`` does not apply).
     """
     if tie_policy not in ("first", "fast", "split"):
         raise ValueError(f"tie_policy must be 'first', 'fast' or 'split', "
@@ -402,6 +618,12 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     n, d = points.shape
     k = centroids.shape[0]
     _check_block(n, block_n)
+    if k_tile is not None:
+        if tie_policy != "first":
+            raise ValueError("the stats kernel tiled over k assigns by the "
+                             f"'first' policy alone, got {tie_policy!r}")
+        return _update_stats_ktiled(points, centroids, block_n, k_tile,
+                                    interpret)
     c2 = jnp.sum(centroids * centroids, axis=1, keepdims=True)
 
     # (n, d) -> (d, n): the chip keeps narrow rows column-major, so for
@@ -499,6 +721,7 @@ def pad_correction(counts: jnp.ndarray, centroids: jnp.ndarray,
 
 def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
                          mesh, *, block_n: int = 8192,
+                         k_tile: Optional[int] = None,
                          tie_policy: str = "fast",
                          compute_dtype=jnp.float32,
                          interpret: bool = False
@@ -513,8 +736,9 @@ def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
 
     def shard_fn(pts, cents):
         sums, counts = kmeans_update_stats(
-            pts, cents, block_n=block_n, tie_policy=tie_policy,
-            compute_dtype=compute_dtype, interpret=interpret)
+            pts, cents, block_n=block_n, k_tile=k_tile,
+            tie_policy=tie_policy, compute_dtype=compute_dtype,
+            interpret=interpret)
         return (jax.lax.psum(sums, "data"), jax.lax.psum(counts, "data"))
 
     # shard_map_fn turns the varying-axes check off (pallas_call

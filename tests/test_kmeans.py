@@ -250,6 +250,83 @@ def test_plan_leaves_a_shard_eight_blocks(monkeypatch, n, d, k, data_devs,
         "pallas", block_n, block_n, "zero")
 
 
+def test_plan_tiles_over_k_where_k_does_not_fit_vmem(monkeypatch):
+    """``kmeans_mnist8m``'s shapes: the plan is the kernel tiled over k at
+    the tiles ``stats_tiles`` gives, noted on the span with the share of
+    the MXU's operand area that is padding (784 on 896 lanes); HiBench's
+    shapes plan what they planned before, block included, and another
+    ``tiePolicy`` than the kernel's takes the XLA body."""
+    import jax
+
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel.mesh import device_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = device_mesh({"data": 1}, devices=jax.devices()[:1])
+    euclid = DistanceMeasure.get_instance("euclidean")
+    plan = km._fit_plan(2_025_000, 784, 4096, euclid, mesh)
+    assert (plan.impl, plan.block_n, plan.k_tile, plan.row_multiple,
+            plan.fill) == ("pallas", 512, 512, 512, "zero")
+    assert plan.notes() == {"stats_plan": "k_tiled", "block_n": 512,
+                            "k_tile": 512,
+                            "mxu_padded_share": 1.0 - 784 / 896}
+    hibench = km._fit_plan(20_000_000, 20, 10, euclid, mesh)
+    assert hibench == km.FitPlan("pallas", 32768, 32768, "zero", 10, 20)
+    assert hibench.notes()["stats_plan"] == "feature_major"
+    assert km._fit_plan(2_025_000, 784, 4096, euclid, mesh,
+                        tie_policy="split").impl == "xla"
+    assert km._fit_plan(20_000_000, 20, 10, euclid, mesh,
+                        tie_policy="split").impl == "pallas"
+
+
+def test_fit_through_the_ktiled_body_matches_plain_lloyd(monkeypatch):
+    """``KMeans.fit`` under a plan that takes the kernel tiled over k (the
+    interpreter stands in for the chip; the test steers the plan, no
+    option of the program does) against Lloyd's algorithm written out in
+    the kernel's stated arithmetic: operands of the scores rounded to
+    bfloat16, the first index on a tie, an empty cluster keeps its
+    centroid.  On whole grey levels the sums are exact on both sides."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    rng = np.random.default_rng(11)
+    protos = rng.integers(0, 256, size=(12, 48)).astype(np.float32)
+    pts = np.clip(protos[rng.integers(0, 12, size=1000)]
+                  + rng.integers(-9, 10, size=(1000, 48)), 0, 255
+                  ).astype(np.float32)
+    k, seed, rounds = 20, 5, 4
+
+    def bf16(a):
+        return np.asarray(jnp.asarray(a, jnp.float32).astype(jnp.bfloat16)
+                          .astype(jnp.float32), np.float64)
+
+    cents = km.select_random_centroids(pts, k, seed)
+    for _ in range(rounds):
+        scores = (cents.astype(np.float64) ** 2).sum(1)[None] - 2.0 * (
+            bf16(pts) @ bf16(cents).T)
+        assign = scores.argmin(1)
+        counts = np.bincount(assign, minlength=k)
+        sums = np.zeros((k, pts.shape[1]))
+        np.add.at(sums, assign, pts)
+        cents = np.where(counts[:, None] > 0,
+                         sums / np.maximum(counts, 1)[:, None],
+                         cents).astype(np.float32)
+
+    step = km.kmeans_epoch_step_pallas
+    monkeypatch.setattr(
+        km, "_fit_plan", lambda n, d, k, measure, mesh, **how:
+        km.FitPlan("pallas", 128, 128, "zero", k, d, k_tile=8))
+    monkeypatch.setattr(
+        km, "kmeans_epoch_step_pallas",
+        lambda *a, **kw: step(*a, **kw, interpret=True))
+    model = (KMeans().set_k(k).set_seed(seed).set_max_iter(rounds)
+             .fit(Table({"features": pts})))
+    got = np.asarray(model.get_model_data()[0]["centroids"][0])
+    np.testing.assert_allclose(got, cents, rtol=1e-6)
+
+
 def test_pallas_step_fractional_split_counts_divide_exactly():
     # A cluster whose total "split" count is fractional (< 1) must divide by
     # the fractional count, not a clamp-to-1 (regression: centroid scaled by
@@ -584,9 +661,11 @@ def small_relayout_pieces(monkeypatch):
     from flink_ml_tpu.models.clustering import kmeans as km
 
     km._rows_on_device.cache_clear()
+    km._rows_from_pieces.cache_clear()
     monkeypatch.setattr(km, "_RELAYOUT_ROWS", 64)
     yield
     km._rows_on_device.cache_clear()
+    km._rows_from_pieces.cache_clear()
 
 
 def _parent_fit(column, mesh, *, k, seed, max_iter, row_multiple, fill):
@@ -628,7 +707,7 @@ def _fit_recording_puts(monkeypatch, column, mesh, *, row_multiple, fill):
 
     monkeypatch.setattr(
         km, "_fit_plan",
-        lambda n, d, k, measure, mesh, workset=False:
+        lambda n, d, k, measure, mesh, **how:
         km.FitPlan("xla", None, row_multiple, fill, k, d))
     puts = []
     real_put = km.put_sharded
@@ -732,6 +811,75 @@ def test_rows_on_device_equals_the_host_pad(small_relayout_pieces, shape,
     with count_compiles() as compiles:
         on_device(pts + 1)
     assert compiles() == 0
+
+
+@pytest.mark.parametrize("fill", ["zero", "first_row"])
+@pytest.mark.parametrize("n,put_rows", [(250, 128), (256, 128), (300, 192),
+                                        (100, 192)])
+def test_rows_put_in_pieces_equal_the_host_pad(small_relayout_pieces, n,
+                                               put_rows, fill):
+    """A buffer put in pieces of ``put_rows`` rows (a last piece shorter
+    than the rest, shorter than a relayout step, or the only one) is laid
+    out as the whole buffer is."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.models.clustering.kmeans import _rows_from_pieces
+    from flink_ml_tpu.parallel.mesh import device_mesh, put_sharded
+    from flink_ml_tpu.utils.padding import pad_rows_with_mask
+
+    mesh = device_mesh(devices=jax.devices()[:1])
+    pts = np.random.default_rng(6).normal(size=(n, 5)).astype(np.float32)
+    empty, place, finish = _rows_from_pieces(
+        (n, 5), -n % 64, fill, NamedSharding(mesh, P("data")))
+    points, mask = empty()
+    for first in range(0, n, put_rows):
+        points = place(points, put_sharded(
+            pts[first:first + put_rows].reshape(-1), mesh, P("data")), first)
+    got = finish(points), mask
+    for have, expected in zip(got, pad_rows_with_mask(pts, 64, fill=fill)):
+        assert np.asarray(have).tobytes() == expected.tobytes()
+
+
+def test_fit_from_a_table_put_in_pieces_is_the_fit_from_one_put(
+        monkeypatch, small_relayout_pieces):
+    """``KMeans.fit`` on a one-device mesh with the thresholds lowered so
+    that 300 rows go up in three puts: the same centroids, bit for bit,
+    as from the one put."""
+    import jax
+
+    from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel.mesh import device_mesh, use_mesh
+
+    pts = np.random.default_rng(8).normal(size=(300, 5)).astype(np.float32)
+
+    def fit():
+        with use_mesh(device_mesh(devices=jax.devices()[:1])):
+            model = (KMeans().set_k(4).set_seed(3).set_max_iter(3)
+                     .fit(Table({"features": pts})))
+        return np.asarray(model.get_model_data()[0]["centroids"][0])
+
+    whole = fit()
+    puts = []
+    real_put = km.put_sharded
+    monkeypatch.setattr(km, "put_sharded",
+                        lambda arr, *a: (puts.append(arr), real_put(arr, *a))[1])
+    monkeypatch.setattr(km, "_PUT_BYTES", 2 * 64 * 5 * 4)
+    assert fit().tobytes() == whole.tobytes()
+    assert [a.shape for a in puts] == [(640,), (640,), (220,)]
+    assert all(np.shares_memory(a, pts) for a in puts)
+
+
+def test_put_rows_pieces_only_what_one_put_is_slow_at():
+    """HiBench's 1.6 GB go in one put, as before; ``kmeans_mnist8m``'s
+    6.35 GB in four pieces of whole relayout steps, 2 GiB at most."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    assert km._put_rows(20_000_000, 20) == 20_000_000
+    rows = km._put_rows(2_025_000, 784)
+    assert rows % km._RELAYOUT_ROWS == 0 and rows == 655360
+    assert 4 * rows * 784 <= 1 << 31
+    assert km._put_rows(10_000_000, 100_000) == km._RELAYOUT_ROWS
 
 
 @pytest.mark.parametrize("kind", _COLUMN_KINDS + ["i64"])

@@ -257,3 +257,113 @@ def test_pad_correction_exact_under_min_norm_ties_first():
     np.testing.assert_allclose(counts[0], exp_counts[:2].sum(), atol=1e-3)
     np.testing.assert_allclose(counts[1], 0.0, atol=1e-4)
     assert (counts >= -1e-4).all()
+
+
+# -- the stats kernel tiled over k ------------------------------------------
+
+def _grey_problem(n, d, k, n_pad, seed):
+    """Whole grey levels 0-255 (exact in bfloat16, as the benchmark's
+    images are), ``n_pad`` zero rows last, and centroids that are rows of
+    the set with the faults a fit meets planted: centroid ``k - 2`` is
+    centroid 1 again, bit for bit (every row of theirs ties exactly, the
+    two a whole tile apart), centroid 2 is centroid 0 again (a tie inside
+    one tile), and the last centroid lies where no row is."""
+    rng = np.random.default_rng(seed)
+    pts = rng.integers(0, 256, size=(n, d)).astype(np.float32)
+    pts[n - n_pad:] = 0.0
+    cents = pts[rng.permutation(n - n_pad)[:k]].copy()
+    cents[k - 2] = cents[1]
+    cents[2] = cents[0]
+    cents[k - 1] = 4096.0
+    return pts, cents
+
+
+def _lloyd_stats(pts, cents, n_pad):
+    """Plain ``jax.numpy`` Lloyd statistics in the kernel's stated
+    arithmetic: operands rounded to bfloat16, products exact, ``c2`` of
+    the float32 centroids, first index on a tie, real rows only."""
+    real = jnp.asarray(pts[:len(pts) - n_pad])
+    c = jnp.asarray(cents)
+    with jax.default_matmul_precision("highest"):
+        rounded = real.astype(jnp.bfloat16).astype(jnp.float32)
+        scores = jnp.sum(c * c, axis=1)[None] - 2.0 * (
+            rounded @ c.astype(jnp.bfloat16).astype(jnp.float32).T)
+        assign = jnp.argmin(scores, axis=1)
+        onehot = jax.nn.one_hot(assign, len(cents), dtype=jnp.float32)
+        return (np.asarray(assign), np.asarray(onehot.T @ rounded),
+                np.asarray(onehot.sum(0)))
+
+
+@pytest.mark.parametrize("d,k,block_n,k_tile", [
+    (200, 70, 128, 16),     # rows on lanes; k 70 on five tiles of 16
+    (784, 100, 256, 64),    # the benchmark's width; a last tile of 36
+    (40, 24, 128, 8),       # one group of eight a tile
+    (256, 70, 128, 32),     # whole lane tiles: row-major blocks
+    (128, 40, 256, 128),    # row-major, one tile wider than k
+], ids=["d200-k70", "d784-k100", "d40-k24", "d256-k70", "d128-k40"])
+def test_ktiled_stats_match_plain_lloyd(d, k, block_n, k_tile):
+    """Tiles that do not divide k, a width off the lane tile and on it,
+    exact ties inside a tile and across tiles, an empty cluster and zero
+    rows under ``pad_correction``: counts to the unit, sums exact (whole
+    grey levels add exactly in float32 in any order)."""
+    n, n_pad = 3 * block_n, 37
+    pts, cents = _grey_problem(n, d, k, n_pad, seed=d + k)
+    assign, exp_sums, exp_counts = _lloyd_stats(pts, cents, n_pad)
+    sums, counts = kmeans_update_stats(
+        jnp.asarray(pts), jnp.asarray(cents), block_n=block_n,
+        k_tile=k_tile, tie_policy="first", interpret=True)
+    counts = pad_correction(counts, jnp.asarray(cents), n_pad,
+                            tie_policy="first")
+    np.testing.assert_array_equal(np.asarray(counts), exp_counts)
+    np.testing.assert_array_equal(np.asarray(sums), exp_sums)
+    # the planted faults did what they were planted for
+    assert exp_counts[1] > 0 and exp_counts[k - 2] == 0
+    assert exp_counts[0] > 0 and exp_counts[2] == 0
+    assert exp_counts[k - 1] == 0 and not np.asarray(sums)[k - 1].any()
+
+
+def test_ktiled_stats_take_a_tie_across_tiles_to_the_first_index():
+    """Every centroid the same row: all of k ties for every point, over
+    all the tiles, and centroid 0 takes everything."""
+    rng = np.random.default_rng(3)
+    pts = rng.integers(0, 256, size=(256, 24)).astype(np.float32)
+    cents = np.repeat(pts[:1], 40, axis=0)
+    sums, counts = kmeans_update_stats(
+        jnp.asarray(pts), jnp.asarray(cents), block_n=128, k_tile=16,
+        tie_policy="first", interpret=True)
+    assert np.asarray(counts).tolist() == [256.0] + [0.0] * 39
+    np.testing.assert_array_equal(np.asarray(sums)[0], pts.sum(0))
+
+
+def test_ktiled_stats_know_the_first_policy_alone():
+    pts, cents, _ = _problem()
+    with pytest.raises(ValueError, match="'first' policy alone"):
+        kmeans_update_stats(pts, cents, block_n=128, k_tile=8,
+                            tie_policy="fast", interpret=True)
+
+
+def test_ktiled_stats_sharded_matches_single(cpu_mesh_8):
+    pts, cents = _grey_problem(1024, 40, 24, 0, seed=9)
+    sharded = update_stats_sharded(
+        jnp.asarray(pts), jnp.asarray(cents), cpu_mesh_8, block_n=128,
+        k_tile=8, tie_policy="first", interpret=True)
+    single = kmeans_update_stats(
+        jnp.asarray(pts), jnp.asarray(cents), block_n=128, k_tile=8,
+        tie_policy="first", interpret=True)
+    for got, want in zip(sharded, single):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("d,k,tiles", [
+    (20, 10, (32768, None)),      # HiBench: all of k resident, as before
+    (64, 256, (8192, None)),      # chip_smoke.py's
+    (784, 256, (1024, None)),     # the source's other k still fits whole
+    (784, 4096, (512, 512)),      # kmeans_mnist8m: tiled over k
+    (128, 16384, (512, 512)),     # row-major, 16 K centroids
+    (784, 65536, None),           # 400 MB of resident sums: XLA
+], ids=["hibench", "chip-smoke", "mnist-k256", "mnist-k4096", "d128-k16384",
+        "too-wide"])
+def test_stats_tiles_follow_the_shapes(d, k, tiles):
+    from flink_ml_tpu.ops.kmeans_pallas import stats_tiles
+
+    assert stats_tiles(d, k) == tiles

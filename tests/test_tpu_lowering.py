@@ -75,12 +75,14 @@ def _lr_step(d, batch, n_dense):
 
 
 def _kmeans_stats(n, d, k, block_n, tie_policy):
-    """``block_n`` None: the block the fit's plan picks at (d, k)."""
+    """``block_n`` None: the tiles the fit's plan picks at (d, k)."""
     from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
-                                                pick_block_n)
+                                                stats_tiles)
 
-    block_n = block_n or pick_block_n(n, d, k)
-    return (partial(kmeans_update_stats, block_n=block_n,
+    k_tile = None
+    if block_n is None:
+        block_n, k_tile = stats_tiles(d, k)
+    return (partial(kmeans_update_stats, block_n=block_n, k_tile=k_tile,
                     tie_policy=tie_policy),
             (Shape((n, d), F32), Shape((k, d), F32)))
 
@@ -169,6 +171,9 @@ CASES = {
            for tie in ("first", "fast", "split")},
         "full-width": lambda: _kmeans_stats(1 << 20, 64, 256, 8192, "first"),
         "hibench": lambda: _kmeans_stats(1 << 20, 20, 10, None, "first"),
+        "mnist8m": lambda: _kmeans_stats(1 << 17, 784, 4096, None, "first"),
+        "tiled-over-k-row-major": lambda: _kmeans_stats(
+            1 << 17, 128, 16384, None, "first"),
     },
     ("kmeans_workset_update", "pallas"): {
         "smallest": lambda: _kmeans_workset(128, 8, 4, 128),
@@ -306,6 +311,52 @@ def test_kmeans_stats_compiles_at_the_block_the_model_picks(one_v5e, d, k):
                     tie_policy="first")).lower(
         Shape((2 * block_n, d), F32, sharding=one_v5e),
         Shape((k, d), F32, sharding=one_v5e)).compile()
+
+
+@pytest.mark.parametrize("d,k", [(784, 4096), (128, 16384), (200, 5000)],
+                         ids=["mnist8m", "row-major-16k", "ragged"])
+def test_kmeans_stats_tiled_over_k_compile_alone(one_v5e, d, k):
+    """The kernel tiled over k, ALONE, at the tiles its VMEM model picks:
+    ``kmeans_mnist8m``'s shapes (rows on lanes: ``points.T`` is the
+    column-major array the chip keeps a row of 784 floats as), 16 K
+    centroids of whole lane tiles (row-major blocks), and a k and a d
+    that divide by neither tile.  Mosaic must take the resident ``(k, d)``
+    blocks under the raised VMEM limit, the dynamic tile slices and the
+    contraction over 784; and no operand is copied on the way in."""
+    from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
+                                                stats_tiles)
+
+    block_n, k_tile = stats_tiles(d, k)
+    assert k_tile is not None
+    compiled = jax.jit(partial(kmeans_update_stats, block_n=block_n,
+                               k_tile=k_tile, tie_policy="first")).lower(
+        Shape((8 * block_n, d), F32, sharding=one_v5e),
+        Shape((k, d), F32, sharding=one_v5e)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < block_n * d * 4
+
+
+def test_kmeans_fit_program_tiled_over_k_keeps_no_copy_of_the_points(one_v5e):
+    """The fused program of ``kmeans_mnist8m.fit`` (2,025,472 x 784 with
+    the fill rows, k 4096, 20 iterations): 6.35 GB of points go into the
+    kernel as they lie; a kernel that wanted rows of 784 floats on 896
+    lanes made XLA copy them (7.27 GB of temporaries, compiled here)."""
+    from flink_ml_tpu.models.clustering.kmeans import kmeans_epoch_step_pallas
+    from flink_ml_tpu.ops.kmeans_pallas import stats_tiles
+
+    n, d, k = 2_025_472, 784, 4096
+    block_n, k_tile = stats_tiles(d, k)
+    body = kmeans_epoch_step_pallas(k, block_n=block_n, k_tile=k_tile)
+
+    def run(centroids, data):
+        return jax.lax.scan(
+            lambda c, epoch: (body(c, epoch, data).feedback, None),
+            centroids, jnp.arange(20, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(run).lower(
+        Shape((k, d), F32, sharding=one_v5e),
+        (Shape((n, d), F32, sharding=one_v5e),
+         Shape((n,), F32, sharding=one_v5e))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_kmeans_fit_program_keeps_no_copy_of_the_points(one_v5e):

@@ -224,6 +224,77 @@ def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k, block_n):
                                rtol=2e-3, atol=0.5)
 
 
+def _grey_images(rng, n, dcol, k):
+    """Whole grey levels 0-255 (exact in bfloat16): 2 k sparse shapes
+    (more than centroids, or every row would lie between near-twins)
+    under a gain and noise on their inked pixels, and k of the rows as
+    centroids, with a duplicate a whole tile away and one far off."""
+    protos = ((rng.random((2 * k, dcol)) < 0.19)
+              * rng.integers(60, 256, size=(2 * k, dcol))).astype(np.float32)
+    pts = protos[rng.integers(0, 2 * k, size=n)]
+    pts = np.clip(np.rint(pts * rng.uniform(0.6, 1.0, size=(n, 1))
+                          + (pts > 0) * rng.integers(-16, 17, size=pts.shape)),
+                  0, 255).astype(np.float32)
+    cents = pts[rng.permutation(n)[:k]].copy()
+    cents[k - 2] = cents[1]
+    cents[k - 1] = 4096.0
+    return pts, cents
+
+
+@pytest.mark.parametrize("n,dcol,k", [(1 << 17, 784, 4096),
+                                      (1 << 15, 128, 16384),
+                                      (20_000, 200, 5000)],
+                         ids=["mnist8m", "row-major-16k", "ragged"])
+def test_kmeans_kernel_tiled_over_k_parity(tpu, rng, n, dcol, k):
+    """kmeans_update_stats tiled over k, at the tiles the plan picks,
+    against float64 sums of the bfloat16-rounded points under the
+    first-index assignment: ``kmeans_mnist8m``'s shapes (rows on lanes),
+    16 K centroids of whole lane tiles (row-major blocks), and a k, a d
+    and an n that divide by nothing.  The points are whole grey levels,
+    so the sums are exact; a row whose two best scores lie within what
+    float32 accumulation can move (8 of about 3e6) may go either way, and
+    only the clusters such a row could touch are let off."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.ops.kmeans_pallas import (
+        kmeans_update_stats, pad_correction, stats_tiles)
+    from flink_ml_tpu.utils.padding import pad_rows_to_block
+
+    block_n, k_tile = stats_tiles(dcol, k)
+    assert k_tile is not None
+    pts, cents = _grey_images(rng, n, dcol, k)
+    (padded,), _ = pad_rows_to_block((pts,), block_n)
+    sums, counts = kmeans_update_stats(
+        jnp.asarray(padded), jnp.asarray(cents), block_n=block_n,
+        k_tile=k_tile, tie_policy="first")
+    counts = pad_correction(counts, jnp.asarray(cents),
+                            padded.shape[0] - n, tie_policy="first")
+    sums, counts = np.asarray(sums, np.float64), np.asarray(counts)
+
+    cb = np.asarray(jnp.asarray(cents).astype(jnp.bfloat16)
+                    .astype(jnp.float32), np.float64)
+    c2 = (cents.astype(np.float64) ** 2).sum(1)
+    assign = np.empty(n, np.int64)
+    loose = np.zeros(k, bool)
+    for lo in range(0, n, 4096):
+        sc = c2[None] - 2.0 * pts[lo:lo + 4096].astype(np.float64) @ cb.T
+        assign[lo:lo + 4096] = sc.argmin(1)
+        two = np.argpartition(sc, 1, axis=1)[:, :2]
+        near = np.abs(np.take_along_axis(sc, two, 1) @ [1.0, -1.0]) < 8.0
+        # an exact tie is the first index's, in float32 as in float64
+        near &= sc[np.arange(len(sc)), two[:, 0]] != sc[
+            np.arange(len(sc)), two[:, 1]]
+        loose[two[near].reshape(-1)] = True
+    want_counts = np.bincount(assign, minlength=k)
+    want_sums = np.zeros((k, dcol))
+    np.add.at(want_sums, assign, pts.astype(np.float64))
+    assert loose.mean() < 0.2
+    assert counts.sum() == n and want_counts[k - 2] == 0
+    np.testing.assert_array_equal(counts[~loose], want_counts[~loose])
+    np.testing.assert_array_equal(sums[~loose], want_sums[~loose])
+    np.testing.assert_array_equal(sums.sum(0), pts.sum(0, dtype=np.float64))
+
+
 @_KMEANS_SHAPES
 def test_kmeans_workset_kernel_parity(tpu, rng, n, dcol, k):
     """kmeans_workset_update (fused Hamerly scoring + stats) vs the numpy
