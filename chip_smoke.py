@@ -49,7 +49,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 NUM_FEATURES = 1 << 20
 N_DENSE, N_CAT = 13, 26
-NATIVE_LIBS = ("ell_layout", "datacache", "criteo")
+NATIVE_LIBS = ("ell_layout", "datacache", "criteo", "als_plan")
 
 # (rows, batch) per leg — rows only for kmeans: the chip run, and the
 # --cpu-rehearsal cut

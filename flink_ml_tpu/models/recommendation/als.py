@@ -14,7 +14,10 @@ items against the new users).  Its normal equations come in two forms
 - ``grouped`` (``'sorted'``, and ``'auto'`` wherever one side's dense
   ``(n_groups, rank, rank)`` would outgrow a block): one host plan a side
   (:class:`GroupedPlan`) lays every group's ratings out at a padded
-  length, groups of one length together, and the epoch body forms AND
+  length, groups of one length together (a rating reaches its slot, its
+  group's first plus its rank in the group, by one native counting pass,
+  ``native/als_plan.cpp``, and by a stable order of the ratings in NumPy
+  where no library loads: the same slots), and the epoch body forms AND
   solves the equations a block of groups at a time under ``lax.scan``:
   gather the other side's rows, ``A_g = Y_g^T diag(w) Y_g`` and ``b_g`` as
   batched contractions over the group's own slots, Cholesky with the
@@ -37,6 +40,8 @@ would be singular).
 
 from __future__ import annotations
 
+import ctypes
+import functools
 import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, NamedTuple, Optional
@@ -83,8 +88,8 @@ _BLOCK_A_SHARE = 1 / 8
 _BLOCK_ROWS_SHARE = 1 / 12
 
 
-#: host threads of a fit's index and plan: the sorts, searches and
-#: scatters over all the ratings release the GIL
+#: host threads of a fit's index and plan: the sorts and searches over all
+#: the ratings release the GIL, and so does the native placement
 _HOST_THREADS = min(8, os.cpu_count() or 1)
 
 
@@ -119,10 +124,29 @@ def _block_sizes(rank: int) -> tuple:
             1 << max(slots.bit_length() - 1, 3))
 
 
+@functools.lru_cache(maxsize=None)
+def _native_plan() -> Optional[ctypes.CDLL]:
+    """``native/als_plan.cpp`` built and loaded, or ``None`` on a machine
+    with no ``make`` and no built library (the plan then places its
+    ratings in NumPy): ``load_native_lib``'s policy."""
+    from ...utils.native_lib import load_native_lib
+
+    lib = load_native_lib("als_plan")
+    if lib is not None:
+        pointer, size = ctypes.c_void_p, ctypes.c_int64
+        lib.als_place.argtypes = [pointer, size, pointer, size, size,
+                                  pointer, pointer, pointer,
+                                  pointer, pointer, pointer, size]
+        lib.als_place.restype = ctypes.c_int
+    return lib
+
+
 def _stable_group_order(group_idx: np.ndarray, n_groups: int) -> np.ndarray:
     """``np.argsort(group_idx, kind="stable")`` in passes of 16 bits: a
     stable sort of 16-bit keys is NumPy's radix sort, linear in the
-    ratings where the comparison sort of int32 keys is not."""
+    ratings where the comparison sort of int32 keys is not.  Serves the
+    plan's NumPy form only (:attr:`GroupedPlan.slot`): the native pass
+    orders nothing."""
     order = np.argsort((group_idx & 0xFFFF).astype(np.uint16), kind="stable")
     if n_groups > 1 << 16:
         high = (group_idx >> 16).astype(np.uint16)[order]
@@ -167,23 +191,24 @@ class GroupedPlan:
     ``block_slots`` is split instead into parts of that many slots, one
     part a scan step, whose partial sums the scan carries (``split_rows``
     ``(parts, 1)``, ``first`` / ``last`` ``(parts,)``).  A group with no
-    rating is nowhere (it keeps its factors).  ``slot`` sends every rating
-    to its place in the flat slots (class after class, then the parts);
-    the slots no rating fills are padding, of weight 0."""
+    rating is nowhere (it keeps its factors).
+
+    The constructor counts and lays out; it places nothing.  Rating ``k``
+    of group ``g`` belongs in the flat slots (class after class, then the
+    parts) at ``g``'s first slot plus the number of ``g``'s ratings before
+    ``k``; the slots no rating fills are padding, of weight 0.
+    :meth:`arrays` puts the columns there by one native counting pass
+    (``native/als_plan.cpp``: no order of the ratings, no slot index) or,
+    where no library loads, through :attr:`slot`, the same places made in
+    NumPy from a stable order of the ratings by group."""
 
     def __init__(self, group_idx: np.ndarray, n_groups: int, rank: int,
                  block_groups: Optional[int] = None,
                  block_slots: Optional[int] = None):
-        group_idx = np.asarray(group_idx)
-        group_idx = np.asarray(group_idx)
-        counts = np.bincount(group_idx, minlength=n_groups)
-        slot0 = self._lay_out(counts, rank, block_groups, block_slots)
-        # in the stable order by group the k-th rating goes to its group's
-        # first slot plus its rank in the group: k, shifted group by group
-        shift = slot0 - (np.cumsum(counts) - counts)
-        self.slot = np.empty(self.nnz, np.int64)
-        self.slot[_stable_group_order(group_idx, n_groups)] = (
-            np.arange(self.nnz) + np.repeat(shift, counts))
+        self._group_idx = np.ascontiguousarray(group_idx, np.int64)
+        self._slot0 = self._lay_out(
+            np.bincount(self._group_idx, minlength=n_groups), rank,
+            block_groups, block_slots)
 
     @classmethod
     def of_counts(cls, counts: np.ndarray, rank: int,
@@ -192,8 +217,9 @@ class GroupedPlan:
         """The plan's classes for groups of ``counts`` ratings, without a
         place for any rating: the shapes of the epoch body's program."""
         plan = cls.__new__(cls)
-        plan._lay_out(np.asarray(counts), rank, block_groups, block_slots)
-        plan.slot = None
+        plan._group_idx = None
+        plan._slot0 = plan._lay_out(np.asarray(counts), rank, block_groups,
+                                    block_slots)
         return plan
 
     def _lay_out(self, counts, rank, block_groups, block_slots):
@@ -250,15 +276,60 @@ class GroupedPlan:
         """Pad slots over all slots."""
         return 1.0 - self.nnz / max(self.slots, 1)
 
-    def arrange(self, values: np.ndarray) -> tuple:
-        """``values`` (one a rating) in the plan's slots, the padding 0:
-        ``(a (blocks, groups * length) array a class, the (parts,
-        block_slots) array of the split groups)``."""
-        flat = np.zeros(self.slots, np.asarray(values).dtype)
-        flat[self.slot] = values
+    @property
+    def slot(self) -> Optional[np.ndarray]:
+        """Every rating's place in the flat slots, made on demand in
+        NumPy (``None`` for a plan :meth:`of_counts`): in the stable order
+        by group the k-th rating goes to its group's first slot plus its
+        rank in the group, which is k, shifted group by group."""
+        if self._group_idx is None:
+            return None
+        shift = self._slot0 - (np.cumsum(self.counts) - self.counts)
+        slot = np.empty(self.nnz, np.int64)
+        slot[_stable_group_order(self._group_idx, self.n_groups)] = (
+            np.arange(self.nnz) + np.repeat(shift, self.counts))
+        return slot
+
+    def _by_block(self, flat: np.ndarray) -> tuple:
+        """Flat slots as the epoch body scans them: ``(a (blocks, groups *
+        length) array a class, the (parts, block_slots) array of the split
+        groups)``."""
         return (tuple(flat[c.offset:c.offset + c.rows.size * c.length]
                       .reshape(self.blocks, -1) for c in self.classes),
                 flat[self.split_offset:].reshape(self.parts, self.split_length))
+
+    def arrange(self, values: np.ndarray,
+                slot: Optional[np.ndarray] = None) -> tuple:
+        """``values`` (one a rating) in the plan's slots, the padding 0,
+        :meth:`_by_block`: the NumPy form, one scatter through ``slot``
+        (:attr:`slot` where none is given)."""
+        flat = np.zeros(self.slots, np.asarray(values).dtype)
+        flat[self.slot if slot is None else slot] = values
+        return self._by_block(flat)
+
+    def _place_native(self, lib, other_idx, ratings, weights) -> list:
+        """The columns in the plan's slots by ``als_place``: ratings in
+        their own order, every column written as the pass goes."""
+        columns = [np.ascontiguousarray(other_idx, np.int64),
+                   np.ascontiguousarray(ratings, np.float32),
+                   None if weights is None
+                   else np.ascontiguousarray(weights, np.float32)]
+        if any(c is not None and c.shape != (self.nnz,) for c in columns):
+            raise ValueError(f"the plan places columns of {self.nnz} "
+                             "ratings, one value a rating")
+        flats = [None if c is None else np.zeros(self.slots, dtype)
+                 for c, dtype in zip(columns,
+                                     (np.int32, np.float32, np.float32))]
+        # half of the host's threads: a fit plans its two sides at once
+        failed = lib.als_place(
+            self._group_idx.ctypes.data, self.nnz, self._slot0.ctypes.data,
+            self.n_groups, self.slots,
+            *(None if a is None else a.ctypes.data for a in columns + flats),
+            max(1, _HOST_THREADS // 2))
+        if failed:
+            raise RuntimeError(f"als_place failed with {failed}: the "
+                               "plan's lay-out does not hold its ratings")
+        return [self._by_block(flat) for flat in flats if flat is not None]
 
     def unit_weights(self) -> tuple:
         """What :meth:`arrange` gives for a weight of 1 on every rating,
@@ -284,12 +355,18 @@ class GroupedPlan:
         The padding has weight 0 (every term of the normal equations is
         scaled by the weight, which is what makes it inert) and points at
         row 0.  ``weights=None`` is a weight of 1 on every rating."""
-        columns = [(other_idx, np.int32), (ratings, np.float32)]
-        if weights is not None:
-            columns.append((weights, np.float32))
-        with ThreadPoolExecutor(len(columns)) as pool:
-            cols = list(pool.map(
-                lambda c: self.arrange(np.asarray(c[0], c[1])), columns))
+        lib = _native_plan()
+        if lib is not None:
+            cols = self._place_native(lib, other_idx, ratings, weights)
+        else:
+            columns = [(other_idx, np.int32), (ratings, np.float32)]
+            if weights is not None:
+                columns.append((weights, np.float32))
+            slot = self.slot
+            with ThreadPoolExecutor(len(columns)) as pool:
+                cols = list(pool.map(
+                    lambda c: self.arrange(np.asarray(c[0], c[1]), slot),
+                    columns))
         if weights is None:
             cols.append(self.unit_weights())
         whole = tuple(
@@ -893,6 +970,9 @@ class ALS(ALSParams, Estimator[ALSModel]):
                 and max(n_users, n_items) > _block_sizes(rank)[0]))
             with tracer.span("fit.arrange.plan", "fit") as span:
                 plans = None
+                # loaded (and, on a clean tree, built) before the two
+                # sides' threads ask for it
+                placed_native = grouped and _native_plan() is not None
                 if grouped:
                     # one static host plan per side (the ratings are fixed
                     # for the whole fit), the two sides side by side; the
@@ -908,6 +988,7 @@ class ALS(ALSParams, Estimator[ALSModel]):
                 solve_plan = _solve_plan(plans, rank) if grouped else "xla"
                 span.note(neq_plan="grouped" if grouped else "scatter",
                           solve=solve_plan,
+                          placed_native=int(placed_native),
                           route_bytes=sum(int(a.nbytes) for a in
                                           jax.tree_util.tree_leaves(data)))
                 if grouped:

@@ -550,6 +550,120 @@ def test_grouped_plan_places_every_rating_once(block_groups, block_slots):
             1.0 - len(g) / plan.slots)
 
 
+@pytest.mark.parametrize("host_threads", [2, 4, 6, 16])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("block_groups,block_slots",
+                         [(7, 64), (64, 512), (8192, 1 << 21)])
+def test_native_placement_equals_the_numpy_form(monkeypatch, block_groups,
+                                                block_slots, weighted,
+                                                host_threads):
+    """``native/als_plan.cpp`` against the NumPy form it stands in for:
+    every leaf of ``plan.arrays(...)`` the same in value, dtype and shape,
+    over the sets of the test above (split groups at the small blocks) and
+    sets of one and of three ratings, which have fewer ratings than the
+    pass has threads; with and without a weights column; at 1, 2, 3 and 8
+    threads (a side takes half of ``_HOST_THREADS``)."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    lib = als_mod._native_plan()
+    assert lib is not None
+    monkeypatch.setattr(als_mod, "_HOST_THREADS", host_threads)
+    for g in _plan_cases() + [np.array([3]), np.array([0, 0, 5])]:
+        rng = np.random.default_rng(len(g))
+        other = rng.integers(0, 1 << 20, len(g))
+        ratings = rng.normal(size=len(g)).astype(np.float32)
+        weights = (rng.random(len(g)).astype(np.float32) if weighted
+                   else None)
+        plan = als_mod.GroupedPlan(g, int(g.max()) + 2, 4, block_groups,
+                                   block_slots)
+        monkeypatch.setattr(als_mod, "_native_plan", lambda: None)
+        want = jax.tree_util.tree_leaves(
+            plan.arrays(other, ratings, weights))
+        monkeypatch.setattr(als_mod, "_native_plan", lambda: lib)
+        got = jax.tree_util.tree_leaves(plan.arrays(other, ratings, weights))
+        for a, b in zip(got, want, strict=True):
+            assert (a.dtype, a.shape) == (b.dtype, b.shape)
+            np.testing.assert_array_equal(a, b)
+
+
+def test_native_placement_refuses_what_the_lay_out_cannot_hold():
+    """``als_place`` checks what it is given before it writes: a group
+    index outside the lay-out and a group whose ratings would run past the
+    last slot are errors, not writes out of bounds."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    g = np.array([0, 1, 1, 2])
+    plan = als_mod.GroupedPlan(g, 3, 4, 8, 64)
+    lib = als_mod._native_plan()
+    assert lib is not None
+    plan._group_idx = np.array([0, 1, 1, 3])
+    with pytest.raises(RuntimeError, match="als_place failed with 1"):
+        plan.arrays(g, g + 1.0)
+    plan._group_idx, plan._slot0 = g, plan._slot0 + plan.slots - 1
+    with pytest.raises(RuntimeError, match="als_place failed with 2"):
+        plan.arrays(g, g + 1.0)
+    with pytest.raises(ValueError, match="one value a rating"):
+        als_mod.GroupedPlan(g, 3, 4, 8, 64).arrays(g[:3], g + 1.0)
+
+
+def _fit_and_its_plan_span(est, table):
+    """The notes of the fit's span ``fit.arrange.plan``, and the model's
+    columns."""
+    from flink_ml_tpu.obs.trace import tracer
+
+    tracer.enable()
+    try:
+        model = est.fit(table)
+        (span,) = tracer.find("fit.arrange.plan")
+    finally:
+        tracer.disable()
+        tracer.clear()
+    (data,) = model.get_model_data()
+    return span.ids, {col: np.asarray(data[col]) for col in
+                      ("userIds", "itemIds", "userFactors", "itemFactors")}
+
+
+def _grouped_als():
+    return (ALS().set_rank(6).set_max_iter(3).set_seed(1)
+            .set(ALS.NEQ_IMPL, "sorted"))
+
+
+def _ratings_table(seed=46, n=4000):
+    rng = np.random.default_rng(seed)
+    return Table({"user": rng.integers(0, 300, n).astype(np.int64),
+                  "item": rng.integers(0, 40, n).astype(np.int64),
+                  "rating": rng.normal(size=n).astype(np.float32)})
+
+
+def test_grouped_fit_places_natively_and_says_so():
+    """Guard against a silent fall back: where the library loads (this
+    machine has ``make``), a grouped fit's span ``fit.arrange.plan`` notes
+    ``placed_native`` 1; a scatter fit places nothing and notes 0."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    assert als_mod._native_plan() is not None
+    ids, _ = _fit_and_its_plan_span(_grouped_als(), _ratings_table())
+    assert (ids["neq_plan"], ids["placed_native"]) == ("grouped", 1)
+    ids, _ = _fit_and_its_plan_span(ALS().set_rank(4).set_max_iter(1),
+                                    _ratings_table(n=400))
+    assert (ids["neq_plan"], ids["placed_native"]) == ("scatter", 0)
+
+
+def test_fit_without_the_library_gives_the_same_model(monkeypatch):
+    """A machine with no ``make`` and no built library: the loader gives
+    ``None``, the plan places in NumPy, the span says ``placed_native`` 0
+    and the model is the native fit's to the bit."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    table = _ratings_table(seed=47)
+    native_ids, native = _fit_and_its_plan_span(_grouped_als(), table)
+    monkeypatch.setattr(als_mod, "_native_plan", lambda: None)
+    numpy_ids, numpy_ = _fit_and_its_plan_span(_grouped_als(), table)
+    assert (native_ids["placed_native"], numpy_ids["placed_native"]) == (1, 0)
+    for col, value in native.items():
+        np.testing.assert_array_equal(value, numpy_[col])
+
+
 def test_index_labels_is_np_unique_with_inverse():
     """The threaded index of a long label column (a part a thread, then
     binary search) gives ``np.unique(..., return_inverse=True)``'s ids and
