@@ -50,6 +50,7 @@ from .body import (
     normalize_body_result,
 )
 from ..obs.trace import tracer
+from ..utils.backend import cache_hit_count
 from .checkpoint import CheckpointConfig, CheckpointManager
 
 __all__ = ["iterate", "IterationResult"]
@@ -417,9 +418,14 @@ def iterate(
             # whenever the body emits NO outputs — then there are no output
             # semantics to lose and the fused while_loop (plus its epoch
             # trace) is the point of the feature.
-            probe = jax.eval_shape(
-                lambda s, e: _call_body(body, s, e, provider(0)),
-                initial_state, jax.ShapeDtypeStruct((), jnp.int32))
+            # A piece of ``iterate.dispatch`` of its own, in front of the
+            # one ``_iterate_fused`` or nothing (the hosted loop) follows
+            # with.
+            with tracer.span("iterate.dispatch", "fit"), \
+                    tracer.span("iterate.dispatch.probe", "fit"):
+                probe = jax.eval_shape(
+                    lambda s, e: _call_body(body, s, e, provider(0)),
+                    initial_state, jax.ShapeDtypeStruct((), jnp.int32))
             fusible = (probe.termination is None
                        or (workset is not None and probe.outputs is None))
         mode = "fused" if fusible else "hosted"
@@ -448,12 +454,15 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
                    frac_fn: Optional[Callable[[Any], Any]] = None,
                    handed_over: bool = False) -> IterationResult:
     # ``iterate.dispatch``: what the host does to get the fused program
-    # running (probe, trace, lower, the compile-cache request, the
-    # enqueue); it ends when the jitted call returns, not when the device
-    # has finished.  ``fit.fetch``: the host blocked on the device.
+    # running, its five stages flat children of it (``.probe`` here,
+    # ``.trace``, ``.lower``, ``.compile`` and ``.enqueue`` in
+    # ``_dispatch_staged``); it ends when the compiled call returns, not
+    # when the device has finished.  ``fit.fetch``: the host blocked on
+    # the device.
     with tracer.span("iterate.dispatch", "fit"):
-        final_state, outputs, num_epochs, trace = _dispatch_fused(
-            body, initial_state, provider, config, frac_fn, handed_over)
+        final_state, outputs, num_epochs, trace = _in_one_chunk(
+            _dispatch_fused, body, initial_state, provider, config, frac_fn,
+            handed_over)
     if num_epochs is None:
         return IterationResult(final_state, outputs, config.max_epochs, {})
     # on a process-spanning mesh the loop counter comes back as a
@@ -465,6 +474,28 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
         side = {"epoch_trace": trace.fetch(
             get=lambda v: np.asarray(fetch_replicated(v)))}
     return IterationResult(final_state, outputs, n_run, side)
+
+
+def _in_one_chunk(fn, *args):
+    """``fn(*args)``, the interpreter's frames for it in one piece.
+
+    CPython (3.11 on) keeps a thread's Python frames in chunks of 16 KiB
+    and unmaps a chunk the moment its first frame returns, so a call
+    sequence that straddles a chunk's end maps and unmaps 16 KiB a call
+    (a loop of plain calls runs 150 times slower there).  Tracing and
+    lowering recurse hundreds of frames deep, through several such ends,
+    and which of their loops straddle one follows from the depth of
+    whoever called ``fit``: the same lowering of ALS's program took 0.5 to
+    4.4 s on the chip's host over five depths of the caller, and one frame
+    more or less in this module moved it by a second (PERF.md section 6,
+    PR 37).  A frame that declares 2**16 stack slots fits no such chunk:
+    the interpreter maps one of 1 MiB for it, and whatever it calls runs
+    in that chunk's other half, no end in reach.  About 10 microseconds a
+    call."""
+    return fn(*args)
+
+
+_in_one_chunk.__code__ = _in_one_chunk.__code__.replace(co_stacksize=1 << 16)
 
 
 def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
@@ -484,9 +515,10 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
     max_epochs = config.max_epochs
 
     # Probe the body's output structure without running it.
-    probe = jax.eval_shape(
-        lambda s, e: _call_body(body, s, e, data),
-        initial_state, jax.ShapeDtypeStruct((), jnp.int32))
+    with tracer.span("iterate.dispatch.probe", "fit"):
+        probe = jax.eval_shape(
+            lambda s, e: _call_body(body, s, e, data),
+            initial_state, jax.ShapeDtypeStruct((), jnp.int32))
     has_criteria = probe.termination is not None
 
     if not has_criteria:
@@ -500,7 +532,7 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
             return jax.lax.scan(scan_step, state,
                                 jnp.arange(max_epochs, dtype=jnp.int32))
 
-        return (*run(initial_state, data), None, None)
+        return (*_dispatch_staged(run, initial_state, data), None, None)
 
     # Criteria-driven: lax.while_loop; keeps only the last outputs.
     if probe.outputs is not None:
@@ -549,8 +581,27 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
             cond, step, (state, zero_out, jnp.asarray(0, jnp.int32),
                          jnp.asarray(True), trace0))
 
-    final_state, outputs, num_epochs, _, trace = run(initial_state, data)
+    final_state, outputs, num_epochs, _, trace = _dispatch_staged(
+        run, initial_state, data)
     return final_state, outputs, num_epochs, trace
+
+
+def _dispatch_staged(run, state, data):
+    """``run(state, data)`` of the jitted ``run`` by the stages JAX itself
+    makes of such a call, each under a span: the same trace, module,
+    cache key, executable and donation, nothing waited for.  The span
+    ``iterate.dispatch.compile`` notes ``cache_hit``: 1 where the
+    persistent compile cache served the request, 0 where XLA compiled."""
+    with tracer.span("iterate.dispatch.trace", "fit"):
+        traced = run.trace(state, data)
+    with tracer.span("iterate.dispatch.lower", "fit"):
+        lowered = traced.lower()
+    with tracer.span("iterate.dispatch.compile", "fit") as span:
+        hits = cache_hit_count()
+        compiled = lowered.compile()
+        span.note(cache_hit=cache_hit_count() - hits)
+    with tracer.span("iterate.dispatch.enqueue", "fit"):
+        return compiled(state, data)
 
 
 # ---------------------------------------------------------------------------

@@ -992,10 +992,8 @@ class ALS(ALSParams, Estimator[ALSModel]):
                           route_bytes=sum(int(a.nbytes) for a in
                                           jax.tree_util.tree_leaves(data)))
                 if grouped:
-                    slots = sum(p.slots for p in plans)
-                    span.note(blocks=sum(p.blocks + p.parts for p in plans),
-                              slots=slots,
-                              padded_share=1.0 - 2.0 * len(ratings) / slots)
+                    span.note(padded_share=1.0 - 2.0 * len(ratings)
+                              / sum(p.slots for p in plans))
 
         if ws_tol > 0:
             return self._fit_workset(user_ids, item_ids, data, U0, V0, ws_tol)

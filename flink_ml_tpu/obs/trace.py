@@ -78,9 +78,10 @@ __all__ = ["Span", "SpanTracer", "tracer", "CORRELATION_KEYS"]
 #: ``fit()`` call (a process-unique integer from
 #: :meth:`SpanTracer.fit_span`, shared by every span opened inside it).
 #: Beside its ids a span may carry, through ``note()``, counters of the
-#: work it did, named where they are noted (``fit.arrange.route`` of
-#: ``WideDeep.fit``: ``placement``, ``fold_passes``, ``unique_max``,
-#: ``unique_mean``, ``route_bytes``); nobody joins spans on those.
+#: work it did, named where they are noted; PERF.md section 3 lists every
+#: span's notes with the metric that reads each, and ARCHITECTURE.md
+#: "Observability" the operator's question behind the ones no metric
+#: reads.  Nobody joins spans on those.
 CORRELATION_KEYS = ("request_id", "generation", "step", "window",
                     "epoch", "op", "bucket", "tenant", "fit")
 

@@ -1,6 +1,6 @@
 """Process-level JAX backend control: the virtual CPU platform the tests
 and dry runs use, the persistent compile cache, and the process-wide
-compile counter.
+counters of compiles and of persistent-cache hits.
 
 ``force_virtual_cpu`` is the one shared implementation of the "reset to
 an n-device virtual CPU platform" step used by the driver's multi-chip
@@ -24,8 +24,8 @@ import os
 import threading
 from typing import Callable, Iterator
 
-__all__ = ["compile_count", "count_compiles", "enable_compile_cache",
-           "force_virtual_cpu"]
+__all__ = ["cache_hit_count", "compile_count", "count_compiles",
+           "enable_compile_cache", "force_virtual_cpu"]
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -80,8 +80,12 @@ def enable_compile_cache() -> str:
 # whichever thread it happens) under this event; a program loaded from a
 # serialized executable does not compile and does not report.
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# ... and every compile request that the persistent cache served under
+# this one
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _COMPILE_LOCK = threading.Lock()
 _COMPILES = [0]
+_CACHE_HITS = [0]
 _LISTENING = [False]
 
 
@@ -91,17 +95,40 @@ def _on_duration_event(event: str, duration_secs: float, **_) -> None:
             _COMPILES[0] += 1
 
 
-def compile_count() -> int:
-    """XLA compiles in this process, on any thread, since the first call
-    (which installs the listener and returns 0)."""
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        with _COMPILE_LOCK:
+            _CACHE_HITS[0] += 1
+
+
+def _count(counter: list) -> int:
     import jax.monitoring
 
     with _COMPILE_LOCK:
         if not _LISTENING[0]:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_duration_event)
+            jax.monitoring.register_event_listener(_on_event)
             _LISTENING[0] = True
-        return _COMPILES[0]
+        return counter[0]
+
+
+def compile_count() -> int:
+    """XLA compiles in this process, on any thread, since the first call
+    of this or of :func:`cache_hit_count` (which installs the listeners
+    and returns 0)."""
+    return _count(_COMPILES)
+
+
+def cache_hit_count() -> int:
+    """Compile requests of this process, on any thread, that JAX's
+    persistent compile cache served (a program read and deserialised,
+    nothing compiled), since the first call of this or of
+    :func:`compile_count`.  ``iterate`` reads it before and after its one
+    ``.compile()`` and notes the difference as ``cache_hit`` on the span
+    ``iterate.dispatch.compile``; a compile on another thread inside that
+    interval would be counted with it, which no caller does today."""
+    return _count(_CACHE_HITS)
 
 
 @contextlib.contextmanager

@@ -473,3 +473,41 @@ def test_workset_active_fraction_spans_mask_pytree():
     ws = Workset({"users": jnp.asarray([1.0, 0.0, 1.0]),
                   "items": jnp.asarray([0.0])})
     assert float(active_fraction(ws)) == 0.5
+
+
+def test_the_fused_dispatch_runs_in_one_chunk_of_the_interpreters_frames():
+    """CPython keeps a thread's frames in 16 KiB chunks and unmaps a chunk
+    when its first frame returns: a loop of calls that straddles a chunk's
+    end pays a map and an unmap a call.  ``core._in_one_chunk`` gives what
+    it calls a chunk of its own, so no depth of the caller puts such an
+    end inside the dispatch's tracing and lowering."""
+    import time
+
+    from flink_ml_tpu.iteration import core
+
+    assert core._in_one_chunk.__code__.co_stacksize >= 1 << 16
+    assert core._in_one_chunk(lambda a, b: (a, b), 1, 2) == (1, 2)
+
+    def callee(a, b, c):
+        return a
+
+    def loop(n=20000):
+        t = time.perf_counter()
+        for _ in range(n):
+            callee(1, 2, 3)
+        return time.perf_counter() - t
+
+    def at_depth(k, fn):
+        return fn() if k == 0 else at_depth(k - 1, fn)
+
+    bare = [at_depth(k, loop) for k in range(260)]
+    typical = sorted(bare)[len(bare) // 2]
+    worst = max(range(len(bare)), key=bare.__getitem__)
+    if bare[worst] < 20 * typical:
+        pytest.skip("this interpreter frees no frame chunk under a loop")
+    # at the caller's depth where the loop straddled a chunk's end, and
+    # a frame either side of it, the loop inside the chunk runs as ever
+    for k in (max(worst - 1, 0), worst, worst + 1):
+        inside = min(at_depth(k, lambda: core._in_one_chunk(loop))
+                     for _ in range(3))
+        assert inside < 5 * typical, (k, inside, typical, bare[worst])

@@ -4,7 +4,9 @@ Four blocks:
 
 1. **Tracer mechanics** — ring wraparound, disabled-path no-op,
    retroactive spans, Chrome-trace/JSONL export round trips; a span is
-   also a profiler annotation, and ``fit()`` carries its phase spans.
+   also a profiler annotation, and ``fit()`` carries its phase spans;
+   ``iterate.dispatch`` is its five stages, the staged call is the plain
+   jitted call to the bit, and ``cache_hit`` says what served it.
 2. **One metrics tree** — every surface merges into one snapshot, the
    Prometheus exposition parses line by line, the never-published
    staleness gauge exports ABSENT (the ``-1`` sentinel regression),
@@ -196,6 +198,206 @@ def test_fit_is_the_same_model_traced_or_not_and_records_nothing_when_off(
     assert len(plain) == len(traced)
     for a, b in zip(plain, traced):
         assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+# the stages of ``iterate.dispatch``, its flat children, in order
+DISPATCH_STAGES = tuple("iterate.dispatch." + stage for stage in (
+    "probe", "trace", "lower", "compile", "enqueue"))
+
+
+def _loop(votes, mode="fused", handed_over=False, width=6):
+    """``run() -> (result, the state it was given)``: one ``iterate``
+    inside a root span, as an estimator's ``fit`` would call it.  With
+    ``votes`` the body has a criterion (fused: the ``while_loop`` branch),
+    without it emits an output an epoch (the ``lax.scan`` branch)."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.iteration import (
+        HandedOver,
+        IterationBodyResult,
+        IterationConfig,
+        iterate,
+    )
+
+    def body(state, epoch, data):
+        new = jnp.tanh(state @ data) * 0.5 + state * 0.25
+        if votes:
+            return IterationBodyResult(
+                new, termination=jnp.abs(new - state).max() > 1e-3)
+        return IterationBodyResult(new, outputs=jnp.sum(new * new))
+
+    def run():
+        rng = np.random.default_rng(3)
+        state = jnp.asarray(rng.normal(size=(4, width)), jnp.float32)
+        data = jnp.asarray(rng.normal(size=(width, width)), jnp.float32)
+        with trace_mod.tracer.fit_span("Loop"):
+            result = iterate(
+                body, HandedOver(state) if handed_over else state, data,
+                max_epochs=9 if votes else 5,
+                config=IterationConfig(mode=mode))
+        return result, state
+
+    return run
+
+
+def _criteria_loop(**kwargs):
+    return _loop(True, **kwargs)
+
+
+def _scan_loop(**kwargs):
+    return _loop(False, **kwargs)
+
+
+@pytest.mark.parametrize("case", ["kmeans", "lr_mixed", "criteria"])
+def test_the_dispatch_is_its_five_stages_in_the_profiler_trace(
+        case, profiler_session):
+    if case == "criteria":
+        run = _criteria_loop()
+    else:
+        make, table, _ = _fit_case(case)
+        run = lambda: make().fit(table)  # noqa: E731
+    _, spans = profiler_session(lambda: [run(), run()])
+    roots = [s for s in spans if s[0] == "fit"]
+    assert len(roots) == 2
+    for _, lo, hi, stats in roots:
+        inside = [s for s in spans if lo <= s[1] and s[2] <= hi]
+        (whole,) = [s for s in inside if s[0] == "iterate.dispatch"]
+        stages = [s for s in inside if s[0].startswith("iterate.dispatch.")]
+        # once each, in order, one after the other, inside the dispatch
+        assert tuple(s[0] for s in stages) == DISPATCH_STAGES
+        assert all(a[2] <= b[1] for a, b in zip(stages, stages[1:]))
+        assert whole[1] <= stages[0][1] and stages[-1][2] <= whole[2]
+        assert {s[3]["fit"] for s in stages} == {stats["fit"]}
+        # what the compile-cache request was answered with, and nothing
+        # of the kind on another stage
+        assert stages[3][3]["cache_hit"] in (0, 1)
+        assert all("cache_hit" not in s[3] for s in stages[:3] + stages[4:])
+
+
+@pytest.fixture
+def compile_cache_at(tmp_path):
+    """JAX's persistent compile cache in an empty directory of the
+    test's own, every compile kept; the process's settings put back."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    settings = {"jax_compilation_cache_dir": str(tmp_path / "xla_cache"),
+                "jax_persistent_cache_min_compile_time_secs": 0.0,
+                "jax_persistent_cache_min_entry_size_bytes": 0}
+    before = {name: getattr(jax.config, name) for name in settings}
+    for name, value in settings.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+    yield tmp_path / "xla_cache"
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("case", ["kmeans", "criteria"])
+def test_cache_hit_is_0_on_a_shapes_first_fit_and_1_on_its_second(
+        case, compile_cache_at):
+    if case == "criteria":
+        run = _criteria_loop(width=7)
+    else:
+        from flink_ml_tpu.models.clustering.kmeans import KMeans
+
+        table = Table({"features":
+                       np.random.default_rng(2).normal(size=(211, 7))})
+        run = lambda: (KMeans().set_k(4).set_max_iter(2)  # noqa: E731
+                       .set_seed(3).fit(table))
+    tracer = trace_mod.tracer
+    tracer.enable()
+    run()
+    entries = len(os.listdir(compile_cache_at))
+    assert entries >= 1
+    run()
+    tracer.disable()
+    requests = list(tracer.find("iterate.dispatch.compile"))
+    assert [s.ids["cache_hit"] for s in requests] == [0, 1]
+    # the second fit asked with the first's key: served, nothing added
+    assert len(os.listdir(compile_cache_at)) == entries
+    fits = [s.ids["fit"] for s in tracer.find("fit")]
+    assert [s.ids["fit"] for s in requests] == fits
+
+
+@pytest.mark.parametrize("branch", ["scan", "while_loop"])
+def test_the_staged_dispatch_is_the_plain_jitted_call_to_the_bit(
+        branch, monkeypatch, compile_cache_at):
+    import jax
+
+    from flink_ml_tpu.iteration import core
+
+    make = _scan_loop if branch == "scan" else _criteria_loop
+    staged, given = make(handed_over=True)()
+    # a handed-over state is donated as it is: consumed by the call
+    assert given.is_deleted()
+    kept, mine = make()()
+    assert not mine.is_deleted()
+    entries = sorted(os.listdir(compile_cache_at))
+
+    calls = []
+
+    def plain(run, state, data):
+        calls.append(run)
+        return run(state, data)        # the jitted call, as it was
+
+    monkeypatch.setattr(core, "_dispatch_staged", plain)
+    reference, given = make(handed_over=True)()
+    assert len(calls) == 1 and given.is_deleted()
+    # the jitted call asks the persistent cache with the staged call's
+    # key: it is served, and adds no entry
+    assert sorted(os.listdir(compile_cache_at)) == entries
+
+    for result in (staged, kept):
+        assert result.num_epochs == reference.num_epochs
+        got = jax.tree_util.tree_leaves((result.state, result.outputs))
+        want = jax.tree_util.tree_leaves(
+            (reference.state, reference.outputs))
+        assert len(got) == len(want) >= 1
+        for a, b in zip(got, want):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.tobytes() == b.tobytes()
+    if branch == "while_loop":
+        assert 1 < staged.num_epochs <= 9
+        for name, curve in reference.side["epoch_trace"].items():
+            assert (staged.side["epoch_trace"][name].tobytes()
+                    == curve.tobytes())
+    else:
+        assert np.asarray(staged.outputs).shape == (5,)
+
+
+def test_an_auto_calls_probe_is_a_dispatch_piece_of_its_own():
+    tracer = trace_mod.tracer
+    tracer.enable()
+    # no vote: auto takes the fused loop, whose dispatch probes again
+    result, _ = _scan_loop(mode="auto")()
+    spans = [s for s in tracer.spans() if s.name.startswith("iterate")]
+    pieces = [s for s in spans if s.name == "iterate.dispatch"]
+    probes = [s for s in spans if s.name == "iterate.dispatch.probe"]
+    assert len(pieces) == 2 and len(probes) == 2
+    pieces.sort(key=lambda s: s.t0)
+    probes.sort(key=lambda s: s.t0)
+    first, second = pieces
+    assert first.t0 + first.dur <= second.t0
+    for probe, piece in zip(probes, pieces):
+        assert piece.t0 <= probe.t0
+        assert probe.t0 + probe.dur <= piece.t0 + piece.dur
+    # the first piece holds the probe and nothing else
+    others = [s for s in spans if s.name.startswith("iterate.dispatch.")
+              and s.name != "iterate.dispatch.probe"]
+    assert sorted(s.name for s in others) == sorted(DISPATCH_STAGES[1:])
+    assert all(second.t0 <= s.t0 for s in others)
+    assert len({s.ids["fit"] for s in spans}) == 1
+    assert result.num_epochs == 5
+
+    # a vote: auto takes the hosted loop, and the probe is all there is
+    tracer.clear()
+    hosted, _ = _criteria_loop(mode="auto")()
+    spans = [s.name for s in tracer.spans() if s.name.startswith("iterate")]
+    assert sorted(spans) == ["iterate.dispatch", "iterate.dispatch.probe"]
+    assert hosted.side["termination_reason"] in ("criteria", "max_epochs")
 
 
 def test_tracer_ring_wraparound_keeps_newest():
