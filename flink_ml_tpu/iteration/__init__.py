@@ -7,6 +7,7 @@ from .body import (  # noqa: F401
     OperatorLifeCycle,
     Workset,
     active_fraction,
+    with_program_key,
 )
 from .checkpoint import (  # noqa: F401
     CheckpointConfig,
@@ -19,5 +20,6 @@ from .core import (  # noqa: F401
     IterationResult,
     PerEpoch,
     Replayed,
+    clear_programs,
     iterate,
 )

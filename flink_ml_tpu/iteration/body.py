@@ -29,6 +29,7 @@ __all__ = [
     "Workset",
     "active_fraction",
     "normalize_body_result",
+    "with_program_key",
 ]
 
 
@@ -170,6 +171,29 @@ def normalize_body_result(result: Any) -> IterationBodyResult:
     if isinstance(result, IterationBodyResult):
         return result
     return IterationBodyResult(result)
+
+
+def with_program_key(body: Callable, *facts) -> Callable:
+    """``body``, stating its **program key** ``facts``: a hashable value
+    that, together with the shapes of its arguments, determines the
+    program a trace of it makes.  ``iterate``'s fused dispatch builds the
+    program of a keyed body once a process and key and enqueues that
+    executable for every later body of an equal key (``core.py:
+    _dispatch_fused``); a body without one (every plain closure) is
+    equal to nothing but itself and is traced, lowered and compiled
+    again, which is always safe.
+
+    The contract, the factory's to keep: the key names the factory and
+    EVERY fact the trace reads from anywhere but the body's arguments:
+    the Python values it bakes in, a registry lookup by its result, a
+    module constant a test may patch, the mesh.  A fact left out is a
+    stale program served silently.  And the key holds no array and
+    nothing that holds one (a plan, a route, parameters): the entry
+    outlives the ``fit()``.  An array in it is refused here (it does not
+    hash); an object that holds one cannot be seen."""
+    hash(facts)
+    body.program_key = facts
+    return body
 
 
 @dataclass

@@ -30,10 +30,13 @@ Two execution modes:
 from __future__ import annotations
 
 import dataclasses
+import threading
 
+from collections import OrderedDict
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Iterator, Optional, Sequence, Union
+from typing import (Any, Callable, Iterator, NamedTuple, Optional, Sequence,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +56,12 @@ from ..obs.trace import tracer
 from ..utils.backend import cache_hit_count
 from .checkpoint import CheckpointConfig, CheckpointManager
 
-__all__ = ["iterate", "IterationResult"]
+# what JAX itself keys a trace by: every configuration value a trace
+# depends on and a caller can change (x64, the default matmul precision,
+# dtype promotion, the mesh and axis context, ...)
+from jax._src.config import trace_context as _trace_context
+
+__all__ = ["iterate", "IterationResult", "clear_programs"]
 
 BodyFn = Callable[..., Any]
 
@@ -454,11 +462,11 @@ def _iterate_fused(body: BodyFn, initial_state, provider: _DataProvider,
                    frac_fn: Optional[Callable[[Any], Any]] = None,
                    handed_over: bool = False) -> IterationResult:
     # ``iterate.dispatch``: what the host does to get the fused program
-    # running, its five stages flat children of it (``.probe`` here,
-    # ``.trace``, ``.lower``, ``.compile`` and ``.enqueue`` in
-    # ``_dispatch_staged``); it ends when the compiled call returns, not
-    # when the device has finished.  ``fit.fetch``: the host blocked on
-    # the device.
+    # running, its five stages flat children of it (``.probe`` and
+    # ``.enqueue`` in ``_dispatch_fused``, ``.trace``, ``.lower`` and
+    # ``.compile`` in ``_compile_staged``, or in ``_reuse_staged`` around
+    # nothing); it ends when the compiled call returns, not when the
+    # device has finished.  ``fit.fetch``: the host blocked on the device.
     with tracer.span("iterate.dispatch", "fit"):
         final_state, outputs, num_epochs, trace = _in_one_chunk(
             _dispatch_fused, body, initial_state, provider, config, frac_fn,
@@ -498,13 +506,89 @@ def _in_one_chunk(fn, *args):
 _in_one_chunk.__code__ = _in_one_chunk.__code__.replace(co_stacksize=1 << 16)
 
 
+class _Program(NamedTuple):
+    """What the five stages made of one program key."""
+
+    compiled: Any        # the executable of ``run`` (``jax.stages.Compiled``)
+    has_criteria: bool   # the probe: the body votes (a while_loop, no scan)
+    drops_outputs: bool  # the probe: a voting body emits (the last is kept)
+
+
+#: Fused programs a process keeps, least recently used out.
+_PROGRAMS_KEPT = 8
+_programs: "OrderedDict[tuple, _Program]" = OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def clear_programs() -> None:
+    """Forget every fused program ``iterate`` has kept for a keyed body
+    (:func:`~.body.with_program_key`): the next dispatch of each builds
+    it again.  For tests, and for a caller who changes something a body's
+    program reads that its key does not name (a kernel swapped in
+    place)."""
+    with _programs_lock:
+        _programs.clear()
+
+
+def _kept_program(key: Optional[tuple]) -> Optional[_Program]:
+    if key is None:
+        return None
+    with _programs_lock:
+        program = _programs.get(key)
+        if program is not None:
+            _programs.move_to_end(key)
+        return program
+
+
+def _keep_program(key: Optional[tuple], program: _Program) -> None:
+    # two threads that built one key both store: the entries are equal in
+    # everything but identity and whole either way, the later one stays
+    if key is None:
+        return
+    with _programs_lock:
+        _programs[key] = program
+        _programs.move_to_end(key)
+        while len(_programs) > _PROGRAMS_KEPT:
+            _programs.popitem(last=False)
+
+
+def _leaf_key(x) -> tuple:
+    """What a jitted call keys one argument by: its abstract value
+    (shape, dtype, weak type) and, of an array that lies somewhere, where
+    and whether it is committed there."""
+    return (jax.typeof(x), getattr(x, "sharding", None),
+            getattr(x, "committed", None))
+
+
+def _program_key(body: BodyFn, state, data, config: IterationConfig,
+                 frac_fn) -> Optional[tuple]:
+    """Everything the fused program of ``body`` follows from, known before
+    any of it is traced, or ``None`` for a body that states no key of its
+    own (:func:`~.body.with_program_key`): such a body is equal to
+    nothing but itself and is built anew, as ever."""
+    stated = getattr(body, "program_key", None)
+    if stated is None:
+        return None
+    leaves, structure = jax.tree_util.tree_flatten((state, data))
+    return (stated, config.max_epochs, config.donate_state,
+            frac_fn is not None, structure,
+            tuple(_leaf_key(x) for x in leaves), _trace_context())
+
+
 def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
                     config: IterationConfig,
                     frac_fn: Optional[Callable[[Any], Any]],
                     handed_over: bool = False) -> tuple:
-    """Build the fused program and enqueue it, nothing fetched:
+    """Build the fused program, or take the one this process has built
+    for the same program key, and enqueue it, nothing fetched:
     ``(final_state, outputs, num_epochs, epoch_trace)``, the last two
-    ``None`` where no criteria ask for them."""
+    ``None`` where no criteria ask for them.
+
+    A body that states a program key is built once a process and key
+    (:func:`_program_key`): every later dispatch asks ``_programs`` in
+    the probe's span and goes to the enqueue; the three stages between
+    open their spans around nothing, and the compile's notes ``reused``
+    1.  A body that states none takes the five stages every time."""
     if not provider.is_static:
         raise ValueError("fused mode requires device-resident (static) data")
     if config.max_epochs is None:
@@ -512,30 +596,27 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
     if config.donate_state and not handed_over:
         initial_state = _private_copy(initial_state)
     data = provider(0)
-    max_epochs = config.max_epochs
+    key = _program_key(body, initial_state, data, config, frac_fn)
 
-    # Probe the body's output structure without running it.
     with tracer.span("iterate.dispatch.probe", "fit"):
-        probe = jax.eval_shape(
-            lambda s, e: _call_body(body, s, e, data),
-            initial_state, jax.ShapeDtypeStruct((), jnp.int32))
-    has_criteria = probe.termination is not None
+        program = _kept_program(key)
+        if program is None:
+            # Probe the body's output structure without running it.
+            probe = jax.eval_shape(
+                lambda s, e: _call_body(body, s, e, data),
+                initial_state, jax.ShapeDtypeStruct((), jnp.int32))
+    if program is not None:
+        _reuse_staged()
+    else:
+        has_criteria = probe.termination is not None
+        run = (_while_loop(body, config, frac_fn, probe.outputs)
+               if has_criteria else _scan_loop(body, config))
+        program = _Program(
+            _compile_staged(run, initial_state, data), has_criteria,
+            has_criteria and probe.outputs is not None)
+        _keep_program(key, program)
 
-    if not has_criteria:
-        # Fixed epoch count: lax.scan stacks per-epoch outputs.
-        @partial(jax.jit, donate_argnums=(0,) if config.donate_state else ())
-        def run(state, data):
-            def scan_step(state, epoch):
-                res = _call_body(body, state, epoch, data)
-                return res.feedback, res.outputs
-
-            return jax.lax.scan(scan_step, state,
-                                jnp.arange(max_epochs, dtype=jnp.int32))
-
-        return (*_dispatch_staged(run, initial_state, data), None, None)
-
-    # Criteria-driven: lax.while_loop; keeps only the last outputs.
-    if probe.outputs is not None:
+    if program.drops_outputs:
         import warnings
 
         warnings.warn(
@@ -544,8 +625,37 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
             "number of them); use mode='hosted' (or carry a fixed-size "
             "buffer in state) to keep the full per-epoch output log",
             stacklevel=4)
+    with tracer.span("iterate.dispatch.enqueue", "fit"):
+        out = program.compiled(initial_state, data)
+    if not program.has_criteria:
+        return (*out, None, None)
+    final_state, outputs, num_epochs, _, trace = out
+    return final_state, outputs, num_epochs, trace
+
+
+def _scan_loop(body: BodyFn, config: IterationConfig):
+    """Fixed epoch count: lax.scan stacks per-epoch outputs."""
+    max_epochs = config.max_epochs
+
+    @partial(jax.jit, donate_argnums=(0,) if config.donate_state else ())
+    def run(state, data):
+        def scan_step(state, epoch):
+            res = _call_body(body, state, epoch, data)
+            return res.feedback, res.outputs
+
+        return jax.lax.scan(scan_step, state,
+                            jnp.arange(max_epochs, dtype=jnp.int32))
+
+    return run
+
+
+def _while_loop(body: BodyFn, config: IterationConfig, frac_fn,
+                outputs_shape):
+    """Criteria-driven: lax.while_loop; keeps only the last outputs (of
+    the probe's ``outputs_shape``)."""
+    max_epochs = config.max_epochs
     zero_out = jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, s.dtype), probe.outputs)
+        lambda s: jnp.zeros(s.shape, s.dtype), outputs_shape)
 
     # Per-epoch convergence curves survive the fused loop in a
     # fixed-size NaN-prefilled StepProbe riding the carry (obs/probe.py
@@ -581,17 +691,17 @@ def _dispatch_fused(body: BodyFn, initial_state, provider: _DataProvider,
             cond, step, (state, zero_out, jnp.asarray(0, jnp.int32),
                          jnp.asarray(True), trace0))
 
-    final_state, outputs, num_epochs, _, trace = _dispatch_staged(
-        run, initial_state, data)
-    return final_state, outputs, num_epochs, trace
+    return run
 
 
-def _dispatch_staged(run, state, data):
-    """``run(state, data)`` of the jitted ``run`` by the stages JAX itself
-    makes of such a call, each under a span: the same trace, module,
-    cache key, executable and donation, nothing waited for.  The span
-    ``iterate.dispatch.compile`` notes ``cache_hit``: 1 where the
-    persistent compile cache served the request, 0 where XLA compiled."""
+def _compile_staged(run, state, data):
+    """The executable of the jitted ``run`` for ``(state, data)`` by the
+    stages JAX itself makes of such a call, each under a span: the same
+    trace, module, cache key, executable and donation as ``run(state,
+    data)``.  The span ``iterate.dispatch.compile`` notes ``cache_hit``
+    (1 where XLA compiled nothing for this fit's program: here, where the
+    persistent compile cache served the request; 0 where XLA compiled)
+    and ``reused`` 0: the stages ran."""
     with tracer.span("iterate.dispatch.trace", "fit"):
         traced = run.trace(state, data)
     with tracer.span("iterate.dispatch.lower", "fit"):
@@ -599,9 +709,20 @@ def _dispatch_staged(run, state, data):
     with tracer.span("iterate.dispatch.compile", "fit") as span:
         hits = cache_hit_count()
         compiled = lowered.compile()
-        span.note(cache_hit=cache_hit_count() - hits)
-    with tracer.span("iterate.dispatch.enqueue", "fit"):
-        return compiled(state, data)
+        span.note(cache_hit=cache_hit_count() - hits, reused=0)
+    return compiled
+
+
+def _reuse_staged() -> None:
+    """The same three spans around nothing, for a fit whose program this
+    process had kept: every fit's dispatch has its five stages, and the
+    compile's notes say where the executable came from (``reused`` 1: the
+    process's own entry; ``cache_hit`` 1: XLA compiled nothing)."""
+    for stage in ("trace", "lower"):
+        with tracer.span(f"iterate.dispatch.{stage}", "fit"):
+            pass
+    with tracer.span("iterate.dispatch.compile", "fit") as span:
+        span.note(cache_hit=1, reused=1)
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,7 @@ from ...iteration import (
     IterationConfig,
     Workset,
     iterate,
+    with_program_key,
 )
 from ...linalg import float32_rows, stack_vectors
 from ...obs.trace import tracer
@@ -434,7 +435,10 @@ def _update_centroids(centroids, sums, counts, xp=jnp):
 
 def kmeans_epoch_step(measure: DistanceMeasure, k: int):
     """One Lloyd's iteration as a pure jnp function (points, mask are closed
-    over by ``iterate``'s static data)."""
+    over by ``iterate``'s static data).  The body states its program key
+    (``iteration/body.py: with_program_key``): the measure, ``k`` and the
+    module's functions the trace calls (by what their names hold now: a
+    test that patches one gets its own program)."""
 
     def body(centroids, epoch, data):
         points, mask = data
@@ -444,7 +448,9 @@ def kmeans_epoch_step(measure: DistanceMeasure, k: int):
             new_centroids = _update_centroids(centroids, sums, counts)
         return IterationBodyResult(feedback=new_centroids)
 
-    return body
+    return with_program_key(body, kmeans_epoch_step, measure.name,
+                            type(measure), k, _assign_stats,
+                            _stats_from_assign, _update_centroids)
 
 
 def workset_points_scored(active_fraction, n_real: int,
@@ -603,7 +609,12 @@ def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
     Two ``jax.named_scope`` s say what a device operation is for:
     ``kmeans.stats`` (points and centroids to sums and counts: the kernel
     and the XLA around it) and ``kmeans.update`` (the padding's
-    correction, the division, the empty clusters)."""
+    correction, the division, the empty clusters).
+
+    The body states its program key (``iteration/body.py:
+    with_program_key``): every argument here, the mesh among them, and
+    the kernel module's functions the trace calls; the metric is the
+    kernel's one, euclidean."""
     from ...ops import kmeans_pallas as kp
 
     sharded = mesh is not None and int(mesh.shape.get("data", 1)) > 1
@@ -629,7 +640,10 @@ def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
             new_centroids = jnp.where(counts > 0, sums / safe, centroids)
         return IterationBodyResult(feedback=new_centroids)
 
-    return body
+    return with_program_key(body, kmeans_epoch_step_pallas, k, mesh, block_n,
+                            k_tile, tie_policy, interpret,
+                            kp.kmeans_update_stats, kp.update_stats_sharded,
+                            kp.pad_correction)
 
 
 # Pallas engages only above this row count — below it the XLA path is within

@@ -58,6 +58,7 @@ from ...iteration import (
     IterationConfig,
     Workset,
     iterate,
+    with_program_key,
 )
 from ...params.param import (
     BoolParam,
@@ -178,6 +179,32 @@ class GroupedClass(NamedTuple):
     offset: int
 
 
+class ClassShape(NamedTuple):
+    """A :class:`GroupedClass` without its rows."""
+
+    length: int
+    groups: int
+
+
+class PlanShape(NamedTuple):
+    """What the epoch body's program follows from of a
+    :class:`GroupedPlan` beside its arrays' shapes (:attr:`GroupedPlan.
+    shape`): small, hashable, no array in it.  The body takes this view
+    and not the plan, so that a program ``iterate`` keeps for it
+    (``iteration/body.py: with_program_key``) cannot keep a fit's 24.8 M
+    group indices alive."""
+
+    n_groups: int
+    rank: int
+    parts: int
+    classes: tuple
+
+    @property
+    def block_groups(self) -> int:
+        """Groups a block holds, fill included."""
+        return sum(c.groups for c in self.classes)
+
+
 class GroupedPlan:
     """Static layout of one side's ratings for the grouped normal
     equations: one host pass a fit (the ratings are fixed for the whole
@@ -232,7 +259,7 @@ class GroupedPlan:
             raise ValueError("block_slots must be a multiple of "
                              f"{_MIN_LENGTH}")
         n_groups = len(counts)
-        self.n_groups, self.nnz = n_groups, int(counts.sum())
+        self.n_groups, self.nnz, self.rank = n_groups, int(counts.sum()), rank
         self.counts = counts
         length = _padded_lengths(counts)
         whole = (counts > 0) & (length <= block_slots)
@@ -270,6 +297,12 @@ class GroupedPlan:
     def block_groups(self) -> int:
         """Groups a block holds, fill included."""
         return sum(c.groups for c in self.classes)
+
+    @property
+    def shape(self) -> PlanShape:
+        """The plan as the epoch body's program sees it."""
+        return PlanShape(self.n_groups, self.rank, self.parts, tuple(
+            ClassShape(c.length, c.groups) for c in self.classes))
 
     @property
     def padded_share(self) -> float:
@@ -434,10 +467,11 @@ def _regularized(A, cnt, gram, reg: float, implicit: bool):
     return A + (reg * jnp.maximum(cnt, 1.0))[:, None, None] * eye[None, :, :]
 
 
-def _solve_side_grouped(prev, factors, plan: "GroupedPlan", arrays,
+def _solve_side_grouped(prev, factors, plan, arrays,
                         reg: float, implicit: bool, alpha: float):
     """Grouped half-epoch: ``prev``-side factors re-solved against fixed
-    ``factors``: a block of groups a scan step (class by class the
+    ``factors`` under ``plan`` (a :class:`GroupedPlan` or, all the epoch
+    body has, its :class:`PlanShape`): a block of groups a scan step (class by class the
     gather and the contractions, then one solve for the block), then a
     split group's parts, one a step."""
     gram = factors.T @ factors if implicit else None      # shared Y^T Y
@@ -689,14 +723,31 @@ def _solve_side(prev, factors, group_idx, other_idx, ratings, weights,
     return _solve_from_neq(prev, factors, A, b, cnt, reg, implicit)
 
 
+def _block_solves(shape: "PlanShape") -> tuple:
+    """``(backend, fn)`` of every :func:`_block_solve` the trace of one
+    side's half-epoch asks the registry for: its whole blocks', its split
+    parts'.  The epoch body's program key names them, since the trace
+    reads them from the registry and not from its arguments."""
+    sizes = (([shape.block_groups] if shape.classes else [])
+             + ([1] if shape.parts else []))
+    return tuple((solve.backend, solve.fn) for solve in
+                 (_block_solve(shape.rank, groups) for groups in sizes))
+
+
 def als_epoch_step(n_users: int, n_items: int, reg: float, implicit: bool,
                    alpha: float, plans=None):
     """One ALS epoch (users then items) as an ``iterate`` body.
 
-    ``plans=(plan_u, plan_v)`` (:class:`GroupedPlan`) switches to the
-    grouped normal equations — the data is then each side's
+    ``plans=(shape_u, shape_v)`` (each side's :attr:`GroupedPlan.shape`,
+    a :class:`PlanShape`: the body holds no plan) switches to the grouped
+    normal equations — the data is then each side's
     :meth:`GroupedPlan.arrays` instead of the raw ``(u_idx, i_idx, r,
-    w)``."""
+    w)``.
+
+    The body states its program key (``iteration/body.py:
+    with_program_key``): every argument here, the scatter form's chunk,
+    for the grouped form what the registry answers each side's solves
+    with, and the module's functions the trace calls."""
 
     def body(state, epoch, data):
         U, V = state
@@ -712,6 +763,10 @@ def als_epoch_step(n_users: int, n_items: int, reg: float, implicit: bool,
                                 implicit, alpha)
             else:
                 plan_u, plan_v = plans
+                if not U.shape[1] == plan_u.rank == plan_v.rank:
+                    raise ValueError(
+                        f"factors of rank {U.shape[1]} under plans of rank "
+                        f"{plan_u.rank} and {plan_v.rank}")
                 by_user, by_item = data
                 U = _solve_side_grouped(U, V, plan_u, by_user, reg,
                                         implicit, alpha)
@@ -719,7 +774,15 @@ def als_epoch_step(n_users: int, n_items: int, reg: float, implicit: bool,
                                         implicit, alpha)
         return IterationBodyResult(feedback=(U, V))
 
-    return body
+    # the module's own functions the trace calls, by what their names hold
+    # NOW: a test that patches one (the benchmark's fault ``plain_lambda``
+    # patches ``_regularized``) must get a program of its own
+    how = ((_CHUNK, _solve_side, _normal_equations, _solve_from_neq,
+            _regularized) if plans is None else
+           (tuple(plans), tuple(_block_solves(p) for p in plans),
+            _solve_side_grouped, _block_normal_equations, _regularized))
+    return with_program_key(body, als_epoch_step, n_users, n_items, reg,
+                            implicit, alpha, how)
 
 
 def als_workset_epoch_step(n_users: int, n_items: int, reg: float,
@@ -1006,7 +1069,7 @@ class ALS(ALSParams, Estimator[ALSModel]):
         result = iterate(
             als_epoch_step(n_users, n_items, self.get_reg_param(),
                            self.get_implicit_prefs(), self.get_alpha(),
-                           plans=plans),
+                           plans=plans and tuple(p.shape for p in plans)),
             state, data,
             max_epochs=self.get_max_iter(),
             config=IterationConfig(mode="fused"),

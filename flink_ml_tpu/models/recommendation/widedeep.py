@@ -34,6 +34,7 @@ from ...iteration import (
     IterationBodyResult,
     IterationConfig,
     iterate,
+    with_program_key,
 )
 from ...linalg import float32_rows
 from ...obs.trace import tracer
@@ -340,31 +341,13 @@ class WideDeep(WideDeepParams, Estimator["WideDeepModel"]):
                 route_data = tuple(replicate(a, mesh)
                                    for a in route.stacked_arrays())
 
-        def epoch_body(state, epoch, data):
-            Xd, Cd, yd, md = data[:4]
-            rt = data[4:]
-            params, opt_state, loss_log = state
-
-            def batch_step(carry, i):
-                params, opt_state = carry
-                params, opt_state, loss = step_fn(
-                    params, opt_state, Xd[i], Cd[i], yd[i], md[i],
-                    *(a[i] for a in rt))
-                return (params, opt_state), loss
-
-            (params, opt_state), losses = jax.lax.scan(
-                batch_step, (params, opt_state),
-                jnp.arange(steps, dtype=jnp.int32))
-            loss_log = loss_log.at[epoch].set(jnp.mean(losses))
-            return IterationBodyResult((params, opt_state, loss_log))
-
         max_epochs = self.get_max_iter()
         init_state = HandedOver((
             params, opt_state,
             jnp.full((max_epochs,), jnp.nan, jnp.float32)))
         del params, opt_state
-        result = iterate(epoch_body, init_state, (X, C, y, mask) + route_data,
-                         max_epochs=max_epochs,
+        result = iterate(_epoch_body(step_fn, steps), init_state,
+                         (X, C, y, mask) + route_data, max_epochs=max_epochs,
                          config=IterationConfig(mode="fused"))
         fitted, _, loss_buf = result.state
 
@@ -866,6 +849,35 @@ class WideDeepModel(WideDeepParams, Model):
         return model
 
 
+def _epoch_body(step_fn, steps: int):
+    """One epoch of ``fit`` as an ``iterate`` body: ``steps`` batches of
+    the replayed epoch tensors through ``step_fn`` (:func:`_make_train_ops`'),
+    the epoch's mean loss written into the state's log.  It states its
+    program key (``iteration/body.py: with_program_key``): ``steps`` and
+    the step's own."""
+
+    def epoch_body(state, epoch, data):
+        Xd, Cd, yd, md = data[:4]
+        rt = data[4:]
+        params, opt_state, loss_log = state
+
+        def batch_step(carry, i):
+            params, opt_state = carry
+            params, opt_state, loss = step_fn(
+                params, opt_state, Xd[i], Cd[i], yd[i], md[i],
+                *(a[i] for a in rt))
+            return (params, opt_state), loss
+
+        (params, opt_state), losses = jax.lax.scan(
+            batch_step, (params, opt_state),
+            jnp.arange(steps, dtype=jnp.int32))
+        loss_log = loss_log.at[epoch].set(jnp.mean(losses))
+        return IterationBodyResult((params, opt_state, loss_log))
+
+    return with_program_key(epoch_body, _epoch_body, steps,
+                            step_fn.program_key)
+
+
 # embedding-shaped tables whose per-step gradient support is the batch's
 # id set — the lazy optimizer updates only those rows
 _LAZY_TABLE_KEYS = ("emb", "wide_cat")
@@ -985,6 +997,15 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
     opt = optax.adam(lr)
     grad_fn = jax.value_and_grad(bce_loss)
 
+    def keyed(step, *how):
+        # what the step's trace reads beside its arguments, for the epoch
+        # body's program key (``_epoch_body``): the builder's scalars, the
+        # module's functions it calls (by what their names hold now) and
+        # ``how`` the tables are updated; no array, and not the route
+        return with_program_key(
+            step, _make_train_ops, lr, lazy, b1, b2, eps, forward_from_rows,
+            bce_loss, logistic_loss, _LAZY_TABLE_KEYS, *how)
+
     def split(tree):
         tables = {k: tree[k] for k in _LAZY_TABLE_KEYS}
         rest = {k: v for k, v in tree.items() if k not in _LAZY_TABLE_KEYS}
@@ -1060,14 +1081,22 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
             return params, opt_state, loss
 
         batch_step.table_update = _table_update_name(adam_entries)
-        return batch_step, opt.init(params)
+        # the registry's answers by their entries: ``routed_table_grad``'s
+        # where the step forms the table gradients, else both tables'
+        # ``routed_adam_update``
+        resolved = (table_grad.entry if adam_entries is None else
+                    (routed_run_sums,
+                     *(adam_entries[k] for k in _LAZY_TABLE_KEYS)))
+        return keyed(batch_step, route.placement, route.fold_passes,
+                     route.num_rows, batch_step.table_update,
+                     resolved), opt.init(params)
     if not lazy:
         def batch_step(params, opt_state, dense, cat_ids, labels, mask):
             loss, grads = grad_fn(params, dense, cat_ids, labels, mask)
             updates, opt_state = opt.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss
 
-        return batch_step, opt.init(params)
+        return keyed(batch_step), opt.init(params)
 
     tables0, rest0 = split(params)
     opt_state0 = {
@@ -1110,7 +1139,7 @@ def _make_train_ops(params, lr: float, lazy: bool, route=None,
         new_state = {"rest": rest_state, "m": new_m, "v": new_v, "t": t}
         return {**rest, **new_tab}, new_state, loss
 
-    return batch_step, opt_state0
+    return keyed(batch_step), opt_state0
 
 
 def build_reference_train_step(d_dense: int, vocab_sizes, emb_dim: int,

@@ -153,7 +153,8 @@ class EmbGradRoute:
         lowering — on TPU the fused Mosaic fold
         (``ops/emb_grad_pallas.py``) is picked up automatically, off TPU
         (or with ``backend="xla"`` forced) this is exactly
-        :meth:`apply`."""
+        :meth:`apply`.  ``fn.entry`` is the registry's answer, for a
+        caller that has to name it (a step's program key)."""
         from ..kernels.registry import lookup
 
         entry = lookup("routed_table_grad", sig=self.kernel_sig(),
@@ -162,6 +163,7 @@ class EmbGradRoute:
         def apply_fn(g_flat, *step_arrays):
             return entry.fn(self, g_flat, *step_arrays)
 
+        apply_fn.entry = entry
         return apply_fn
 
 

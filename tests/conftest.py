@@ -39,3 +39,34 @@ def cpu_mesh_8():
     from flink_ml_tpu.parallel.mesh import device_mesh
 
     return device_mesh({"data": 8})
+
+
+@pytest.fixture(autouse=True)
+def _no_kept_programs():
+    """Every test starts with no fused program kept by ``iterate``
+    (``iteration/core.py: clear_programs``): whether a fit builds or
+    reuses then follows from the test alone, not from the tests the
+    worker ran before it."""
+    from flink_ml_tpu.iteration import clear_programs
+
+    clear_programs()
+
+
+@pytest.fixture
+def fit_noting_reuse():
+    """``fit(est, table) -> (model, reused)``: ``reused`` is what the
+    fit's dispatch noted on ``iterate.dispatch.compile``, 1 where
+    ``iterate`` enqueued a program this process had kept."""
+    from flink_ml_tpu.obs.trace import tracer
+
+    def fit(est, table):
+        tracer.enable()
+        try:
+            model = est.fit(table)
+            (span,) = tracer.find("iterate.dispatch.compile")
+        finally:
+            tracer.disable()
+            tracer.clear()
+        return model, span.ids["reused"]
+
+    return fit

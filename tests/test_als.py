@@ -774,3 +774,131 @@ def test_workset_zero_tol_is_plain_bsp_fit():
     np.testing.assert_array_equal(
         a.get_model_data()[0]["userFactors"][0],
         b.get_model_data()[0]["userFactors"][0])
+
+
+# ---------------------------------------------------------------------------
+# the epoch body's program key (iteration/body.py: with_program_key)
+# ---------------------------------------------------------------------------
+
+def _ratings(seed, n=4000, users=300, items=40):
+    rng = np.random.default_rng(seed)
+    return Table({"user": rng.integers(0, users, n).astype(np.int64),
+                  "item": rng.integers(0, items, n).astype(np.int64),
+                  "rating": rng.normal(size=n).astype(np.float32)})
+
+
+def _factors(model):
+    (data,) = model.get_model_data()
+    return [np.asarray(data[c]).tobytes()
+            for c in ("userFactors", "itemFactors")]
+
+
+@pytest.mark.parametrize("neq", ["sorted", "scatter"])
+def test_a_second_fit_of_one_table_reuses_the_firsts_program(
+        neq,
+        fit_noting_reuse):
+    """Two fresh estimators, one table, one process: the second fit's
+    body states the first's program key (each side's ``PlanShape``, the
+    scalars, the solves' backends), so its dispatch enqueues the kept
+    executable, and the model is the first's bit for bit."""
+    table = _ratings(44)
+
+    def est():
+        return (ALS().set_rank(6).set_max_iter(3).set_seed(1)
+                .set(ALS.NEQ_IMPL, neq))
+
+    first, reused_first = fit_noting_reuse(est(), table)
+    second, reused_second = fit_noting_reuse(est(), table)
+    assert (reused_first, reused_second) == (0, 1)
+    assert first.neq_plan == ("grouped" if neq == "sorted" else "scatter")
+    assert _factors(second) == _factors(first)
+
+
+@pytest.mark.parametrize("what", ["degrees", "reg", "alpha_implicit"])
+def test_another_histogram_or_scalar_is_another_program(
+        what,
+        fit_noting_reuse):
+    """The same counts of users, items and ratings under another degree
+    histogram are other classes, hence another program; so is one scalar
+    the trace bakes in."""
+    table = _ratings(44)
+    est = (ALS().set_rank(6).set_max_iter(3).set_seed(1)
+           .set(ALS.NEQ_IMPL, "sorted"))
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    if what == "degrees":
+        other = _ratings(45)
+        assert len(np.unique(other["user"])) == len(np.unique(table["user"]))
+        table = other
+    elif what == "reg":
+        est = est.set_reg_param(0.3)
+    else:
+        table = Table({"user": table["user"], "item": table["item"],
+                       "rating": np.abs(table["rating"])})
+        _, reused = fit_noting_reuse(est, table)
+        assert reused == 1               # the ratings' values are data
+        est = est.set_implicit_prefs(True).set_alpha(2.0)
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 1
+
+
+def test_a_solve_the_registry_answers_otherwise_is_not_served_the_kept_program(
+        monkeypatch,
+        fit_noting_reuse):
+    """The trace reads the block solve from the registry, not from its
+    arguments, so the key names what the registry answered: a fit of
+    equal shapes under another solve builds its own program."""
+    import types
+
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    table = _ratings(44)
+    est = (ALS().set_rank(6).set_max_iter(3).set_seed(1)
+           .set(ALS.NEQ_IMPL, "sorted"))
+    first, _ = fit_noting_reuse(est, table)
+    real = als_mod._block_solve
+
+    def halved(rank, groups):
+        solve = real(rank, groups)
+        return types.SimpleNamespace(
+            backend=solve.backend, fn=lambda At, bt: 0.5 * solve.fn(At, bt))
+
+    monkeypatch.setattr(als_mod, "_block_solve", halved)
+    other, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    assert _factors(other) != _factors(first)
+    monkeypatch.setattr(als_mod, "_block_solve", real)
+    again, reused = fit_noting_reuse(est, table)
+    assert reused == 1 and _factors(again) == _factors(first)
+
+
+def test_the_epoch_body_holds_a_plans_shape_and_not_the_plan():
+    """``GroupedPlan.shape`` is all the body's program follows from: small,
+    hashable, equal for equal lay-outs, and the body refuses factors of
+    another rank than the plans' (the key names the solves at that
+    rank)."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    g = np.random.default_rng(5).integers(0, 50, 700)
+    plans = [als_mod.GroupedPlan(g, 51, 4, 8, 64) for _ in range(2)]
+    shape = plans[0].shape
+    assert shape == plans[1].shape and hash(shape) == hash(plans[1].shape)
+    assert (shape.n_groups, shape.rank, shape.parts) == (51, 4, plans[0].parts)
+    assert shape.classes == tuple((c.length, c.groups)
+                                  for c in plans[0].classes)
+    assert shape.block_groups == plans[0].block_groups
+    assert all(not isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(tuple(shape)))
+    body = als_mod.als_epoch_step(51, 51, 0.1, False, 1.0,
+                                  plans=(shape, shape))
+    assert body.program_key == als_mod.als_epoch_step(
+        51, 51, 0.1, False, 1.0, plans=(plans[1].shape,) * 2).program_key
+    assert body.__closure__ is not None and not any(
+        isinstance(c.cell_contents, als_mod.GroupedPlan)
+        for c in body.__closure__)
+    arrays = plans[0].arrays(g, np.ones(700, np.float32))
+    wrong = (jnp.zeros((51, 5)), jnp.zeros((51, 5)))
+    with pytest.raises(ValueError, match="rank"):
+        body(wrong, 0, (arrays, arrays))

@@ -511,3 +511,310 @@ def test_the_fused_dispatch_runs_in_one_chunk_of_the_interpreters_frames():
         inside = min(at_depth(k, lambda: core._in_one_chunk(loop))
                      for _ in range(3))
         assert inside < 5 * typical, (k, inside, typical, bare[worst])
+
+
+# ---------------------------------------------------------------------------
+# a fused program is built once a process and program key
+# ---------------------------------------------------------------------------
+
+def _keyed_loop(votes=False, scale=0.5, keyed=True):
+    """``(make, calls)``: ``make()`` is a fresh body each call, as an
+    estimator's factory gives one a ``fit``, stating the program key
+    ``(_keyed_loop, votes, scale)`` if ``keyed``; ``calls`` counts its
+    Python traces.  With ``votes`` it has a criterion (the ``while_loop``
+    branch) and emits nothing, else an output an epoch (``lax.scan``)."""
+    from flink_ml_tpu.iteration import with_program_key
+
+    calls = []
+
+    def make():
+        def body(state, epoch, data):
+            calls.append(epoch)
+            new = jnp.tanh(state @ data) * scale + state * 0.25
+            if votes:
+                return IterationBodyResult(
+                    new, termination=jnp.abs(new - state).max() > 1e-3)
+            return IterationBodyResult(new, outputs=jnp.sum(new * new))
+
+        return (with_program_key(body, _keyed_loop, votes, scale)
+                if keyed else body)
+
+    return make, calls
+
+
+def _loop_arrays(width=6, dtype=jnp.float32):
+    rng = np.random.default_rng(3)
+    return (jnp.asarray(rng.normal(size=(4, width)), dtype),
+            jnp.asarray(rng.normal(size=(width, width)), dtype))
+
+
+def _fused(body, state, data, max_epochs=5, **config):
+    return iterate(body, state, data, max_epochs=max_epochs,
+                   config=IterationConfig(mode="fused", **config))
+
+
+def _bits(result):
+    return [np.asarray(x).tobytes() for x in jax.tree_util.tree_leaves(
+        (result.state, result.outputs, result.num_epochs))]
+
+
+@pytest.mark.parametrize("votes", [False, True],
+                         ids=["scan", "while_loop"])
+def test_a_keyed_body_is_traced_in_its_first_dispatch_alone(votes):
+    from flink_ml_tpu.iteration import clear_programs, core
+
+    make, calls = _keyed_loop(votes)
+    state, data = _loop_arrays()
+    first = _fused(make(), state, data, max_epochs=9)
+    traces = len(calls)
+    assert traces == 2                    # the probe's and the trace's
+    again = [_fused(make(), state, data, max_epochs=9) for _ in range(2)]
+    assert len(calls) == traces and len(core._programs) == 1
+    clear_programs()
+    assert len(core._programs) == 0
+    rebuilt = _fused(make(), state, data, max_epochs=9)
+    assert len(calls) == 2 * traces
+    for other in again + [rebuilt]:
+        assert _bits(other) == _bits(first)
+    if votes:
+        assert 1 < first.num_epochs <= 9
+        for other in again + [rebuilt]:
+            for name, curve in first.side["epoch_trace"].items():
+                assert other.side["epoch_trace"][name].tobytes() == \
+                    curve.tobytes()
+    else:
+        assert np.asarray(first.outputs).shape == (9,)
+
+
+def _on(device):
+    return lambda x: jax.device_put(x, jax.devices()[device])
+
+
+@pytest.mark.parametrize("what", [
+    "max_epochs", "shape", "dtype", "sharding", "committed", "donate_state",
+    "key_scalar", "data_structure", "matmul_precision"])
+def test_what_a_program_follows_from_builds_a_new_one_when_it_changes(what):
+    """Each part of the full key, changed alone: another program is
+    built (the body is traced again, a second entry is kept) and the
+    first stays what the first dispatch's arguments get."""
+    import contextlib
+
+    from flink_ml_tpu.iteration import core
+
+    make, calls = _keyed_loop()
+    state, data = _loop_arrays()
+    first = _fused(make(), state, data)
+    traces = len(calls)
+
+    other_make, other_state, other_data, how = make, state, data, {}
+    context = contextlib.nullcontext()
+    if what == "max_epochs":
+        how = {"max_epochs": 6}
+    elif what == "shape":
+        other_state, other_data = _loop_arrays(width=7)
+    elif what == "dtype":
+        other_state, other_data = _loop_arrays(dtype=jnp.bfloat16)
+    elif what == "sharding":
+        other_state, other_data = _on(1)(state), _on(1)(data)
+    elif what == "committed":
+        assert not data.committed
+        other_state, other_data = _on(0)(state), _on(0)(data)
+        assert other_data.committed and other_data.sharding == data.sharding
+    elif what == "donate_state":
+        how = {"donate_state": False}
+    elif what == "key_scalar":
+        other_make, other_calls = _keyed_loop(scale=0.75)
+    elif what == "data_structure":
+        class Pair(tuple):
+            pass
+
+        jax.tree_util.register_pytree_node(
+            Pair, lambda p: (tuple(p), None), lambda _, xs: Pair(xs))
+        inner = make
+
+        def other_make():
+            body = inner()
+            wrapped = lambda s, e, d: body(s, e, d[0])  # noqa: E731
+            wrapped.program_key = body.program_key + ("first of a pair",)
+            return wrapped
+
+        other_data = Pair((data, data))
+    else:
+        context = jax.default_matmul_precision("highest")
+
+    with context:
+        second = _fused(other_make(), other_state, other_data, **how)
+    assert len(core._programs) == 2
+    if what == "key_scalar":
+        assert len(other_calls) == 2
+    else:
+        assert len(calls) == 2 * traces
+    # both stay: each argument list is served its own program again
+    assert _bits(_fused(make(), state, data)) == _bits(first)
+    with context:
+        assert _bits(_fused(other_make(), other_state, other_data,
+                            **how)) == _bits(second)
+    assert len(core._programs) == 2
+    if what in ("sharding", "committed", "donate_state", "data_structure"):
+        assert _bits(second) == _bits(first)
+    if what == "sharding":
+        assert second.state.sharding != first.state.sharding
+
+
+def test_an_unkeyed_closure_is_never_reused_and_leaves_no_entry():
+    from flink_ml_tpu.iteration import core
+
+    make, calls = _keyed_loop(keyed=False)
+    state, data = _loop_arrays()
+    results = [_fused(make(), state, data) for _ in range(3)]
+    assert len(calls) == 3 * 2 and len(core._programs) == 0
+    assert _bits(results[1]) == _bits(results[0]) == _bits(results[2])
+    # nor are the wrappers iterate makes itself, around a keyed body
+    keyed, calls = _keyed_loop()
+    carried = {"x": state, "scratch": jnp.zeros(3)}
+
+    def per_round_body(s, e, d):
+        return {**s, "x": keyed()(s["x"], e, d).feedback}
+
+    per_round_body.program_key = ("stated, but wrapped",)
+    for _ in range(2):
+        iterate(per_round_body, carried, data, max_epochs=3,
+                per_round=["scratch"], config=IterationConfig(mode="fused"))
+    assert len(core._programs) == 0
+
+
+def test_a_program_key_holds_no_array():
+    from flink_ml_tpu.iteration import with_program_key
+
+    def body(s, e):
+        return s
+
+    for array in (np.zeros(3), jnp.zeros(3)):
+        with pytest.raises(TypeError):
+            with_program_key(body, "factory", array)
+    assert not hasattr(body, "program_key")
+    assert with_program_key(body, "factory", 3, 0.5).program_key == (
+        "factory", 3, 0.5)
+
+
+@pytest.mark.parametrize("votes", [False, True],
+                         ids=["scan", "while_loop"])
+def test_a_reuse_consumes_a_handed_over_state_and_spares_a_plain_one(votes):
+    from flink_ml_tpu.iteration import HandedOver, core
+
+    make, calls = _keyed_loop(votes)
+    results = []
+    for dispatch in ("build", "reuse", "reuse"):
+        state, data = _loop_arrays()
+        results.append(_fused(make(), state, data))
+        assert not state.is_deleted() and not data.is_deleted()
+        np.asarray(state)
+        given, data = _loop_arrays()
+        results.append(_fused(make(), HandedOver(given), data))
+        assert given.is_deleted() and not data.is_deleted()
+    # one program serves both: handing over is the caller's, not the
+    # program's
+    assert len(calls) == 2 and len(core._programs) == 1
+    assert all(_bits(r) == _bits(results[0]) for r in results)
+
+
+def test_no_entry_keeps_a_fits_arrays_alive():
+    import gc
+    import weakref
+
+    from flink_ml_tpu.iteration import core
+
+    class Plan:
+        """What a factory's closure may hold beside the key's facts."""
+
+        def __init__(self):
+            self.index = np.arange(1 << 16)
+
+    def fit():
+        plan = Plan()
+        make, _ = _keyed_loop()
+        keyed = make()
+
+        def body(state, epoch, data):
+            assert plan.index.shape == (1 << 16,)
+            return keyed(state, epoch, data)
+
+        body.program_key = keyed.program_key
+        state, data = _loop_arrays()
+        result = _fused(body, state, data)
+        refs = [weakref.ref(x) for x in
+                (plan, body, state, data, result.state, result.outputs)]
+        # bytes: on the CPU ``np.asarray`` is a view that holds the array
+        return np.asarray(result.state).tobytes(), refs
+
+    first, refs = fit()
+    again, refs_again = fit()
+    assert len(core._programs) == 1 and first == again
+    gc.collect()
+    assert [r() for r in refs + refs_again] == [None] * 12
+
+
+def test_the_kept_programs_are_bounded_least_recently_used_out():
+    from flink_ml_tpu.iteration import core
+
+    state, data = _loop_arrays()
+    bound = core._PROGRAMS_KEPT
+    loops = [_keyed_loop(scale=0.1 + 0.01 * i) for i in range(bound + 1)]
+    for make, _ in loops[:bound]:
+        _fused(make(), state, data)
+    assert len(core._programs) == bound
+    _fused(loops[0][0](), state, data)           # the oldest, used again
+    _fused(loops[bound][0](), state, data)       # one more than the bound
+    assert len(core._programs) == bound
+    _fused(loops[0][0](), state, data)           # still kept
+    assert len(loops[0][1]) == 2
+    _fused(loops[1][0](), state, data)           # the least recent went
+    assert len(loops[1][1]) == 4
+    assert len(core._programs) == bound
+
+
+def test_threads_dispatching_shared_keys_all_get_the_answer():
+    """More threads than the bound has room for keys, each dispatching
+    every key, the interpreter switching every few bytecodes: a duplicate
+    build is harmless, a torn or lost entry would show as a wrong answer,
+    an error or more programs kept than the bound."""
+    import sys
+    import threading
+
+    from flink_ml_tpu.iteration import core
+
+    state, data = _loop_arrays()
+    scales = [0.1 + 0.01 * i for i in range(core._PROGRAMS_KEPT + 2)]
+    loops = [_keyed_loop(scale=scale) for scale in scales]
+    want = [_bits(_fused(_keyed_loop(scale=scale, keyed=False)[0](),
+                         state, data)) for scale in scales]
+    n_threads = len(scales)
+    barrier = threading.Barrier(n_threads)
+    wrong, errors = [], []
+
+    def client(first):
+        try:
+            barrier.wait(timeout=60)
+            for turn in range(len(scales)):
+                i = (first + turn) % len(scales)
+                if _bits(_fused(loops[i][0](), state, data)) != want[i]:
+                    wrong.append(i)
+                if len(core._programs) > core._PROGRAMS_KEPT:
+                    wrong.append("bound")
+        except BaseException as e:      # noqa: BLE001 - reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(i,), daemon=True)
+               for i in range(n_threads)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong
+    assert len(core._programs) == core._PROGRAMS_KEPT

@@ -896,3 +896,103 @@ def test_float32_rows_values_and_copies(kind):
     assert got.tobytes() == expected.tobytes()
     assert (column.dtype != object and np.shares_memory(got, column)) == (
         kind in ("f32_c", "f32_1d"))
+
+
+# ---------------------------------------------------------------------------
+# the epoch bodies' program keys (iteration/body.py: with_program_key)
+# ---------------------------------------------------------------------------
+
+def _centroids(model):
+    return np.asarray(model.get_model_data()[0]["centroids"][0])
+
+
+def _blobs(n=1024, d=8, seed=2):
+    return Table({"features": np.random.default_rng(seed).normal(
+        size=(n, d)).astype(np.float32)})
+
+
+def _steer_to_the_kernel(monkeypatch, km, k_tile=None):
+    """The plan and the body a TPU would take, the interpreter standing
+    in for the chip (the hook ``test_fit_through_the_ktiled_body_...``
+    uses)."""
+    step = km.kmeans_epoch_step_pallas
+    monkeypatch.setattr(
+        km, "_fit_plan", lambda n, d, k, measure, mesh, **how:
+        km.FitPlan("pallas", 128, 128, "zero", k, d, k_tile=k_tile))
+    monkeypatch.setattr(
+        km, "kmeans_epoch_step_pallas",
+        lambda *a, **kw: step(*a, **{**kw, "interpret": True}))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_second_fit_of_one_table_reuses_the_firsts_program(
+        impl,
+        monkeypatch,
+        fit_noting_reuse):
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    if impl == "pallas":
+        _steer_to_the_kernel(monkeypatch, km)
+    table = _blobs()
+
+    def est():
+        return KMeans().set_k(5).set_max_iter(3).set_seed(7)
+
+    first, reused_first = fit_noting_reuse(est(), table)
+    second, reused_second = fit_noting_reuse(est(), table)
+    assert (reused_first, reused_second) == (0, 1)
+    assert _centroids(second).tobytes() == _centroids(first).tobytes()
+    # another start is data, not program
+    third, reused = fit_noting_reuse(est().set_seed(8), table)
+    assert reused == 1
+    assert _centroids(third).tobytes() != _centroids(first).tobytes()
+
+
+@pytest.mark.parametrize("what", ["k", "max_iter", "measure", "rows"])
+def test_another_k_or_shape_is_another_program(what, fit_noting_reuse):
+    table = _blobs()
+    est = KMeans().set_k(5).set_max_iter(3).set_seed(7)
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    if what == "k":
+        est = est.set_k(6)
+    elif what == "max_iter":
+        est = est.set_max_iter(4)
+    elif what == "measure":
+        est = est.set_distance_measure("cosine")
+    else:
+        table = _blobs(n=1000)
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 1
+
+
+def test_a_fit_planned_onto_the_kernel_is_not_served_the_xla_fits_program(
+        monkeypatch,
+        fit_noting_reuse):
+    """Equal shapes (rows padded to the same multiple, zero fill), the
+    stats through the XLA body, then through the kernel's two layouts:
+    the factory and its tiles are in the key, so each builds its own
+    program, and all three end at the same centroids."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    table = _blobs()
+    est = KMeans().set_k(5).set_max_iter(3).set_seed(7)
+    monkeypatch.setattr(
+        km, "_fit_plan", lambda n, d, k, measure, mesh, **how:
+        km.FitPlan("xla", None, 128, "zero", k, d))
+    plain, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    _steer_to_the_kernel(monkeypatch, km)
+    feature_major, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    _steer_to_the_kernel(monkeypatch, km, k_tile=8)
+    k_tiled, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    np.testing.assert_allclose(_centroids(feature_major), _centroids(plain),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(_centroids(k_tiled), _centroids(plain),
+                               rtol=2e-2, atol=2e-2)
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 1

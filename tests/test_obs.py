@@ -259,19 +259,27 @@ def test_the_dispatch_is_its_five_stages_in_the_profiler_trace(
     _, spans = profiler_session(lambda: [run(), run()])
     roots = [s for s in spans if s[0] == "fit"]
     assert len(roots) == 2
+    reused = []
     for _, lo, hi, stats in roots:
         inside = [s for s in spans if lo <= s[1] and s[2] <= hi]
         (whole,) = [s for s in inside if s[0] == "iterate.dispatch"]
         stages = [s for s in inside if s[0].startswith("iterate.dispatch.")]
-        # once each, in order, one after the other, inside the dispatch
+        # once each, in order, one after the other, inside the dispatch:
+        # in a fit that builds its program and in one that reuses it
         assert tuple(s[0] for s in stages) == DISPATCH_STAGES
         assert all(a[2] <= b[1] for a, b in zip(stages, stages[1:]))
         assert whole[1] <= stages[0][1] and stages[-1][2] <= whole[2]
         assert {s[3]["fit"] for s in stages} == {stats["fit"]}
-        # what the compile-cache request was answered with, and nothing
-        # of the kind on another stage
+        # what the compile-cache request was answered with and whether
+        # the process's own entry answered, and nothing of the kind on
+        # another stage
         assert stages[3][3]["cache_hit"] in (0, 1)
-        assert all("cache_hit" not in s[3] for s in stages[:3] + stages[4:])
+        reused.append(stages[3][3]["reused"])
+        for note in ("cache_hit", "reused"):
+            assert all(note not in s[3] for s in stages[:3] + stages[4:])
+    # KMeans' body states its program key; LR's and the loop's are plain
+    # closures, built anew in every fit
+    assert reused == ([0, 1] if case == "kmeans" else [0, 0])
 
 
 @pytest.fixture
@@ -297,6 +305,13 @@ def compile_cache_at(tmp_path):
 @pytest.mark.parametrize("case", ["kmeans", "criteria"])
 def test_cache_hit_is_0_on_a_shapes_first_fit_and_1_on_its_second(
         case, compile_cache_at):
+    """What answers a fit's request for its program.  A plain closure
+    (``criteria``) asks the persistent cache every time: compiled, then
+    served.  A keyed body (``kmeans``): compiled; then the process's own
+    entry, the cache not asked; then, the entries emptied, the persistent
+    cache again."""
+    from flink_ml_tpu.iteration import clear_programs
+
     if case == "criteria":
         run = _criteria_loop(width=7)
     else:
@@ -312,10 +327,15 @@ def test_cache_hit_is_0_on_a_shapes_first_fit_and_1_on_its_second(
     entries = len(os.listdir(compile_cache_at))
     assert entries >= 1
     run()
+    clear_programs()
+    run()
     tracer.disable()
     requests = list(tracer.find("iterate.dispatch.compile"))
-    assert [s.ids["cache_hit"] for s in requests] == [0, 1]
-    # the second fit asked with the first's key: served, nothing added
+    assert [s.ids["cache_hit"] for s in requests] == [0, 1, 1]
+    assert [s.ids["reused"] for s in requests] == (
+        [0, 1, 0] if case == "kmeans" else [0, 0, 0])
+    # the later fits asked with the first's key or not at all: nothing
+    # added
     assert len(os.listdir(compile_cache_at)) == entries
     fits = [s.ids["fit"] for s in tracer.find("fit")]
     assert [s.ids["fit"] for s in requests] == fits
@@ -340,9 +360,9 @@ def test_the_staged_dispatch_is_the_plain_jitted_call_to_the_bit(
 
     def plain(run, state, data):
         calls.append(run)
-        return run(state, data)        # the jitted call, as it was
+        return run      # the enqueue is then the jitted call, as it was
 
-    monkeypatch.setattr(core, "_dispatch_staged", plain)
+    monkeypatch.setattr(core, "_compile_staged", plain)
     reference, given = make(handed_over=True)()
     assert len(calls) == 1 and given.is_deleted()
     # the jitted call asks the persistent cache with the staged call's

@@ -549,7 +549,7 @@ def test_als_fit_program_holds_one_block_of_normal_equations(
         return whole, ()
 
     body = als.als_epoch_step(users, items, config["reg_param"], False, 1.0,
-                              plans=plans)
+                              plans=tuple(p.shape for p in plans))
 
     args = ((on_chip((users, rank), F32), on_chip((items, rank), F32)),
             (arrays(plans[0]), arrays(plans[1])))

@@ -741,3 +741,102 @@ def test_the_start_is_one_rule(through):
     idle = np.r_[10:50, 60:80]
     np.testing.assert_array_equal(emb[idle], emb0[idle])
     assert not np.array_equal(emb[:10], emb0[:10])
+
+
+# ---------------------------------------------------------------------------
+# the epoch body's program key (iteration/body.py: with_program_key)
+# ---------------------------------------------------------------------------
+
+def _small_est(**how):
+    est = (WideDeep().set_vocab_sizes([10, 7]).set_embedding_dim(4)
+           .set_hidden_units([8, 4]).set_global_batch_size(128)
+           .set_max_iter(2).set_seed(3))
+    for name, value in how.items():
+        est = getattr(est, "set_" + name)(value)
+    return est
+
+
+def _same_bits(a, b):
+    a, b = _answer(a), _answer(b)
+    return all(np.asarray(a[name]).tobytes() == np.asarray(b[name]).tobytes()
+               for name in a)
+
+
+@pytest.mark.parametrize("routed", ["auto", "off"])
+def test_a_second_fit_of_one_table_reuses_the_firsts_program(
+        routed,
+        fit_noting_reuse):
+    """Two fresh estimators, one table, one process: the second's epoch
+    body states the first's program key (the steps, the step builder's
+    scalars, how the tables are updated), its dispatch enqueues the kept
+    executable with the new fit's own tables handed over, and the model
+    is the first's bit for bit."""
+    table = _ctr_table()
+
+    def est():
+        return _small_est().set(WideDeep.ROUTED_EMB_GRAD, routed)
+
+    first, reused_first = fit_noting_reuse(est(), table)
+    second, reused_second = fit_noting_reuse(est(), table)
+    assert (reused_first, reused_second) == (0, 1)
+    assert first.route_placement == ("gather" if routed == "auto" else None)
+    assert _same_bits(first, second)
+
+
+@pytest.mark.parametrize("what", ["learning_rate", "global_batch_size",
+                                  "hidden_units", "routed"])
+def test_another_lr_or_layout_is_another_program(what, fit_noting_reuse):
+    table = _ctr_table()
+    _, reused = fit_noting_reuse(_small_est(), table)
+    assert reused == 0
+    other = {"learning_rate": 0.02, "global_batch_size": 256,
+             "hidden_units": [8, 8]}
+    est = (_small_est().set(WideDeep.ROUTED_EMB_GRAD, "off")
+           if what == "routed" else _small_est(**{what: other[what]}))
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 0
+    _, reused = fit_noting_reuse(est, table)
+    assert reused == 1
+    _, reused = fit_noting_reuse(_small_est(), table)
+    assert reused == 1                   # the first's is still kept
+
+
+def test_a_table_update_the_registry_answers_otherwise_is_not_served_the_kept_program(
+        monkeypatch,
+        fit_noting_reuse):
+    """Equal shapes (one device, the ``scatter`` placement), op
+    ``routed_adam_update`` answered by its XLA composition and then, as
+    on a TPU, by the fused pass (the interpreter here): the step's key
+    names the registry's answers, so the second fit builds its own
+    program and says ``fused``."""
+    import dataclasses
+
+    from flink_ml_tpu.kernels import registry as kreg
+    from flink_ml_tpu.ops import emb_grad
+    from flink_ml_tpu.parallel.mesh import device_mesh, use_mesh
+
+    _, data, est = _reference_fixture()
+    table = Table(data)
+    monkeypatch.setattr(emb_grad, "_POS_MAP_BUDGET_BYTES", 0)
+    kreg.ops()                                   # the catalog is loaded
+    entries = kreg._REGISTRY["routed_adam_update"]
+    saved = dict(entries)
+    try:
+        with use_mesh(device_mesh({"data": 1}, devices=jax.devices()[:1])):
+            del entries["pallas"]
+            composed, reused = fit_noting_reuse(est, table)
+            assert (reused, composed.table_update) == (0, "dense_grad")
+            entries["pallas"] = dataclasses.replace(
+                saved["pallas"], available=None,
+                fn=partial(saved["pallas"].fn, interpret=True))
+            fused, reused = fit_noting_reuse(est, table)
+            assert (reused, fused.table_update) == (0, "fused")
+            again, reused = fit_noting_reuse(est, table)
+            assert (reused, again.table_update) == (1, "fused")
+    finally:
+        entries.clear()
+        entries.update(saved)
+    assert _same_bits(fused, again)
+    for name, value in _answer(composed).items():
+        np.testing.assert_allclose(_answer(fused)[name], value, rtol=2e-4,
+                                   atol=2e-6, err_msg=name)
