@@ -14,7 +14,9 @@ weights start at zero, data comes from a seed):
               float64 sums under the first-index assignment
 - ``als``     ALS(rank 32).fit on 2^20 skewed ratings, the grouped normal
               equations by blocks, against a float64 solve of a sample
-- ``mesh4``   the fit leg on a four-device data mesh (only with >= 4 chips)
+- ``mesh4``   the fit leg on a four-device data mesh, and KMeans.fit with its
+              rows divided over it, bit for bit the one-device fit's
+              centroids (only with >= 4 chips)
 
 Each leg checks what came out: finite values of the expected shape, the
 Pallas plan the chip should get, and agreement with NumPy.  The linear
@@ -614,8 +616,56 @@ def leg_mesh4(ctx) -> dict:
     out.update({"max_weight_diff_vs_one_device": diff,
                 "peak_bytes_per_device": peaks,
                 "two_steps_vs_numpy": check_two_steps(
-                    ctx, mesh, "mesh4, two steps")})
+                    ctx, mesh, "mesh4, two steps"),
+                "kmeans": kmeans_mesh4(ctx, mesh)})
     return out
+
+
+def kmeans_mesh4(ctx, mesh) -> dict:
+    """``KMeans.fit`` with the rows divided over the four-device ``data``
+    mesh (every device its run of them flat, laid out and padded on the
+    device; the kernel a shard, one all-reduce a step) against the fit on
+    one device: whole-numbered points, so the shards' partial sums are
+    exact in any order and the centroids must agree bit for bit."""
+    import numpy as np
+
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.models.clustering import KMeans
+    from flink_ml_tpu.obs.trace import tracer
+    from flink_ml_tpu.parallel.mesh import use_mesh
+
+    rows, k, dim = ctx["sizes"]["kmeans"] + 5, 256, 64
+    rng = np.random.default_rng(4)
+    centers = 4.0 * rng.normal(size=(k, dim))
+    table = Table({"features": np.rint(8.0 * (
+        centers[rng.integers(0, k, size=rows)]
+        + rng.normal(size=(rows, dim)))).astype(np.float32)})
+
+    def fit(on):
+        tracer.enable()
+        try:
+            with use_mesh(on):
+                model = (KMeans().set_k(k).set_max_iter(5).set_seed(0)
+                         .fit(table))
+            (arrange,) = [s for s in tracer.find("fit.arrange")
+                          if "shards" in s.ids]
+        finally:
+            tracer.disable()
+            tracer.clear()
+        (data,) = model.get_model_data()
+        return np.asarray(data["centroids"][0]), arrange.ids
+
+    one, _ = fit(ctx["mesh1"])
+    four, notes = fit(mesh)
+    check(notes["shards"] == 4, f"mesh4 kmeans: the fit noted {notes}")
+    if ctx["chip"]:
+        check(notes["stats_plan"] != "xla",
+              f"mesh4 kmeans: the sharded fit planned {notes['stats_plan']}")
+    check(np.isfinite(four).all() and four.tobytes() == one.tobytes(),
+          "mesh4 kmeans: centroids differ from the one-device fit by "
+          f"{float(np.max(np.abs(four - one)))}")
+    return {"shards": notes["shards"], "stats_plan": notes["stats_plan"],
+            "bit_for_bit_vs_one_device": True}
 
 
 def rebuild_native() -> dict:

@@ -61,7 +61,10 @@ from ...parallel.mesh import (
     fetch_replicated,
     mesh_process_count,
     put_sharded,
+    put_sharded_in_pieces,
     replicate,
+    rows_a_put,
+    shard_devices,
 )
 from ...utils import persist
 from ...utils.padding import pad_rows_to_bucket, pad_rows_with_mask
@@ -160,14 +163,15 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
 def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
                 fill: str = "first_row",
                 cross_host_checked: bool = False) -> tuple:
-    """The host half of host -> device on every mesh that SHARDS the rows
-    (more than one device on the ``data`` axis, or more than one process):
-    pad rows to a multiple of the data-axis size (and of ``row_multiple``
-    per shard; mask marks real rows).  The caller shards the batch dim of
-    both (``put_sharded(.., P("data"))``).  With a remainder this is a
-    whole copy of the points (``pad_rows_with_mask``), without one it is
-    the array it was given; a mesh whose ``data`` axis is one device of
-    one process pads on the device instead (:func:`_rows_on_device`).
+    """The host half of host -> device on a mesh of SEVERAL PROCESSES (the
+    only one left since PR 39; a one-process mesh pads on its devices,
+    :func:`_put_and_lay_out` and :func:`_put_and_lay_out_sharded`, and the
+    tests keep this as the statement of what they must make): pad rows to
+    a multiple of the data-axis size (and of ``row_multiple`` per shard;
+    mask marks real rows).  The caller shards the batch dim of both
+    (``put_sharded(.., P("data"))``).  With a remainder this is a whole
+    copy of the points (``pad_rows_with_mask``), without one it is the
+    array it was given.
 
     On a process-spanning mesh ``points`` is THIS process's shard; each
     host pads to its local device multiple and the global array assembles
@@ -235,11 +239,14 @@ def _placed(points, flat, first, rows: int):
                              points)
 
 
-def _filled(points, n: int, pad: int, fill: str):
+def _filled(points, n: int, pad: int, fill: str, first_row=None):
+    """``points`` with its ``pad`` last rows filled: with ``first_row``
+    (``(1, d)``; the array's own first row unless given: a shard other
+    than the first is filled with the TABLE's first row), or left zero."""
     if pad and fill == "first_row":
+        row = points[:1] if first_row is None else first_row
         return jax.lax.dynamic_update_slice(
-            points, jnp.broadcast_to(points[:1], (pad, points.shape[1])),
-            (n, 0))
+            points, jnp.broadcast_to(row, (pad, points.shape[1])), (n, 0))
     return points
 
 
@@ -247,22 +254,12 @@ def _row_mask(n: int, pad: int):
     return (jnp.arange(n + pad) < n).astype(jnp.float32)
 
 
-#: The most one put hands over.  The runtime moves a buffer of 0.8 to 3.2 GB
-#: at 6.3-6.6 GB/s and one of 6.35 GB at 0.25 (24.5-26.5 s; the same rows
-#: as a 2-D array 33 s); sixteen pieces of 0.4 GB all in flight at once
-#: 1.0 GB/s, four of 1.6 GB 0.7: what it has in flight at its fast pace is
-#: bounded somewhere between 3.2 and 6.35 GB (my chip runs, PR 35, one
-#: v5e).  So a larger table goes up a piece of this size at a time, each
-#: waited for before the next is put.
-_PUT_BYTES = 1 << 31
-
-
-def _put_rows(n: int, d: int) -> int:
-    """Rows of ``(n, d)`` float32 points a put hands over at a time: all
-    of them up to ``_PUT_BYTES``, else whole ``_RELAYOUT_ROWS``."""
-    if 4 * n * d <= _PUT_BYTES:
-        return n
-    return max(1, _PUT_BYTES // (4 * d) // _RELAYOUT_ROWS) * _RELAYOUT_ROWS
+def _put_rows(n: int, d: int, devices: int = 1) -> int:
+    """Rows of ``(n, d)`` float32 points a put hands a device at a time,
+    where ``devices`` get as many each in one round: all of them while the
+    round stays under ``parallel/mesh.py: PUT_BYTES`` (the cap and why it
+    is 2 GiB a process are there), else whole ``_RELAYOUT_ROWS``."""
+    return rows_a_put(n, 4 * d * devices, _RELAYOUT_ROWS)
 
 
 @lru_cache(maxsize=None)
@@ -272,7 +269,9 @@ def _rows_from_pieces(shape: tuple, pad: int, fill: str, sharding):
     flat, first) -> points`` for the piece whose first row is ``first``
     (``points`` donated: the rows are laid out in place, a piece's buffer
     is free once it is placed; one program a piece length, so two a
-    table), and ``finish(points)`` for the ``fill`` rows."""
+    table), and ``finish(points)`` for the ``fill`` rows
+    (``finish(points, first_row)`` fills with that row instead of the
+    array's own first: a shard's program, :func:`_put_and_lay_out_sharded`)."""
     n, d = shape
 
     def empty():
@@ -283,7 +282,8 @@ def _rows_from_pieces(shape: tuple, pad: int, fill: str, sharding):
 
     return (jax.jit(empty, out_shardings=(sharding, sharding)),
             jax.jit(place, donate_argnums=0, out_shardings=sharding),
-            jax.jit(lambda points: _filled(points, n, pad, fill),
+            jax.jit(lambda points, first_row=None: _filled(
+                points, n, pad, fill, first_row),
                     donate_argnums=0, out_shardings=sharding))
 
 
@@ -291,7 +291,7 @@ def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
     """Host -> device on a mesh of one process whose ``data`` axis is one
     device: the rows' buffer put flat and laid out, padded and masked on
     the device, under the spans ``fit.upload`` and ``fit.arrange.pad``.
-    Up to ``_PUT_BYTES`` that is one asynchronous put (the transfer runs
+    Up to ``PUT_BYTES`` that is one asynchronous put (the transfer runs
     while the host draws the start) and :func:`_rows_on_device`; a larger
     table goes up a piece at a time, each waited for, and the device lays
     a piece out (:func:`_rows_from_pieces`) while the next one arrives."""
@@ -310,7 +310,8 @@ def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
         return tracer.span("fit.arrange.pad", "fit")
 
     if put_rows == n:
-        with tracer.span("fit.upload", "fit"):
+        with tracer.span("fit.upload", "fit") as upload:
+            upload.note(pieces=1)
             flat = put(0)
         with tracer.span("fit.arrange", "fit"), arranging():
             return _rows_on_device(*laid_out)(flat)
@@ -318,7 +319,9 @@ def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
     with tracer.span("fit.arrange", "fit"), arranging():
         points, mask = empty()
     for first in range(0, n, put_rows):
-        with tracer.span("fit.upload", "fit"):
+        with tracer.span("fit.upload", "fit") as upload:
+            if not first:
+                upload.note(pieces=-(-n // put_rows))
             flat = put(first)
             flat.block_until_ready()
         with tracer.span("fit.arrange", "fit"), arranging():
@@ -326,6 +329,77 @@ def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
             if first + put_rows >= n:
                 points = finish(points)
     return points, mask
+
+
+def _put_and_lay_out_sharded(host_points: np.ndarray, plan, mesh,
+                             spec) -> tuple:
+    """:func:`_put_and_lay_out` on a mesh of one process whose ``data``
+    axis has SEVERAL devices: what ``_pad_points`` + ``put_sharded`` make
+    (the rows padded at the table's end to the mesh's multiple and divided
+    in contiguous runs, the mask beside them), bit for bit, with no copy
+    of the table on the host.  Each device gets its run of rows flat, in
+    pieces that are views of the column (``parallel/mesh.py:
+    put_sharded_in_pieces``: the devices' pieces of a round in flight
+    together, a round of at most ``PUT_BYTES`` in all, waited for before
+    the next: the runtime's bound is on what the process has in flight), and
+    lays it out, pads and masks it ITSELF with the one-device route's
+    three programs (:func:`_rows_from_pieces` under that device's own
+    sharding: the runs' lengths differ where the table does not divide,
+    and arrays of different lengths make no one sharded array without a
+    copy; a program a device is also what lets a device start on its piece
+    when that piece has arrived).  The finished per-device arrays ARE the
+    global arrays' shards (``jax.make_array_from_single_device_arrays``:
+    nothing moves).  Same spans as the one-device route; ``fit.upload``
+    notes ``pieces``, the puts the fullest device received."""
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+
+    n, d = host_points.shape
+    lines = shard_devices(mesh)
+    shard_rows = (n + -n % plan.local_multiple(mesh)) // len(lines)
+    put_rows = _put_rows(shard_rows, d, mesh.size)
+    programs, laid_out, short = {}, {}, []
+    for i, line in enumerate(lines):
+        rows = min(max(n - i * shard_rows, 0), shard_rows)
+        for device in line:
+            programs[device] = _rows_from_pieces(
+                (rows, d), shard_rows - rows, plan.fill,
+                SingleDeviceSharding(device))
+            if rows < shard_rows and plan.fill == "first_row":
+                short.append((device, i > 0))
+
+    def arranging():
+        return tracer.span("fit.arrange.pad", "fit")
+
+    with tracer.span("fit.arrange", "fit"), arranging():
+        for device, (empty, _, _) in programs.items():
+            laid_out[device] = empty()
+    rounds = put_sharded_in_pieces(host_points, mesh, shard_rows, put_rows)
+    pieces = -(-max(min(shard_rows, n), 1) // put_rows)
+    for piece in range(pieces):
+        with tracer.span("fit.upload", "fit") as upload:
+            if not piece:
+                upload.note(pieces=pieces)
+            first, put = next(rounds)
+        with tracer.span("fit.arrange", "fit"), arranging():
+            for line, flats in zip(lines, put):
+                for device, flat in zip(line, flats):
+                    if flat.shape[0]:
+                        points, mask = laid_out[device]
+                        laid_out[device] = (programs[device][1](
+                            points, flat, first), mask)
+    with tracer.span("fit.arrange", "fit"), arranging():
+        for device, of_the_table in short:
+            # the fill rows are the TABLE's first row on every shard
+            points, mask = laid_out[device]
+            row = (jax.device_put(host_points[:1], device) if of_the_table
+                   else None)
+            laid_out[device] = programs[device][2](points, row), mask
+        sharding = NamedSharding(mesh, spec)
+        total = shard_rows * len(lines)
+        return tuple(
+            jax.make_array_from_single_device_arrays(
+                shape, sharding, [pair[j] for pair in laid_out.values()])
+            for j, shape in enumerate(((total, d), (total,))))
 
 
 @partial(jax.jit, static_argnums=0)
@@ -606,9 +680,11 @@ def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
     count a multiple of ``block_n``; euclidean metric only.  With a
     multi-device ``mesh``, per-shard partial sums meet in one ICI psum.
 
-    Two ``jax.named_scope`` s say what a device operation is for:
+    ``jax.named_scope`` s say what a device operation is for:
     ``kmeans.stats`` (points and centroids to sums and counts: the kernel
-    and the XLA around it) and ``kmeans.update`` (the padding's
+    and the XLA around it), on a multi-device mesh ``kmeans.reduce`` (the
+    ``psum`` of the shards' sums and counts, and nothing else:
+    ``update_stats_sharded``) and ``kmeans.update`` (the padding's
     correction, the division, the empty clusters).
 
     The body states its program key (``iteration/body.py:
@@ -898,15 +974,28 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
         float32 array is read IN PLACE (``fit`` never writes into it, and
         the table must not be mutated from another thread while ``fit``
         runs, which was never allowed), any other numeric array is
-        converted once, an object column of vectors is stacked first.  On
-        a mesh of one process whose ``data`` axis is one device the rows
-        are put as they are (their buffer, flat), BEFORE the start is
-        drawn, so that the transfer runs under the start's permutation,
-        and the device gives them their layout, the mask, and the fill
-        rows of a remainder against the plan's row multiple
-        (:func:`_rows_on_device`; no remainder, no row added).  Any other
-        mesh shards the rows, so they are padded on the host first
-        (:func:`_pad_points`) and put after the start, as before."""
+        converted once, an object column of vectors is stacked first.
+        Which mesh takes which route:
+
+        - one process, one device on ``data`` (:func:`_put_and_lay_out`):
+          the rows are put as they are (their buffer, flat, whole up to
+          ``parallel/mesh.py: PUT_BYTES``, else a piece at a time), BEFORE
+          the start is drawn, so that the transfer runs under the start's
+          permutation, and the device gives them their layout, the mask,
+          and the fill rows of a remainder against the plan's row multiple
+          (:func:`_rows_on_device`; no remainder, no row added);
+        - one process, several devices on ``data``
+          (:func:`_put_and_lay_out_sharded`, PR 39): the same, a device a
+          contiguous run of the rows: every device's run flat, in pieces,
+          the devices' pieces in flight together, laid out, padded and
+          masked on its own device; no copy of the table on the host;
+        - several processes: every process passed its own shard, which is
+          padded on the host (:func:`_pad_points`) and put after the
+          start, as before.
+
+        ``fit.arrange`` notes ``shards``, the devices on ``data`` the rows
+        were divided over, and ``fit.upload`` ``pieces``, the puts the
+        fullest device received."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         # report describes THIS fit only — a reused estimator must not
@@ -952,7 +1041,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
             plan = _fit_plan(n_for_plan, host_points.shape[1], k, measure,
                              mesh, workset=workset_mode,
                              tie_policy=self.get_tie_policy())
-            arrange.note(**plan.notes())
+            arrange.note(shards=int(mesh.shape["data"]), **plan.notes())
             impl, block_n = plan.impl, plan.block_n
             select_init = _INIT_MODES[self.get_init_mode()]
 
@@ -974,8 +1063,10 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                 return np.asarray(broadcast_from_host0(init))
 
         spec = P("data")
-        if not multi_host and int(mesh.shape["data"]) == 1:
-            points, mask = _put_and_lay_out(host_points, plan, mesh, spec)
+        if not multi_host:
+            route = (_put_and_lay_out if int(mesh.shape["data"]) == 1
+                     else _put_and_lay_out_sharded)
+            points, mask = route(host_points, plan, mesh, spec)
             with tracer.span("fit.arrange", "fit"):
                 init = draw_start()
         else:

@@ -729,7 +729,10 @@ def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
     """Mesh-parallel stats: each device runs the kernel on its row shard,
     partial (k, d)/(k,) results are summed with one ``psum`` over the
     ``data`` axis (the ICI allreduce replacing the reference's keyed network
-    shuffle).  Per-shard row count must be a multiple of ``block_n``."""
+    shuffle).  Per-shard row count must be a multiple of ``block_n``.  The
+    ``psum`` and nothing else lies under the ``jax.named_scope``
+    ``kmeans.reduce``, so that a device trace says what the all-reduce
+    costs a step (the kernel stays under the caller's scope)."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.collectives import shard_map_fn
@@ -739,7 +742,9 @@ def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
             pts, cents, block_n=block_n, k_tile=k_tile,
             tie_policy=tie_policy, compute_dtype=compute_dtype,
             interpret=interpret)
-        return (jax.lax.psum(sums, "data"), jax.lax.psum(counts, "data"))
+        with jax.named_scope("kmeans.reduce"):
+            return (jax.lax.psum(sums, "data"),
+                    jax.lax.psum(counts, "data"))
 
     # shard_map_fn turns the varying-axes check off (pallas_call
     # out_shapes carry no varying-mesh-axes annotation)

@@ -17,7 +17,7 @@ from __future__ import annotations
 import math
 
 from contextlib import contextmanager
-from typing import Any, Mapping, Optional, Sequence
+from typing import Any, Iterator, List, Mapping, Optional, Sequence
 
 import jax
 import numpy as np
@@ -35,7 +35,10 @@ __all__ = [
     "local_axis_multiple",
     "mesh_process_count",
     "put_sharded",
+    "put_sharded_in_pieces",
     "replicated",
+    "rows_a_put",
+    "shard_devices",
     "shard_batch",
     "replicate",
     "default_mesh",
@@ -214,6 +217,82 @@ def put_sharded(arr: np.ndarray, mesh: Mesh, spec: P):
     if mesh_process_count(mesh) > 1:
         return jax.make_array_from_process_local_data(sharding, arr)
     return jax.device_put(arr, sharding)
+
+
+#: The most a process hands its devices at a time.  The runtime moves a
+#: buffer of 0.8 to 3.2 GB to one chip at 6.3-6.6 GB/s and one of 6.35 GB at
+#: 0.25 (24.5-26.5 s; the same rows as a 2-D array 33 s); sixteen pieces of
+#: 0.4 GB all in flight at once 1.0 GB/s, four of 1.6 GB 0.7: what it has in
+#: flight at its fast pace is bounded somewhere between 3.2 and 6.35 GB
+#: (chip runs of PR 35, one v5e, seed 2147483501).  The bound is the
+#: PROCESS's, not a device's: 25.4 GB to the four chips of a host in rounds
+#: of 4 x 2.06 GB took 21-25 s, of 4 x 3.1 GB 23.7 s, of 4 x 1.03 GB 3.03 s
+#: and of 4 x 0.41 GB 3.14 s, 8.1 GB/s over the four links together (chip
+#: runs of PR 39, four v5e, seed 2147483777).  So a larger table goes up in
+#: pieces, each waited for before the next is put, and on a sharded axis a
+#: ROUND of all the devices' pieces is at most this size
+#: (:func:`put_sharded_in_pieces`).
+PUT_BYTES = 1 << 31
+
+
+def rows_a_put(n: int, row_bytes: int, whole: int = 1) -> int:
+    """Rows of an ``n``-row table of ``row_bytes`` a row that one put
+    hands over: all of them up to :data:`PUT_BYTES`, else as many whole
+    multiples of ``whole`` rows as the cap holds (at least one).  For a
+    round of puts to several devices ``row_bytes`` is a row's bytes times
+    the devices that get one each."""
+    if n * row_bytes <= PUT_BYTES:
+        return n
+    return max(1, PUT_BYTES // row_bytes // whole) * whole
+
+
+def shard_devices(mesh: Mesh, axis: str = DATA_AXIS) -> List[list]:
+    """The devices that hold each shard of an array split over ``axis``
+    alone, in the shards' order: one device a shard on a mesh of that one
+    axis, every device along the mesh's other axes otherwise (they hold
+    the shard replicated)."""
+    ax = list(mesh.axis_names).index(axis)
+    devs = np.moveaxis(np.asarray(mesh.devices), ax, 0)
+    return [list(line) for line in devs.reshape(devs.shape[0], -1)]
+
+
+def put_sharded_in_pieces(rows: np.ndarray, mesh: Mesh, shard_rows: int,
+                          piece_rows: int, *,
+                          axis: str = DATA_AXIS) -> Iterator[tuple]:
+    """The host half of a sharded put that copies nothing: the C-contiguous
+    ``rows`` divided over ``axis`` in contiguous runs of ``shard_rows``
+    rows (the last runs are shorter, or empty, where the table ends), each
+    run handed to its shard's devices FLAT (one dimension: the runtime
+    lays nothing out on the host) in pieces of ``piece_rows`` rows.
+
+    A generator of rounds ``(first, pieces)``: ``pieces[i]`` holds, one
+    per device of shard ``i`` (:func:`shard_devices`), the device array of
+    rows ``[first, first + piece_rows)`` of that shard's run, cut at the
+    run's end (length 0 past it).  Every piece is a view of ``rows``; all
+    the devices' pieces of a round are handed over together, and a round
+    is waited for before the next one is put, so that the process has no
+    more than a round in flight: the caller sizes ``piece_rows`` so that a
+    round stays under :data:`PUT_BYTES` (``rows_a_put(shard_rows,
+    row_bytes * mesh.size)``).  The last round is not waited for: the
+    caller's next program is.  One process only: every device of the mesh
+    must be addressable."""
+    if mesh_process_count(mesh) > 1:
+        raise ValueError("put_sharded_in_pieces puts from one process; a "
+                         "process-spanning mesh takes put_sharded")
+    n = len(rows)
+    devices = shard_devices(mesh, axis)
+    flying: list = []
+    for first in range(0, max(min(shard_rows, n), 1), piece_rows):
+        for piece in flying:
+            piece.block_until_ready()
+        pieces = []
+        for i, line in enumerate(devices):
+            lo = min(n, i * shard_rows + first)
+            hi = min(n, i * shard_rows + min(first + piece_rows, shard_rows))
+            flat = rows[lo:hi].reshape(-1)
+            pieces.append([jax.device_put(flat, device) for device in line])
+        flying = [piece for line in pieces for piece in line]
+        yield first, pieces
 
 
 def assemble_process_local(batch: Any, shardings: Any) -> tuple:

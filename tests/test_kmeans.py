@@ -765,18 +765,32 @@ def test_fit_reads_the_column_in_place_bitexact_vs_parent(
 
 
 @pytest.mark.parametrize("n", [256, 250])
-def test_fit_on_a_sharding_mesh_pads_on_the_host_bitexact_vs_parent(
+def test_fit_on_a_sharding_mesh_pads_on_its_devices_bitexact_vs_parent(
         monkeypatch, cpu_mesh_8, n):
-    """Eight devices on the ``data`` axis: the rows are sharded, so
-    ``_pad_points`` still pads them on the host (8 x 16 = 128 a
-    multiple); with no remainder that is the column itself."""
+    """Eight devices on the ``data`` axis of one process: the rows are
+    sharded, and since PR 39 every device gets its run of them flat and
+    pads it itself (``_put_and_lay_out_sharded``; 8 x 16 = 128 a
+    multiple): the centroids are those of the parent's host pad
+    (``_pad_points``, kept as the expectation in ``_parent_fit``), and
+    what is handed to a device is a view of the column, remainder or
+    not."""
+    import jax
+
     column = _column_of("f32_c", n)
     before = column.tobytes()
+    handed = []
+    real_put = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put", lambda x, *a, **kw: (
+            handed.append(x) if isinstance(x, np.ndarray) else None,
+            real_put(x, *a, **kw))[1])
     got, expected, puts = _fit_recording_puts(
         monkeypatch, column, cpu_mesh_8, row_multiple=16, fill="zero")
     assert got.tobytes() == expected.tobytes()
-    assert [a.shape for a in puts] == [(256, 5), (256,)]
-    assert np.shares_memory(puts[0], column) == (n == 256)
+    pieces = [a for a in handed if a.ndim == 1 and a.dtype == np.float32
+              and a.size and np.shares_memory(a, column)]
+    assert sum(a.size for a in pieces) == n * 5      # each row put once
+    assert puts == []        # no 2-D put of the rows, no host pad
     assert column.tobytes() == before
 
 
@@ -849,6 +863,7 @@ def test_fit_from_a_table_put_in_pieces_is_the_fit_from_one_put(
     import jax
 
     from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel import mesh as pm
     from flink_ml_tpu.parallel.mesh import device_mesh, use_mesh
 
     pts = np.random.default_rng(8).normal(size=(300, 5)).astype(np.float32)
@@ -864,7 +879,7 @@ def test_fit_from_a_table_put_in_pieces_is_the_fit_from_one_put(
     real_put = km.put_sharded
     monkeypatch.setattr(km, "put_sharded",
                         lambda arr, *a: (puts.append(arr), real_put(arr, *a))[1])
-    monkeypatch.setattr(km, "_PUT_BYTES", 2 * 64 * 5 * 4)
+    monkeypatch.setattr(pm, "PUT_BYTES", 2 * 64 * 5 * 4)
     assert fit().tobytes() == whole.tobytes()
     assert [a.shape for a in puts] == [(640,), (640,), (220,)]
     assert all(np.shares_memory(a, pts) for a in puts)
@@ -872,8 +887,18 @@ def test_fit_from_a_table_put_in_pieces_is_the_fit_from_one_put(
 
 def test_put_rows_pieces_only_what_one_put_is_slow_at():
     """HiBench's 1.6 GB go in one put, as before; ``kmeans_mnist8m``'s
-    6.35 GB in four pieces of whole relayout steps, 2 GiB at most."""
+    6.35 GB in four pieces of whole relayout steps, 2 GiB at most (the
+    cap is ``parallel/mesh.py``'s since PR 39; a shard of
+    ``kmeans_mnist8m_full`` is cut the same way)."""
     from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.parallel.mesh import PUT_BYTES, rows_a_put
+
+    assert PUT_BYTES == 1 << 31
+    assert km._put_rows(2_025_472, 784) == 655360
+    # ... and four chips' pieces of a round are 2 GiB in all: the cap is
+    # on what the process has in flight (16 rounds of 4 x 411 MB)
+    assert km._put_rows(2_025_472, 784, 4) == 131072
+    assert rows_a_put(10, 8) == 10 and rows_a_put(1 << 30, 8, 1000) == 268435000
 
     assert km._put_rows(20_000_000, 20) == 20_000_000
     rows = km._put_rows(2_025_000, 784)

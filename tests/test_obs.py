@@ -153,11 +153,18 @@ def test_fit_spans_land_in_the_profiler_trace_with_the_ring_off(
         assert all(s[2] <= hi for s in inside)
         assert {s[3]["fit"] for s in inside} == {stats["fit"]}
         phases = [s for s in inside if s[0] in FIT_PHASES]
-        # each phase is there, in this order (a phase may come in two
-        # adjacent pieces, one per function that does part of it) ...
-        order = [s[0] for i, s in enumerate(phases)
-                 if i == 0 or phases[i - 1][0] != s[0]]
-        assert tuple(order) == FIT_PHASES
+        # each phase is there, first met in this order (a phase may come
+        # in adjacent pieces, one per function that does part of it; on a
+        # one-process mesh KMeans' rows go up in rounds and the devices lay
+        # a round out while the next is put, PR 39, so ``fit.arrange`` and
+        # ``fit.upload`` may take turns; nothing else comes twice) ...
+        names = [s[0] for s in phases]
+        assert tuple(dict.fromkeys(names)) == FIT_PHASES
+        order = [n for i, n in enumerate(names)
+                 if i == 0 or names[i - 1] != n]
+        assert [n for n in order
+                if n not in ("fit.arrange", "fit.upload")] == [
+                    "fit.gather", "iterate.dispatch", "fit.fetch"]
         # ... and no two overlap
         assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
         # a child span lies inside a span of its parent's name

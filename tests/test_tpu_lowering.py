@@ -280,22 +280,28 @@ def test_parked_kernel_still_does_not_lower(case):
 # -- compiled for a described v5e (no chip; nothing runs) -------------------
 
 @pytest.fixture(scope="module")
-def one_v5e():
-    """A single-device sharding on a described v5e, or a skip where none
-    can be described.  Made in a fixture: only the worker that runs this
-    file may load the TPU's library."""
+def v5e_2x2():
+    """A described host of four v5e chips, or a skip where none can be
+    described.  Made in a fixture: only the worker that runs this file may
+    load the TPU's library."""
     import os
 
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - whatever the plugin raises
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_v5e(v5e_2x2):
+    """A single-device sharding on the described host's first chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("d,k", [(20, 10), (64, 256), (784, 256)],
@@ -357,6 +363,55 @@ def test_kmeans_fit_program_tiled_over_k_keeps_no_copy_of_the_points(one_v5e):
         (Shape((n, d), F32, sharding=one_v5e),
          Shape((n,), F32, sharding=one_v5e))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_sharded_kmeans_fit_program_compiles_for_four_chips(v5e_2x2):
+    """The fused program of ``kmeans_mnist8m_full.fit``: 8,100,000 x 784
+    with the fill rows (8,101,888), divided over the ``data`` axis of the
+    described host's four chips, k 4096, 20 iterations, the kernel tiled
+    over k inside ``shard_map`` and the ``psum`` of the sums and counts
+    in the loop.  A chip keeps no copy of its 6.35 GB of points (the
+    program's temporaries stay under 64 MB a device), the kernel is there
+    with its 58 MB of VMEM under the raised limit, and the sums and counts
+    meet in ONE all-reduce under the scope ``kmeans.reduce``."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.models.clustering.kmeans import kmeans_epoch_step_pallas
+    from flink_ml_tpu.ops.kmeans_pallas import _stats_tile_bytes, stats_tiles
+
+    n, d, k = 8_101_888, 784, 4096
+    mesh = Mesh(v5e_2x2.devices, ("data",))
+    rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    block_n, k_tile = stats_tiles(d, k)
+    body = kmeans_epoch_step_pallas(k, mesh, block_n=block_n, k_tile=k_tile)
+
+    def run(centroids, data):
+        return jax.lax.scan(
+            lambda c, epoch: (body(c, epoch, data).feedback, None),
+            centroids, jnp.arange(20, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(run, out_shardings=whole).lower(
+        Shape((k, d), F32, sharding=whole),
+        (Shape((n, d), F32, sharding=rows),
+         Shape((n,), F32, sharding=rows))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.argument_size_in_bytes < (n // 4) * (d + 1) * 4 + (32 << 20)
+    # the kernel's blocks as its VMEM model counts them (Mosaic took them
+    # under the limit the call raises: a compile over it is refused)
+    assert round(_stats_tile_bytes(d, k, block_n, k_tile) / 2 ** 20) == 58
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    reduces = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    # the sums and counts in one all-reduce under its scope; the only
+    # other collective is the scalar count of the fill rows
+    big = [line for line in reduces if "f32[4096,784]" in line]
+    assert len(big) == 1 and len(reduces) == 2, reduces
+    assert "kmeans.reduce/psum" in text
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 def test_kmeans_fit_program_keeps_no_copy_of_the_points(one_v5e):

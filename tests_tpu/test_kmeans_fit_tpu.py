@@ -127,3 +127,128 @@ def test_rows_on_device_equals_the_host_pad_on_chip(tpu, rng, fill):
     for have, want in zip(got, pad_rows_with_mask(pts, 8192, fill=fill)):
         assert have.shape == want.shape
         assert np.asarray(have).tobytes() == want.tobytes()
+
+
+# -- four chips: the rows divided over a ``data`` mesh (PR 39) --------------
+
+@pytest.fixture(scope="module")
+def four_chips(tpu):
+    import jax
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip(f"needs four chips on one host (chiprun --chips 4); "
+                    f"JAX found {len(devices)}")
+    return devices[:4]
+
+
+def _grey_levels(rows: int, seed: int) -> np.ndarray:
+    """Whole grey levels 0-255 of 784 pixels: the benchmark's generator
+    under ``kmeans_mnist8m``'s own parameters."""
+    import json
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from generators import digit_images
+
+    with open(os.path.join(bench, "configs", "kmeans_mnist8m.json")) as f:
+        config = json.load(f)
+    return digit_images.generate(
+        {**config, **config["generator_params"], "rows": rows},
+        seed)["features"]
+
+
+def test_sharded_fit_is_the_one_chip_fit_bit_for_bit(four_chips):
+    """4 x 262,144 + 5 rows of 784 grey levels, k 4096, the Pallas plan
+    tiled over k on both meshes with the same tiles: every chip's run of
+    rows put flat in pieces and laid out on it, the kernel a shard, one
+    all-reduce a step.  The sums are of whole levels, so the four partial
+    sums are exact in any order and the centroids are the one-chip fit's
+    bit for bit; the fit notes its four shards and a second fit reuses the
+    program."""
+    from flink_ml_tpu import Table
+    from flink_ml_tpu.distance import DistanceMeasure
+    from flink_ml_tpu.models.clustering.kmeans import KMeans, _fit_plan
+    from flink_ml_tpu.obs.trace import tracer
+    from flink_ml_tpu.parallel.mesh import device_mesh, use_mesh
+
+    n, d, k = 4 * 262_144 + 5, 784, 4096
+    column = _grey_levels(n, 2147483693)
+    before = column[::4099].tobytes()
+    table = Table({"features": column})
+    euclid = DistanceMeasure.get_instance("euclidean")
+
+    def fit(mesh):
+        plan = _fit_plan(n, d, k, euclid, mesh)
+        tracer.enable()
+        try:
+            with use_mesh(mesh):
+                model = (KMeans().set_k(k).set_seed(5).set_max_iter(4)
+                         .fit(table))
+            notes = {s.name: s.ids for s in tracer.spans()
+                     if s.name in ("fit.arrange", "fit.upload",
+                                   "iterate.dispatch.compile")
+                     and {"shards", "pieces", "reused"} & set(s.ids)}
+        finally:
+            tracer.disable()
+            tracer.clear()
+        (data,) = model.get_model_data()
+        return np.asarray(data["centroids"][0]), plan, notes
+
+    one, plan_one, notes_one = fit(device_mesh(devices=four_chips[:1]))
+    four, plan_four, notes_four = fit(device_mesh(devices=four_chips))
+    assert plan_one.impl == plan_four.impl == "pallas"
+    assert (plan_one.block_n, plan_one.k_tile) == (plan_four.block_n,
+                                                   plan_four.k_tile)
+    assert plan_four.k_tile and n % (4 * plan_four.block_n)
+    assert notes_one["fit.arrange"]["shards"] == 1
+    assert notes_four["fit.arrange"]["shards"] == 4
+    assert notes_four["fit.arrange"]["stats_plan"] == "k_tiled"
+    assert np.isfinite(four).all() and four.tobytes() == one.tobytes()
+    again, _, notes_again = fit(device_mesh(devices=four_chips))
+    assert notes_again["iterate.dispatch.compile"]["reused"] == 1
+    assert again.tobytes() == four.tobytes()
+    assert column[::4099].tobytes() == before
+
+
+def test_sharded_put_in_pieces_is_the_host_route_at_10_gb(four_chips):
+    """4 x 800,000 + 7 rows of 784 floats (2.5 GB a chip, in seven rounds
+    of 4 x 411 MB: a round is 2 GiB at most): the piecewise sharded put
+    and the on-device layout against the host pad and one 2-D
+    ``put_sharded``, compared where they lie; every chip then holds its
+    2.5 GB."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from flink_ml_tpu.models.clustering.kmeans import (
+        FitPlan, _pad_points, _put_and_lay_out_sharded)
+    from flink_ml_tpu.parallel.mesh import device_mesh, put_sharded
+
+    n, d = 4 * 800_000 + 7, 784
+    column = _grey_levels(n, 2147483701)
+    mesh = device_mesh(devices=four_chips)
+    plan = FitPlan("pallas", 512, 512, "zero", 4096, d, k_tile=512)
+    t = time.perf_counter()
+    points, mask = _put_and_lay_out_sharded(column, plan, mesh, P("data"))
+    jax.block_until_ready((points, mask))
+    pieces_s = time.perf_counter() - t
+    t = time.perf_counter()
+    padded, host_mask = _pad_points(column, mesh, row_multiple=512,
+                                    fill="zero")
+    want = put_sharded(padded, mesh, P("data"))
+    want.block_until_ready()
+    host_s = time.perf_counter() - t
+    print(f"\nsharded put in pieces {pieces_s:.2f} s, host pad and one 2-D "
+          f"put {host_s:.2f} s for {column.nbytes / 1e9:.2f} GB")
+    assert points.shape == want.shape and points.sharding == want.sharding
+    assert bool(jax.jit(jnp.array_equal)(points, want))
+    assert np.asarray(mask).tobytes() == host_mask.tobytes()
+    held = [s.data.nbytes for s in points.addressable_shards]
+    assert len(held) == 4 and min(held) == points.nbytes // 4
