@@ -22,6 +22,7 @@ inside the iteration body.
 
 from __future__ import annotations
 
+import ctypes
 from dataclasses import dataclass
 from functools import lru_cache, partial
 from typing import List, Optional
@@ -420,14 +421,74 @@ def _kmeans_chain_kernel(static, params, cols):
     return {acol: jnp.argmin(dists, axis=1)}
 
 
-def select_random_centroids(points: np.ndarray, k: int, seed: int) -> np.ndarray:
-    """Semantics of ``KMeans.selectRandomCentroids`` (``KMeans.java:317-336``):
-    shuffle all points with the seed, take k."""
+def _draw_prefix(lib, rng: np.random.Generator, n: int,
+                 k: int) -> Optional[np.ndarray]:
+    """``rng.permutation(n)[:k]`` by ``native/kmeans_start.cpp``, drawn
+    through ``rng``'s own bit generator, which ends where the permutation
+    leaves it; ``None`` where the pass declines (then before its first
+    draw)."""
+    out = np.empty(k, np.int64)
+    bits = rng.bit_generator
+    with bits.lock:
+        iface = bits.ctypes
+        status = lib.perm_prefix(
+            ctypes.cast(iface.next_uint32, ctypes.c_void_p), iface.state,
+            n, k, out.ctypes.data)
+    return out if status == 0 else None
+
+
+@lru_cache(maxsize=None)
+def _native_start():
+    """``native/kmeans_start.cpp`` built and loaded, or ``None`` on a
+    machine with no ``make`` and no built library, or where the pass does
+    not give this NumPy's permutation (a NumPy whose shuffle changed then
+    costs speed, not the answer): once a process, on a small case."""
+    from ...utils.native_lib import load_native_lib
+
+    lib = load_native_lib("kmeans_start")
+    if lib is None:
+        return None
+    lib.perm_prefix.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
+                                ctypes.c_int64, ctypes.c_int64,
+                                ctypes.c_void_p]
+    lib.perm_prefix.restype = ctypes.c_int
+    native, numpy_ = np.random.default_rng(7), np.random.default_rng(7)
+    prefix = _draw_prefix(lib, native, 1000, 10)
+    if (prefix is None
+            or not np.array_equal(prefix, numpy_.permutation(1000)[:10])
+            or native.bit_generator.state != numpy_.bit_generator.state):
+        return None
+    return lib
+
+
+def random_start(n: int, k: int, seed: int) -> tuple[np.ndarray, bool]:
+    """``np.random.default_rng(seed).permutation(n)[:k]``, and whether the
+    native pass drew it: the k indices come out of every draw of the
+    shuffle without a permutation of n being built (160 MB of random
+    swaps at 20 M rows).  NumPy draws them where there is no library or
+    the pass declines: ``n - 1`` needs more than 32 bits (NumPy's shuffle
+    draws 64 there) or its buffer of n draws cannot be had."""
+    rng = np.random.default_rng(seed)
+    lib = _native_start()
+    idx = None if lib is None else _draw_prefix(lib, rng, n, k)
+    if idx is not None:
+        return idx, True
+    return rng.permutation(n)[:k], False
+
+
+def _random_centroids(points: np.ndarray, k: int,
+                      seed: int) -> tuple[np.ndarray, bool]:
     n = points.shape[0]
     if n < k:
         raise ValueError(f"Need at least k={k} points, got {n}")
-    idx = np.random.default_rng(seed).permutation(n)[:k]
-    return points[idx]
+    idx, native = random_start(n, k, seed)
+    return points[idx], native
+
+
+def select_random_centroids(points: np.ndarray, k: int, seed: int) -> np.ndarray:
+    """Semantics of ``KMeans.selectRandomCentroids`` (``KMeans.java:317-336``):
+    shuffle all points with the seed, take k."""
+    return _random_centroids(points, k, seed)[0]
 
 
 def select_kmeanspp_centroids(points: np.ndarray, k: int,
@@ -471,8 +532,10 @@ def _kmeanspp_run(pts, key, k: int):
     return chosen
 
 
-_INIT_MODES = {"random": select_random_centroids,
-               "k-means++": select_kmeanspp_centroids}
+# mode -> (points, k, seed) -> (centroids, whether the native pass drew them)
+_INIT_MODES = {"random": _random_centroids,
+               "k-means++": lambda points, k, seed: (
+                   select_kmeanspp_centroids(points, k, seed), False)}
 
 
 def _stats_from_assign(k: int, points, mask, assign):
@@ -981,9 +1044,10 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
           the rows are put as they are (their buffer, flat, whole up to
           ``parallel/mesh.py: PUT_BYTES``, else a piece at a time), BEFORE
           the start is drawn, so that the transfer runs under the start's
-          permutation, and the device gives them their layout, the mask,
-          and the fill rows of a remainder against the plan's row multiple
-          (:func:`_rows_on_device`; no remainder, no row added);
+          draw (:func:`random_start`), and the device gives them their
+          layout, the mask, and the fill rows of a remainder against the
+          plan's row multiple (:func:`_rows_on_device`; no remainder, no
+          row added);
         - one process, several devices on ``data``
           (:func:`_put_and_lay_out_sharded`, PR 39): the same, a device a
           contiguous run of the rows: every device's run flat, in pieces,
@@ -1046,9 +1110,15 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
             select_init = _INIT_MODES[self.get_init_mode()]
 
         def draw_start():
-            with tracer.span("fit.arrange.init", "fit"):
+            with tracer.span("fit.arrange.init", "fit") as span:
+                def draw():
+                    init, native = select_init(host_points, k,
+                                               self.get_seed())
+                    span.note(native=int(native))
+                    return init
+
                 if not multi_host:
-                    return select_init(host_points, k, self.get_seed())
+                    return draw()
                 from ...parallel.distributed import broadcast_from_host0
 
                 multiple = plan.local_multiple(mesh)
@@ -1057,8 +1127,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                     raise ValueError(
                         "multi-host KMeans requires equal padded row "
                         f"counts per process; got {padded_rows.tolist()}")
-                init = (select_init(host_points, k, self.get_seed())
-                        if jax.process_index() == 0
+                init = (draw() if jax.process_index() == 0
                         else np.zeros((k, host_points.shape[1]), np.float32))
                 return np.asarray(broadcast_from_host0(init))
 

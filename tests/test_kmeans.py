@@ -123,6 +123,124 @@ def test_select_random_centroids_semantics():
         select_random_centroids(pts[:2], 3, seed=0)
 
 
+_START_CASES = [
+    (3, 1, 1), (3, 4096, 4096), (3, 1000, 1), (3, 1000, 1000),
+    (5, 7, 3), (5, 8, 3), (5, 9, 3),                   # mask edges 2^3
+    (11, 65535, 64), (11, 65536, 64), (11, 65537, 64),  # mask edges 2^16
+    ((1 << 31) + 12345, 4097, 10),                      # seed above 2^31
+    ((1 << 40) + 7, 50_000, 4096),
+    (29, 2_025_000, 4096),                              # kmeans_mnist8m's
+]
+
+
+@pytest.mark.parametrize("seed,n,k", _START_CASES)
+def test_random_start_is_numpys_permutation_prefix(seed, n, k):
+    """The native pass gives ``default_rng(seed).permutation(n)[:k]``
+    index for index and leaves the generator in NumPy's state; the start
+    is those rows."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    lib = km._native_start()
+    assert lib is not None
+    native, numpy_ = np.random.default_rng(seed), np.random.default_rng(seed)
+    want = numpy_.permutation(n)[:k]
+    np.testing.assert_array_equal(km._draw_prefix(lib, native, n, k), want)
+    assert native.bit_generator.state == numpy_.bit_generator.state
+    points = np.arange(2 * n, dtype=np.float64).reshape(n, 2)
+    np.testing.assert_array_equal(select_random_centroids(points, k, seed),
+                                  points[want])
+
+
+@pytest.mark.parametrize("loader", ["none", "declines"])
+@pytest.mark.parametrize("seed,n,k", [(3, 1000, 10), (5, 9, 3),
+                                      ((1 << 31) + 12345, 4097, 10)])
+def test_random_start_without_the_native_pass_is_numpys(
+        monkeypatch, loader, seed, n, k):
+    """No library, or a pass that declines (as it does past the 32-bit
+    limit or without its buffer): NumPy draws, with the same answer, and
+    says so."""
+    from types import SimpleNamespace
+
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    want = np.random.default_rng(seed).permutation(n)[:k]
+    declining = SimpleNamespace(perm_prefix=lambda *args: 1)
+    monkeypatch.setattr(km, "_native_start",
+                        lambda: None if loader == "none" else declining)
+    idx, native = km.random_start(n, k, seed)
+    np.testing.assert_array_equal(idx, want)
+    assert native is False
+
+
+def test_native_start_declines_over_32_bits_before_drawing():
+    """n - 1 past 2^32 - 1 (NumPy draws 64 bits there): the pass returns
+    an error before its first draw, the generator untouched."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    rng = np.random.default_rng(1)
+    before = rng.bit_generator.state
+    assert km._draw_prefix(km._native_start(), rng, (1 << 32) + 1, 3) is None
+    assert rng.bit_generator.state == before
+
+
+def test_native_start_self_check_refuses_a_wrong_pass(monkeypatch):
+    """The loader's one self-check a process: a pass that does not give
+    this NumPy's permutation is not used."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    monkeypatch.setattr(km, "_draw_prefix",
+                        lambda lib, rng, n, k: np.arange(k))
+    km._native_start.cache_clear()
+    try:
+        assert km._native_start() is None
+    finally:
+        km._native_start.cache_clear()
+
+
+def _fit_and_its_init_span(est, table):
+    """The ``native`` note of the fit's span ``fit.arrange.init``, and the
+    model's centroids."""
+    from flink_ml_tpu.obs.trace import tracer
+
+    tracer.enable()
+    try:
+        model = est.fit(table)
+        (span,) = tracer.find("fit.arrange.init")
+    finally:
+        tracer.disable()
+        tracer.clear()
+    return (span.ids["native"],
+            np.asarray(model.get_model_data()[0]["centroids"][0]))
+
+
+def _start_table():
+    return Table({"features": np.random.default_rng(4).normal(size=(300, 3))})
+
+
+@pytest.mark.parametrize("init_mode,native", [("random", 1),
+                                              ("k-means++", 0)])
+def test_fit_notes_whether_the_start_was_native(init_mode, native):
+    """``fit.arrange.init`` notes ``native``: 1 where the native pass drew
+    the start, 0 where it did not."""
+    est = KMeans().set_k(4).set_max_iter(2).set_seed(9)
+    note, _ = _fit_and_its_init_span(est.set_init_mode(init_mode),
+                                     _start_table())
+    assert note == native
+
+
+def test_fit_without_the_library_gives_the_same_model(monkeypatch):
+    """No library: NumPy draws the start, the span says ``native`` 0 and
+    the centroids are the native fit's to the bit."""
+    from flink_ml_tpu.models.clustering import kmeans as km
+
+    est = KMeans().set_k(4).set_max_iter(2).set_seed(9)
+    native_note, native = _fit_and_its_init_span(est, _start_table())
+    monkeypatch.setattr(km, "_native_start", lambda: None)
+    numpy_note, numpy_ = _fit_and_its_init_span(est, _start_table())
+    assert (native_note, numpy_note) == (1, 0)
+    np.testing.assert_array_equal(native, numpy_)
+
+
 def test_transform_without_model_data_errors():
     with pytest.raises(RuntimeError):
         KMeansModel().transform(_table())
