@@ -30,6 +30,19 @@ def _sigmoid(x: np.ndarray) -> np.ndarray:
     return 0.5 * (1.0 + np.tanh(0.5 * x))
 
 
+def _two_values(y: np.ndarray) -> Optional[np.ndarray]:
+    """The sorted two distinct values of a numeric label column that
+    holds exactly two, by its least and its greatest (a few passes, where
+    ``np.unique`` sorts the column); ``None`` for any other column."""
+    if y.dtype.kind not in "biuf" or y.size == 0:
+        return None
+    lo, hi = y.min(), y.max()
+    if not lo < hi or np.count_nonzero(y == lo) + np.count_nonzero(
+            y == hi) != y.size:
+        return None
+    return np.asarray([lo, hi])
+
+
 class GBTClassifierModel(GBTModelBase):
     def __init__(self):
         super().__init__()
@@ -134,16 +147,14 @@ class GBTClassifierModel(GBTModelBase):
 
 
 class GBTClassifier(GBTEstimatorBase):
-    model_cls = GBTClassifierModel
+    """Two label values: logistic loss, the shared fused path; more:
+    the softmax objective, one tree per class per round."""
 
-    def fit(self, *inputs):
-        (table,) = inputs
-        labels, y_ids = np.unique(np.asarray(table[self.get_label_col()]),
-                                  return_inverse=True)
-        if len(labels) <= 2:
-            return super().fit(table)   # binary: shared logistic path
-        # multiclass: softmax objective, one tree per class per round
-        X = stack_vectors(table[self.get_features_col()]).astype(np.float64)
+    model_cls = GBTClassifierModel
+    _loss = "logistic"
+
+    def _fit_multiclass(self, X: np.ndarray, label_values):
+        labels, y_ids = label_values
         forest = train_forest_softmax(X, y_ids, len(labels), self._config())
         model = self.model_cls()
         model.copy_params_from(self)
@@ -152,16 +163,19 @@ class GBTClassifier(GBTEstimatorBase):
         return model
 
     def _prepare_labels(self, y_raw: np.ndarray):
+        """0/1 float32 targets and the two label values, or ``(None,
+        (labels, ids))`` for more than two (the multiclass fit)."""
+        labels = _two_values(y_raw)
+        if labels is not None:
+            return (y_raw == labels[1]).astype(np.float32), labels
         labels, y = np.unique(y_raw, return_inverse=True)
+        if len(labels) > 2:
+            return None, (labels, y)
         if len(labels) != 2:
             raise ValueError(
                 f"GBTClassifier binary path needs 2 label values; got "
                 f"{len(labels)}")
-        return y.astype(np.float64), labels
-
-    def _grad_hess(self, y, pred):
-        p = _sigmoid(pred)
-        return p - y, np.maximum(p * (1.0 - p), 1e-12)
+        return y.astype(np.float32), labels
 
     def _streaming_labels(self, y_raw: np.ndarray) -> np.ndarray:
         y = np.asarray(y_raw, np.float64)
@@ -176,7 +190,7 @@ class GBTClassifier(GBTEstimatorBase):
         return np.asarray([0.0, 1.0])
 
     def _base_score(self, y) -> float:
-        p = np.clip(y.mean(), 1e-6, 1 - 1e-6)
+        p = np.clip(y.mean(dtype=np.float64), 1e-6, 1 - 1e-6)
         return float(np.log(p / (1.0 - p)))
 
     def _finalize_model(self, model, label_values) -> None:

@@ -4,35 +4,56 @@ Member of the later Flink ML 2.x library line (GBTClassifier/GBTRegressor).
 CPU GBT implementations walk rows per node; the TPU-native formulation is
 the histogram method with everything vectorized over rows:
 
-- **Binning** (host, once): per-feature quantile bins -> int32 bin ids.
-- **Histograms** (device): per level, one ``segment_sum`` over the flattened
-  ``(node, feature, bin)`` key accumulates (grad, hess, count) for ALL nodes
-  and features at once — the analog of the keyed shuffle+reduce a dataflow
-  engine would run, fused on-chip.
-- **Split finding** (device): cumulative sums over bins give every candidate
-  split's left/right (G, H); the XGBoost gain
+- **Binning** (host, once a fit): per-feature quantile edges from a
+  bounded sample of the rows, then ``bin = #edges strictly below x``
+  (:func:`bin_features`; the rule is stated there).
+- **Histograms** (device): per level, the (node, feature, bin) sums of
+  (grad, hess) for ALL nodes and features at once — op
+  ``gbt_level_histograms`` of the kernel registry: an exact one-hot
+  contraction on the MXU on a TPU (``ops/gbt_hist_pallas.py``), one
+  ``segment_sum`` over the flattened key elsewhere.
+- **Split finding** (device): cumulative sums over bins give every
+  candidate split's left/right (G, H); the XGBoost gain
   ``G_L^2/(H_L+l) + G_R^2/(H_R+l) - G^2/(H+l)`` is argmaxed per node.
-- **Routing** (device): rows step to ``2*node+1 (+1)`` by comparing their
+- **Routing** (device): rows step to ``2*node (+1)`` by comparing their
   bin to the split threshold — no gather-scatter trees, just arrays.
 
-Trees are complete binary arrays (node i's children are 2i+1/2i+2), so one
-jitted ``build_level`` per depth serves every tree; the boosting loop runs
-hosted (each tree depends on the previous residuals).
+On the device a table is FEATURE-MAJOR: ``d`` columns of bin ids, each
+``(n,)`` int32, beside the rows' ``(n,)`` vectors (labels, margins,
+gradients, node ids).  No array is ``(n, d)``: the chip tiles the last
+dimension of an array to 128 lanes, so 13 features a row would take ten
+times their bytes.  The additive pieces (:func:`_level_histograms`,
+:func:`_level_splits`, :func:`_apply_split`) take the columns.
+
+Trees are complete binary arrays (node i's children are 2i+1/2i+2).  The
+binary in-core fit (:func:`train_forest`, the path ``GBTClassifier`` and
+``GBTRegressor`` share) is ONE fused ``iterate`` program: a round is a
+tree — the loss's gradient and hessian from the margins, ``max_depth``
+levels unrolled (histogram, split, route), the leaves' Newton values and
+the margin update — and the forest is fetched once, at the end.  The
+multiclass (:func:`train_forest_softmax`) and streamed
+(:func:`train_forest_outofcore`) trainers loop on the host around the
+same pieces.
 """
 
 from __future__ import annotations
 
+import ctypes
 import os
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Callable, List, Optional, Tuple
+from functools import lru_cache, partial
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...iteration import IterationConfig, iterate
+from ...iteration.body import with_program_key
+from ...iteration.core import HandedOver
 from ...kernels.aot import aot_jit
+from ...obs.trace import tracer
 
 __all__ = ["GBTConfig", "bin_features", "train_forest", "predict_forest",
            "Forest", "SoftmaxForest", "train_forest_softmax",
@@ -74,22 +95,41 @@ class Forest:
     learning_rate: float
 
 
+#: the most rows the in-core fit reads to place its bin edges
+EDGE_SAMPLE_ROWS = 1 << 18
+
+
+def edge_sample(X: np.ndarray) -> np.ndarray:
+    """The rows the in-core fit places its bin edges on: every row of a
+    table of up to :data:`EDGE_SAMPLE_ROWS`, else every ``ceil(n /
+    EDGE_SAMPLE_ROWS)``-th row from the first (a strided view, no copy).
+    Spark's and Flink ML's GBT place theirs on a bounded sample too."""
+    stride = -(-len(X) // EDGE_SAMPLE_ROWS)
+    return X[::stride]
+
+
 def quantile_edges(X: np.ndarray, max_bins: int) -> np.ndarray:
-    """Per-feature quantile edges (d, bins-1) — the sketch half of
-    :func:`bin_features` (the out-of-core trainer needs only this from
-    its bounded leading sample)."""
+    """Per-feature quantile edges (d, bins-1) of the rows ``X``:
+    ``np.quantile`` at ``linspace(0, 1, bins + 1)[1:-1]`` (linear
+    interpolation, in float64)."""
     d = X.shape[1]
     edges = np.empty((d, max_bins - 1))
     qs = np.linspace(0, 1, max_bins + 1)[1:-1]
     for j in range(d):
         # duplicates collapse constant regions
-        edges[j] = np.quantile(X[:, j], qs)
+        edges[j] = np.quantile(np.asarray(X[:, j], np.float64), qs)
     return edges
 
 
 def bin_features(X: np.ndarray, max_bins: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Quantile binning on host: (binned int32 (n, d), edges (d, bins-1))."""
-    edges = quantile_edges(X, max_bins)
+    """The in-core fit's binning rule: ``(binned int32 (n, d), edges (d,
+    bins-1))``.  The edges are :func:`quantile_edges` of
+    :func:`edge_sample` (all the rows of a table of up to
+    ``EDGE_SAMPLE_ROWS``); a value's bin is the number of edges strictly
+    below it (``np.searchsorted(edges, x, side="left")``), a NaN's the
+    number of edges that are not NaN.  The values are compared as given:
+    a float32 table is binned as float32."""
+    edges = quantile_edges(edge_sample(X), max_bins)
     return apply_bins(X, edges), edges
 
 
@@ -98,6 +138,46 @@ def apply_bins(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
     for j in range(X.shape[1]):
         binned[:, j] = np.searchsorted(edges[j], X[:, j], side="left")
     return binned
+
+
+@lru_cache(maxsize=None)
+def _native_bins():
+    """``native/gbt_bin.cpp`` built and loaded, or ``None`` on a machine
+    with no ``make`` and no built library."""
+    from ...utils.native_lib import load_native_lib
+
+    lib = load_native_lib("gbt_bin")
+    if lib is not None:
+        lib.bin_columns.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int]
+        lib.bin_columns.restype = ctypes.c_int
+    return lib
+
+
+def bin_columns(X: np.ndarray, edges: np.ndarray,
+                rows: Optional[int] = None) -> np.ndarray:
+    """``apply_bins(X, edges).T`` laid out for the device: ``(d, rows)``
+    int32, row ``j`` the bin ids of feature ``j`` (C-contiguous, so each
+    is one buffer to put), ``rows >= n`` with zeros after the ``n`` rows
+    of ``X``.  A C-contiguous float32 ``X`` is binned by
+    ``native/gbt_bin.cpp`` on every core (the same bins: see there);
+    anything else, or no library, by NumPy."""
+    n, d = X.shape
+    rows = n if rows is None else rows
+    out = np.empty((d, rows), np.int32)
+    out[:, n:] = 0
+    lib = _native_bins()
+    if (lib is not None and X.dtype == np.float32
+            and X.flags.c_contiguous and edges.shape[1] > 0):
+        edges = np.ascontiguousarray(edges, np.float64)
+        lib.bin_columns(X.ctypes.data, n, d, edges.ctypes.data,
+                        edges.shape[1], out.ctypes.data, rows,
+                        os.cpu_count() or 1)
+        return out
+    for j in range(d):
+        out[j, :n] = np.searchsorted(edges[j], X[:, j], side="left")
+    return out
 
 
 @jax.jit
@@ -118,125 +198,112 @@ def apply_bins_device(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(jnp.isnan(X), edges.shape[1], count)
 
 
-#: histogram implementation: "auto" (the kernel registry picks — XLA
-#: segment_sum everywhere today, see the registrations at the end of
-#: this module), "segsum" (force the XLA scatter-adds, the r1-r4 path)
-#: or "mxu" (force the double one-hot matmul: exact up to f32 summation
-#: order off TPU, bf16-truncated addends on the MXU).  Neither forced
-#: value is timed on the chip (ROADMAP S12).
+#: histogram implementation: "auto" (the kernel registry picks: the
+#: Pallas contraction on a TPU, segment_sum elsewhere; see the
+#: registrations at the end of this module), "segsum" (force the XLA
+#: scatter-adds) or "pallas" (force the kernel; interpreted off a TPU).
 HIST_IMPL = "auto"
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "d", "bins"))
-def _level_histograms_segsum(binned, node_ids, grad, hess, n_nodes: int,
+def _level_histograms_segsum(cols, node_ids, grad, hess, n_nodes: int,
                              d: int, bins: int):
-    """segment_sum form: one scatter-add per (row, feature) key."""
+    """segment_sum form: one scatter-add per (feature, row) key.  It
+    makes three (d, n) temporaries: for tables a host holds, not for one
+    that fills a chip."""
     live = node_ids >= 0
     safe_node = jnp.where(live, node_ids, 0)
     # (node, feature, bin) -> flat key; dead rows land in a scratch key 0
     # with zero weights
-    keys = (safe_node[:, None] * (d * bins)
-            + jnp.arange(d, dtype=jnp.int32)[None, :] * bins
-            + binned)                                           # (n, d)
+    keys = (safe_node[None, :] * (d * bins)
+            + jnp.arange(d, dtype=jnp.int32)[:, None] * bins
+            + jnp.stack(list(cols)))                            # (d, n)
     w = live.astype(grad.dtype)
     seg = n_nodes * d * bins
     flat = keys.reshape(-1)
-    g_hist = jax.ops.segment_sum((grad * w)[:, None].repeat(d, 1).reshape(-1),
-                                 flat, seg)
-    h_hist = jax.ops.segment_sum((hess * w)[:, None].repeat(d, 1).reshape(-1),
-                                 flat, seg)
-    return (g_hist.reshape(n_nodes, d, bins),
-            h_hist.reshape(n_nodes, d, bins))
+
+    def summed(x):
+        return jax.ops.segment_sum(
+            jnp.broadcast_to(x * w, keys.shape).reshape(-1), flat, seg)
+
+    # the key is feature-major: (feature, node, bin) in memory order
+    return (summed(grad).reshape(n_nodes, d, bins),
+            summed(hess).reshape(n_nodes, d, bins))
 
 
-@partial(jax.jit, static_argnames=("n_nodes", "d", "bins"))
-def _level_histograms_mxu(binned, node_ids, grad, hess, n_nodes: int,
-                          d: int, bins: int):
-    """MXU form: hist[node, f, bin] = (onehot_node * value)^T @
-    onehot_bin_f — histogramming as n x n_nodes x bins matmul
-    contractions (no scatter anywhere), scanned over features so the
-    transient one-hots stay at (n, n_nodes) + (n, bins).  ~2*n*nodes*
-    bins MAC per (feature, value) — MXU work standing in for
-    segment_sum's per-element random accumulation."""
-    live = node_ids >= 0
-    safe_node = jnp.where(live, node_ids, 0)
-    w = live.astype(grad.dtype)
-    # (n, n_nodes) one-hots pre-scaled by the two accumulated values —
-    # rows of dead nodes carry zeros, so scratch-node pollution is moot
-    node_oh = (safe_node[:, None]
-               == jnp.arange(n_nodes, dtype=jnp.int32)[None, :])
-    gv = jnp.where(node_oh, (grad * w)[:, None], 0.0)   # (n, n_nodes)
-    hv = jnp.where(node_oh, (hess * w)[:, None], 0.0)
+def _level_histograms_pallas(cols, node_ids, grad, hess, n_nodes: int,
+                             d: int, bins: int):
+    """The exact one-hot contraction of ``ops/gbt_hist_pallas.py`` (the
+    interpreter runs it off a TPU)."""
+    from ...ops.gbt_hist_pallas import level_histograms
 
-    def per_feature(_, f):
-        bin_oh = (binned[:, f][:, None]
-                  == jnp.arange(bins, dtype=jnp.int32)[None, :]
-                  ).astype(grad.dtype)                  # (n, bins)
-        g_f = jax.lax.dot_general(
-            gv, bin_oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (n_nodes, bins)
-        h_f = jax.lax.dot_general(
-            hv, bin_oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return None, (g_f, h_f)
-
-    _, (g_hist, h_hist) = jax.lax.scan(
-        per_feature, None, jnp.arange(d, dtype=jnp.int32))
-    # scan stacks (d, n_nodes, bins) -> (n_nodes, d, bins)
-    return (jnp.transpose(g_hist, (1, 0, 2)),
-            jnp.transpose(h_hist, (1, 0, 2)))
+    return level_histograms(tuple(cols), node_ids, grad, hess, n_nodes, d,
+                            bins, interpret=jax.default_backend() != "tpu")
 
 
 #: the dispatch table — unknown HIST_IMPL values raise KeyError instead
 #: of silently running the wrong implementation
 _HIST_IMPLS = {"segsum": _level_histograms_segsum,
-               "mxu": _level_histograms_mxu}
+               "pallas": _level_histograms_pallas}
 
 
-def resolve_hist_impl(name: str = None) -> str:
+def resolve_hist_impl(name: str = None, sig: tuple = ()) -> str:
     """Resolve a histogram impl name ("auto" -> the kernel registry's
-    pick for this backend; "segsum"/"mxu" force) to a concrete
-    ``_HIST_IMPLS`` key.  Unknown names raise KeyError — never a silent
-    fallback."""
+    pick for this backend and the level ``sig = (d, bins, n_nodes)``: the
+    Pallas kernel only where its VMEM holds the level; "segsum"/"pallas"
+    force) to a concrete ``_HIST_IMPLS`` key.  Unknown names raise
+    KeyError — never a silent fallback."""
     name = HIST_IMPL if name is None else name
     if name == "auto":
         from ...kernels.registry import lookup
 
-        backend = lookup("gbt_level_histograms").backend
+        backend = lookup("gbt_level_histograms", sig).backend
         return {"xla": "segsum"}.get(backend, backend)
     if name not in _HIST_IMPLS:
         raise KeyError(name)
     return name
 
 
-def _level_histograms(binned, node_ids, grad, hess, n_nodes: int,
+def _level_histograms(cols, node_ids, grad, hess, n_nodes: int,
                       d: int, bins: int):
-    """Per-(node, feature, bin) grad/hess sums for one level — the
-    ADDITIVE piece of split finding: the out-of-core trainer accumulates
-    these over streamed batches and decides splits from the totals.
-    Dispatches on :data:`HIST_IMPL` through :func:`resolve_hist_impl`."""
-    return _HIST_IMPLS[resolve_hist_impl()](binned, node_ids, grad, hess,
-                                            n_nodes, d, bins)
+    """Per-(node, feature, bin) grad/hess sums for one level, each
+    ``(n_nodes, d, bins)`` — the ADDITIVE piece of split finding: the
+    out-of-core trainer accumulates these over streamed batches and
+    decides splits from the totals.  ``cols`` are the ``d`` bin-id
+    columns.  Dispatches on :data:`HIST_IMPL` through
+    :func:`resolve_hist_impl`."""
+    impl = resolve_hist_impl(sig=(d, bins, n_nodes))
+    return _HIST_IMPLS[impl](cols, node_ids, grad, hess, n_nodes, d, bins)
+
+
+def _above(hist):
+    """Per bin, the sum of the bins above it (0 above the last)."""
+    suffix = jnp.flip(jnp.cumsum(jnp.flip(hist, -1), axis=-1), -1)
+    return jnp.concatenate([suffix[..., 1:], jnp.zeros_like(hist[..., :1])],
+                           axis=-1)
 
 
 def _level_splits(g_hist, h_hist, reg_lambda: float,
                   min_child_weight: float):
-    """Best (feature, bin, gain) per node from the level histograms."""
-    n_nodes, d, bins = g_hist.shape
-    g_tot = jnp.sum(g_hist, axis=(1, 2)) / d                    # per node
-    h_tot = jnp.sum(h_hist, axis=(1, 2)) / d
+    """Best (feature, bin, gain) per node from the level histograms.
 
-    # candidate split at bin b: left = bins <= b (cumsum), right = rest
+    A candidate split at bin b sends bins <= b left: the left side's
+    (G, H) are the cumulative sums up to b, the right side's the sums of
+    the bins above b, and the node's a feature's whole sum.  The right
+    side is NOT the node's total less the left: where the bins above b
+    hold no row it must be exactly 0 (no viable split), and at 10^8 rows
+    a float32 total less a float32 prefix leaves units of rounding there,
+    enough for a split of a few rows' noise to outscore every real one."""
+    n_nodes, d, bins = g_hist.shape
     g_left = jnp.cumsum(g_hist, axis=2)
     h_left = jnp.cumsum(h_hist, axis=2)
-    g_right = g_tot[:, None, None] - g_left
-    h_right = h_tot[:, None, None] - h_left
+    g_right, h_right = _above(g_hist), _above(h_hist)
 
     def score(g, h):
         return g * g / (h + reg_lambda)
 
     gain = (score(g_left, h_left) + score(g_right, h_right)
-            - score(g_tot, h_tot)[:, None, None])               # (nodes,d,bins)
+            - score(g_left[:, :, -1:], h_left[:, :, -1:]))       # (nodes,d,bins)
     viable = ((h_left >= min_child_weight)
               & (h_right >= min_child_weight))
     gain = jnp.where(viable, gain, -jnp.inf)
@@ -251,39 +318,254 @@ def _level_splits(g_hist, h_hist, reg_lambda: float,
     return best_feature, best_bin, best_gain
 
 
-def _apply_split(binned, node_ids, best_feature, best_bin, best_gain):
+def _newton(g, h, reg_lambda: float):
+    """The Newton leaf value ``-G / (H + lambda)``; 0 for a node no row
+    reaches (``H + lambda`` 0)."""
+    denom = h + reg_lambda
+    return jnp.where(denom > 0, -g / jnp.where(denom > 0, denom, 1.0), 0.0)
+
+
+#: a table lookup by a row's node or feature is a chain of selects up to
+#: this many entries (one pass over the rows, no gather), a gather above
+_SELECT_MAX = 64
+
+
+def _take(table, idx):
+    """``table[idx]`` for a small ``table`` and a long ``idx`` in range."""
+    if table.shape[0] > _SELECT_MAX:
+        return table[idx]
+    out = jnp.broadcast_to(table[0], idx.shape)
+    for c in range(1, table.shape[0]):
+        out = jnp.where(idx == c, table[c], out)
+    return out
+
+
+def _row_bins(cols, feature):
+    """Each row's bin of the feature ``feature`` (one per row)."""
+    if len(cols) > _SELECT_MAX:
+        return jnp.take_along_axis(jnp.stack(list(cols)), feature[None],
+                                   0)[0]
+    out = cols[0]
+    for f in range(1, len(cols)):
+        out = jnp.where(feature == f, cols[f], out)
+    return out
+
+
+def _apply_split(cols, node_ids, best_feature, best_bin, best_gain):
     """Route live rows through the level's chosen splits: 2*node (+1 for
     right) in the next level's local numbering, -1 where the node did not
     split."""
     live = node_ids >= 0
     safe_node = jnp.where(live, node_ids, 0)
-    row_bin = jnp.take_along_axis(binned, best_feature[safe_node][:, None],
-                                  1)[:, 0]
-    goes_right = row_bin > best_bin[safe_node]
-    node_split = best_gain[safe_node] > 0
+    row_bin = _row_bins(cols, _take(best_feature, safe_node))
+    goes_right = row_bin > _take(best_bin, safe_node)
+    node_split = _take(best_gain > 0, safe_node)
     return jnp.where(live & node_split,
                      2 * safe_node + goes_right.astype(jnp.int32), -1)
 
 
+# ---------------------------------------------------------------------------
+# the binary in-core fit: one fused program
+# ---------------------------------------------------------------------------
+
+def _logistic_grad_hess(y, margins):
+    p = jax.nn.sigmoid(margins)
+    return p - y, jnp.maximum(p * (1.0 - p), 1e-12)
+
+
+def _squared_grad_hess(y, margins):
+    return margins - y, jnp.ones_like(margins)
+
+
+#: the losses of the fused fit: ``(y, margins) -> (grad, hess)`` in float32
+GRAD_HESS = {"logistic": _logistic_grad_hess, "squared": _squared_grad_hess}
+
+
+def _logistic_grad_hess_host(y, margins):
+    p = 0.5 * (1.0 + np.tanh(0.5 * margins))
+    return p - y, np.maximum(p * (1.0 - p), 1e-12)
+
+
+def _squared_grad_hess_host(y, margins):
+    return margins - y, np.ones_like(margins)
+
+
+#: the same losses in NumPy for the streamed fit, whose margins stay on
+#: the host (a float64 memmap) and whose batches reach the device as
+#: gradients: one device call a batch for two vectors it sends anyway
+#: would add nothing but a dispatch
+HOST_GRAD_HESS = {"logistic": _logistic_grad_hess_host,
+                  "squared": _squared_grad_hess_host}
+
+
+def _node_sums(node_ids, grad, hess, n_nodes: int):
+    """``(G, H)`` of each of ``n_nodes`` nodes: the sums over the rows it
+    holds (a row of node -1 is in none).  Up to ``_SELECT_MAX`` nodes one
+    pass of the rows, a select per node and row reduced (no scatter: the
+    fused fit's leaves); a ``segment_sum`` above."""
+    if n_nodes > _SELECT_MAX:
+        live = node_ids >= 0
+        safe = jnp.where(live, node_ids, 0)
+        w = live.astype(grad.dtype)
+        return (jax.ops.segment_sum(grad * w, safe, n_nodes),
+                jax.ops.segment_sum(hess * w, safe, n_nodes))
+    hit = node_ids[None, :] == jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
+    return (jnp.sum(jnp.where(hit, grad[None, :], 0.0), axis=1),
+            jnp.sum(jnp.where(hit, hess[None, :], 0.0), axis=1))
+
+
+def boost_round(n_rows: int, d: int, config: GBTConfig, loss: str,
+                hist_impl: str):
+    """The body of the fused fit: one tree a round (program key stated,
+    ``iteration/body.py: with_program_key``: everything the trace reads).
+
+    State ``(margins (n,), feature, threshold, value)``, the last three
+    ``(trees, nodes)``; data ``(cols, y)``: the ``d`` bin-id columns and
+    the labels, ``n`` rows of which the first ``n_rows`` are the table's
+    (the rest pad the kernel's blocks and stay in no node).  A round:
+    ``gbt.grad`` the loss's gradient and hessian from the margins;
+    ``max_depth`` levels of ``gbt.hist`` (the level histograms),
+    ``gbt.split`` (best split per node, the Newton values of the nodes
+    that stop) and ``gbt.route`` (rows to children); then, under
+    ``gbt.route``, the Newton values of the last level's leaves from the
+    sums of their rows (a child's sums as its parent's total less its
+    sibling's would cancel where a leaf is small), each row's value and
+    the margin update."""
+    depth, bins = config.max_depth, config.max_bins
+    lam, mcw = float(config.reg_lambda), float(config.min_child_weight)
+    lr = float(config.learning_rate)
+    grad_hess, histograms = GRAD_HESS[loss], _HIST_IMPLS[hist_impl]
+
+    def body(state, tree, data):
+        margins, feature, threshold, value = state
+        cols, y = data
+        with jax.named_scope("gbt.grad"):
+            g, h = grad_hess(y, margins)
+        ids = jnp.where(jnp.arange(margins.shape[0]) < n_rows, 0,
+                        -1).astype(jnp.int32)
+        row_value = jnp.zeros_like(margins)
+        f_rows, t_rows, v_rows = [], [], []
+        for level in range(depth):
+            n_nodes = 2 ** level
+            with jax.named_scope("gbt.hist"):
+                g_hist, h_hist = histograms(cols, ids, g, h, n_nodes, d,
+                                            bins)
+            with jax.named_scope("gbt.split"):
+                f, b, gain = _level_splits(g_hist, h_hist, lam, mcw)
+                split = gain > 0
+                stops = _newton(jnp.sum(g_hist, axis=(1, 2)) / d,
+                                jnp.sum(h_hist, axis=(1, 2)) / d, lam)
+                f_rows.append(jnp.where(split, f, -1))
+                t_rows.append(b)
+                v_rows.append(jnp.where(split, 0.0, stops))
+            with jax.named_scope("gbt.route"):
+                live = ids >= 0
+                safe = jnp.where(live, ids, 0)
+                row_value = jnp.where(live & ~_take(split, safe),
+                                      _take(stops, safe), row_value)
+                ids = _apply_split(cols, ids, f, b, gain)
+        with jax.named_scope("gbt.route"):
+            leaves = _newton(*_node_sums(ids, g, h, 2 ** depth), lam)
+            row_value = jnp.where(ids >= 0, _take(leaves, jnp.maximum(ids, 0)),
+                                  row_value)
+            margins = margins + lr * row_value
+            leaf_level = jnp.full((2 ** depth,), -1, jnp.int32)
+            feature = feature.at[tree].set(jnp.concatenate(f_rows
+                                                           + [leaf_level]))
+            threshold = threshold.at[tree].set(jnp.concatenate(
+                t_rows + [jnp.zeros((2 ** depth,), jnp.int32)]))
+            value = value.at[tree].set(jnp.concatenate(v_rows + [leaves]))
+        return margins, feature, threshold, value
+
+    return with_program_key(body, boost_round, n_rows, d, depth, bins, lam,
+                            mcw, lr, loss, hist_impl)
+
+
+def _start(rows: int, trees: int, nodes: int, base_score: float):
+    return (jnp.full((rows,), base_score, jnp.float32),
+            jnp.full((trees, nodes), -1, jnp.int32),
+            jnp.zeros((trees, nodes), jnp.int32),
+            jnp.zeros((trees, nodes), jnp.float32))
+
+
+_start_jit = jax.jit(_start, static_argnums=(0, 1, 2))
+
+
+def train_forest(X: np.ndarray, y: np.ndarray, loss: str,
+                 base_score: float, config: GBTConfig) -> Tuple[Forest, str]:
+    """Boost ``num_trees`` trees of the loss ``loss`` (a key of
+    :data:`GRAD_HESS`) from the margin ``base_score``: ``(forest, the
+    histogram backend the fit took)``.
+
+    On the host, under the span ``fit.arrange`` (which notes
+    ``hist_impl`` and ``trees``): the bin edges and, under
+    ``fit.arrange.bin``, the feature-major bin ids of every row
+    (:func:`bin_features`' rule, :func:`bin_columns`), rows padded to the
+    histogram kernel's blocks where the Pallas backend runs.  Under
+    ``fit.upload`` each column and the labels go to the device as arrays
+    of their own, a round of at most ``parallel/mesh.py: PUT_BYTES`` in
+    flight (``put_in_rounds``): the bins are never copied on the device.
+    Then the fused program (:func:`boost_round`) and, under
+    ``fit.fetch``, the forest."""
+    from ...parallel.mesh import default_mesh, put_in_rounds
+
+    n, d = X.shape
+    T, depth, bins = config.num_trees, config.max_depth, config.max_bins
+    nodes = 2 ** (depth + 1) - 1
+    with tracer.span("fit.arrange", "fit") as arrange:
+        # one backend for every level: the widest level's pick
+        impl = resolve_hist_impl(sig=(d, bins, 2 ** max(depth - 1, 0)))
+        arrange.note(hist_impl=impl, trees=T)
+        if impl == "pallas":
+            from ...ops.gbt_hist_pallas import padded_rows
+
+            rows = padded_rows(n)
+        else:
+            rows = n
+        with tracer.span("fit.arrange.bin", "fit"):
+            edges = quantile_edges(edge_sample(X), bins)
+            host_cols = bin_columns(X, edges, rows)
+            labels = np.zeros((rows,), np.float32)
+            labels[:n] = y
+    device = default_mesh().devices.flat[0]
+    with tracer.span("fit.upload", "fit") as upload:
+        *cols, labels = put_in_rounds(list(host_cols) + [labels], device)
+        upload.note(pieces=d + 1)
+        with jax.default_device(device):
+            start = _start_jit(rows, T, nodes, float(base_score))
+    del host_cols
+    result = iterate(boost_round(n, d, config, loss, impl), HandedOver(start),
+                     (tuple(cols), labels), max_epochs=T,
+                     config=IterationConfig(mode="fused"))
+    with tracer.span("fit.fetch", "fit"):
+        feature, threshold, value = (np.asarray(a) for a in
+                                     jax.device_get(result.state[1:]))
+    return Forest(feature, threshold, value, edges, float(base_score),
+                  config.learning_rate), impl
+
+
+# ---------------------------------------------------------------------------
+# the hosted trainers' pieces (multiclass, streamed)
+# ---------------------------------------------------------------------------
+
 @partial(aot_jit, static_argnames=("n_nodes", "d", "bins", "reg_lambda",
                                    "min_child_weight", "hist_impl"))
-def _build_level(binned, node_ids, grad, hess, n_nodes: int,
+def _build_level(cols, node_ids, grad, hess, n_nodes: int,
                  d: int, bins: int, reg_lambda: float,
                  min_child_weight: float, hist_impl: str = "segsum"):
     """One tree level for all ``n_nodes`` nodes at once
-    (histograms -> splits -> routing; the three pieces are separate
-    functions so the out-of-core trainer can accumulate histograms over
-    batches and reuse the identical split/routing math).
+    (histograms -> splits -> routing).
 
     Returns (feature (n_nodes,), threshold (n_nodes,), gain (n_nodes,),
     new_node_ids (n,)).  ``node_ids`` are level-local in [0, n_nodes) with
     -1 marking rows already settled in a leaf.
     """
-    g_hist, h_hist = _HIST_IMPLS[resolve_hist_impl(hist_impl)](
-        binned, node_ids, grad, hess, n_nodes, d, bins)
+    g_hist, h_hist = _HIST_IMPLS[resolve_hist_impl(
+        hist_impl, (d, bins, n_nodes))](cols, node_ids, grad, hess,
+                                        n_nodes, d, bins)
     best_feature, best_bin, best_gain = _level_splits(
         g_hist, h_hist, reg_lambda, min_child_weight)
-    new_ids = _apply_split(binned, node_ids, best_feature, best_bin,
+    new_ids = _apply_split(cols, node_ids, best_feature, best_bin,
                            best_gain)
     return best_feature, best_bin, best_gain, new_ids
 
@@ -291,18 +573,20 @@ def _build_level(binned, node_ids, grad, hess, n_nodes: int,
 @partial(aot_jit, static_argnames=("n_nodes", "reg_lambda"))
 def _leaf_values(node_ids, grad, hess, n_nodes: int, reg_lambda: float):
     """Newton leaf weights -G/(H+lambda) for every level-local node."""
-    live = node_ids >= 0
-    safe = jnp.where(live, node_ids, 0)
-    w = live.astype(grad.dtype)
-    g = jax.ops.segment_sum(grad * w, safe, n_nodes)
-    h = jax.ops.segment_sum(hess * w, safe, n_nodes)
-    return -g / (h + reg_lambda)
+    return _newton(*_node_sums(node_ids, grad, hess, n_nodes), reg_lambda)
 
 
-def _train_one_tree(binned, g, h, d: int, config: GBTConfig):
+@jax.jit
+def _columns(binned):
+    """The feature-major columns of a row-major ``(n, d)`` bin table."""
+    return tuple(binned[:, f] for f in range(binned.shape[1]))
+
+
+def _train_one_tree(binned, cols, g, h, d: int, config: GBTConfig):
     """Grow one tree against device gradients/hessians; returns the host
     (feature, threshold, value) node rows plus the tree's DEVICE in-sample
-    prediction (margin scale, before learning-rate shrinkage)."""
+    prediction (margin scale, before learning-rate shrinkage).
+    ``binned`` is the ``(n, d)`` table, ``cols`` its columns."""
     n = binned.shape[0]
     bins = config.max_bins
     depth = config.max_depth
@@ -320,11 +604,11 @@ def _train_one_tree(binned, g, h, d: int, config: GBTConfig):
         n_nodes = 2 ** level
         # hist impl resolved to a CONCRETE name before it becomes a
         # static arg: "auto" would be ambiguous in the persistent AOT
-        # key (the registry/autotune pick can differ across processes)
+        # key (the registry pick can differ across processes)
         f, b, gain, node_ids = _build_level(
-            binned, node_ids, g, h, n_nodes, d, bins,
+            cols, node_ids, g, h, n_nodes, d, bins,
             config.reg_lambda, config.min_child_weight,
-            hist_impl=resolve_hist_impl())
+            hist_impl=resolve_hist_impl(sig=(d, bins, n_nodes)))
         level_feature.append(np.asarray(f))
         level_bin.append(np.asarray(b))
         level_gain.append(np.asarray(gain))
@@ -358,79 +642,6 @@ def _train_one_tree(binned, g, h, d: int, config: GBTConfig):
                              jnp.asarray(threshold_row),
                              jnp.asarray(value_row), depth)
     return feature_row, threshold_row, value_row, pred
-
-
-def _maybe_autotune_hist(binned, g, h, d: int, bins: int) -> None:
-    """First-encounter autotune of the histogram backend (ISSUE 12):
-    when several registry backends are AVAILABLE on this device (none
-    today: ``mxu`` is forced-lookup only, so there is nothing to search)
-    and a persistent cache root is configured, time both on a probe slice of the REAL binned
-    data and record the winner — ``resolve_hist_impl("auto")`` then
-    resolves through ``registry.lookup``, which honors the decision in
-    this and every later process.  A recorded decision short-circuits
-    (zero search cost)."""
-    from ...kernels import autotune
-    from ...kernels.registry import backends, lookup
-
-    if HIST_IMPL != "auto" or not autotune.enabled():
-        return
-    avail = [b for b in backends("gbt_level_histograms")
-             if lookup("gbt_level_histograms", backend=b).is_available()]
-    if len(avail) < 2:
-        return
-    rows = min(int(binned.shape[0]), 8192)
-    bp, gp, hp = binned[:rows], g[:rows], h[:rows]
-    ids = jnp.zeros((rows,), jnp.int32)
-    impl_of = {"xla": "segsum"}
-
-    def runner(backend):
-        impl = _HIST_IMPLS[impl_of.get(backend, backend)]
-        return lambda: impl(bp, ids, gp, hp, 4, d, bins)
-
-    autotune.choose("gbt_level_histograms", (),
-                    {b: runner(b) for b in avail},
-                    probe=f"real-data slice rows={rows} d={d} bins={bins} "
-                          "n_nodes=4")
-
-
-def train_forest(X: np.ndarray, y: np.ndarray,
-                 grad_hess: Callable[[np.ndarray, np.ndarray],
-                                     Tuple[np.ndarray, np.ndarray]],
-                 base_score: float, config: GBTConfig) -> Forest:
-    """Boost ``num_trees`` trees against ``grad_hess(y, pred)``."""
-    n, d = X.shape
-    binned_host, edges = bin_features(X, config.max_bins)
-    binned = jnp.asarray(binned_host)
-    n_nodes_total = 2 ** (config.max_depth + 1) - 1
-
-    features = np.full((config.num_trees, n_nodes_total), -1, np.int32)
-    thresholds = np.zeros((config.num_trees, n_nodes_total), np.int32)
-    values = np.zeros((config.num_trees, n_nodes_total), np.float32)
-
-    pred = np.full((n,), base_score, np.float64)
-    for t in range(config.num_trees):
-        g, h = grad_hess(y, pred)
-        gd = jnp.asarray(g, jnp.float32)
-        hd = jnp.asarray(h, jnp.float32)
-        if t == 0:
-            _maybe_autotune_hist(binned, gd, hd, d, config.max_bins)
-        features[t], thresholds[t], values[t], tree_pred = _train_one_tree(
-            binned, gd, hd, d, config)
-        pred = pred + config.learning_rate * np.asarray(tree_pred, np.float64)
-
-    return Forest(features, thresholds, values, edges, base_score,
-                  config.learning_rate)
-
-
-@partial(jax.jit, static_argnames=("n_nodes",))
-def _leaf_sums(node_ids, grad, hess, n_nodes: int):
-    """Per-node (G, H) sums — the additive form of :func:`_leaf_values`
-    for streamed batches."""
-    live = node_ids >= 0
-    safe = jnp.where(live, node_ids, 0)
-    w = live.astype(grad.dtype)
-    return (jax.ops.segment_sum(grad * w, safe, n_nodes),
-            jax.ops.segment_sum(hess * w, safe, n_nodes))
 
 
 @partial(jax.jit, static_argnames=("level",))
@@ -474,8 +685,9 @@ def _chunk_level_histograms(binned_c, g_c, h_c, feature_rows,
         gh_acc, hh_acc = carry
         b, g, h = xs
         ids = _route_to_level(b, feature_rows, threshold_rows, level)
-        gh, hh = _HIST_IMPLS[resolve_hist_impl(hist_impl)](
-            b, ids, g, h, n_nodes, d, bins)
+        gh, hh = _HIST_IMPLS[resolve_hist_impl(hist_impl, (d, bins,
+                                                           n_nodes))](
+            tuple(b[:, f] for f in range(d)), ids, g, h, n_nodes, d, bins)
         return (gh_acc + gh, hh_acc + hh), None
 
     (g_hist, h_hist), _ = jax.lax.scan(scan_step, (g_init, h_init),
@@ -492,7 +704,7 @@ def _chunk_leaf_sums(binned_c, g_c, h_c, feature_rows, threshold_rows,
     def scan_step(_, xs):
         b, g, h = xs
         ids = _route_to_level(b, feature_rows, threshold_rows, depth)
-        return None, _leaf_sums(ids, g, h, n_nodes)
+        return None, _node_sums(ids, g, h, n_nodes)
 
     _, (gs, hs) = jax.lax.scan(scan_step, None, (binned_c, g_c, h_c))
     return gs, hs
@@ -791,6 +1003,7 @@ def train_forest_softmax(X: np.ndarray, y_ids: np.ndarray, n_classes: int,
     n, d = X.shape
     binned_host, edges = bin_features(X, config.max_bins)
     binned = jnp.asarray(binned_host)
+    cols = _columns(binned)
     n_nodes_total = 2 ** (config.max_depth + 1) - 1
     T, K = config.num_trees, n_classes
 
@@ -810,7 +1023,7 @@ def train_forest_softmax(X: np.ndarray, y_ids: np.ndarray, n_classes: int,
             h = np.maximum(p[:, k] * (1.0 - p[:, k]), 1e-12)
             (features[t, k], thresholds[t, k], values[t, k],
              tree_pred) = _train_one_tree(
-                binned, jnp.asarray(g, jnp.float32),
+                binned, cols, jnp.asarray(g, jnp.float32),
                 jnp.asarray(h, jnp.float32), d, config)
             margins[:, k] += config.learning_rate * np.asarray(tree_pred,
                                                                np.float64)
@@ -887,27 +1100,23 @@ def predict_forest(X: np.ndarray, forest: Forest) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# kernel-registry entries: op ``gbt_level_histograms``.  segsum is what
-# "auto" plans on every device.  The MXU form (PR 10: histogramming as
-# one-hot systolic matmuls instead of segment_sum's per-element random
-# accumulation — the decision-forest-literature TPU-histogram trick) was
-# the TPU default until it first ran on a chip (PR 21): its contraction
-# runs at default MXU precision, so every gradient/hessian is truncated to
-# bf16 before it is summed, and it no longer matches segsum to f32
-# summation order.  It stays registered for a forced lookup and
-# ``HIST_IMPL = "mxu"``; whether a higher-precision contraction still
-# beats segment_sum is ROADMAP S3.  Both feed the streamed histogram carry
-# unchanged (accumulation over batches is a plain add either way).
+# kernel-registry entries: op ``gbt_level_histograms``, signature ``(d,
+# bins, n_nodes)`` of a level.  On a TPU "auto" plans the Pallas
+# contraction (``ops/gbt_hist_pallas.py``) wherever its VMEM holds the
+# level: exact float32 sums, no (rows x features) temporary, every level
+# of a 115 M-row table in one pass of the rows.  Elsewhere, and for a
+# level too wide or deep for it, segment_sum.  Both feed the streamed
+# histogram carry unchanged (accumulation over batches is a plain add
+# either way).
 # ---------------------------------------------------------------------------
 
 def _register_gbt_kernels() -> None:
-    from ...kernels.registry import register_kernel
+    from ...kernels.registry import register_kernel, tpu_only
+    from ...ops.gbt_hist_pallas import supported
 
-    register_kernel(
-        "gbt_level_histograms", "mxu", _level_histograms_mxu, priority=10,
-        forced_only="bf16-truncated gradients: max |diff| 0.0079 from "
-                    "segment_sum on sums of ~3 (TPU v5 lite, rtol 1e-4 "
-                    "wanted)")
+    register_kernel("gbt_level_histograms", "pallas",
+                    _level_histograms_pallas, priority=10,
+                    supports=supported, available=tpu_only)
     register_kernel("gbt_level_histograms", "xla", _level_histograms_segsum)
 
 

@@ -8,7 +8,8 @@ import numpy as np
 
 from ...api.stage import Estimator, Model
 from ...data.table import Table
-from ...linalg import stack_vectors
+from ...linalg import float32_rows, stack_vectors
+from ...obs.trace import tracer
 from ...params.param import FloatParam, IntParam, ParamValidators
 from ...params.shared import (
     HasFeaturesCol,
@@ -76,6 +77,9 @@ class GBTModelBase(GBTModelParams, Model):
     def __init__(self):
         super().__init__()
         self._forest: Optional[Forest] = None
+        #: the histogram backend the fit that made this model took
+        #: (``gbt.resolve_hist_impl``); None for a model loaded or set
+        self.hist_impl: Optional[str] = None
 
     def _margins(self, table: Table) -> np.ndarray:
         X = stack_vectors(table[self.get_features_col()]).astype(np.float64)
@@ -138,9 +142,12 @@ class GBTModelBase(GBTModelParams, Model):
 
 class GBTEstimatorBase(GBTParams, Estimator):
     """Subclasses define ``_prepare_labels`` (-> float targets + label map),
-    ``_grad_hess``, ``_base_score``, and ``model_cls``."""
+    ``_loss`` (a key of ``gbt.GRAD_HESS``: the fused fit's loss, and in
+    ``gbt.HOST_GRAD_HESS`` the streamed fit's), ``_base_score``, and
+    ``model_cls``."""
 
     model_cls: type
+    _loss: str
 
     def _config(self) -> GBTConfig:
         return GBTConfig(
@@ -153,21 +160,38 @@ class GBTEstimatorBase(GBTParams, Estimator):
         )
 
     def fit(self, *inputs):
+        """One fused program on the device (``gbt.train_forest``) under
+        the root span ``fit``: ``fit.gather`` reads the features as
+        float32 rows (``linalg.float32_rows``: a C-contiguous float32
+        column in place, no copy) and prepares the labels; the trainer
+        opens ``fit.arrange``, ``fit.upload``, ``iterate.dispatch`` and
+        ``fit.fetch``.  A ``_prepare_labels`` that gives no targets hands
+        the rows to ``_fit_multiclass``."""
         (table,) = inputs
-        X = stack_vectors(table[self.get_features_col()]).astype(np.float64)
-        if len(X) == 0:
-            raise ValueError(f"{type(self).__name__}.fit requires rows")
-        # Label values thread through fit (never stored on the estimator):
-        # concurrent fits on one estimator stay independent.
-        y, label_values = self._prepare_labels(
-            np.asarray(table[self.get_label_col()]))
-        forest = train_forest(X, y, self._grad_hess, self._base_score(y),
-                              self._config())
+        with tracer.fit_span(type(self).__name__):
+            with tracer.span("fit.gather", "fit"):
+                X = float32_rows(table[self.get_features_col()])
+                if len(X) == 0:
+                    raise ValueError(
+                        f"{type(self).__name__}.fit requires rows")
+                # Label values thread through fit (never stored on the
+                # estimator): concurrent fits on one estimator stay
+                # independent.
+                y, label_values = self._prepare_labels(
+                    np.asarray(table[self.get_label_col()]))
+            if y is None:
+                return self._fit_multiclass(X, label_values)
+            forest, impl = train_forest(X, y, self._loss,
+                                        self._base_score(y), self._config())
         model = self.model_cls()
         model.copy_params_from(self)
         model._forest = forest
+        model.hist_impl = impl
         self._finalize_model(model, label_values)
         return model
+
+    def _fit_multiclass(self, X: np.ndarray, label_values):
+        raise NotImplementedError
 
     def fit_outofcore(self, make_reader, *, features_key: str = None,
                       label_key: str = None, work_dir: str = None,
@@ -181,7 +205,7 @@ class GBTEstimatorBase(GBTParams, Estimator):
         Binary-classification label note: the streamed labels must
         already be 0/1 floats (the in-core fit's arbitrary-label mapping
         needs the full label set up front)."""
-        from .gbt import train_forest_outofcore
+        from .gbt import HOST_GRAD_HESS, train_forest_outofcore
 
         def prepared_reader():
             for batch in make_reader():
@@ -194,7 +218,7 @@ class GBTEstimatorBase(GBTParams, Estimator):
         # base score folds into the trainer's pass A over the same
         # leading sample (no extra head read of a slow source)
         forest = train_forest_outofcore(
-            prepared_reader, self._grad_hess, self._base_score,
+            prepared_reader, HOST_GRAD_HESS[self._loss], self._base_score,
             self._config(), work_dir=work_dir, sample_rows=sample_rows)
         model = self.model_cls()
         model.copy_params_from(self)

@@ -26,12 +26,10 @@ class GBTRegressorModel(GBTModelBase):
 
 class GBTRegressor(GBTEstimatorBase):
     model_cls = GBTRegressorModel
+    _loss = "squared"
 
     def _prepare_labels(self, y_raw: np.ndarray):
-        return np.asarray(y_raw, np.float64), None
-
-    def _grad_hess(self, y, pred):
-        return pred - y, np.ones_like(pred)
+        return np.asarray(y_raw, np.float32), None
 
     def _base_score(self, y) -> float:
-        return float(y.mean())
+        return float(y.mean(dtype=np.float64))

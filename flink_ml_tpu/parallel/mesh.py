@@ -35,6 +35,7 @@ __all__ = [
     "local_axis_multiple",
     "mesh_process_count",
     "put_sharded",
+    "put_in_rounds",
     "put_sharded_in_pieces",
     "replicated",
     "rows_a_put",
@@ -293,6 +294,26 @@ def put_sharded_in_pieces(rows: np.ndarray, mesh: Mesh, shard_rows: int,
             pieces.append([jax.device_put(flat, device) for device in line])
         flying = [piece for line in pieces for piece in line]
         yield first, pieces
+
+
+def put_in_rounds(arrays: Sequence[np.ndarray], device) -> List[jax.Array]:
+    """Each host array to ``device`` as a device array of its own (no
+    copy on the device: what is put is what the program reads), in rounds
+    of at most :data:`PUT_BYTES` in flight, each round waited for before
+    the next is put.  The last round is not waited for: the caller's next
+    program is.  An array larger than the cap is a round of its own."""
+    out: List[jax.Array] = []
+    flying: list = []
+    held = 0
+    for arr in arrays:
+        if flying and held + arr.nbytes > PUT_BYTES:
+            for piece in flying:
+                piece.block_until_ready()
+            flying, held = [], 0
+        out.append(jax.device_put(arr, device))
+        flying.append(out[-1])
+        held += arr.nbytes
+    return out
 
 
 def assemble_process_local(batch: Any, shardings: Any) -> tuple:

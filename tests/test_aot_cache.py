@@ -456,31 +456,29 @@ def _gbt_fixture():
 
     rng = np.random.default_rng(23)
     X = rng.normal(size=(512, 6)).astype(np.float32)
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
-
-    def grad_hess(y, pred):
-        p = 1.0 / (1.0 + np.exp(-pred))
-        return (p - y), np.maximum(p * (1.0 - p), 1e-16)
-
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.int64)
     cfg = GBTConfig(num_trees=2, max_depth=3, max_bins=16,
                     learning_rate=0.3)
-    return X, y, grad_hess, cfg
+    return X, y, cfg
 
 
 def test_gbt_train_forest_through_aot_cache(cache):
-    from flink_ml_tpu.models.common.gbt import train_forest
+    """The hosted GBT trainer (the multiclass one: the binary fit is one
+    fused ``iterate`` program) builds its levels, leaves and in-sample
+    predictions through ``aot_jit``."""
+    from flink_ml_tpu.models.common.gbt import train_forest_softmax
     from flink_ml_tpu.utils.backend import count_compiles
 
-    X, y, grad_hess, cfg = _gbt_fixture()
+    X, y, cfg = _gbt_fixture()
     aot.set_cache(None)
-    baseline = train_forest(X, y, grad_hess, 0.0, cfg)
+    baseline = train_forest_softmax(X, y, 2, cfg)
 
     aot.set_cache(cache)
-    first = train_forest(X, y, grad_hess, 0.0, cfg)   # compile + store
+    first = train_forest_softmax(X, y, 2, cfg)   # compile + store
 
     aot.set_cache(aot.ExecutableCache(cache.root))    # restarted process
     with count_compiles() as count:
-        second = train_forest(X, y, grad_hess, 0.0, cfg)
+        second = train_forest_softmax(X, y, 2, cfg)
     assert count() == 0, (
         f"{count()} lowerings on the warm-cache GBT run — the aot_jit "
         "wrapper did not cover the training step builders")

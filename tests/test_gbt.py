@@ -236,7 +236,7 @@ class TestOutOfCore:
             p = 1.0 / (1.0 + np.exp(-pred))
             return p - yv, np.maximum(p * (1.0 - p), 1e-12)
 
-        incore = train_forest(X, y, grad_hess, 0.0, cfg)
+        incore, _ = train_forest(X, y, "logistic", 0.0, cfg)
 
         def make_reader(batch=700):
             def gen():
@@ -356,25 +356,27 @@ def test_outofcore_workdir_reusable_and_cleaned(tmp_path):
 
 
 def test_mxu_histograms_match_segsum():
-    """The MXU double-one-hot histogram must equal the segment_sum form
-    (f32 summation order aside) — including dead rows (-1) and empty
-    nodes — and produce identical trees end-to-end."""
+    """The MXU one-hot contraction (the Pallas kernel, interpreted here)
+    must equal the segment_sum form to float32 summation order —
+    including dead rows (-1) and empty nodes — and grow the same forest
+    end-to-end."""
     import jax.numpy as jnp
 
     from flink_ml_tpu.models.common import gbt
 
     rng = np.random.default_rng(21)
     n, d, bins, n_nodes = 512, 5, 16, 4
-    binned = jnp.asarray(rng.integers(0, bins, size=(n, d)), jnp.int32)
+    cols = tuple(jnp.asarray(rng.integers(0, bins, size=n), jnp.int32)
+                 for _ in range(d))
     ids = jnp.asarray(
         np.where(rng.random(n) < 0.2, -1,
                  rng.integers(0, n_nodes, size=n)), jnp.int32)
     g = jnp.asarray(rng.normal(size=n), jnp.float32)
     h = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
-    gs, hs = gbt._level_histograms_segsum(binned, ids, g, h, n_nodes, d,
+    gs, hs = gbt._level_histograms_segsum(cols, ids, g, h, n_nodes, d,
                                           bins)
-    gm, hm = gbt._level_histograms_mxu(binned, ids, g, h, n_nodes, d,
-                                       bins)
+    gm, hm = gbt._level_histograms_pallas(cols, ids, g, h, n_nodes, d,
+                                          bins)
     np.testing.assert_allclose(np.asarray(gm), np.asarray(gs),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(hm), np.asarray(hs),
@@ -384,28 +386,273 @@ def test_mxu_histograms_match_segsum():
     X = rng.normal(size=(1024, 4))
     y = (X[:, 0] + X[:, 1] > 0).astype(np.float64)
 
-    def gh_fn(y, pred):
-        p = 1.0 / (1.0 + np.exp(-pred))
-        return (p - y), np.maximum(p * (1.0 - p), 1e-16)
-
     cfg = gbt.GBTConfig(num_trees=3, max_depth=3)
     old = gbt.HIST_IMPL
     try:
         gbt.HIST_IMPL = "segsum"
-        f1 = gbt.train_forest(X, y, gh_fn, 0.0, cfg)
-        gbt.HIST_IMPL = "mxu"
-        f2 = gbt.train_forest(X, y, gh_fn, 0.0, cfg)
+        f1, impl1 = gbt.train_forest(X, y, "logistic", 0.0, cfg)
+        gbt.HIST_IMPL = "pallas"
+        f2, impl2 = gbt.train_forest(X, y, "logistic", 0.0, cfg)
     finally:
         gbt.HIST_IMPL = old
-    # prediction-space equivalence, not exact trees: near-tie argmax
-    # splits may legitimately differ under f32 summation order
+    assert (impl1, impl2) == ("segsum", "pallas")
+    np.testing.assert_array_equal(f1.feature, f2.feature)
+    np.testing.assert_array_equal(f1.threshold, f2.threshold)
     np.testing.assert_allclose(gbt.predict_forest(X, f1),
                                gbt.predict_forest(X, f2),
-                               rtol=1e-3, atol=1e-3)
+                               rtol=1e-5, atol=1e-5)
     # unknown impl names fail loudly, never silently fall back
     try:
         gbt.HIST_IMPL = "typo"
         with pytest.raises(KeyError):
-            gbt._level_histograms(binned, ids, g, h, n_nodes, d, bins)
+            gbt._level_histograms(cols, ids, g, h, n_nodes, d, bins)
     finally:
         gbt.HIST_IMPL = old
+
+
+# ------------------------------------------------- the fused binary fit
+
+def _reference():
+    """``benchmarks/references/gbt_hist.py``: the plain reference the
+    benchmark's ``gbt_airline.fit`` compares with (it imports nothing of
+    the program)."""
+    import importlib
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    return importlib.import_module("benchmarks.references.gbt_hist")
+
+
+def _airline_like(n=6000, d=5, seed=3):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    X[:, 1] = np.round(X[:, 1] * 2)            # a few distinct values
+    logit = 1.5 * X[:, 0] - X[:, 1] * (X[:, 2] > 0) + 0.5 * X[:, 3] ** 2 - 0.4
+    y = (rng.random(n) < 1 / (1 + np.exp(-logit))).astype(np.float32)
+    return X, y
+
+
+_REF_PARAMS = {"depth": 4, "bins": 16, "trees": 6, "lr": 0.3, "lam": 1.0,
+               "mcw": 1e-3, "sample": 1 << 18, "block": 2048,
+               "precision": "highest"}
+
+
+def _fused_and_reference(hist_impl):
+    from flink_ml_tpu.models.common import gbt
+
+    X, y = _airline_like()
+    p = _REF_PARAMS
+    ref = _reference().boost(X, y, p)
+    cfg = gbt.GBTConfig(num_trees=p["trees"], max_depth=p["depth"],
+                        max_bins=p["bins"], learning_rate=p["lr"],
+                        reg_lambda=p["lam"], min_child_weight=p["mcw"])
+    old = gbt.HIST_IMPL
+    try:
+        gbt.HIST_IMPL = hist_impl
+        forest, impl = gbt.train_forest(X, y, "logistic", ref["baseScore"],
+                                        cfg)
+    finally:
+        gbt.HIST_IMPL = old
+    assert impl == hist_impl
+    return X, y, forest, ref
+
+
+@pytest.mark.parametrize("hist_impl", ["segsum", "pallas"])
+def test_fused_fit_matches_the_plain_reference(hist_impl):
+    """Every tree's split features and thresholds exactly as the plain
+    reference's (continuous features: no two splits tie in exact
+    arithmetic), leaves and margins within 1e-5: both sum the same float32
+    values in other orders (the reference's blocks of rows, the program's
+    histograms), a relative 1e-7 a sum here."""
+    X, y, forest, ref = _fused_and_reference(hist_impl)
+    np.testing.assert_array_equal(forest.feature, ref["feature"])
+    np.testing.assert_array_equal(
+        np.where(forest.feature >= 0, forest.threshold, 0),
+        np.where(ref["feature"] >= 0, ref["threshold"], 0))
+    np.testing.assert_allclose(forest.value, ref["value"], atol=1e-5)
+    np.testing.assert_array_equal(forest.bin_edges, ref["binEdges"])
+    from flink_ml_tpu.models.common.gbt import predict_forest
+
+    m_ref = _reference().margins(
+        ref, _reference().bin_table(X, ref["binEdges"], len(X)),
+        _REF_PARAMS["depth"])
+    np.testing.assert_allclose(predict_forest(X, forest), np.asarray(m_ref),
+                               atol=1e-5)
+
+
+def _airline_limits():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gbt_airline.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("kind", ["sound", "control", "half_rows", "start",
+                                  "tied_splits"])
+def test_reference_comparison_fails_the_control_and_the_faults(kind):
+    """``gbt_airline.fit``'s comparison at a small size: the fused fit
+    passes every limit; the reference with bfloat16-rounded addends (what
+    a one-hot contraction at the MXU's default precision sums), every
+    second row left out, the start margins returned, and the reference's
+    forest with its tied nodes' splits moved to their worst each read over
+    one at least."""
+    from flink_ml_tpu.models.classification import GBTClassifier
+
+    ref_mod = _reference()
+    config = _airline_limits()
+    config["reference_params"].update(trees=6, block_rows=2048)
+    data = {"features": _airline_like(n=8000, d=13, seed=9)[0]}
+    data["label"] = _airline_like(n=8000, d=13, seed=9)[1]
+    if kind == "sound":
+        model = (GBTClassifier().set_max_iter(6).set_max_depth(5)
+                 .set_max_bins(32).set_reg_lambda(config["reg_lambda"])
+                 .fit(Table(data)))
+        (t,) = model.get_model_data()[:1]
+        answer = {c: np.asarray(t[c]) for c in ("feature", "threshold",
+                                                "value")}
+        answer.update(binEdges=np.asarray(t["binEdges"][0]),
+                      baseScore=float(t["baseScore"][0]),
+                      learningRate=float(t["learningRate"][0]))
+    elif kind == "control":
+        answer = ref_mod.control(config, data, 0)
+    else:
+        answer = ref_mod.fault(config, data, 0, kind)
+    numbers = ref_mod.compare(config, data, answer, 0)
+    over = [n for n, limit in config["limits"].items()
+            if not numbers[n] <= limit]
+    assert (not over) if kind == "sound" else over, (kind, numbers)
+
+
+def test_histogram_kernel_matches_segsum_ragged_and_wide():
+    """The Pallas histogram (interpreted) against segment_sum where the
+    rows are no multiple of the kernel's block and the (feature, bin)
+    one-hot takes two matmuls a block (64 bins x 13 features)."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.models.common import gbt
+
+    rng = np.random.default_rng(8)
+    n, d, bins, n_nodes = 5000, 13, 64, 8
+    cols = tuple(jnp.asarray(rng.integers(0, bins, size=n), jnp.int32)
+                 for _ in range(d))
+    ids = jnp.asarray(np.where(rng.random(n) < 0.1, -1,
+                               rng.integers(0, n_nodes, size=n)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=n) * 3, jnp.float32)
+    h = jnp.asarray(rng.random(n), jnp.float32)
+    want = gbt._level_histograms_segsum(cols, ids, g, h, n_nodes, d, bins)
+    got = gbt._level_histograms_pallas(cols, ids, g, h, n_nodes, d, bins)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=2e-5)
+
+
+def test_exact_bf16_parts_sum_to_the_float32_value():
+    """Three bfloat16 values carry a float32 value exactly, where its last
+    part is a normal float (the value above about 1e-31)."""
+    import jax.numpy as jnp
+
+    from flink_ml_tpu.ops.gbt_hist_pallas import _exact_bf16_parts
+
+    rng = np.random.default_rng(2)
+    x = np.concatenate([rng.normal(size=4096) * 10.0 ** rng.integers(
+        -20, 30, 4096), [0.0, -0.0, 1e-12, 3.0e38]]).astype(np.float32)
+    parts = _exact_bf16_parts(jnp.asarray(x))
+    for part in parts:
+        p = np.asarray(part)
+        assert np.array_equal(p, np.asarray(jnp.asarray(p).astype(
+            jnp.bfloat16).astype(jnp.float32)))
+    total = (np.asarray(parts[0], np.float64) + np.asarray(parts[1])
+             + np.asarray(parts[2]))
+    np.testing.assert_array_equal(total.astype(np.float32), x)
+
+
+def test_native_binning_is_the_numpy_rule():
+    """``bin_columns`` (``native/gbt_bin.cpp`` where it builds) gives
+    ``np.searchsorted(edges, x, side="left")`` of every float32 value
+    against float64 edges — values on, just under and just over an edge,
+    infinities, NaN — laid out feature-major with zero padding."""
+    from flink_ml_tpu.models.common import gbt
+
+    rng = np.random.default_rng(6)
+    X = rng.normal(size=(20_000, 4)).astype(np.float32)
+    X[:, 2] = np.round(X[:, 2])
+    edges = gbt.quantile_edges(X, 32)
+    # the float32 values on either side of an edge between two of them,
+    # and one on an edge
+    below = np.float32(edges[0, 5])
+    if np.float64(below) > edges[0, 5]:
+        below = np.nextafter(below, np.float32(-np.inf))
+    X[7, 0], X[8, 0] = below, np.nextafter(below, np.float32(np.inf))
+    X[9, 0] = edges[0, 6] = np.float32(edges[0, 6])
+    X[:3, 1] = (np.inf, -np.inf, np.nan)
+    got = gbt.bin_columns(X, edges, 20_480)
+    want = gbt.apply_bins(X, edges).T
+    assert got.shape == (4, 20_480) and got.dtype == np.int32
+    np.testing.assert_array_equal(got[:, :20_000], want)
+    assert not got[:, 20_000:].any()
+    assert got[1, 2] == 31                          # NaN: the last bin
+
+
+def test_edges_come_from_a_strided_sample_of_a_large_table(monkeypatch):
+    from flink_ml_tpu.models.common import gbt
+
+    monkeypatch.setattr(gbt, "EDGE_SAMPLE_ROWS", 100)
+    X = np.arange(1000, dtype=np.float32)[:, None]
+    np.testing.assert_array_equal(gbt.edge_sample(X)[:, 0],
+                                  np.arange(0, 1000, 10))
+    _, edges = gbt.bin_features(X, 4)
+    np.testing.assert_array_equal(
+        edges, gbt.quantile_edges(X[::10], 4))
+
+
+def test_fit_notes_its_plan_and_spans_and_reuses_its_program(
+        fit_noting_reuse):
+    """The binary fit is one fused program: a second fit of the same
+    shapes in the process enqueues the kept executable; the model names
+    the histogram backend; the root span holds the phases the benchmark
+    reads."""
+    from flink_ml_tpu.models.classification import GBTClassifier
+    from flink_ml_tpu.obs.trace import tracer
+
+    X, y = _airline_like(n=3000)
+    table = Table({"features": X, "label": y})
+
+    def est():
+        return GBTClassifier().set_max_iter(3).set_max_depth(3)
+
+    first, reused_first = fit_noting_reuse(est(), table)
+    second, reused_second = fit_noting_reuse(est(), table)
+    assert (reused_first, reused_second) == (0, 1)
+    assert first.hist_impl == "segsum"
+    np.testing.assert_array_equal(second._forest.value, first._forest.value)
+    tracer.enable()
+    try:
+        est().fit(table)
+        names = {s.name for s in tracer.find()}
+        (arrange,) = [s for s in tracer.find("fit.arrange")]
+    finally:
+        tracer.disable()
+        tracer.clear()
+    assert {"fit", "fit.gather", "fit.arrange", "fit.arrange.bin",
+            "fit.upload", "iterate.dispatch", "fit.fetch"} <= names
+    assert (arrange.ids["hist_impl"], arrange.ids["trees"]) == ("segsum", 3)
+
+
+def test_regressor_fit_is_the_fused_squared_loss():
+    from flink_ml_tpu.models.common import gbt
+
+    table, X, y = _friedman(n=600, seed=4)
+    model = (GBTRegressor().set_max_iter(5).set_max_depth(3)
+             .set_learning_rate(0.3).fit(table))
+    forest, _ = gbt.train_forest(
+        X.astype(np.float32), y.astype(np.float32), "squared",
+        float(np.mean(y.astype(np.float32), dtype=np.float64)),
+        gbt.GBTConfig(num_trees=5, max_depth=3, learning_rate=0.3))
+    np.testing.assert_array_equal(model._forest.feature, forest.feature)
+    np.testing.assert_allclose(model._forest.value, forest.value, atol=1e-6)

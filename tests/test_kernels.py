@@ -241,8 +241,8 @@ def test_training_builders_resolve_the_same_registry_entries():
         is ell_scatter.ell_scatter_apply_xla_entry
     assert lookup("gbt_level_histograms", backend="xla").fn \
         is gbt._level_histograms_segsum
-    assert lookup("gbt_level_histograms", backend="mxu").fn \
-        is gbt._level_histograms_mxu
+    assert lookup("gbt_level_histograms", backend="pallas").fn \
+        is gbt._level_histograms_pallas
     assert lookup("routed_table_grad", backend="xla").fn \
         is emb_grad.routed_apply_xla
     # off TPU the automatic picks are the XLA lowerings (the fallback
@@ -307,12 +307,13 @@ def _parity_ell_scatter_apply(backends):
 def _parity_gbt_hist(backends):
     rng = np.random.default_rng(5)
     n, d, bins, nodes = 512, 6, 16, 4
-    binned = jnp.asarray(rng.integers(0, bins, size=(n, d)), jnp.int32)
+    cols = tuple(jnp.asarray(rng.integers(0, bins, size=n), jnp.int32)
+                 for _ in range(d))
     ids = jnp.asarray(rng.integers(-1, nodes, size=n), jnp.int32)
     g = jnp.asarray(rng.normal(size=n).astype(np.float32))
     h = jnp.asarray((rng.random(n) + 0.1).astype(np.float32))
     outs = {b: lookup("gbt_level_histograms", backend=b).fn(
-        binned, ids, g, h, nodes, d, bins) for b in backends}
+        cols, ids, g, h, nodes, d, bins) for b in backends}
     gr, hr = outs.pop("xla")
     for b, (gg, hh) in outs.items():
         np.testing.assert_allclose(np.asarray(gg), np.asarray(gr),

@@ -119,6 +119,32 @@ def test_put_sharded_in_pieces_rounds_and_views():
     assert pm.shard_devices(mesh) == [[d] for d in jax.devices()[:4]]
 
 
+def test_put_in_rounds_waits_between_rounds_under_the_cap(monkeypatch):
+    """Arrays of 40, 40, 40 and 100 bytes under a cap of 100: rounds of
+    two, one and one, each waited for before the next is put; every
+    array arrives whole on the device as an array of its own."""
+    waited = []
+
+    class Put:
+        def __init__(self, arr, device):
+            self.arr, self.device = arr, device
+
+        def block_until_ready(self):
+            waited.append(int(self.arr[0]))
+
+    monkeypatch.setattr(pm, "PUT_BYTES", 100)
+    monkeypatch.setattr(pm.jax, "device_put", Put)
+    arrays = [np.full(10, i, np.float32) for i in range(3)] + [
+        np.full(25, 3, np.float32)]
+    out = pm.put_in_rounds(arrays, "dev")
+    assert [p.arr is a for p, a in zip(out, arrays)] == [True] * 4
+    assert waited == [0, 1, 2] and {p.device for p in out} == {"dev"}
+    monkeypatch.undo()
+    (got,) = pm.put_in_rounds([arrays[3]], jax.devices()[1])
+    assert got.devices() == {jax.devices()[1]}
+    np.testing.assert_array_equal(np.asarray(got), arrays[3])
+
+
 def _grey_levels(rows: int, seed: int = 2147483659) -> np.ndarray:
     """Whole grey levels 0-255 of 784 pixels: the benchmark's generator
     under ``kmeans_mnist8m``'s own parameters."""

@@ -128,6 +128,15 @@ def _als_solve(rank, groups):
                                  Shape((rank, groups), F32))
 
 
+def _gbt_hist(n, d, bins, n_nodes):
+    from flink_ml_tpu.models.common.gbt import _level_histograms_pallas
+
+    return (partial(_level_histograms_pallas, n_nodes=n_nodes, d=d,
+                    bins=bins),
+            ([Shape((n,), I32)] * d, Shape((n,), I32), Shape((n,), F32),
+             Shape((n,), F32)))
+
+
 # the groups of a users' and of an items' block of ``als_netflix.fit``
 # (four blocks of 120,047 users, three of 17,770 items, each class
 # rounded up; PERF.md section 4), solved at the cell's rank 100
@@ -144,9 +153,10 @@ _CRITEO_ROWS, _CRITEO_UNIQUE = 33_762_577, 126_629
 # (d = 2^20, batch 32768) and the KMeans fit (n = 2^20, d = 64, k = 256)
 # of chip_smoke.py; "hibench" is the benchmark cell's d 20, k 10 at the
 # block of 32768 lanes its plan picks, rows contracted on lanes.
-# (routed_table_grad/pallas lowers too, but Mosaic refuses it on the
-# chip; gbt_level_histograms/mxu is plain XLA and misses its twin's
-# numbers there.  Both are forced-lookup only.)
+# "airline" is ``gbt_airline.fit``'s 115,069,017 rows (padded to the
+# kernel's blocks) of 13 features and 32 bins at a tree's first and last
+# level.  (routed_table_grad/pallas lowers too, but Mosaic refuses it on
+# the chip: forced-lookup only.)
 CASES = {
     ("ell_margin", "pallas"): {
         "smallest": lambda: _ell_margin(128, 64, False),
@@ -190,6 +200,12 @@ CASES = {
                                             _NETFLIX_BLOCKS[0]),
         "netflix-items": lambda: _als_solve(_NETFLIX_RANK,
                                             _NETFLIX_BLOCKS[1]),
+    },
+    ("gbt_level_histograms", "pallas"): {
+        "smallest": lambda: _gbt_hist(2048, 1, 2, 1),
+        "ragged": lambda: _gbt_hist(5000, 3, 8, 4),
+        "airline-root": lambda: _gbt_hist(115_081_216, 13, 32, 1),
+        "airline-depth-4": lambda: _gbt_hist(115_081_216, 13, 32, 16),
     },
     ("retrieve", "pallas"): {
         **{f"flat-rows{b}": partial(_retrieve, b, 128, 16, 128)
@@ -664,3 +680,99 @@ def test_als_fit_program_holds_one_block_of_normal_equations(
     assert sorted(copied) == sorted(blocks), copied
     assert temporaries <= 1.02 * twin_temporaries, (
         temporaries, twin_temporaries)
+
+
+def test_gbt_fit_program_keeps_the_table_as_it_was_put(one_v5e, monkeypatch):
+    """The fused program of ``GBTClassifier.fit`` at the benchmark cell
+    ``gbt_airline.fit``'s shapes (115,069,017 rows padded to the
+    histogram kernel's blocks, 13 int32 bin columns, 20 trees of depth 5
+    over 32 bins) compiled for a described v5e, the histograms as the
+    registry picks them on a TPU: one Pallas call a level, no copy of a
+    row-sized array, and temporaries of a few row vectors (no (rows x
+    features) array: ``segment_sum``'s three would take 18 GB), so that
+    the program and its arguments fit the chip with room."""
+    import re
+
+    from flink_ml_tpu.iteration import IterationConfig
+    from flink_ml_tpu.iteration.core import _scan_loop
+    from flink_ml_tpu.models.common import gbt
+    from flink_ml_tpu.ops.gbt_hist_pallas import padded_rows
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, trees = 115_069_017, 13, 20
+    rows = padded_rows(n)
+    config = gbt.GBTConfig(num_trees=trees, max_depth=5, max_bins=32,
+                           learning_rate=0.1, reg_lambda=1.0)
+    run = _scan_loop(gbt.boost_round(n, d, config, "logistic", "pallas"),
+                     IterationConfig(mode="fused", max_epochs=trees))
+
+    def arg(shape, dtype):
+        return Shape(shape, dtype, sharding=one_v5e)
+
+    nodes = (trees, 63)
+    compiled = run.lower(
+        (arg((rows,), F32), arg(nodes, I32), arg(nodes, I32),
+         arg(nodes, F32)),
+        (tuple(arg((rows,), I32) for _ in range(d)), arg((rows,), F32)),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert not re.search(r"\[%d\]\S* copy\(" % rows, text)
+    assert mem.temp_size_in_bytes < 5 * 4 * rows, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9
+
+
+@pytest.mark.parametrize("d,bins,n_nodes", [(13, 32, 256), (13, 256, 128),
+                                            (145, 32, 16)],
+                         ids=["depth-9", "bins-256-depth-8", "145-features"])
+def test_gbt_hist_compiles_at_the_widest_level_its_vmem_model_admits(
+        one_v5e, d, bins, n_nodes):
+    """``ops/gbt_hist_pallas.py: supported`` against the compiler: at 13
+    features the deepest level it admits over 32 and over 256 bins, and
+    near the most features it admits at a depth-5 tree's last level,
+    compile for a v5e; a level of twice the nodes is refused (the
+    registry then plans segment_sum)."""
+    from flink_ml_tpu.ops.gbt_hist_pallas import level_histograms, supported
+
+    assert supported((d, bins, n_nodes))
+    assert not supported((d, bins, 2 * n_nodes))
+    n = 1 << 16
+
+    def arg(dtype):
+        return Shape((n,), dtype, sharding=one_v5e)
+
+    compiled = jax.jit(partial(level_histograms, n_nodes=n_nodes, d=d,
+                               bins=bins)).lower(
+        [arg(I32)] * d, arg(I32), arg(F32), arg(F32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gbt_fit_deeper_than_the_kernel_holds_compiles_on_segsum(
+        one_v5e, monkeypatch):
+    """``maxDepth`` 12 over ``maxBins`` 256: the fit's widest level (2048
+    nodes) would need about 490 MB of VMEM, so on a TPU the registry
+    plans segment_sum for the fused fit, whose program compiles for a v5e;
+    the hosted trainers' levels take the kernel up to 128 nodes."""
+    from flink_ml_tpu.iteration import IterationConfig
+    from flink_ml_tpu.iteration.core import _scan_loop
+    from flink_ml_tpu.models.common import gbt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, depth, bins, trees = 4096, 13, 12, 256, 2
+    impl = gbt.resolve_hist_impl("auto", (d, bins, 2 ** (depth - 1)))
+    assert impl == "segsum"
+    assert gbt.resolve_hist_impl("auto", (d, bins, 128)) == "pallas"
+    assert gbt.resolve_hist_impl("auto", (d, bins, 256)) == "segsum"
+    config = gbt.GBTConfig(num_trees=trees, max_depth=depth, max_bins=bins)
+    run = _scan_loop(gbt.boost_round(n, d, config, "logistic", impl),
+                     IterationConfig(mode="fused", max_epochs=trees))
+
+    def arg(shape, dtype):
+        return Shape(shape, dtype, sharding=one_v5e)
+
+    nodes = (trees, 2 ** (depth + 1) - 1)
+    compiled = run.lower(
+        (arg((n,), F32), arg(nodes, I32), arg(nodes, I32), arg(nodes, F32)),
+        (tuple(arg((n,), I32) for _ in range(d)), arg((n,), F32)),
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

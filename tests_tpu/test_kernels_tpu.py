@@ -1,6 +1,6 @@
 """Mosaic compile + XLA-twin parity for every kernel the registry can
 select by itself on a TPU, plus the forced-lookup entries: the three
-int8 kernels, and the parked fold and GBT-MXU kernels as expected
+int8 kernels, and the parked fold kernel as expected
 failures that turn into errors the day they pass.  Most shapes are the
 smallest each kernel supports, so a failure there is a compiler/runtime
 break, never an OOM or capacity artifact; the
@@ -692,40 +692,33 @@ def test_als_fit_plans_grouped_on_device(tpu, rng):
                                    rtol=1e-3, atol=1e-4)
 
 
-@pytest.mark.parametrize("precision", [
-    pytest.param("default", marks=pytest.mark.xfail(
-        strict=True, raises=AssertionError,
-        reason="PARKED (models/common/gbt.py): at default MXU precision "
-               "every gradient/hessian is truncated to bf16 before it is "
-               "summed — measured on the chip (PR 21): max |diff| 0.0079 "
-               "on sums of ~3, where segment_sum is exact f32")),
-    "highest"])
-def test_gbt_mxu_hist_on_device(tpu, rng, precision):
-    """gbt_level_histograms/mxu (double one-hot matmuls, forced lookup
-    only) vs the segment_sum twin "auto" plans.  At the precision it runs
-    with today it must keep failing — the day it matches, un-park it; at
-    "highest" (what ROADMAP S3 would have to time) it matches."""
-    import jax
+@pytest.mark.parametrize("n,d,bins,n_nodes", [(256, 4, 16, 4),
+                                              (40_000, 13, 32, 16)])
+def test_gbt_mxu_hist_on_device(tpu, rng, n, d, bins, n_nodes):
+    """gbt_level_histograms/pallas (the exact one-hot contraction "auto"
+    plans on a TPU: gradients carried as three bfloat16 parts) against
+    the segment_sum twin, to float32 summation order.  The MXU form this
+    replaced summed bf16-truncated addends: max |diff| 0.0079 on sums of
+    ~3 on a v5e."""
     import jax.numpy as jnp
 
     from flink_ml_tpu.kernels.registry import lookup
     from flink_ml_tpu.models.common import gbt
 
-    assert gbt.resolve_hist_impl("auto") == "segsum"
-    n, d, bins, n_nodes = 256, 4, 16, 4
-    binned = jnp.asarray(rng.integers(0, bins, size=(n, d)), jnp.int32)
+    assert gbt.resolve_hist_impl("auto", (d, bins, n_nodes)) == "pallas"
+    cols = tuple(jnp.asarray(rng.integers(0, bins, size=n), jnp.int32)
+                 for _ in range(d))
     ids = jnp.asarray(rng.integers(-1, n_nodes, size=n), jnp.int32)
     g = jnp.asarray(rng.normal(size=n), jnp.float32)
     h = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
-    gs, hs = lookup("gbt_level_histograms").fn(binned, ids, g, h, n_nodes,
-                                               d, bins)
-    with jax.default_matmul_precision(precision):
-        gm, hm = lookup("gbt_level_histograms", backend="mxu").fn(
-            binned, ids, g, h, n_nodes, d, bins)
+    gs, hs = lookup("gbt_level_histograms", backend="xla").fn(
+        cols, ids, g, h, n_nodes, d, bins)
+    gm, hm = lookup("gbt_level_histograms").fn(cols, ids, g, h, n_nodes, d,
+                                               bins)
     np.testing.assert_allclose(np.asarray(gm), np.asarray(gs),
-                               rtol=1e-4, atol=1e-4)
+                               rtol=1e-5, atol=1e-4)
     np.testing.assert_allclose(np.asarray(hm), np.asarray(hs),
-                               rtol=1e-4, atol=1e-4)
+                               rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("emb", [8, 64])
