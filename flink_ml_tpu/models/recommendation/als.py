@@ -89,23 +89,41 @@ _BLOCK_A_SHARE = 1 / 8
 _BLOCK_ROWS_SHARE = 1 / 12
 
 
-#: host threads of a fit's index and plan: the sorts and searches over all
-#: the ratings release the GIL, and so does the native placement
+#: host threads of a fit's index and plan: the native passes and the
+#: sorts and searches over all the ratings release the GIL
 _HOST_THREADS = min(8, os.cpu_count() or 1)
+
+#: labels a column needs before it is indexed a part a thread
+_THREADED_LABELS = 1 << 20
 
 
 def _index_labels(labels: np.ndarray) -> tuple:
-    """``np.unique(labels, return_inverse=True)``, a part of the labels a
-    thread: each part's distinct labels, the distinct of those, and every
-    label's place among them by binary search."""
-    if len(labels) < (1 << 20) or _HOST_THREADS == 1:
-        return np.unique(labels, return_inverse=True)
+    """``(ids, index, native)``: ``np.unique(labels, return_inverse=True)``
+    and whether the native pass gave it.  A long integer column takes
+    ``als_index`` (``native/als_plan.cpp``: a hash of each thread's part,
+    only the distinct labels sorted); any other long column a part a
+    thread in NumPy (each part's distinct labels, the distinct of those,
+    every label's place among them by binary search); a short one
+    ``np.unique`` itself."""
+    if (len(labels) >= _THREADED_LABELS and labels.dtype.kind in "iu"
+            and np.can_cast(labels.dtype, np.int64)
+            and (lib := _native_plan()) is not None):
+        wide = np.ascontiguousarray(labels, np.int64)
+        index = np.empty(len(labels), np.int64)
+        # the front of a buffer of one a label: pages never written are
+        # never mapped
+        ids = np.empty(len(labels), np.int64)
+        m = lib.als_index(wide.ctypes.data, len(labels), ids.ctypes.data,
+                          index.ctypes.data, _HOST_THREADS)
+        return ids[:m].astype(labels.dtype), index, True
+    if len(labels) < _THREADED_LABELS or _HOST_THREADS == 1:
+        return (*np.unique(labels, return_inverse=True), False)
     parts = np.array_split(labels, _HOST_THREADS)
     with ThreadPoolExecutor(_HOST_THREADS) as pool:
         ids = np.unique(np.concatenate(list(pool.map(np.unique, parts))))
         index = np.concatenate(list(pool.map(
             lambda part: np.searchsorted(ids, part), parts)))
-    return ids, index
+    return ids, index, False
 
 
 def _round_up(n: int, to: int) -> int:
@@ -128,8 +146,9 @@ def _block_sizes(rank: int) -> tuple:
 @functools.lru_cache(maxsize=None)
 def _native_plan() -> Optional[ctypes.CDLL]:
     """``native/als_plan.cpp`` built and loaded, or ``None`` on a machine
-    with no ``make`` and no built library (the plan then places its
-    ratings in NumPy): ``load_native_lib``'s policy."""
+    with no ``make`` and no built library (the labels are then indexed
+    and the plan places its ratings in NumPy): ``load_native_lib``'s
+    policy."""
     from ...utils.native_lib import load_native_lib
 
     lib = load_native_lib("als_plan")
@@ -139,6 +158,8 @@ def _native_plan() -> Optional[ctypes.CDLL]:
                                   pointer, pointer, pointer,
                                   pointer, pointer, pointer, size]
         lib.als_place.restype = ctypes.c_int
+        lib.als_index.argtypes = [pointer, size, pointer, pointer, size]
+        lib.als_index.restype = size
     return lib
 
 
@@ -1011,9 +1032,10 @@ class ALS(ALSParams, Estimator[ALSModel]):
             if self.get_implicit_prefs() and np.any(ratings < 0):
                 raise ValueError("implicitPrefs expects non-negative "
                                  "ratings (interaction strengths)")
-            with tracer.span("fit.gather.index", "fit"):
-                user_ids, u_idx = _index_labels(users)
-                item_ids, i_idx = _index_labels(items)
+            with tracer.span("fit.gather.index", "fit") as span:
+                user_ids, u_idx, u_native = _index_labels(users)
+                item_ids, i_idx, i_native = _index_labels(items)
+                span.note(native=int(u_native and i_native))
         n_users, n_items = len(user_ids), len(item_ids)
         rank = self.get_rank()
 

@@ -606,15 +606,14 @@ def test_native_placement_refuses_what_the_lay_out_cannot_hold():
         als_mod.GroupedPlan(g, 3, 4, 8, 64).arrays(g[:3], g + 1.0)
 
 
-def _fit_and_its_plan_span(est, table):
-    """The notes of the fit's span ``fit.arrange.plan``, and the model's
-    columns."""
+def _fit_and_its_plan_span(est, table, name="fit.arrange.plan"):
+    """The notes of the fit's span ``name``, and the model's columns."""
     from flink_ml_tpu.obs.trace import tracer
 
     tracer.enable()
     try:
         model = est.fit(table)
-        (span,) = tracer.find("fit.arrange.plan")
+        (span,) = tracer.find(name)
     finally:
         tracer.disable()
         tracer.clear()
@@ -664,20 +663,98 @@ def test_fit_without_the_library_gives_the_same_model(monkeypatch):
         np.testing.assert_array_equal(value, numpy_[col])
 
 
-def test_index_labels_is_np_unique_with_inverse():
-    """The threaded index of a long label column (a part a thread, then
-    binary search) gives ``np.unique(..., return_inverse=True)``'s ids and
-    positions; a short column takes ``np.unique`` itself."""
-    from flink_ml_tpu.models.recommendation.als import _index_labels
-
+def _labels(case):
+    """A label column of the named kind."""
     rng = np.random.default_rng(5)
     pool = rng.integers(1, 1 << 40, size=5000)
-    for n in (1000, (1 << 20) + 77):
-        labels = pool[rng.integers(0, len(pool), size=n)]
-        ids, index = _index_labels(labels)
-        want_ids, want_index = np.unique(labels, return_inverse=True)
-        np.testing.assert_array_equal(ids, want_ids)
-        np.testing.assert_array_equal(index, want_index)
+    if case.startswith("pool"):
+        return pool[rng.integers(0, len(pool), size=int(case.split("_")[1]))]
+    n = (1 << 20) + 77
+    if case == "one_label":
+        return np.full(n, -3, np.int64)
+    if case == "all_distinct":
+        return rng.permutation(np.arange(n, dtype=np.int64) * 7919 - (1 << 40))
+    if case == "extremes":
+        info = np.iinfo(np.int64)
+        return np.array([info.min, info.max, 0, -1, -(1 << 40), 5])[
+            rng.integers(0, 6, size=n)]
+    if case == "int32":
+        return pool[rng.integers(0, len(pool), size=n)].astype(np.int32)
+    if case == "float":
+        return pool[rng.integers(0, len(pool), size=n)] / 3.0
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case,threads,native", [
+    ("pool_1000", 8, False),
+    (f"pool_{(1 << 20) - 1}", 8, False),
+    (f"pool_{1 << 20}", 8, True),
+    (f"pool_{(1 << 20) + 77}", 8, True),
+    ("one_label", 8, True),
+    ("all_distinct", 8, True),
+    ("extremes", 8, True),
+    ("int32", 8, True),
+    ("float", 8, False),
+    # parts of 149,808 rows, the seventh of 149,805
+    (f"pool_{(1 << 20) + 77}", 7, True),
+    (f"pool_{(1 << 20) + 77}", 1, True),
+])
+def test_index_labels_is_np_unique_with_inverse(monkeypatch, case, threads,
+                                                native):
+    """The index of a label column gives ``np.unique(...,
+    return_inverse=True)``'s ids and positions, in value and dtype: a
+    long integer column by the native pass (every int64 a label, the
+    extremes and 0 too; one label or all distinct; int32 widened into it
+    and kept), a long float column a part a thread in NumPy, a short column
+    ``np.unique`` itself; at 8 threads, at 7 (the last part shorter than
+    the others) and at 1."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    assert als_mod._native_plan() is not None
+    monkeypatch.setattr(als_mod, "_HOST_THREADS", threads)
+    labels = _labels(case)
+    ids, index, was_native = als_mod._index_labels(labels)
+    want_ids, want_index = np.unique(labels, return_inverse=True)
+    assert was_native == native
+    assert (ids.dtype, index.dtype) == (want_ids.dtype, want_index.dtype)
+    assert index.shape == want_index.shape
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(index, want_index)
+
+
+def test_fit_indexes_natively_and_says_so(monkeypatch):
+    """The span ``fit.gather.index`` notes ``native`` 1 where both columns
+    took the native pass and 0 where the machine has no library; the
+    grouped fit's plan arrays and its model are the same to the bit under
+    both forms (the columns count as long here)."""
+    from flink_ml_tpu.models.recommendation import als as als_mod
+
+    monkeypatch.setattr(als_mod, "_THREADED_LABELS", 1000)
+    planned = {}
+    planned_side = als_mod._planned_side
+
+    def recorded_side(*side):    # the two sides plan on threads of their own
+        planned[side[2]] = planned_side(*side)
+        return planned[side[2]]
+
+    monkeypatch.setattr(als_mod, "_planned_side", recorded_side)
+    table = _ratings_table(seed=48)
+    runs = []
+    for lib in (als_mod._native_plan(), None):
+        monkeypatch.setattr(als_mod, "_native_plan", lambda lib=lib: lib)
+        planned.clear()
+        ids, model = _fit_and_its_plan_span(_grouped_als(), table,
+                                            "fit.gather.index")
+        runs.append((ids["native"], jax.tree_util.tree_leaves(
+            [planned[n][1] for n in sorted(planned)]), model))
+    (native, native_arrays, native_model), (numpy_, numpy_arrays,
+                                            numpy_model) = runs
+    assert (native, numpy_) == (1, 0)
+    for a, b in zip(native_arrays, numpy_arrays, strict=True):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape)
+        np.testing.assert_array_equal(a, b)
+    for col, value in native_model.items():
+        np.testing.assert_array_equal(value, numpy_model[col])
 
 
 def _benchmark_module(kind, name):
