@@ -844,8 +844,10 @@ class FitPlan:
 
     def notes(self) -> dict:
         """What ``fit.arrange`` notes of the plan: which stats kernel the
-        fit took, its tiles, and the share of the MXU passes' operand
-        area that is padding at them."""
+        fit took, its tiles, the share of the MXU passes' operand area
+        that is padding at them, and how the kernel sums the rows:
+        ``"by_row"`` tiled over k, each row added to its winner's row of
+        the sums, or ``"onehot"`` feature-major, a contraction."""
         from ...ops import kmeans_pallas as kp
 
         if self.impl != "pallas":
@@ -853,7 +855,8 @@ class FitPlan:
         return {"stats_plan": ("k_tiled" if self.k_tile else "feature_major"),
                 "block_n": self.block_n, "k_tile": self.k_tile or self.k,
                 "mxu_padded_share": kp.mxu_padded_share(
-                    self.d, self.k, self.k_tile)}
+                    self.d, self.k, self.k_tile),
+                "sums": "by_row" if self.k_tile else "onehot"}
 
     def local_multiple(self, mesh) -> int:
         """Per-process padded-row multiple on ``mesh`` under this plan."""

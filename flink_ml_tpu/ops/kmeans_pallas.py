@@ -86,20 +86,30 @@ blocks against 12.  There the same call (``k_tile`` given:
 :func:`stats_tiles` decides from ``(d, k)`` alone) runs
 :func:`_ktiled_stats_kernel`: the centroids (as ``-2 C`` in bfloat16)
 and the float32 sums stay resident under a raised VMEM limit, and a
-block of rows meets them a tile of ``k_tile`` centroids at a time, twice:
-a scoring pass that carries every row's running minimum and the first
-index that reached it across the tiles, then a pass that adds each
-tile's one-hot contraction to that tile's rows of the sums.  Every row is
+block of rows meets the centroids a tile of ``k_tile`` at a time in a
+scoring pass that carries every row's running minimum and the first
+index that reached it across the tiles.  Then each bfloat16-rounded
+row of the block is added in float32 to its winner's row of the sums,
+the count riding in lane ``d``.  Every row is
 scored against every centroid; the points are read from HBM once an
 iteration.  The block's orientation follows the layout the chip keeps the
 points in, which follows from ``d`` (:func:`_update_stats_ktiled`).
 Measured on one v5e, the kernel alone in a jitted scan that feeds the
 centroids back (my chip runs, PR 35): 2^18 x 784 grey-level images, k 4096,
-tiles (512, 512): 20.98 ms an iteration, 80.2 TFLOP/s of the scoring
-contraction's 2 n k d alone and as much again for the sums, with 784 on 896
-lanes 93% of the bf16 peak; counts and sums equal to float64's under the
-first-index rule, to the unit; the XLA expansion of the same step, which
-the compiler fuses without ever holding an ``(n, k)`` array, 25.21 ms.
+tiles (512, 512), the sums then a second, one-hot contraction: 20.98 ms
+an iteration, 80.2 TFLOP/s of the scoring contraction's 2 n k d alone and
+as much again for the sums, with 784 on 896 lanes 93% of the bf16 peak;
+counts and sums equal to float64's under the first-index rule, to the
+unit; the XLA expansion of the same step, which the compiler fuses
+without ever holding an ``(n, k)`` array, 25.21 ms.  The sums by row take
+that iteration to 12.58 ms (my chip runs, PR 43, one v5e, 20.94 by
+one-hot in the same process), sums and counts equal to the one-hot's bit
+for bit: on whole grey levels both forms are exact while a cluster's sum
+stays under 2^24.  The row form costs a read-modify-write of
+ceil((d+1)/128) lane tiles a row whatever k is, the one-hot ``k_pad`` x
+ceil(d/128)*128 MXU products a row: by row wins from a padded k of about
+800 on (at d 784), and loses 7-10% of the kernel's time at a padded k
+of 512 (k 512 at d 784 and at d 1536, where no benchmark cell runs).
 
 Design notes:
 
@@ -198,19 +208,23 @@ def _stats_tile_bytes(d: int, k: int, block_n: int,
     of ``block_n`` rows, reckoned on ceil(d/128)*128 lanes (row-major; the
     feature-major block pads less), two of it, and its bfloat16 copy;
     resident for the whole call, two buffers each, the bfloat16 centroids
-    and the float32 sums ``(k, d)`` with k rounded up to the tile, and
-    ``c2`` and the counts ``(k, 1)`` on 128 lanes; a score tile and a
-    one-hot ``(k_tile, block_n)`` and one contraction's result
-    ``(k_tile, d)``, float32.  58 MB at d 784, k 4096 and tiles of 512,
-    which Mosaic compiles under the limit this number sets."""
+    ``(k, d)`` with k rounded up to the tile and ``c2`` ``(k, 1)`` on 128
+    lanes; a float32 score tile ``(k_tile, block_n)``; the two float32
+    sums ``(k, d + 1)`` on ceil((d+1)/128)*128 lanes (one buffer each:
+    the result leaves by DMA) and the block's rows on as many lanes three
+    times (the block widened by the count's lanes, its transposition, the
+    scratch that holds it).  57 MiB at d 784, k 4096 and tiles of 512,
+    which Mosaic compiles under the limit this number sets.  It counts
+    high: compiled for a described v5e, the kernel asks 36.7 MiB of
+    scoped VMEM there (a resident input is held once) and is refused a
+    limit of 36.6; 32.5 at d 128, k 16384, where this says 59.1."""
     if k_tile is None:
         d8, k8 = _up(d, 8), _up(k, 8)
         return ((2 * d8 * 4 + k8 * 2) * block_n
                 + 4 * k8 * (_up(d, 128) + 128) * 4)
-    dp, kp = _up(d, 128), _up(k, k_tile)
-    return ((2 * 4 + 2) * block_n * dp
-            + 2 * kp * (dp * (2 + 4) + 2 * 128 * 4)
-            + k_tile * (2 * block_n + dp) * 4)
+    dp, kp, width = _up(d, 128), _up(k, k_tile), _up(d + 1, 128)
+    return ((2 * 4 + 2) * block_n * dp + 2 * kp * (dp * 2 + 128 * 4)
+            + k_tile * block_n * 4 + (2 * kp + 3 * block_n) * width * 4)
 
 
 def supported(d: int, k: int, block_n: int = 8192,
@@ -225,9 +239,10 @@ def supported(d: int, k: int, block_n: int = 8192,
 #: tiles hardly matter once both are a few MXU passes wide: at 2^18 x 784,
 #: k 4096 an iteration takes 20.98 ms at (512, 512), 20.68 at (1024, 512),
 #: 20.53 at (512, 1024), 20.47 at (1024, 1024), 20.24 at (2048, 512) and
-#: 21.92 at (256, 512) (my chip runs, PR 35, one v5e: the MXU passes of
-#: both contractions, padding included, at 93% of the bf16 peak), so the
-#: first pick is the one that holds least
+#: 21.92 at (256, 512) (my chip runs, PR 35, one v5e, the sums then a
+#: one-hot contraction: the MXU passes of both contractions, padding
+#: included, at 93% of the bf16 peak), so the first pick is the one that
+#: holds least
 _KTILED_TILES = ((512, 512), (512, 256), (256, 256), (256, 128), (128, 128))
 
 
@@ -246,10 +261,11 @@ def stats_tiles(d: int, k: int) -> Optional[Tuple[int, Optional[int]]]:
 
 
 def mxu_padded_share(d: int, k: int, k_tile: Optional[int]) -> float:
-    """The share of both contractions' centroid-shaped operand that is
+    """The share of the contractions' centroid-shaped operand that is
     padding as the kernel lays it out: ``(k, d)`` on ceil(k/8)*8 x
-    ceil(d/8)*8 feature-major, on whole k tiles x ceil(d/128)*128 lanes
-    tiled over k.  The MXU passes over the padding as over data."""
+    ceil(d/8)*8 feature-major (the scoring and the one-hot contraction),
+    on whole k tiles x ceil(d/128)*128 lanes tiled over k (the scoring
+    contraction alone).  The MXU passes over the padding as over data."""
     kp, dp = ((_up(k, 8), _up(d, 8)) if k_tile is None
               else (_up(k, k_tile), _up(d, 128)))
     return 1.0 - (k * d) / (kp * dp)
@@ -439,10 +455,9 @@ def _ktiled_stats_kernel(k_tile: int, n_tiles: int, rows_on_lanes: bool):
     with k on sublanes and the block's rows on lanes, as in the kernel
     with all of k resident; the points block is ``(d, block_n)`` of
     ``points.T`` (``rows_on_lanes``) or ``(block_n, d)`` of ``points``,
-    whichever the chip holds (:func:`_update_stats_ktiled`), and the two
-    contractions swap their forms with it: ``C_j Xt`` / ``onehot_j . Xt``
-    over the lanes of both, or ``C_j . X`` over the lanes of both /
-    ``onehot_j X``.  Nothing is transposed in VMEM either way.
+    whichever the chip holds (:func:`_update_stats_ktiled`), and the
+    scoring contraction swaps its form with it: ``C_j Xt``, or ``C_j . X``
+    over the lanes of both.
 
     Pass 1 carries, for every row, the minimum so far and where it was
     first seen, as ``(8, block_n)`` vregs: slot ``s`` of a lane holds the
@@ -451,37 +466,32 @@ def _ktiled_stats_kernel(k_tile: int, n_tiles: int, rows_on_lanes: bool):
     too), so a tile costs three VPU passes over its scores and no
     reduction; the eight slots meet once a block: the minimum over
     sublanes, then the smallest centroid index among the slots that hold
-    it: the first index of the whole row.  Pass 2 forms each tile's
-    one-hot from that index and adds its contraction with the block to
-    the tile's rows of the resident sums."""
+    it: the first index of the whole row.
+
+    Pass 2: the indices go to SMEM by a local DMA, the block's
+    bfloat16-rounded rows are laid one to a sublane (``rows_on_lanes``:
+    one transposition on the XLU) with 1.0 in the lanes past ``d``, and
+    each row is added, in float32, to its winner's row of a resident
+    ``(k, d + 1)`` sum whose lane ``d`` is the count.  Rows alternate
+    between two such sums, folded once at the last block, so that a row's
+    read of the sums does not wait on the store of the row before it; the
+    folded sum goes to HBM by one DMA."""
     over_lanes = (((1,), (1,)), ((), ()))
     groups = k_tile // 8
 
-    def contract(lhs, rhs, lanes_of_both: bool):
-        if lanes_of_both:
-            return jax.lax.dot_general(lhs, rhs, over_lanes,
-                                       preferred_element_type=jnp.float32)
-        return jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
+    def score(cb, xb):
+        if rows_on_lanes:
+            return jnp.dot(cb, xb, preferred_element_type=jnp.float32)
+        return jax.lax.dot_general(cb, xb, over_lanes,
+                                   preferred_element_type=jnp.float32)
 
-    def kern(x_ref, cb_ref, c2_ref, sums_ref, counts_ref):
-        block_n = x_ref.shape[1 if rows_on_lanes else 0]
-
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            sums_ref[:] = jnp.zeros_like(sums_ref)
-            counts_ref[:] = jnp.zeros_like(counts_ref)
-
-        xb = x_ref[:].astype(jnp.bfloat16)
-
-        def tile(j):
-            return pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
-
-        def score(j, carry):
+    def first_index(xb, cb_ref, c2_ref, block_n):
+        def best_of_tile(j, carry):
             best, seen = carry
+            tile = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
             # cb holds -2 C rounded to bfloat16: c2 - 2 C.x, float32
-            scores = (contract(cb_ref[tile(j), :], xb, not rows_on_lanes)
-                      + c2_ref[tile(j), :])               # (k_tile, bn)
-            for g in range(groups):
+            scores = score(cb_ref[tile, :], xb) + c2_ref[tile, :]
+            for g in range(groups):                       # (k_tile, bn)
                 part = scores[8 * g:8 * (g + 1), :]
                 less = part < best
                 best = jnp.where(less, part, best)
@@ -489,25 +499,55 @@ def _ktiled_stats_kernel(k_tile: int, n_tiles: int, rows_on_lanes: bool):
             return best, seen
 
         best, seen = jax.lax.fori_loop(
-            0, n_tiles, score,
+            0, n_tiles, best_of_tile,
             (jnp.full((8, block_n), jnp.inf, jnp.float32),
              jnp.zeros((8, block_n), jnp.int32)))
         index = 8 * seen + jax.lax.broadcasted_iota(jnp.int32, seen.shape, 0)
         lowest = jnp.min(best, axis=0, keepdims=True)
-        first = jnp.min(jnp.where(best == lowest, index,
-                                  jnp.iinfo(jnp.int32).max),
-                        axis=0, keepdims=True)            # (1, bn)
+        return jnp.min(jnp.where(best == lowest, index,
+                                 jnp.iinfo(jnp.int32).max),
+                       axis=0, keepdims=True)             # (1, bn)
 
-        def accumulate(j, carry):
-            ids = j * k_tile + jax.lax.broadcasted_iota(
-                jnp.int32, (k_tile, block_n), 0)
-            onehot = (ids == first).astype(jnp.float32)
-            sums_ref[tile(j), :] += contract(
-                onehot.astype(jnp.bfloat16), xb, rows_on_lanes)
-            counts_ref[tile(j), :] += jnp.sum(onehot, axis=1, keepdims=True)
+    def kern(x_ref, cb_ref, c2_ref, out_ref, sums_ref, other_ref, rows_ref,
+             first_vmem, first_smem, sem):
+        block_n, width = rows_ref.shape
+        d = x_ref.shape[0 if rows_on_lanes else 1]
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            sums_ref[:] = jnp.zeros_like(sums_ref)
+            other_ref[:] = jnp.zeros_like(other_ref)
+
+        xb = x_ref[:].astype(jnp.bfloat16)
+        first_vmem[:] = first_index(xb, cb_ref, c2_ref, block_n)
+        to_smem = pltpu.make_async_copy(first_vmem, first_smem, sem)
+        to_smem.start()
+        if rows_on_lanes:
+            rows_ref[:] = jnp.concatenate(
+                [xb.astype(jnp.float32),
+                 jnp.ones((width - d, block_n), jnp.float32)], axis=0).T
+        else:
+            rows_ref[:] = jnp.concatenate(
+                [xb.astype(jnp.float32),
+                 jnp.ones((block_n, width - d), jnp.float32)], axis=1)
+        to_smem.wait()
+
+        def add(q, carry):
+            for u in range(8):
+                r = q * 8 + u
+                sums = other_ref if u % 2 else sums_ref
+                winner = first_smem[0, r]
+                sums[pl.ds(winner, 1), :] += rows_ref[pl.ds(r, 1), :]
             return carry
 
-        jax.lax.fori_loop(0, n_tiles, accumulate, None)
+        jax.lax.fori_loop(0, block_n // 8, add, None)
+
+        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+        def _out():
+            sums_ref[:] += other_ref[:]
+            to_hbm = pltpu.make_async_copy(sums_ref, out_ref, sem)
+            to_hbm.start()
+            to_hbm.wait()
 
     return kern
 
@@ -524,7 +564,8 @@ def _update_stats_ktiled(points, centroids, block_n: int, k_tile: int,
     XLA copy the points (7.26 GB beside 6.35 at 2,025,000 x 784).  The
     centroids go in as ``-2 C`` rounded to bfloat16 on whole tiles (zero
     rows, whose ``c2`` is infinite, so that no row picks one); the sums
-    come back on the same padded shape and are cut to ``k`` rows."""
+    come back on the same padded k as one ``(k, d + 1)`` array on whole
+    lane tiles whose column ``d`` is the counts, cut to ``k`` rows."""
     n, d = points.shape
     k = centroids.shape[0]
     k_pad = _up(k, k_tile)
@@ -543,22 +584,26 @@ def _update_stats_ktiled(points, centroids, block_n: int, k_tile: int,
     else:
         x, x_spec = points, pl.BlockSpec(
             (block_n, d), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    sums, counts = pl.pallas_call(
+    width = _up(d + 1, 128)
+    out = pl.pallas_call(
         _ktiled_stats_kernel(k_tile, k_pad // k_tile, rows_on_lanes),
         grid=(n // block_n,),
         in_specs=[x_spec, resident((k_pad, d)), resident((k_pad, 1))],
-        out_specs=[resident((k_pad, d)), resident((k_pad, 1))],
-        out_shape=[
-            jax.ShapeDtypeStruct((k_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((k_pad, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((k_pad, width), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k_pad, width), jnp.float32),
+                        pltpu.VMEM((k_pad, width), jnp.float32),
+                        pltpu.VMEM((block_n, width), jnp.float32),
+                        pltpu.VMEM((1, block_n), jnp.int32),
+                        pltpu.SMEM((1, block_n), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=(_stats_tile_bytes(d, k, block_n, k_tile)
                               + _KTILED_VMEM_HEADROOM)),
         interpret=interpret,
     )(x, cb, c2)
-    return sums[:k], counts[:k, 0]
+    return out[:k, :d], out[:k, d]
 
 
 def _assign_kernel(points_ref, cent_ref, c2_ref,
@@ -609,8 +654,8 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     interpreter takes any).  ``(block_n, k_tile)`` come from
     :func:`stats_tiles`: ``k_tile`` None is the feature-major kernel with
     all of ``k`` resident, a number the kernel tiled over k, which knows
-    the ``"first"`` policy alone and rounds both contractions' operands
-    to bfloat16 itself (``compute_dtype`` does not apply).
+    the ``"first"`` policy alone and rounds the points and centroids to
+    bfloat16 itself (``compute_dtype`` does not apply).
     """
     if tie_policy not in ("first", "fast", "split"):
         raise ValueError(f"tie_policy must be 'first', 'fast' or 'split', "

@@ -388,10 +388,17 @@ def test_plan_tiles_over_k_where_k_does_not_fit_vmem(monkeypatch):
             plan.fill) == ("pallas", 512, 512, 512, "zero")
     assert plan.notes() == {"stats_plan": "k_tiled", "block_n": 512,
                             "k_tile": 512,
-                            "mxu_padded_share": 1.0 - 784 / 896}
+                            "mxu_padded_share": 1.0 - 784 / 896,
+                            "sums": "by_row"}
     hibench = km._fit_plan(20_000_000, 20, 10, euclid, mesh)
     assert hibench == km.FitPlan("pallas", 32768, 32768, "zero", 10, 20)
     assert hibench.notes()["stats_plan"] == "feature_major"
+    assert hibench.notes()["sums"] == "onehot"
+    # a small k too wide for the feature-major tile sums by row as well
+    wide = km._fit_plan(2_025_000, 1536, 512, euclid, mesh)
+    assert (wide.k_tile, wide.notes()["sums"]) == (512, "by_row")
+    assert "sums" not in km._fit_plan(
+        2_025_000, 784, 4096, euclid, mesh, tie_policy="split").notes()
     assert km._fit_plan(2_025_000, 784, 4096, euclid, mesh,
                         tie_policy="split").impl == "xla"
     assert km._fit_plan(20_000_000, 20, 10, euclid, mesh,

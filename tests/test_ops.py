@@ -300,7 +300,9 @@ def _lloyd_stats(pts, cents, n_pad):
     (40, 24, 128, 8),       # one group of eight a tile
     (256, 70, 128, 32),     # whole lane tiles: row-major blocks
     (128, 40, 256, 128),    # row-major, one tile wider than k
-], ids=["d200-k70", "d784-k100", "d40-k24", "d256-k70", "d128-k40"])
+    (40, 1030, 512, 512),   # three tiles, the last of six centroids
+], ids=["d200-k70", "d784-k100", "d40-k24", "d256-k70", "d128-k40",
+        "d40-k1030"])
 def test_ktiled_stats_match_plain_lloyd(d, k, block_n, k_tile):
     """Tiles that do not divide k, a width off the lane tile and on it,
     exact ties inside a tile and across tiles, an empty cluster and zero
@@ -324,7 +326,8 @@ def test_ktiled_stats_match_plain_lloyd(d, k, block_n, k_tile):
 
 def test_ktiled_stats_take_a_tie_across_tiles_to_the_first_index():
     """Every centroid the same row: all of k ties for every point, over
-    all the tiles, and centroid 0 takes everything."""
+    all the tiles, and centroid 0 takes everything (every row of every
+    block adds to the same row of the sums)."""
     rng = np.random.default_rng(3)
     pts = rng.integers(0, 256, size=(256, 24)).astype(np.float32)
     cents = np.repeat(pts[:1], 40, axis=0)
@@ -333,6 +336,43 @@ def test_ktiled_stats_take_a_tie_across_tiles_to_the_first_index():
         tie_policy="first", interpret=True)
     assert np.asarray(counts).tolist() == [256.0] + [0.0] * 39
     np.testing.assert_array_equal(np.asarray(sums)[0], pts.sum(0))
+
+
+@pytest.mark.parametrize("d,winners", [
+    (200, "one"),     # rows on lanes: every row of a block, one winner
+    (128, "ends"),    # row-major: a block's rows to centroids 0 and k - 1
+], ids=["one-winner", "both-ends"])
+def test_ktiled_stats_by_row_chains_and_ends(d, winners):
+    """Every row of a block adds to one row of the sums, one after the
+    other (the read-modify-write chain: a lost update shows in the count),
+    or a block's rows alternate between the first and the last centroid,
+    on the first and the last tile; counts to the unit, sums exact."""
+    k, block_n, k_tile, n, n_pad = 70, 128, 16, 384, 5
+    rng = np.random.default_rng(d)
+    cents = np.full((k, d), 4096.0, np.float32)
+    pts = rng.integers(0, 256, size=(n, d)).astype(np.float32)
+    if winners == "one":
+        ends = [k_tile + 3]
+        cents[ends[0]] = 128.0
+    else:
+        ends = [0, k - 1]
+        cents[0], cents[k - 1] = 60.0, 200.0
+        pts = np.clip(np.where(np.arange(n)[:, None] % 2, 200.0, 60.0)
+                      + rng.integers(-40, 41, size=(n, d)), 0, 255
+                      ).astype(np.float32)
+    pts[n - n_pad:] = 0.0
+    assign, exp_sums, exp_counts = _lloyd_stats(pts, cents, n_pad)
+    assert set(assign.tolist()) == set(ends)
+    if winners == "ends":
+        assert (assign[::2] == 0).all() and (assign[1::2] == k - 1).all()
+    sums, counts = kmeans_update_stats(
+        jnp.asarray(pts), jnp.asarray(cents), block_n=block_n,
+        k_tile=k_tile, tie_policy="first", interpret=True)
+    counts = pad_correction(counts, jnp.asarray(cents), n_pad,
+                            tie_policy="first")
+    np.testing.assert_array_equal(np.asarray(counts), exp_counts)
+    np.testing.assert_array_equal(np.asarray(sums), exp_sums)
+    assert np.asarray(counts)[ends].sum() == n - n_pad
 
 
 def test_ktiled_stats_know_the_first_policy_alone():

@@ -335,19 +335,28 @@ def test_kmeans_stats_compiles_at_the_block_the_model_picks(one_v5e, d, k):
         Shape((k, d), F32, sharding=one_v5e)).compile()
 
 
-@pytest.mark.parametrize("d,k", [(784, 4096), (128, 16384), (200, 5000)],
-                         ids=["mnist8m", "row-major-16k", "ragged"])
-def test_kmeans_stats_tiled_over_k_compile_alone(one_v5e, d, k):
+@pytest.mark.parametrize("d,k", [(784, 4096), (128, 16384), (200, 5000),
+                                 (1536, 512)],
+                         ids=["mnist8m", "row-major-16k", "ragged",
+                              "wide-512"])
+def test_kmeans_stats_tiled_over_k_compile_alone(one_v5e, d, k,
+                                                 monkeypatch):
     """The kernel tiled over k, ALONE, at the tiles its VMEM model picks:
     ``kmeans_mnist8m``'s shapes (rows on lanes: ``points.T`` is the
     column-major array the chip keeps a row of 784 floats as), 16 K
-    centroids of whole lane tiles (row-major blocks), and a k and a d
-    that divide by neither tile.  Mosaic must take the resident ``(k, d)``
-    blocks under the raised VMEM limit, the dynamic tile slices and the
-    contraction over 784; and no operand is copied on the way in."""
+    centroids of whole lane tiles (row-major blocks), a k and a d that
+    divide by neither tile, and one tile of 512 centroids 1536 wide (too
+    wide for the feature-major tile).  Mosaic must take the resident
+    ``(k, d)`` blocks and the two ``(k, d + 1)`` sums under the VMEM limit
+    its model sets, here with no headroom added, the dynamic tile slices,
+    the contraction over 784, the block's transposition on the XLU, the
+    indices' DMA to SMEM and the rows' dynamic sublane reads and writes;
+    and no operand is copied on the way in."""
+    from flink_ml_tpu.ops import kmeans_pallas
     from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
                                                 stats_tiles)
 
+    monkeypatch.setattr(kmeans_pallas, "_KTILED_VMEM_HEADROOM", 0)
     block_n, k_tile = stats_tiles(d, k)
     assert k_tile is not None
     compiled = jax.jit(partial(kmeans_update_stats, block_n=block_n,
@@ -388,7 +397,7 @@ def test_sharded_kmeans_fit_program_compiles_for_four_chips(v5e_2x2):
     over k inside ``shard_map`` and the ``psum`` of the sums and counts
     in the loop.  A chip keeps no copy of its 6.35 GB of points (the
     program's temporaries stay under 64 MB a device), the kernel is there
-    with its 58 MB of VMEM under the raised limit, and the sums and counts
+    with its 57 MiB of VMEM under the raised limit, and the sums and counts
     meet in ONE all-reduce under the scope ``kmeans.reduce``."""
     import re
 
@@ -417,7 +426,7 @@ def test_sharded_kmeans_fit_program_compiles_for_four_chips(v5e_2x2):
     assert memory.argument_size_in_bytes < (n // 4) * (d + 1) * 4 + (32 << 20)
     # the kernel's blocks as its VMEM model counts them (Mosaic took them
     # under the limit the call raises: a compile over it is refused)
-    assert round(_stats_tile_bytes(d, k, block_n, k_tile) / 2 ** 20) == 58
+    assert round(_stats_tile_bytes(d, k, block_n, k_tile) / 2 ** 20) == 57
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     reduces = [line for line in text.splitlines()

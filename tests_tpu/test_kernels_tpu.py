@@ -243,14 +243,17 @@ def _grey_images(rng, n, dcol, k):
 
 @pytest.mark.parametrize("n,dcol,k", [(1 << 17, 784, 4096),
                                       (1 << 15, 128, 16384),
-                                      (20_000, 200, 5000)],
-                         ids=["mnist8m", "row-major-16k", "ragged"])
+                                      (20_000, 200, 5000),
+                                      (1 << 15, 1536, 512)],
+                         ids=["mnist8m", "row-major-16k", "ragged",
+                              "wide-512"])
 def test_kmeans_kernel_tiled_over_k_parity(tpu, rng, n, dcol, k):
     """kmeans_update_stats tiled over k, at the tiles the plan picks,
     against float64 sums of the bfloat16-rounded points under the
     first-index assignment: ``kmeans_mnist8m``'s shapes (rows on lanes),
-    16 K centroids of whole lane tiles (row-major blocks), and a k, a d
-    and an n that divide by nothing.  The points are whole grey levels,
+    16 K centroids of whole lane tiles (row-major blocks), a k, a d and
+    an n that divide by nothing, and one tile of 512 centroids 1536 wide
+    (rows on lanes, too wide for the feature-major tile).  The points are whole grey levels,
     so the sums are exact; a row whose two best scores lie within what
     float32 accumulation can move (8 of about 3e6) may go either way, and
     only the clusters such a row could touch are let off."""
