@@ -450,7 +450,7 @@ def kmeans_tiled_over_k(ctx) -> dict:
         tiles = (128, 16)
     sums, counts = kmeans_update_stats(
         jnp.asarray(points), jnp.asarray(cents), block_n=tiles[0],
-        k_tile=tiles[1], tie_policy="first", interpret=not ctx["chip"])
+        k_tile=tiles[1], interpret=not ctx["chip"])
     sums, counts = np.asarray(sums, np.float64), np.asarray(counts)
     rounded = np.asarray(jnp.asarray(cents).astype(jnp.bfloat16)
                          .astype(jnp.float32), np.float64)

@@ -79,30 +79,9 @@ class KMeansModelParams(HasDistanceMeasure, HasFeaturesCol, HasPredictionCol):
 
 class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
     """``KMeansParams.java``: adds K (>= 2) and the training-only params.
-
-    ``tiePolicy`` (beyond-reference, TPU-specific) picks the Pallas fit
-    kernel's handling of EXACTLY-tied point-to-centroid distances:
-
-    - ``"first"`` (default): first-index argmin — EXACTLY the
-      reference's and the XLA body's single-assignment Lloyd's
-      semantics, ties included, computed without Mosaic's slow argmin
-      loop (smallest tied column index via where/min/compare — cheaper
-      than "split"'s division).
-    - ``"split"``: fractional assignment across the tied minimisers
-      (exact expected-assignment semantics: total cluster mass always
-      sums to n).
-    - ``"fast"`` (opt-in via ``setTiePolicy``; ``kmeans_hibench.fit``
-      measures what ``fit`` plans, i.e. the "first" default): a tied point
-      counts toward EVERY minimizing centroid — its mass is
-      double-counted, biasing the tied centroids' means toward it.  On
-      continuous features exact f32 ties are measure-zero, so this is
-      free; on DISCRETE/quantized features (integer grids, one-hot),
-      distinct equidistant centroids are common and "fast" measurably
-      changes the fit.  Its time against "split" and "first" is not
-      measured on the chip.
-
-    The XLA fallback path (non-TPU, small n, non-euclidean) always uses
-    first-index argmin and ignores this param."""
+    Every fit path assigns a point to the first centroid of least
+    distance (first-index argmin), ties included: the reference's
+    single-assignment Lloyd's semantics."""
 
     K = IntParam("k", "Number of clusters.", default=2,
                  validator=ParamValidators.gt_eq(2))
@@ -113,12 +92,6 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
         "fused device program).",
         default="random",
         validator=ParamValidators.in_array(["random", "k-means++"]))
-    TIE_POLICY = StringParam(
-        "tiePolicy",
-        "Pallas-kernel handling of exactly-tied distances: 'first' "
-        "(reference argmin semantics), 'fast', or 'split'.",
-        default="first",
-        validator=ParamValidators.in_array(["first", "fast", "split"]))
     WORKSET = BoolParam(
         "workset",
         "Delta/workset iteration mode: thread Hamerly center-movement "
@@ -129,7 +102,7 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
         "program still evaluates dense shapes, so the wall-clock win "
         "today is the early exit).  Off TPU the body is XLA — final "
         "centroids bit-identical to the XLA BSP fit (first-index "
-        "argmin; tiePolicy does not apply).  On TPU the registry plans "
+        "argmin).  On TPU the registry plans "
         "the fused scoring+stats kernel (op kmeans_workset_update) "
         "above the Pallas row threshold: same assignments, stats equal "
         "to f32 summation order.  The fit records a per-round "
@@ -148,12 +121,6 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
     def set_k(self, value: int):
         return self.set(KMeansParams.K, value)
 
-    def get_tie_policy(self) -> str:
-        return self.get(KMeansParams.TIE_POLICY)
-
-    def set_tie_policy(self, value: str):
-        return self.set(KMeansParams.TIE_POLICY, value)
-
     def get_init_mode(self) -> str:
         return self.get(KMeansParams.INIT_MODE)
 
@@ -164,10 +131,9 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
 def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
                 fill: str = "first_row",
                 cross_host_checked: bool = False) -> tuple:
-    """The host half of host -> device on a mesh of SEVERAL PROCESSES (the
-    only one left since PR 39; a one-process mesh pads on its devices,
-    :func:`_put_and_lay_out` and :func:`_put_and_lay_out_sharded`, and the
-    tests keep this as the statement of what they must make): pad rows to
+    """The host half of host -> device on a mesh of SEVERAL PROCESSES (a
+    one-process mesh pads on its devices, :func:`_put_and_lay_out`, and
+    the tests keep this as the statement of what it must make): pad rows to
     a multiple of the data-axis size (and of ``row_multiple`` per shard;
     mask marks real rows).  The caller shards the batch dim of both
     (``put_sharded(.., P("data"))``).  With a remainder this is a whole
@@ -193,40 +159,19 @@ def _pad_points(points: np.ndarray, mesh, row_multiple: int = 1,
     return padded, mask
 
 
-#: Rows ``_rows_on_device`` gives their layout at a time: a reshape to
-#: rows of ``d`` < 128 floats is lane-padded to 512 B a row on the chip, so
-#: done whole it would reserve 10 GB beside 20 M rows; done this many at a
-#: time the padded piece stays out of HBM (compiled for a v5e: no
-#: temporary at all).
+#: Rows ``_placed`` gives their layout at a time: a reshape to rows of
+#: ``d`` < 128 floats is lane-padded to 512 B a row on the chip, so done
+#: whole it would reserve 10 GB beside 20 M rows; done this many at a time
+#: the padded piece stays out of HBM (compiled for a v5e: no temporary at
+#: all).
 _RELAYOUT_ROWS = 1 << 16
-
-
-@lru_cache(maxsize=None)
-def _rows_on_device(shape: tuple, pad: int, fill: str, sharding):
-    """The jitted program that makes ``pad_rows_with_mask``'s two arrays
-    where the rows already are.  Its argument is the C-order buffer of
-    the ``shape`` = (n, d) rows as it was put: one dimension, so the
-    runtime transposes nothing on the host on the way (the chip keeps rows
-    whose width is no multiple of 128 column-major, and a put of the 2-D
-    array makes that layout tile by tile on the host's threads).  It gives
-    the rows in that layout, ``_RELAYOUT_ROWS`` at a time (the last piece
-    overlaps the one before it, so that every piece has one shape), with
-    ``pad`` rows of ``fill`` after the last one, and the float32 mask of
-    the real rows, both under ``sharding``.  One program per shapes, kept
-    for the process (as ``_predict``'s jit keeps its own): a refit neither
-    traces nor compiles."""
-    n, d = shape
-
-    def rows_and_mask(flat):
-        points = _placed(jnp.zeros((n + pad, d), flat.dtype), flat, 0, n)
-        return _filled(points, n, pad, fill), _row_mask(n, pad)
-
-    return jax.jit(rows_and_mask, out_shardings=(sharding, sharding))
 
 
 def _placed(points, flat, first, rows: int):
     """``points`` with the ``rows`` rows of the C-order buffer ``flat`` at
-    row ``first``, given their layout ``_RELAYOUT_ROWS`` at a time."""
+    row ``first``, given their layout ``_RELAYOUT_ROWS`` at a time (the
+    last piece overlaps the one before it, so that every piece has one
+    shape)."""
     d = points.shape[1]
     step = min(rows, _RELAYOUT_ROWS)
 
@@ -265,14 +210,21 @@ def _put_rows(n: int, d: int, devices: int = 1) -> int:
 
 @lru_cache(maxsize=None)
 def _rows_from_pieces(shape: tuple, pad: int, fill: str, sharding):
-    """:func:`_rows_on_device` for a buffer that was put in pieces, as
-    three jitted programs: ``empty() -> (points, mask)``, ``place(points,
-    flat, first) -> points`` for the piece whose first row is ``first``
-    (``points`` donated: the rows are laid out in place, a piece's buffer
-    is free once it is placed; one program a piece length, so two a
-    table), and ``finish(points)`` for the ``fill`` rows
-    (``finish(points, first_row)`` fills with that row instead of the
-    array's own first: a shard's program, :func:`_put_and_lay_out_sharded`)."""
+    """The jitted programs that make ``pad_rows_with_mask``'s two arrays
+    of ``shape`` = (n, d) rows where the rows already are, from their
+    C-order buffer put flat in pieces (one dimension, so the runtime
+    transposes nothing on the host on the way: the chip keeps rows whose
+    width is no multiple of 128 column-major, and a put of the 2-D array
+    makes that layout tile by tile on the host's threads).  Three
+    programs under ``sharding``: ``empty() -> (points, mask)``,
+    ``place(points, flat, first) -> points`` for the piece whose first row
+    is ``first`` (``points`` donated: the rows are laid out in place, a
+    piece's buffer is free once it is placed; one program a piece length,
+    so two a table), and ``finish(points)`` for the ``pad`` rows of
+    ``fill`` after the last one (``finish(points, first_row)`` fills with
+    that row instead of the array's own first).  Kept for the process per
+    shapes (as ``_predict``'s jit keeps its own): a refit neither traces
+    nor compiles."""
     n, d = shape
 
     def empty():
@@ -289,75 +241,33 @@ def _rows_from_pieces(shape: tuple, pad: int, fill: str, sharding):
 
 
 def _put_and_lay_out(host_points: np.ndarray, plan, mesh, spec) -> tuple:
-    """Host -> device on a mesh of one process whose ``data`` axis is one
-    device: the rows' buffer put flat and laid out, padded and masked on
-    the device, under the spans ``fit.upload`` and ``fit.arrange.pad``.
-    Up to ``PUT_BYTES`` that is one asynchronous put (the transfer runs
-    while the host draws the start) and :func:`_rows_on_device`; a larger
-    table goes up a piece at a time, each waited for, and the device lays
-    a piece out (:func:`_rows_from_pieces`) while the next one arrives."""
-    from jax.sharding import NamedSharding
-
-    n = len(host_points)
-    put_rows = _put_rows(*host_points.shape)
-    laid_out = (host_points.shape, -n % plan.local_multiple(mesh),
-                plan.fill, NamedSharding(mesh, spec))
-
-    def put(first):
-        return put_sharded(host_points[first:first + put_rows].reshape(-1),
-                           mesh, spec)
-
-    def arranging():
-        return tracer.span("fit.arrange.pad", "fit")
-
-    if put_rows == n:
-        with tracer.span("fit.upload", "fit") as upload:
-            upload.note(pieces=1)
-            flat = put(0)
-        with tracer.span("fit.arrange", "fit"), arranging():
-            return _rows_on_device(*laid_out)(flat)
-    empty, place, finish = _rows_from_pieces(*laid_out)
-    with tracer.span("fit.arrange", "fit"), arranging():
-        points, mask = empty()
-    for first in range(0, n, put_rows):
-        with tracer.span("fit.upload", "fit") as upload:
-            if not first:
-                upload.note(pieces=-(-n // put_rows))
-            flat = put(first)
-            flat.block_until_ready()
-        with tracer.span("fit.arrange", "fit"), arranging():
-            points = place(points, flat, first)
-            if first + put_rows >= n:
-                points = finish(points)
-    return points, mask
-
-
-def _put_and_lay_out_sharded(host_points: np.ndarray, plan, mesh,
-                             spec) -> tuple:
-    """:func:`_put_and_lay_out` on a mesh of one process whose ``data``
-    axis has SEVERAL devices: what ``_pad_points`` + ``put_sharded`` make
-    (the rows padded at the table's end to the mesh's multiple and divided
-    in contiguous runs, the mask beside them), bit for bit, with no copy
-    of the table on the host.  Each device gets its run of rows flat, in
+    """Host -> device on a mesh of one process, whatever the width of its
+    ``data`` axis: what ``_pad_points`` + ``put_sharded`` make (the rows
+    padded at the table's end to the mesh's multiple and divided in
+    contiguous runs, the mask beside them), bit for bit, with no copy of
+    the table on the host.  Each device gets its run of rows flat, in
     pieces that are views of the column (``parallel/mesh.py:
     put_sharded_in_pieces``: the devices' pieces of a round in flight
     together, a round of at most ``PUT_BYTES`` in all, waited for before
-    the next: the runtime's bound is on what the process has in flight), and
-    lays it out, pads and masks it ITSELF with the one-device route's
-    three programs (:func:`_rows_from_pieces` under that device's own
-    sharding: the runs' lengths differ where the table does not divide,
-    and arrays of different lengths make no one sharded array without a
-    copy; a program a device is also what lets a device start on its piece
-    when that piece has arrived).  The finished per-device arrays ARE the
-    global arrays' shards (``jax.make_array_from_single_device_arrays``:
-    nothing moves).  Same spans as the one-device route; ``fit.upload``
-    notes ``pieces``, the puts the fullest device received."""
+    the next, the last one not: the runtime's bound is on what the process
+    has in flight), and lays it out, pads and masks it ITSELF
+    (:func:`_rows_from_pieces` under that device's own sharding: the runs'
+    lengths differ where the table does not divide, and arrays of
+    different lengths make no one sharded array without a copy; a program
+    a device is also what lets a device start on its piece when that piece
+    has arrived).  The fill rows are the table's first row on every shard.
+    The finished per-device arrays ARE the global arrays' shards
+    (``jax.make_array_from_single_device_arrays``: nothing moves).  Spans
+    ``fit.upload``, which notes ``pieces``, the puts the fullest device
+    received, and ``fit.arrange.pad`` under ``fit.arrange``."""
     from jax.sharding import NamedSharding, SingleDeviceSharding
 
     n, d = host_points.shape
     lines = shard_devices(mesh)
     shard_rows = (n + -n % plan.local_multiple(mesh)) // len(lines)
-    put_rows = _put_rows(shard_rows, d, mesh.size)
+    # an empty table makes one round of empty pieces: the start's draw
+    # then says what is wrong with it
+    put_rows = _put_rows(max(shard_rows, 1), d, mesh.size)
     programs, laid_out, short = {}, {}, []
     for i, line in enumerate(lines):
         rows = min(max(n - i * shard_rows, 0), shard_rows)
@@ -724,20 +634,14 @@ def kmeans_workset_epoch_step(measure: DistanceMeasure, k: int, *,
 
 def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
                              k_tile: Optional[int] = None,
-                             tie_policy: str = "first",
                              interpret: bool = False):
     """One Lloyd's iteration on the fused Pallas kernel
     (``ops/kmeans_pallas.py``): score/one-hot tiles stay in VMEM, so the
     points are read from HBM once an iteration and nothing else is.
     ``(block_n, k_tile)`` are the plan's (``kmeans_pallas.stats_tiles``):
     ``k_tile`` None is the feature-major kernel with all of k resident,
-    a number the kernel tiled over k.
-
-    ``tie_policy="first"`` (the default, what ``KMeans.fit`` plans via
-    its ``tiePolicy`` param) keeps the XLA body's exact first-index
-    argmin semantics; ``"split"`` gives fractional expected-assignment
-    ties, ``"fast"`` assigns exactly-tied points to every minimizing
-    centroid — see ``KMeansParams.TIE_POLICY``.
+    a number the kernel tiled over k.  Both assign by the XLA body's
+    first-index argmin, ties included.
 
     Requires zero-filled padding (``fill="zero"``) with the per-shard row
     count a multiple of ``block_n``; euclidean metric only.  With a
@@ -764,23 +668,20 @@ def kmeans_epoch_step_pallas(k: int, mesh=None, *, block_n: int = 8192,
             if sharded:
                 sums, counts = kp.update_stats_sharded(
                     points, centroids, mesh, block_n=block_n, k_tile=k_tile,
-                    tie_policy=tie_policy, interpret=interpret)
+                    interpret=interpret)
             else:
                 sums, counts = kp.kmeans_update_stats(
                     points, centroids, block_n=block_n, k_tile=k_tile,
-                    tie_policy=tie_policy, interpret=interpret)
+                    interpret=interpret)
         with jax.named_scope("kmeans.update"):
             n_pad = points.shape[0] - jnp.sum(mask)
-            counts = kp.pad_correction(counts, centroids, n_pad,
-                                       tie_policy=tie_policy)[:, None]
-            # No clamp-to-1 here: "split" ties legally produce fractional
-            # counts in (0, 1), which must divide as-is.
+            counts = kp.pad_correction(counts, centroids, n_pad)[:, None]
             safe = jnp.where(counts > 0, counts, 1.0)
             new_centroids = jnp.where(counts > 0, sums / safe, centroids)
         return IterationBodyResult(feedback=new_centroids)
 
     return with_program_key(body, kmeans_epoch_step_pallas, k, mesh, block_n,
-                            k_tile, tie_policy, interpret,
+                            k_tile, interpret,
                             kp.kmeans_update_stats, kp.update_stats_sharded,
                             kp.pad_correction)
 
@@ -792,7 +693,7 @@ _MIN_BLOCKS = 8
 
 
 def _plan_fit_impl(n: int, d: int, k: int, measure: DistanceMeasure,
-                   mesh, tie_policy: str = "first") -> tuple:
+                   mesh) -> tuple:
     """Pick (impl, block_n, k_tile) for the BSP fit loop via registry op
     ``kmeans_update_stats`` (the Pallas entry's availability gate is the
     TPU backend; its supports predicate is the euclidean metric, the
@@ -801,17 +702,13 @@ def _plan_fit_impl(n: int, d: int, k: int, measure: DistanceMeasure,
     (``kmeans_pallas.stats_tiles``): feature-major with all of k resident
     (``k_tile`` None) wherever that fits VMEM, else tiled over k.  Padding
     rounds the per-shard row count up to the block, so any supported block
-    size works: the largest that leaves a shard ``_MIN_BLOCKS`` blocks.
-    The kernel tiled over k assigns by the ``"first"`` policy alone, so
-    another ``tiePolicy`` at its shapes takes the XLA body."""
+    size works: the largest that leaves a shard ``_MIN_BLOCKS`` blocks."""
     from ...kernels.registry import lookup
     from ...ops import kmeans_pallas as kp
 
     entry = lookup("kmeans_update_stats", sig=(n, d, k, measure.name))
     if entry.backend == "pallas":
         block_n, k_tile = kp.stats_tiles(d, k)
-        if k_tile is not None and tie_policy != "first":
-            return "xla", None, None
         if k_tile is None:
             # measured-not-analytic when the autotune cache is configured
             # (ISSUE 12): the winner is persisted per (d, k, device kind),
@@ -880,7 +777,7 @@ class FitPlan:
 
 
 def _fit_plan(n: int, d: int, k: int, measure: DistanceMeasure, mesh, *,
-              workset: bool = False, tie_policy: str = "first") -> FitPlan:
+              workset: bool = False) -> FitPlan:
     """Build the shared :class:`FitPlan`.  The workset path plans via
     registry op ``kmeans_workset_update``: the fused scoring+stats
     Pallas kernel (PR 10) where available — TPU, euclidean, a viable
@@ -900,8 +797,7 @@ def _fit_plan(n: int, d: int, k: int, measure: DistanceMeasure, mesh, *,
             block_n = kp.pick_block_n_workset_measured(d, k)
             return FitPlan("pallas_ws", block_n, block_n, "first_row", k, d)
         return FitPlan("xla", None, 1, "first_row", k, d)
-    impl, block_n, k_tile = _plan_fit_impl(n, d, k, measure, mesh,
-                                           tie_policy)
+    impl, block_n, k_tile = _plan_fit_impl(n, d, k, measure, mesh)
     row_multiple, fill = ((block_n, "zero") if impl == "pallas"
                           else (1, "first_row"))
     return FitPlan(impl, block_n, row_multiple, fill, k, d, k_tile)
@@ -1043,22 +939,19 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
         converted once, an object column of vectors is stacked first.
         Which mesh takes which route:
 
-        - one process, one device on ``data`` (:func:`_put_and_lay_out`):
-          the rows are put as they are (their buffer, flat, whole up to
-          ``parallel/mesh.py: PUT_BYTES``, else a piece at a time), BEFORE
-          the start is drawn, so that the transfer runs under the start's
-          draw (:func:`random_start`), and the device gives them their
+        - one process, however many devices on ``data``
+          (:func:`_put_and_lay_out`): every device gets a contiguous run
+          of the rows as they are (their buffer, flat, whole up to
+          ``parallel/mesh.py: PUT_BYTES`` a round, else a piece at a time,
+          the devices' pieces in flight together), BEFORE the start is
+          drawn, so that the last round's transfer runs under the start's
+          draw (:func:`random_start`), and each device gives its run its
           layout, the mask, and the fill rows of a remainder against the
-          plan's row multiple (:func:`_rows_on_device`; no remainder, no
-          row added);
-        - one process, several devices on ``data``
-          (:func:`_put_and_lay_out_sharded`, PR 39): the same, a device a
-          contiguous run of the rows: every device's run flat, in pieces,
-          the devices' pieces in flight together, laid out, padded and
-          masked on its own device; no copy of the table on the host;
+          plan's row multiple (no remainder, no row added); no copy of
+          the table on the host;
         - several processes: every process passed its own shard, which is
           padded on the host (:func:`_pad_points`) and put after the
-          start, as before.
+          start.
 
         ``fit.arrange`` notes ``shards``, the devices on ``data`` the rows
         were divided over, and ``fit.upload`` ``pieces``, the puts the
@@ -1106,8 +999,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
 
             workset_mode = self.get_workset()
             plan = _fit_plan(n_for_plan, host_points.shape[1], k, measure,
-                             mesh, workset=workset_mode,
-                             tie_policy=self.get_tie_policy())
+                             mesh, workset=workset_mode)
             arrange.note(shards=int(mesh.shape["data"]), **plan.notes())
             impl, block_n = plan.impl, plan.block_n
             select_init = _INIT_MODES[self.get_init_mode()]
@@ -1136,9 +1028,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
 
         spec = P("data")
         if not multi_host:
-            route = (_put_and_lay_out if int(mesh.shape["data"]) == 1
-                     else _put_and_lay_out_sharded)
-            points, mask = route(host_points, plan, mesh, spec)
+            points, mask = _put_and_lay_out(host_points, plan, mesh, spec)
             with tracer.span("fit.arrange", "fit"):
                 init = draw_start()
         else:
@@ -1170,8 +1060,7 @@ class KMeans(KMeansParams, Estimator["KMeansModel"]):
                 result, n_real=n_for_plan, n_padded=int(points.shape[0]))
         else:
             body = (kmeans_epoch_step_pallas(k, mesh, block_n=block_n,
-                                             k_tile=plan.k_tile,
-                                             tie_policy=self.get_tie_policy())
+                                             k_tile=plan.k_tile)
                     if impl == "pallas" else kmeans_epoch_step(measure, k))
             result = iterate(
                 body,

@@ -20,7 +20,6 @@ from .ell_scatter import (  # noqa: F401
 )
 from . import retrieve_pallas  # noqa: F401  (the "pallas" retrieve backend)
 from .kmeans_pallas import (  # noqa: F401
-    kmeans_assign_reduce,
     kmeans_update_stats,
     kmeans_workset_update,
     pad_correction,

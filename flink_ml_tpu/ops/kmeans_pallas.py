@@ -37,45 +37,36 @@ parent commit in the same process on the same points):
 
     n 20,021,248 = 611 x 32768, d 20, k 10 (HiBench's; seed 2147488001),
     1.6 GB of points (1.92 GB as laid out: 20 features on 24 sublanes)
-      feature-major  first  block 32768   2.692   (595 GB/s of the 1.6 GB)
-                            block 16384   2.696
-                            block  8192   2.802
-                            block  4096   3.437
-                     fast   block 32768   2.674
-                     split  block 32768   2.679
-      row-major      first  block  8192 115.86    (its lane-padded copy of
-                                                   the points, once a call,
-                                                   shared by 5 iterations)
+      feature-major  block 32768   2.692   (595 GB/s of the 1.6 GB)
+                     block 16384   2.696
+                     block  8192   2.802
+                     block  4096   3.437
+      row-major      block  8192 115.86    (its lane-padded copy of the
+                                            points, once a call, shared
+                                            by 5 iterations)
     n 2^20, d 64, k 256 (chip_smoke.py's; seeds 2147488002 / 3000000303)
-      feature-major  first  block  8192   1.056 / 1.052
-                            block  4096   1.009 / 1.009
-                            block 16384   1.091 / 1.085
-                     fast   block  8192   0.943 / 0.938
-                     split  block  8192   1.043 / 1.036
-      row-major      first  block  8192   1.527 / 1.528
-                            block  4096   1.362 / 1.362
-                     fast   block  8192   0.883 / 0.879
-                     split  block  8192   1.219 / 1.227
+      feature-major  block  8192   1.056 / 1.052
+                     block  4096   1.009 / 1.009
+                     block 16384   1.091 / 1.085
+      row-major      block  8192   1.527 / 1.528
+                     block  4096   1.362 / 1.362
 
 At k 256 both layouts give the MXU the same work (by their shapes, 512
 streamed rows per 128 points) and differ in the VPU/XLU passes around
-it: feature-major is 31% faster under
-``first`` (the fit's policy) and 15% under ``split``, 7% slower under
-``fast``, whose chain has no pass that a lane reduction made dear.  One
-kernel serves every shape.  Counts agree to the unit and sums to 2.3e-6
-relative between the layouts on the same points; against float64 sums on
-separated clusters (seed 3000000301) both are 5e-5 from the sums of the
-points rounded to bfloat16 and 10.9 from the sums of the points as they
-are: one bfloat16 MXU pass, as stated above.
+it: feature-major is 31% faster.  One kernel serves every shape.
+Counts agree to the unit and sums to 2.3e-6 relative between the
+layouts on the same points; against float64 sums on separated clusters
+(seed 3000000301) both are 5e-5 from the sums of the points rounded to
+bfloat16 and 10.9 from the sums of the points as they are: one bfloat16
+MXU pass, as stated above.
 
 The VMEM model (:func:`_stats_tile_bytes`) was held against the compiler
 for a described v5e (no chip): over (d, k) in {(1, 1), (3, 2), (8, 4),
 (20, 10), (33, 65), (64, 256), (100, 100), (128, 16), (256, 256),
 (512, 8), (784, 256), (16, 2048), (64, 1024), (20, 1000)} the block it
-picks compiles under all three tie policies, and the first power of two
-the compiler refuses (16 MiB of scoped VMEM; looked for at nine of them)
-is two to eight times the pick; at d 20, k 10 it refuses 65536 at
-16.45 MiB.  At k 256, d 64 it
+picks compiles, and the first power of two the compiler refuses (16 MiB
+of scoped VMEM; looked for at nine of them) is two to eight times the
+pick; at d 20, k 10 it refuses 65536 at 16.45 MiB.  At k 256, d 64 it
 still compiles 16384 (points twice 8 MiB, a float32 score tile 16 MiB),
 so it never holds a whole score-shaped tile: hence the half.
 
@@ -124,21 +115,13 @@ Design notes:
   (k, block_n) VPU pass over keeping a mask.  (The workset kernel below
   instead uses the MASKED contract: it needs the pad mask anyway to
   merge cached assignments, see :func:`kmeans_workset_update`.)
-- **tie_policy="fast"** assigns a point to *every* centroid at exactly the
-  minimum distance (``scores <= min``).  For continuous f32 data exact ties
-  are measure-zero; the known benign case is duplicated centroids, which
-  receive identical (double-counted) updates and therefore stay identical —
-  the same fixed point Lloyd's has.  **"split"** divides tied points
-  fractionally among the minimisers (exact expected-assignment semantics).
-  **"first"** (the fit default since r4) keeps the reference's exact
-  first-index-argmin semantics: the smallest tied centroid index via
-  where/min/compare over an iota tile — no argmin loop, no division.
+- **One tie rule**: a point goes to the FIRST centroid of least score,
+  the reference's first-index-argmin semantics, ties included: the
+  smallest tied centroid index via where/min/compare over an iota tile.
 - a true ``argmin`` inside a Mosaic kernel lowers to a slow
   index-tracking loop (~6 ms/it measured), so the fit kernels compute
-  assignment one-hots directly (see the policies above) rather than
-  indices; the transform kernel (:func:`kmeans_assign_reduce`) does use
-  argmin, because prediction needs indices and runs once, not
-  ``max_iter`` times.
+  the first index that way, or (tiled over k) carry it through a running
+  minimum, rather than with an argmin.
 - ``||p||^2`` is omitted everywhere: it shifts each score row uniformly and
   cannot change which centroids attain the row minimum.
 
@@ -162,7 +145,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils.padding import require_block_rows
 
 __all__ = [
-    "kmeans_assign_reduce",
     "kmeans_update_stats",
     "kmeans_workset_update",
     "update_stats_sharded",
@@ -405,7 +387,7 @@ def pick_block_n_workset_measured(d: int, k: int, *,
                            analytic=max(cands))
 
 
-def _stats_kernel(tie_policy: str, compute_dtype):
+def _stats_kernel(compute_dtype):
     # contract both operands over their LAST axis (the block's rows, on
     # lanes): the MXU's transposed-operand form, nothing is transposed in
     # VMEM
@@ -425,22 +407,15 @@ def _stats_kernel(tie_policy: str, compute_dtype):
                   + c2_ref[:])                                    # (k, bn)
         mins = jnp.min(scores, axis=0, keepdims=True)
         is_min = scores <= mins
-        if tie_policy == "first":
-            # exact first-index-argmin semantics WITHOUT an argmin loop
-            # (which lowers to a ~6 ms index-tracking scan in Mosaic):
-            # the first minimiser is the smallest centroid index among
-            # the tied minima -- one where + min over the k axis +
-            # compare, all sublane-axis VPU passes (no division like
-            # "split").
-            k = scores.shape[0]
-            iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-            first = jnp.min(jnp.where(is_min, iota, k), axis=0,
-                            keepdims=True)
-            onehot = (iota == first).astype(jnp.float32)
-        else:
-            onehot = is_min.astype(jnp.float32)
-            if tie_policy == "split":
-                onehot = onehot / jnp.sum(onehot, axis=0, keepdims=True)
+        # exact first-index-argmin semantics WITHOUT an argmin loop
+        # (which lowers to a ~6 ms index-tracking scan in Mosaic): the
+        # first minimiser is the smallest centroid index among the tied
+        # minima -- one where + min over the k axis + compare, all
+        # sublane-axis VPU passes.
+        k = scores.shape[0]
+        iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        first = jnp.min(jnp.where(is_min, iota, k), axis=0, keepdims=True)
+        onehot = (iota == first).astype(jnp.float32)
         sums_ref[:] += jax.lax.dot_general(
             onehot.astype(compute_dtype), xt, over_rows,
             preferred_element_type=jnp.float32)                   # (k, d)
@@ -606,31 +581,6 @@ def _update_stats_ktiled(points, centroids, block_n: int, k_tile: int,
     return out[:k, :d], out[:k, d]
 
 
-def _assign_kernel(points_ref, cent_ref, c2_ref,
-                   assign_ref, sums_ref, counts_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sums_ref[:] = jnp.zeros_like(sums_ref)
-        counts_ref[:] = jnp.zeros_like(counts_ref)
-
-    pts = points_ref[:]
-    scores = (-2.0 * jnp.dot(pts, cent_ref[:].T,
-                             preferred_element_type=jnp.float32)
-              + c2_ref[:])
-    assign = jnp.argmin(scores, axis=1)
-    assign_ref[:] = assign.astype(jnp.int32)
-
-    k = sums_ref.shape[0]
-    onehot = (assign[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (pts.shape[0], k), 1))
-    onehot = onehot.astype(jnp.float32)
-    sums_ref[:] += jnp.dot(onehot.T, pts,
-                           preferred_element_type=jnp.float32)
-    counts_ref[:] += jnp.sum(onehot, axis=0)
-
-
 def _check_block(n: int, block_n: int, op: str = "kmeans_pallas") -> None:
     # the shared registered-kernel invariant (utils/padding.py), not a
     # module-local rule: every blocked kernel raises the same message
@@ -638,35 +588,29 @@ def _check_block(n: int, block_n: int, op: str = "kmeans_pallas") -> None:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_n", "k_tile", "tie_policy",
-                                    "compute_dtype", "interpret"))
+                   static_argnames=("block_n", "k_tile", "compute_dtype",
+                                    "interpret"))
 def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
                         block_n: int = 8192, k_tile: Optional[int] = None,
-                        tie_policy: str = "fast",
                         compute_dtype=jnp.float32, interpret: bool = False
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fit hot path: ``(points (n, d), centroids (k, d)) ->
-    (sums (k, d) f32, counts (k,) f32)``.
+    (sums (k, d) f32, counts (k,) f32)``, each point counted on the first
+    centroid of least distance.
 
     ``n`` must be a multiple of ``block_n``; pad with all-zero rows and
     correct the counts with :func:`pad_correction`.  A block is
     ``block_n`` rows on lanes: a multiple of 128 for the chip (the
     interpreter takes any).  ``(block_n, k_tile)`` come from
     :func:`stats_tiles`: ``k_tile`` None is the feature-major kernel with
-    all of ``k`` resident, a number the kernel tiled over k, which knows
-    the ``"first"`` policy alone and rounds the points and centroids to
-    bfloat16 itself (``compute_dtype`` does not apply).
+    all of ``k`` resident, a number the kernel tiled over k, which
+    rounds the points and centroids to bfloat16 itself (``compute_dtype``
+    does not apply).
     """
-    if tie_policy not in ("first", "fast", "split"):
-        raise ValueError(f"tie_policy must be 'first', 'fast' or 'split', "
-                         f"got {tie_policy!r}")
     n, d = points.shape
     k = centroids.shape[0]
     _check_block(n, block_n)
     if k_tile is not None:
-        if tie_policy != "first":
-            raise ValueError("the stats kernel tiled over k assigns by the "
-                             f"'first' policy alone, got {tie_policy!r}")
         return _update_stats_ktiled(points, centroids, block_n, k_tile,
                                     interpret)
     c2 = jnp.sum(centroids * centroids, axis=1, keepdims=True)
@@ -674,7 +618,7 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     # (n, d) -> (d, n): the chip keeps narrow rows column-major, so for
     # them this is the array it already holds under another name
     sums, counts = pl.pallas_call(
-        _stats_kernel(tie_policy, compute_dtype),
+        _stats_kernel(compute_dtype),
         grid=(n // block_n,),
         in_specs=[
             pl.BlockSpec((d, block_n), lambda i: (0, i),
@@ -695,79 +639,21 @@ def kmeans_update_stats(points: jnp.ndarray, centroids: jnp.ndarray, *,
     return sums, counts[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def kmeans_assign_reduce(points: jnp.ndarray, centroids: jnp.ndarray, *,
-                         block_n: int = 2048, interpret: bool = False
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Transform path: also emits per-point assignments (first-index argmin).
-    ``(points (n, d), centroids (k, d)) ->
-    (assignments (n,) int32, sums (k, d), counts (k,))``.
-    Padding rows get a garbage (but in-range) assignment — slice them off."""
-    n, d = points.shape
-    k = centroids.shape[0]
-    _check_block(n, block_n)
-    c2 = jnp.sum(centroids * centroids, axis=1)[None, :]
-
-    return pl.pallas_call(
-        _assign_kernel,
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k,), lambda i: (0,), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(points, centroids, c2)
-
-
 def pad_correction(counts: jnp.ndarray, centroids: jnp.ndarray,
-                   n_pad, tie_policy: str = "fast") -> jnp.ndarray:
+                   n_pad) -> jnp.ndarray:
     """Remove the contribution of ``n_pad`` all-zero padding rows: they all
-    landed on the centroid(s) with the smallest norm, added nothing to
-    ``sums``, and ``n_pad`` to those clusters' counts.
-
-    ``tie_policy`` must name the policy of the kernel that produced
-    ``counts``, so the fix stays exact even when several centroids tie for
-    minimal norm (e.g. duplicated init centroids):
-
-    - ``"fast"``   — :func:`kmeans_update_stats` counted padding fully on
-      *every* tied centroid
-    - ``"split"``  — fractionally across the tied centroids
-    - ``"argmin"`` / ``"first"`` — :func:`kmeans_assign_reduce` /
-      :func:`kmeans_update_stats` with ``tie_policy="first"`` counted it
-      on the first tied index only (first-index argmin semantics)
-    """
+    landed on the first centroid of least norm (:func:`kmeans_update_stats`
+    takes the first index on a tie, also where several centroids tie for
+    the least norm, e.g. duplicated init centroids), added nothing to
+    ``sums``, and ``n_pad`` to that cluster's count."""
     c2 = jnp.sum(centroids * centroids, axis=1)
-    if tie_policy in ("argmin", "first"):
-        tied = jax.nn.one_hot(jnp.argmin(c2), counts.shape[0],
-                              dtype=counts.dtype)
-    elif tie_policy in ("fast", "split"):
-        tied = (c2 <= jnp.min(c2)).astype(counts.dtype)
-        if tie_policy == "split":
-            tied = tied / jnp.sum(tied)
-    else:
-        raise ValueError(
-            f"tie_policy must be 'first', 'fast', 'split' or 'argmin', "
-            f"got {tie_policy!r}")
-    return counts - n_pad * tied
+    return counts - n_pad * jax.nn.one_hot(jnp.argmin(c2), counts.shape[0],
+                                           dtype=counts.dtype)
 
 
 def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
                          mesh, *, block_n: int = 8192,
                          k_tile: Optional[int] = None,
-                         tie_policy: str = "fast",
                          compute_dtype=jnp.float32,
                          interpret: bool = False
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -785,8 +671,7 @@ def update_stats_sharded(points: jnp.ndarray, centroids: jnp.ndarray,
     def shard_fn(pts, cents):
         sums, counts = kmeans_update_stats(
             pts, cents, block_n=block_n, k_tile=k_tile,
-            tie_policy=tie_policy, compute_dtype=compute_dtype,
-            interpret=interpret)
+            compute_dtype=compute_dtype, interpret=interpret)
         with jax.named_scope("kmeans.reduce"):
             return (jax.lax.psum(sums, "data"),
                     jax.lax.psum(counts, "data"))

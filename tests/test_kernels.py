@@ -342,10 +342,8 @@ def _parity_kmeans_update_stats(backends):
                                     jnp.asarray(mask), jnp.asarray(cents))
         else:
             sums, counts = entry.fn(jnp.asarray(pts), jnp.asarray(cents),
-                                    block_n=128, tie_policy="first",
-                                    interpret=True)
-            counts = pad_correction(counts, jnp.asarray(cents), 13,
-                                    tie_policy="first")
+                                    block_n=128, interpret=True)
+            counts = pad_correction(counts, jnp.asarray(cents), 13)
         outs[b] = (np.asarray(sums), np.asarray(counts))
     sr, cr = outs.pop("xla")
     for b, (ss, cc) in outs.items():

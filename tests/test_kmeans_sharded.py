@@ -1,9 +1,10 @@
-"""``KMeans.fit`` on a one-process mesh whose ``data`` axis has several
-devices (PR 39): every device gets its run of the rows flat, in pieces
+"""``KMeans.fit`` on a one-process mesh, whatever the width of its
+``data`` axis: every device gets its run of the rows flat, in pieces
 that are views of the column, and lays it out, pads and masks it itself
-(``kmeans.py: _put_and_lay_out_sharded``, ``parallel/mesh.py:
-put_sharded_in_pieces``); the step is the kernel a shard and one ``psum``
-under the scope ``kmeans.reduce``; the fused program is kept per mesh."""
+(``kmeans.py: _put_and_lay_out``, ``parallel/mesh.py:
+put_sharded_in_pieces``); on several devices the step is the kernel a
+shard and one ``psum`` under the scope ``kmeans.reduce``; the fused
+program is kept per mesh."""
 
 import os
 import re
@@ -51,28 +52,30 @@ def handed_over(monkeypatch):
     return handed
 
 
-# rows a shard of 2, 4, 8 devices x 16 keeps whole / cuts with a remainder
-# / ends in a piece shorter than the rest (and, at 8, in an empty shard)
+# rows a shard of 1, 2, 4, 8 devices x 16 keeps whole / cuts with a
+# remainder / ends in a piece shorter than the rest (and, at 8, in an
+# empty shard)
 _ROWS = {"no_remainder": 512, "remainder": 500, "short_last_piece": 299}
 
 
 @pytest.mark.parametrize("fill", ["zero", "first_row"])
 @pytest.mark.parametrize("case", list(_ROWS))
-@pytest.mark.parametrize("devices", [2, 4, 8])
+@pytest.mark.parametrize("devices", [1, 2, 4, 8])
 def test_sharded_ingest_is_the_host_pad_put_sharded_bit_for_bit(
         small_pieces, handed_over, devices, case, fill):
     n, mesh = _ROWS[case], _mesh(devices)
     column = np.random.default_rng(n).normal(size=(n, 5)).astype(np.float32)
     before = column.tobytes()
     plan = km.FitPlan("xla", None, 16, fill, 4, 5)
-    got = km._put_and_lay_out_sharded(column, plan, mesh, P("data"))
+    got = km._put_and_lay_out(column, plan, mesh, P("data"))
     # (past a run's end a piece is empty: nothing to share)
     pieces = [a for a in handed_over if a.ndim == 1 and a.size]
     assert pieces and all(np.shares_memory(a, column) for a in handed_over
                           if a.size)
     assert sum(a.size for a in pieces) == column.size   # each row once
-    # the cap is on a round, and a put hands over a relayout step at least
-    assert max(a.size for a in pieces) == 8 * 5
+    # the cap (16 rows) is on a round, and a put hands over a relayout
+    # step (8 rows) at least
+    assert max(a.size for a in pieces) == max(16 // devices, 8) * 5
 
     want = km._pad_points(column, mesh, row_multiple=16, fill=fill)
     assert (want[0] is column) == (case == "no_remainder")
@@ -94,12 +97,22 @@ def test_sharded_ingest_replicates_a_shard_over_the_meshs_other_axis(
     mesh = device_mesh({"data": 4, "model": 2})
     column = np.random.default_rng(3).normal(size=(250, 5)).astype(np.float32)
     plan = km.FitPlan("xla", None, 16, "first_row", 4, 5)
-    got = km._put_and_lay_out_sharded(column, plan, mesh, P("data"))
+    got = km._put_and_lay_out(column, plan, mesh, P("data"))
     want = km._pad_points(column, mesh, row_multiple=16, fill="first_row")
     for have, expected in zip(got, want):
         assert have.sharding == put_sharded(expected, mesh, P("data")).sharding
         assert np.asarray(have).tobytes() == expected.tobytes()
         assert len(have.addressable_shards) == 8
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_fit_of_an_empty_table_says_it_has_too_few_rows(devices):
+    """No rows: the ingest makes one round of empty pieces, and the start's
+    draw names the fault, on one device as on several."""
+    empty = Table({"features": np.zeros((0, 3), np.float32)})
+    with use_mesh(_mesh(devices)), pytest.raises(ValueError,
+                                                 match="at least k=2"):
+        KMeans().set_k(2).fit(empty)
 
 
 def test_put_sharded_in_pieces_rounds_and_views():
@@ -167,21 +180,22 @@ def _centroids(model) -> np.ndarray:
     return np.asarray(model.get_model_data()[0]["centroids"][0])
 
 
-def _steer_to_the_ktiled_kernel(monkeypatch):
-    """The plan and the body a TPU takes at k 4096, the interpreter
+def _steer_to_the_ktiled_kernel(monkeypatch, k_tile=16):
+    """The plan and the body a TPU takes at k 4096 (``k_tile`` None: at
+    HiBench's shapes, the kernel with all of k resident), the interpreter
     standing in for the chip, the tiles pinned (a shard's rows would let
     the plan pick another block, and the scores of a row do not depend on
     which chip holds it only where the tiles are the same)."""
     step = km.kmeans_epoch_step_pallas
     monkeypatch.setattr(
         km, "_fit_plan", lambda n, d, k, measure, mesh, **how:
-        km.FitPlan("pallas", 128, 128, "zero", k, d, k_tile=16))
+        km.FitPlan("pallas", 128, 128, "zero", k, d, k_tile=k_tile))
     monkeypatch.setattr(
         km, "kmeans_epoch_step_pallas",
         lambda *a, **kw: step(*a, **{**kw, "interpret": True}))
 
 
-@pytest.mark.parametrize("body", ["xla", "k_tiled"])
+@pytest.mark.parametrize("body", ["xla", "k_tiled", "feature_major"])
 def test_four_device_fit_is_the_one_device_fit_bit_for_bit_on_grey_levels(
         monkeypatch, body):
     """The sums are of whole levels and stay under 2^24, so four partial
@@ -189,6 +203,8 @@ def test_four_device_fit_is_the_one_device_fit_bit_for_bit_on_grey_levels(
     centroids do not depend on how many chips shared the rows."""
     if body == "k_tiled":
         _steer_to_the_ktiled_kernel(monkeypatch)
+    elif body == "feature_major":
+        _steer_to_the_ktiled_kernel(monkeypatch, k_tile=None)
     column = _grey_levels(4096 + 5)
     before = column.tobytes()
 

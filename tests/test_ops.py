@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from flink_ml_tpu.ops.kmeans_pallas import (
-    kmeans_assign_reduce,
     kmeans_update_stats,
     pad_correction,
     pick_block_n,
@@ -70,15 +69,14 @@ _PARITY_CASES = {
 }
 
 
-@pytest.mark.parametrize("tie_policy", ["first", "fast", "split"])
 @pytest.mark.parametrize("case", list(_PARITY_CASES))
-def test_update_stats_matches_oracle(case, tie_policy):
+def test_update_stats_matches_oracle(case):
     problem, block_n = _PARITY_CASES[case]
     pts, cents, n_pad = problem()
     _, exp_sums, exp_counts = _oracle(pts, cents, n_pad)
     sums, counts = kmeans_update_stats(pts, cents, block_n=block_n,
-                                       tie_policy=tie_policy, interpret=True)
-    counts = pad_correction(counts, cents, n_pad, tie_policy=tie_policy)
+                                       interpret=True)
+    counts = pad_correction(counts, cents, n_pad)
     np.testing.assert_allclose(np.asarray(sums), exp_sums, atol=1e-3)
     np.testing.assert_allclose(np.asarray(counts), exp_counts, atol=1e-5)
 
@@ -92,9 +90,8 @@ def test_update_stats_first_takes_exact_ties_to_the_first_centroid():
     cents = cents.at[7].set(cents[3])
     _, exp_sums, exp_counts = _oracle(pts, cents, n_pad)
     assert exp_counts[3] > 0 and exp_counts[7] == 0
-    sums, counts = kmeans_update_stats(pts, cents, block_n=256,
-                                       tie_policy="first", interpret=True)
-    counts = pad_correction(counts, cents, n_pad, tie_policy="first")
+    sums, counts = kmeans_update_stats(pts, cents, block_n=256, interpret=True)
+    counts = pad_correction(counts, cents, n_pad)
     np.testing.assert_array_equal(np.asarray(counts), exp_counts)
     np.testing.assert_allclose(np.asarray(sums), exp_sums, rtol=2e-3,
                                atol=0.5)
@@ -103,49 +100,16 @@ def test_update_stats_first_takes_exact_ties_to_the_first_centroid():
 
 def test_update_stats_bf16_dots_conserve_mass():
     # bf16 scores may flip boundary assignments vs the f32 oracle, so check
-    # the invariants instead: with "split" ties every real row contributes
-    # exactly once, so counts and coordinate mass are conserved.
+    # the invariants instead: every real row counts once, on the first
+    # centroid of least score, so counts and coordinate mass are conserved.
     pts, cents, n_pad = _problem()
     sums, counts = _corrected_stats(pts, cents, n_pad, block_n=128,
-                                    tie_policy="split",
                                     compute_dtype=jnp.bfloat16)
     np.testing.assert_allclose(np.asarray(counts).sum(), 512 - n_pad,
                                atol=1e-3)
     np.testing.assert_allclose(
         np.asarray(sums).sum(0),
         np.asarray(pts)[: 512 - n_pad].sum(0), atol=0.3)
-
-
-def test_assign_reduce_matches_oracle():
-    pts, cents, n_pad = _problem()
-    assign, sums, counts = kmeans_assign_reduce(pts, cents, block_n=128,
-                                                interpret=True)
-    counts = pad_correction(counts, cents, n_pad, tie_policy="argmin")
-    exp_assign, exp_sums, exp_counts = _oracle(pts, cents, n_pad)
-    np.testing.assert_array_equal(np.asarray(assign)[: 512 - n_pad],
-                                  exp_assign)
-    np.testing.assert_allclose(np.asarray(sums), exp_sums, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(counts), exp_counts)
-
-
-def test_split_ties_fractional():
-    # Two identical centroids: "split" halves each point between them,
-    # "fast" double-counts — both leave the centroid *means* identical.
-    rng = np.random.default_rng(1)
-    pts = rng.normal(size=(128, 8)).astype(np.float32)
-    cents = np.stack([pts[0], pts[0]])  # exact duplicates -> every row ties
-    split_sums, split_counts = kmeans_update_stats(
-        jnp.asarray(pts), jnp.asarray(cents), block_n=128,
-        tie_policy="split", interpret=True)
-    fast_sums, fast_counts = kmeans_update_stats(
-        jnp.asarray(pts), jnp.asarray(cents), block_n=128,
-        tie_policy="fast", interpret=True)
-    np.testing.assert_allclose(np.asarray(split_counts).sum(), 128)
-    np.testing.assert_allclose(np.asarray(fast_counts).sum(), 256)
-    for sums, counts in ((split_sums, split_counts), (fast_sums, fast_counts)):
-        means = np.asarray(sums) / np.asarray(counts)[:, None]
-        np.testing.assert_allclose(means[0], means[1], rtol=1e-5)
-        np.testing.assert_allclose(means[0], pts.mean(0), rtol=1e-4)
 
 
 def test_update_stats_sharded_matches_single(cpu_mesh_8):
@@ -168,49 +132,10 @@ def test_pad_correction_only_touches_nearest_to_origin():
     np.testing.assert_allclose(out, [10.0, 13.0, 30.0])
 
 
-def test_pad_correction_exact_under_min_norm_ties():
-    # Two centroids tie for minimal norm (duplicated init): the kernel counts
-    # padding on BOTH under "fast" and half-each under "split"; the
-    # correction must mirror that, not subtract from the first only.
-    rng = np.random.default_rng(5)
-    n, n_pad = 128, 32
-    pts = rng.normal(loc=5.0, size=(n, 8)).astype(np.float32)
-    pts[-n_pad:] = 0.0
-    dup = pts[0] * 0.01  # small-norm duplicate pair
-    cents = jnp.asarray(np.stack([dup, dup, pts[1], pts[2]]))
-    exp_counts = _oracle(jnp.asarray(pts), cents, n_pad)[2]
-    for tie_policy, scale in (("fast", 2.0), ("split", 1.0)):
-        _, counts = kmeans_update_stats(jnp.asarray(pts), cents, block_n=128,
-                                        tie_policy=tie_policy, interpret=True)
-        counts = np.asarray(pad_correction(counts, cents, n_pad,
-                                           tie_policy=tie_policy))
-        # real rows tie on the duplicate pair too, under the same policy
-        np.testing.assert_allclose(counts[2:], exp_counts[2:], atol=1e-4)
-        np.testing.assert_allclose(counts[:2].sum(),
-                                   scale * exp_counts[:2].sum(), atol=1e-3)
-        assert (counts >= -1e-4).all()
-    # argmin kernel under the same min-norm tie: correction must subtract
-    # from the FIRST tied index only (regression: 'fast' correction after
-    # the argmin kernel drove counts negative)
-    _, _, counts = kmeans_assign_reduce(jnp.asarray(pts), cents, block_n=128,
-                                        interpret=True)
-    counts = np.asarray(pad_correction(counts, cents, n_pad,
-                                       tie_policy="argmin"))
-    np.testing.assert_allclose(counts[2:], exp_counts[2:], atol=1e-4)
-    assert (counts >= -1e-4).all()
-
-
 def test_block_divisibility_enforced():
     pts, cents, _ = _problem(n=500, n_pad=3)
     with pytest.raises(ValueError):
         kmeans_update_stats(pts, cents, block_n=128, interpret=True)
-
-
-def test_bad_tie_policy_rejected():
-    pts, cents, _ = _problem()
-    with pytest.raises(ValueError):
-        kmeans_update_stats(pts, cents, block_n=128, tie_policy="nope",
-                            interpret=True)
 
 
 def test_supported_budget_and_block_pick():
@@ -229,14 +154,14 @@ def test_supported_budget_and_block_pick():
 ])
 def test_block_pick_follows_the_feature_major_tile(d, k, block_n):
     """The picks the compiler was asked about for a described v5e (PR 30:
-    each compiles under all three tie policies, and at d 20, k 10 the
+    each compiles, and at d 20, k 10 the
     next power of two is refused at 16.45 MiB of the 16 it may take)."""
     assert pick_block_n(None, d, k) == block_n
     assert block_n == 65536 or not supported(d, k, 2 * block_n)
 
 
 def test_pad_correction_exact_under_min_norm_ties_first():
-    """'first' (the r4 fit default) with duplicated min-norm centroids:
+    """The first-index rule with duplicated min-norm centroids:
     the kernel counts ALL padding on the first tied column, and
     pad_correction's argmin(c2) must name that same column — real rows
     tying on the duplicate pair land on its first index too, so counts
@@ -249,9 +174,8 @@ def test_pad_correction_exact_under_min_norm_ties_first():
     cents = jnp.asarray(np.stack([dup, dup, pts[1], pts[2]]))
     exp_counts = _oracle(jnp.asarray(pts), cents, n_pad)[2]
     _, counts = kmeans_update_stats(jnp.asarray(pts), cents, block_n=128,
-                                    tie_policy="first", interpret=True)
-    counts = np.asarray(pad_correction(counts, cents, n_pad,
-                                       tie_policy="first"))
+                                    interpret=True)
+    counts = np.asarray(pad_correction(counts, cents, n_pad))
     # single assignment: the whole tied mass sits on column 0
     np.testing.assert_allclose(counts[2:], exp_counts[2:], atol=1e-4)
     np.testing.assert_allclose(counts[0], exp_counts[:2].sum(), atol=1e-3)
@@ -313,9 +237,8 @@ def test_ktiled_stats_match_plain_lloyd(d, k, block_n, k_tile):
     assign, exp_sums, exp_counts = _lloyd_stats(pts, cents, n_pad)
     sums, counts = kmeans_update_stats(
         jnp.asarray(pts), jnp.asarray(cents), block_n=block_n,
-        k_tile=k_tile, tie_policy="first", interpret=True)
-    counts = pad_correction(counts, jnp.asarray(cents), n_pad,
-                            tie_policy="first")
+        k_tile=k_tile, interpret=True)
+    counts = pad_correction(counts, jnp.asarray(cents), n_pad)
     np.testing.assert_array_equal(np.asarray(counts), exp_counts)
     np.testing.assert_array_equal(np.asarray(sums), exp_sums)
     # the planted faults did what they were planted for
@@ -333,7 +256,7 @@ def test_ktiled_stats_take_a_tie_across_tiles_to_the_first_index():
     cents = np.repeat(pts[:1], 40, axis=0)
     sums, counts = kmeans_update_stats(
         jnp.asarray(pts), jnp.asarray(cents), block_n=128, k_tile=16,
-        tie_policy="first", interpret=True)
+        interpret=True)
     assert np.asarray(counts).tolist() == [256.0] + [0.0] * 39
     np.testing.assert_array_equal(np.asarray(sums)[0], pts.sum(0))
 
@@ -367,29 +290,21 @@ def test_ktiled_stats_by_row_chains_and_ends(d, winners):
         assert (assign[::2] == 0).all() and (assign[1::2] == k - 1).all()
     sums, counts = kmeans_update_stats(
         jnp.asarray(pts), jnp.asarray(cents), block_n=block_n,
-        k_tile=k_tile, tie_policy="first", interpret=True)
-    counts = pad_correction(counts, jnp.asarray(cents), n_pad,
-                            tie_policy="first")
+        k_tile=k_tile, interpret=True)
+    counts = pad_correction(counts, jnp.asarray(cents), n_pad)
     np.testing.assert_array_equal(np.asarray(counts), exp_counts)
     np.testing.assert_array_equal(np.asarray(sums), exp_sums)
     assert np.asarray(counts)[ends].sum() == n - n_pad
-
-
-def test_ktiled_stats_know_the_first_policy_alone():
-    pts, cents, _ = _problem()
-    with pytest.raises(ValueError, match="'first' policy alone"):
-        kmeans_update_stats(pts, cents, block_n=128, k_tile=8,
-                            tie_policy="fast", interpret=True)
 
 
 def test_ktiled_stats_sharded_matches_single(cpu_mesh_8):
     pts, cents = _grey_problem(1024, 40, 24, 0, seed=9)
     sharded = update_stats_sharded(
         jnp.asarray(pts), jnp.asarray(cents), cpu_mesh_8, block_n=128,
-        k_tile=8, tie_policy="first", interpret=True)
+        k_tile=8, interpret=True)
     single = kmeans_update_stats(
         jnp.asarray(pts), jnp.asarray(cents), block_n=128, k_tile=8,
-        tie_policy="first", interpret=True)
+        interpret=True)
     for got, want in zip(sharded, single):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

@@ -74,7 +74,7 @@ def _lr_step(d, batch, n_dense):
                     Shape((batch,), F32), Shape((batch,), F32))
 
 
-def _kmeans_stats(n, d, k, block_n, tie_policy):
+def _kmeans_stats(n, d, k, block_n):
     """``block_n`` None: the tiles the fit's plan picks at (d, k)."""
     from flink_ml_tpu.ops.kmeans_pallas import (kmeans_update_stats,
                                                 stats_tiles)
@@ -82,8 +82,7 @@ def _kmeans_stats(n, d, k, block_n, tie_policy):
     k_tile = None
     if block_n is None:
         block_n, k_tile = stats_tiles(d, k)
-    return (partial(kmeans_update_stats, block_n=block_n, k_tile=k_tile,
-                    tie_policy=tie_policy),
+    return (partial(kmeans_update_stats, block_n=block_n, k_tile=k_tile),
             (Shape((n, d), F32), Shape((k, d), F32)))
 
 
@@ -177,13 +176,12 @@ CASES = {
             "ell_scatter_apply_pair", 8192, 1 << 15),
     },
     ("kmeans_update_stats", "pallas"): {
-        **{f"smallest-{tie}": partial(_kmeans_stats, 128, 8, 4, 128, tie)
-           for tie in ("first", "fast", "split")},
-        "full-width": lambda: _kmeans_stats(1 << 20, 64, 256, 8192, "first"),
-        "hibench": lambda: _kmeans_stats(1 << 20, 20, 10, None, "first"),
-        "mnist8m": lambda: _kmeans_stats(1 << 17, 784, 4096, None, "first"),
+        "smallest-first": lambda: _kmeans_stats(128, 8, 4, 128),
+        "full-width": lambda: _kmeans_stats(1 << 20, 64, 256, 8192),
+        "hibench": lambda: _kmeans_stats(1 << 20, 20, 10, None),
+        "mnist8m": lambda: _kmeans_stats(1 << 17, 784, 4096, None),
         "tiled-over-k-row-major": lambda: _kmeans_stats(
-            1 << 17, 128, 16384, None, "first"),
+            1 << 17, 128, 16384, None),
     },
     ("kmeans_workset_update", "pallas"): {
         "smallest": lambda: _kmeans_workset(128, 8, 4, 128),
@@ -329,8 +327,7 @@ def test_kmeans_stats_compiles_at_the_block_the_model_picks(one_v5e, d, k):
                                                 pick_block_n)
 
     block_n = pick_block_n(None, d, k)
-    jax.jit(partial(kmeans_update_stats, block_n=block_n,
-                    tie_policy="first")).lower(
+    jax.jit(partial(kmeans_update_stats, block_n=block_n)).lower(
         Shape((2 * block_n, d), F32, sharding=one_v5e),
         Shape((k, d), F32, sharding=one_v5e)).compile()
 
@@ -360,7 +357,7 @@ def test_kmeans_stats_tiled_over_k_compile_alone(one_v5e, d, k,
     block_n, k_tile = stats_tiles(d, k)
     assert k_tile is not None
     compiled = jax.jit(partial(kmeans_update_stats, block_n=block_n,
-                               k_tile=k_tile, tie_policy="first")).lower(
+                               k_tile=k_tile)).lower(
         Shape((8 * block_n, d), F32, sharding=one_v5e),
         Shape((k, d), F32, sharding=one_v5e)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < block_n * d * 4

@@ -167,7 +167,7 @@ def _separated_clusters(rng, n, dcol, k):
 
 def _lloyd_oracle(pts, cents):
     """float64 single-assignment Lloyd's stats (separated clusters have
-    no ties, so all tie policies must agree with it)."""
+    no ties, so the kernel's first-index rule must agree with it)."""
     p, c = pts.astype(np.float64), cents.astype(np.float64)
     d2 = (p * p).sum(1)[:, None] - 2.0 * p @ c.T + (c * c).sum(1)[None, :]
     assign = d2.argmin(1)
@@ -195,8 +195,7 @@ _KMEANS_STATS_SHAPES = pytest.mark.parametrize(
 
 
 @_KMEANS_STATS_SHAPES
-@pytest.mark.parametrize("tie_policy", ["first", "split", "fast"])
-def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k, block_n):
+def test_kmeans_kernel_parity(tpu, rng, n, dcol, k, block_n):
     """kmeans_update_stats (the fused Lloyd's kernel) vs a numpy oracle."""
     import jax.numpy as jnp
 
@@ -208,10 +207,9 @@ def test_kmeans_kernel_parity(tpu, rng, tie_policy, n, dcol, k, block_n):
     pts, cents = _separated_clusters(rng, n, dcol, k)
     (padded,), _ = pad_rows_to_block((pts,), block_n)
     sums, counts = kmeans_update_stats(jnp.asarray(padded),
-                                       jnp.asarray(cents), block_n=block_n,
-                                       tie_policy=tie_policy)
+                                       jnp.asarray(cents), block_n=block_n)
     counts = pad_correction(counts, jnp.asarray(cents),
-                            padded.shape[0] - n, tie_policy=tie_policy)
+                            padded.shape[0] - n)
     _, _, want_sums, want_counts = _lloyd_oracle(pts, cents)
     # counts are the exact-parity guard: any flipped assignment shows up
     # as a whole unit.  sums pass through one default-precision MXU dot
@@ -269,9 +267,9 @@ def test_kmeans_kernel_tiled_over_k_parity(tpu, rng, n, dcol, k):
     (padded,), _ = pad_rows_to_block((pts,), block_n)
     sums, counts = kmeans_update_stats(
         jnp.asarray(padded), jnp.asarray(cents), block_n=block_n,
-        k_tile=k_tile, tie_policy="first")
+        k_tile=k_tile)
     counts = pad_correction(counts, jnp.asarray(cents),
-                            padded.shape[0] - n, tie_policy="first")
+                            padded.shape[0] - n)
     sums, counts = np.asarray(sums, np.float64), np.asarray(counts)
 
     cb = np.asarray(jnp.asarray(cents).astype(jnp.bfloat16)

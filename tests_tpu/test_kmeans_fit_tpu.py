@@ -61,8 +61,7 @@ def test_fit_pads_on_device_bitexact_vs_host_pad_and_refit_compiles_nothing(
         padded, mask = pad_rows_with_mask(host_points, plan.row_multiple,
                                           fill=plan.fill)
         result = iterate(
-            kmeans_epoch_step_pallas(k, mesh, block_n=plan.block_n,
-                                     tie_policy="first"),
+            kmeans_epoch_step_pallas(k, mesh, block_n=plan.block_n),
             replicate(select_random_centroids(host_points, k, seed), mesh),
             (put_sharded(padded, mesh, P("data")),
              put_sharded(mask, mesh, P("data"))),
@@ -109,21 +108,22 @@ def test_fit_program_keeps_no_copy_of_the_points(tpu):
 
 @pytest.mark.parametrize("fill", ["zero", "first_row"])
 def test_rows_on_device_equals_the_host_pad_on_chip(tpu, rng, fill):
-    """The layout program at 2^20 + 5 rows (seventeen pieces, the last
-    one overlapping): rows, fill rows and mask as the host pad makes
-    them, for the Pallas plan's fill and the workset plan's."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    """The one ingest route on one chip at 2^20 + 5 rows (one put,
+    seventeen relayout steps, the last one overlapping): rows, fill rows
+    and mask as the host pad makes them, for the Pallas plan's fill and
+    the workset plan's."""
+    from jax.sharding import PartitionSpec as P
 
-    from flink_ml_tpu.models.clustering.kmeans import _rows_on_device
-    from flink_ml_tpu.parallel.mesh import device_mesh, put_sharded
+    from flink_ml_tpu.models.clustering.kmeans import (FitPlan,
+                                                       _put_and_lay_out)
+    from flink_ml_tpu.parallel.mesh import device_mesh
     from flink_ml_tpu.utils.padding import pad_rows_with_mask
 
     shape = ((1 << 20) + 5, 20)
     mesh = device_mesh(devices=[tpu])
     pts = rng.normal(size=shape).astype(np.float32)
-    got = _rows_on_device(shape, -shape[0] % 8192, fill,
-                          NamedSharding(mesh, P("data")))(
-        put_sharded(pts.reshape(-1), mesh, P("data")))
+    got = _put_and_lay_out(pts, FitPlan("xla", None, 8192, fill, 10, 20),
+                           mesh, P("data"))
     for have, want in zip(got, pad_rows_with_mask(pts, 8192, fill=fill)):
         assert have.shape == want.shape
         assert np.asarray(have).tobytes() == want.tobytes()
@@ -228,7 +228,7 @@ def test_sharded_put_in_pieces_is_the_host_route_at_10_gb(four_chips):
     from jax.sharding import PartitionSpec as P
 
     from flink_ml_tpu.models.clustering.kmeans import (
-        FitPlan, _pad_points, _put_and_lay_out_sharded)
+        FitPlan, _pad_points, _put_and_lay_out)
     from flink_ml_tpu.parallel.mesh import device_mesh, put_sharded
 
     n, d = 4 * 800_000 + 7, 784
@@ -236,7 +236,7 @@ def test_sharded_put_in_pieces_is_the_host_route_at_10_gb(four_chips):
     mesh = device_mesh(devices=four_chips)
     plan = FitPlan("pallas", 512, 512, "zero", 4096, d, k_tile=512)
     t = time.perf_counter()
-    points, mask = _put_and_lay_out_sharded(column, plan, mesh, P("data"))
+    points, mask = _put_and_lay_out(column, plan, mesh, P("data"))
     jax.block_until_ready((points, mask))
     pieces_s = time.perf_counter() - t
     t = time.perf_counter()
