@@ -542,14 +542,16 @@ def _kept_program(key: Optional[tuple]) -> Optional[_Program]:
 
 def _keep_program(key: Optional[tuple], program: _Program) -> None:
     # two threads that built one key both store: the entries are equal in
-    # everything but identity and whole either way, the later one stays
+    # everything but identity and whole either way, the later one stays.
+    # Room is made before the entry goes in, so that a reader outside the
+    # lock never counts more than the bound
     if key is None:
         return
     with _programs_lock:
-        _programs[key] = program
-        _programs.move_to_end(key)
-        while len(_programs) > _PROGRAMS_KEPT:
+        _programs.pop(key, None)
+        while len(_programs) >= _PROGRAMS_KEPT:
             _programs.popitem(last=False)
+        _programs[key] = program
 
 
 def _leaf_key(x) -> tuple:

@@ -15,6 +15,7 @@ recompiles across epochs or batch positions.
 
 from __future__ import annotations
 
+import functools
 import inspect
 import itertools
 import time
@@ -34,7 +35,12 @@ from ...data.replay_cache import (
     batch_fingerprint,
     default_ram_budget,
 )
-from ...iteration import IterationBodyResult, IterationConfig, iterate
+from ...iteration import (
+    IterationBodyResult,
+    IterationConfig,
+    iterate,
+    with_program_key,
+)
 from ...iteration.checkpoint import CheckpointConfig, CheckpointManager
 from ...obs.trace import tracer
 from ...parallel.mesh import (
@@ -42,6 +48,7 @@ from ...parallel.mesh import (
     assemble_process_local as _assemble_process_local,
     fetch_replicated as _fetch_replicated,
     mesh_process_count as _mesh_process_count,
+    put_in_rounds,
     put_sharded as _put_epoch_tensor,
     replicate,
 )
@@ -190,6 +197,38 @@ def prepare_epoch_tensor(arr: np.ndarray, perm: np.ndarray, steps: int,
     return arr.reshape((steps, batch) + arr.shape[1:])
 
 
+def _epoch_order(perm: np.ndarray, steps: int, batch: int) -> np.ndarray:
+    """The ``(steps, batch)`` row numbers of the epoch tensors
+    :func:`prepare_epoch_tensor` makes by ``perm``: ``perm`` itself, then
+    ``len(perm)`` for each padding row."""
+    order = np.full(steps * batch, len(perm), np.int32)
+    order[:len(perm)] = perm
+    return order.reshape(steps, batch)
+
+
+def _one_device(mesh):
+    """The mesh's device where it has one and this process holds it: a
+    fit there makes its epoch tensors on the device
+    (:func:`_epoch_tensors`).  ``None`` on a mesh of several devices or
+    processes, whose epoch tensors are made on the host and put sharded."""
+    devices = np.ravel(mesh.devices)
+    if devices.size != 1 or devices[0].process_index != jax.process_index():
+        return None
+    return devices[0]
+
+
+@functools.partial(jax.jit, static_argnames=("widths", "steps"))
+def _epoch_tensors(order, flats, widths, steps):
+    """The epoch tensors of the columns ``flats`` (each put flat, its row
+    ``widths`` given), bit for bit those :func:`prepare_epoch_tensor`
+    makes on the host: row ``order[i]`` at position ``i``, a zero row
+    where ``order`` holds a number past the last row."""
+    return tuple(
+        jnp.take(flat.reshape((-1,) + width), order, axis=0, mode="fill",
+                 fill_value=0).reshape((steps, -1) + width)
+        for flat, width in zip(flats, widths))
+
+
 def sgd_fit(loss_fn: LossFn, features: np.ndarray, labels: np.ndarray,
             weights: Optional[np.ndarray], config: SGDConfig,
             mesh=None) -> Tuple[LinearState, list]:
@@ -254,8 +293,10 @@ def sgd_fit_params(loss_fn: LossFn, features: np.ndarray, labels: np.ndarray,
 
     if gr is None:
         update = _linear_update(loss_fn, config)
-        return _run_minibatch_epochs(update, (X, y, w), init_params, steps,
-                                     config, mesh)
+        return _run_minibatch_epochs(
+            update, (X, y, w), init_params, steps, config, mesh,
+            program_key=_epoch_program_key(_linear_update, loss_fn, config,
+                                           mesh, steps))
     from ...parallel import grad_reduce as GR
 
     update = _linear_update_reduced(loss_fn, config, mesh)
@@ -272,7 +313,9 @@ def sgd_fit_params(loss_fn: LossFn, features: np.ndarray, labels: np.ndarray,
 
 def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
                           config: SGDConfig, mesh, *,
-                          place_params: bool = True) -> Tuple[dict, list]:
+                          place_params: bool = True,
+                          program_key: Optional[tuple] = None
+                          ) -> Tuple[dict, list]:
     """THE shared epoch driver behind sgd_fit / sgd_fit_sparse /
     sgd_fit_mixed: an inner scan of ``update`` over per-step slices of the
     (steps, batch, ...) device tensors in ``data``, wrapped in a fused
@@ -280,7 +323,12 @@ def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
     loss-log logic so the three trainers can never diverge.  Multi-host:
     the tol-termination vote is computed identically on every host inside
     the fused while_loop (replicated scalars), so early stopping works
-    without any cross-host round-trip per epoch."""
+    without any cross-host round-trip per epoch.
+
+    ``program_key`` (:func:`_epoch_program_key`) is the epoch body's
+    program key (``iteration/body.py: with_program_key``): fits 2..n of
+    a process with an equal key and equal shapes enqueue the program the
+    first one built.  ``None`` builds it anew."""
 
     from ...obs.probe import StepProbe
 
@@ -304,6 +352,9 @@ def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
         return IterationBodyResult(
             feedback=(params, epoch_loss, probe), termination=termination)
 
+    if program_key is not None:
+        epoch_body = with_program_key(epoch_body, _run_minibatch_epochs,
+                                      program_key)
     with tracer.span("fit.upload", "fit"):
         init_state = (replicate(init_params, mesh) if place_params
                       else init_params,
@@ -321,6 +372,30 @@ def _run_minibatch_epochs(update, data: tuple, init_params, steps: int,
         loss_log = list(probe.fetch(
             get=lambda v: _fetch_replicated(v))["loss"][:result.num_epochs])
     return params, loss_log
+
+
+def _epoch_program_key(factory: Callable, loss_fn, config: SGDConfig, mesh,
+                       steps: int, ell_rows: Optional[int] = None):
+    """What the fused program of :func:`_run_minibatch_epochs` over the
+    update ``factory`` builds follows from beyond its arguments' shapes:
+    the factory and the loss, every config field its trace reads
+    (``_step_program_key``'s, then ``tol`` and ``max_epochs``), the steps
+    of an epoch, the mesh and the backend, the registry's answers for the
+    ELL ops at ``ell_rows`` table rows, and the module functions the
+    trace calls, by what their names hold now.  ``None`` where a fact
+    does not hash."""
+    from ...kernels.registry import lookup
+
+    kernels = () if ell_rows is None else tuple(
+        (entry.backend, entry.fn) for entry in (
+            lookup(op, sig=(ell_rows,))
+            for op in ("ell_margin", "ell_scatter_apply")))
+    return _step_program_key(
+        (factory, int(steps), float(config.tol), int(config.max_epochs),
+         jax.default_backend(), kernels, _finish_sparse_step,
+         _gather_weights, _scatter_add_weights, _ell_margin,
+         _apply_ell_categorical, _extended_r),
+        loss_fn, config, mesh)
 
 
 def _linear_update(loss_fn: LossFn, config: SGDConfig):
@@ -609,14 +684,16 @@ def _mixed_update(loss_fn: LossFn, config: SGDConfig):
     def update(params, dense, cat, yb, wb):
         w, b = params["w"], params["b"]
         n_dense, n_cat = dense.shape[-1], cat.shape[-1]
-        margin = (dense @ w[:n_dense]
-                  + jnp.sum(_gather_weights(w, cat), axis=-1) + b)
+        with jax.named_scope("linear.margin"):
+            margin = (dense @ w[:n_dense]
+                      + jnp.sum(_gather_weights(w, cat), axis=-1) + b)
         value, pull = jax.vjp(lambda m: loss_fn(m, yb, wb), margin)
         (r,) = pull(jnp.ones_like(value))
 
         def apply_grad(w):
-            w = _scatter_add_weights(w, cat, jnp.repeat(-lr * r, n_cat))
-            return w.at[:n_dense].add(-lr * (r @ dense))
+            with jax.named_scope("linear.apply"):
+                w = _scatter_add_weights(w, cat, jnp.repeat(-lr * r, n_cat))
+                return w.at[:n_dense].add(-lr * (r @ dense))
 
         return finish(w, b, value, r, apply_grad)
 
@@ -701,7 +778,13 @@ def _mixed_update_ell(loss_fn: LossFn, config: SGDConfig,
     layout stacks produced by ``ell_layout`` at fit time — the raw
     ``cat`` tensor itself is not an input; results differ from the XLA
     path only in f32 summation order (plus the documented
-    ``ell_precision`` truncation of the one-hot contractions)."""
+    ``ell_precision`` truncation of the one-hot contractions).
+
+    The device's work of a step is under two scopes, as in
+    :func:`_mixed_update`: ``linear.margin`` (the dense matvec, the ELL
+    margin kernel, the overflow gather-add, the heavy matvec) and
+    ``linear.apply`` (the ELL scatter kernel, the overflow scatter, the
+    heavy update, the dense update)."""
     lr = config.learning_rate
     finish = _finish_sparse_step(config)
 
@@ -709,20 +792,22 @@ def _mixed_update_ell(loss_fn: LossFn, config: SGDConfig,
                heavy_idx, heavy_cnt, yb, wb):
         w, b = params["w"], params["b"]
         n_dense = dense.shape[-1]
-        margin = (dense @ w[:n_dense]
-                  + _ell_margin(backend, config.ell_precision,
-                                w, dense.shape[0], src, pos,
-                                mask, ovf_idx, ovf_src, heavy_idx,
-                                heavy_cnt) + b)
+        with jax.named_scope("linear.margin"):
+            margin = (dense @ w[:n_dense]
+                      + _ell_margin(backend, config.ell_precision,
+                                    w, dense.shape[0], src, pos,
+                                    mask, ovf_idx, ovf_src, heavy_idx,
+                                    heavy_cnt) + b)
         value, pull = jax.vjp(lambda m: loss_fn(m, yb, wb), margin)
         (r,) = pull(jnp.ones_like(value))
         r_ext = _extended_r(r)
 
         def apply_grad(w):
-            w = _apply_ell_categorical(
-                backend, config.ell_precision, lr, w, r, r_ext, src,
-                pos, mask, ovf_idx, ovf_src, heavy_idx, heavy_cnt)
-            return w.at[:n_dense].add(-lr * (r @ dense))
+            with jax.named_scope("linear.apply"):
+                w = _apply_ell_categorical(
+                    backend, config.ell_precision, lr, w, r, r_ext, src,
+                    pos, mask, ovf_idx, ovf_src, heavy_idx, heavy_cnt)
+                return w.at[:n_dense].add(-lr * (r @ dense))
 
         return finish(w, b, value, r, apply_grad)
 
@@ -914,8 +999,8 @@ def sgd_fit_sparse(loss_fn: LossFn, indices: np.ndarray, values: np.ndarray,
         extra = tuple(dev_stack(a) for a in (
             lay.src, lay.pos, lay.mask, lay.val, lay.ovf_idx, lay.ovf_src,
             lay.ovf_val, lay.heavy_idx, lay.heavy_cnt))
-        update = _sparse_update_ell_sharded(
-            loss_fn, config, mesh, num_features)
+        factory = _sparse_update_ell_sharded
+        update = factory(loss_fn, config, mesh, num_features)
     elif impl == "ell":
         from ...ops.ell_scatter import ell_layout
 
@@ -923,10 +1008,12 @@ def sgd_fit_sparse(loss_fn: LossFn, indices: np.ndarray, values: np.ndarray,
         extra = (layout.src, layout.pos, layout.mask, layout.val,
                  layout.ovf_idx, layout.ovf_src, layout.ovf_val,
                  layout.heavy_idx, layout.heavy_cnt)
-        update = _sparse_update_ell(loss_fn, config)
+        factory = _sparse_update_ell
+        update = factory(loss_fn, config)
     else:
         extra = ()
-        update = _sparse_update(loss_fn, config)
+        factory = _sparse_update
+        update = factory(loss_fn, config)
 
     y = _put_epoch_tensor(y, mesh, P(None, "data"))
     w = _put_epoch_tensor(w, mesh, P(None, "data"))
@@ -950,7 +1037,10 @@ def sgd_fit_sparse(loss_fn: LossFn, indices: np.ndarray, values: np.ndarray,
     params, loss_log = _run_minibatch_epochs(
         update, epoch_args,
         {"w": jnp.zeros((num_features,), jnp.float32),
-         "b": jnp.zeros((), jnp.float32)}, steps, config, mesh)
+         "b": jnp.zeros((), jnp.float32)}, steps, config, mesh,
+        program_key=_epoch_program_key(
+            factory, loss_fn, config, mesh, steps,
+            num_features // _BLOCK_LANES if impl == "ell" else None))
     return LinearState(np.asarray(params["w"], np.float64),
                        float(params["b"]), planned_impl=impl), loss_log
 
@@ -1133,6 +1223,13 @@ def sgd_fit_mixed(loss_fn: LossFn, dense_features: np.ndarray,
     :func:`_mixed_update`), which is why this layout is the fastest LR
     path on TPU for mixed dense/categorical data.
 
+    On a mesh of one device the columns are put flat and the device lays
+    them out in the epoch order (:func:`_epoch_tensors`); the ELL plan's
+    layout reads the categorical rows through that order on the host
+    (``ell_layout(order=...)``), and every array goes to the device in
+    rounds (``parallel/mesh.py: put_in_rounds``).  A fit does the same
+    work every time and keeps nothing across fits.
+
     Multi-host: pass a process-spanning mesh (``distributed.global_mesh``)
     and call from EVERY process with that process's own equal-sized row
     shard; the global batch is the concatenation over processes and the
@@ -1153,100 +1250,102 @@ def sgd_fit_mixed(loss_fn: LossFn, dense_features: np.ndarray,
         steps, batch, perm = _plan_epoch_layout_for_mesh(
             n, resolve_global_batch_size(config, n, num_features), mesh,
             config.seed)
-        # cast then permute, a tensor at a time: a cast copy is freed as
-        # soon as its permuted copy exists
-        with tracer.span("fit.arrange.permute", "fit"):
-            dense = prepare_epoch_tensor(dense_features.astype(np.float32),
-                                         perm, steps, batch)
-            cat = prepare_epoch_tensor(cat_indices.astype(np.int32), perm,
-                                       steps, batch)
-            y = prepare_epoch_tensor(labels.astype(np.float32), perm, steps,
-                                     batch)
-            w_host = (weights.astype(np.float32) if weights is not None
-                      else np.ones((n,), np.float32))
-            w = prepare_epoch_tensor(w_host, perm, steps, batch,
-                                     pad_value=0.0)
-
+        order = _epoch_order(perm, steps, batch)
         model_sharded = int(mesh.shape.get("model", 1)) > 1
         impl = ("sharded" if model_sharded
                 else plan_mixed_impl(num_features, mesh, steps,
                                      allow_sharded=True))
-        n_dev_data = int(mesh.shape.get("data", 1))
-        ell_sharded = impl == "ell" and n_dev_data > 1
+        device = _one_device(mesh)
+        # the columns a step reads as epoch tensors: dense, labels,
+        # weights, and the categorical indices where no ELL layout
+        # carries them
+        columns = [np.asarray(dense_features, np.float32),
+                   np.asarray(labels, np.float32),
+                   np.ones((n,), np.float32) if weights is None
+                   else np.asarray(weights, np.float32)]
+        if impl != "ell":
+            columns.append(np.asarray(cat_indices, np.int32))
+        if device is None:
+            with tracer.span("fit.arrange.permute", "fit"):
+                tensors = [prepare_epoch_tensor(c, perm, steps, batch)
+                           for c in columns]
+    if device is not None:
+        # the columns travel and are laid out while the host builds the
+        # layout below; the flat copies are freed once laid out
+        with tracer.span("fit.upload", "fit"):
+            order_put, *flats = put_in_rounds(
+                [order.reshape(-1)] + [c.reshape(-1) for c in columns],
+                device)
+        with tracer.span("fit.arrange", "fit"), \
+                tracer.span("fit.arrange.permute", "fit"):
+            tensors = _epoch_tensors(
+                order_put, tuple(flats),
+                widths=tuple(c.shape[1:] for c in columns), steps=steps)
+        del order_put, flats
+
+    with tracer.span("fit.arrange", "fit"):
         place_params = True
         init_params = {"w": jnp.zeros((num_features,), jnp.float32),
                        "b": jnp.zeros((), jnp.float32)}
-        if ell_sharded:
-            # per-device shard layouts (VERDICT r3 task 4): slot sources
-            # are numbered inside each device's local (batch/n_dev)-row
+        extra = ()
+        if impl == "ell":
+            # one-time static routing of every step's categorical slots
+            # (amortised over max_epochs replays of the same epoch
+            # order).  Off one device each device routes its own shard:
+            # slot sources are numbered inside its local (batch/n_dev)-row
             # shard, and the stacks gain a device dim sharded over 'data'
             from ...ops.ell_scatter import ell_layout
 
-            local = batch // n_dev_data
-            with tracer.span("fit.arrange.ell_layout", "fit"):
-                lay = ell_layout(
-                    cat.reshape(steps * n_dev_data, local, cat.shape[-1]),
-                    num_features)
-
-            def dev_stack(a):
-                return a.reshape((steps, n_dev_data) + a.shape[1:])
-
-            extra = tuple(dev_stack(a) for a in (
-                lay.src, lay.pos, lay.mask, lay.ovf_idx, lay.ovf_src,
-                lay.heavy_idx, lay.heavy_cnt))
-            update = _mixed_update_ell_sharded(
-                loss_fn, config, mesh, num_features)
-        elif impl == "ell":
-            # one-time static routing of every step's categorical slots
-            # (amortised over max_epochs replays of the same epoch tensor)
-            from ...ops.ell_scatter import ell_layout
-
-            with tracer.span("fit.arrange.ell_layout", "fit"):
-                layout = ell_layout(cat, num_features)
-            extra = (layout.src, layout.pos, layout.mask,
-                     layout.ovf_idx, layout.ovf_src,
-                     layout.heavy_idx, layout.heavy_cnt)
-            update = _mixed_update_ell(loss_fn, config)
+            shards = 1 if device is not None else int(mesh.shape["data"])
+            with tracer.span("fit.arrange.ell_layout", "fit") as span:
+                lay = ell_layout(cat_indices, num_features, device=False,
+                                 order=order.reshape(steps * shards, -1))
+                span.note(need_ovf=int(lay.need_ovf.max()),
+                          need_heavy=int(lay.need_heavy.max()))
+            extra = (lay.src, lay.pos, lay.mask, lay.ovf_idx, lay.ovf_src,
+                     lay.heavy_idx, lay.heavy_cnt)
+            if device is not None:
+                factory = _mixed_update_ell
+                update = factory(loss_fn, config)
+            else:
+                extra = tuple(a.reshape((steps, shards) + a.shape[1:])
+                              for a in extra)
+                factory = _mixed_update_ell_sharded
+                update = factory(loss_fn, config, mesh, num_features)
         elif impl == "sharded":
             # weight sharded over the model axis (2^24+ hash spaces never
             # replicate); see _mixed_update_sharded
-            extra = ()
-            update = _mixed_update_sharded(loss_fn, config, mesh,
-                                           num_features, n_dense)
+            factory = _mixed_update_sharded
+            update = factory(loss_fn, config, mesh, num_features, n_dense)
             init_params = {
                 "w": _place_zeros((num_features,), mesh, P("model")),
                 "b": _place_zeros((), mesh, P()),
             }
             place_params = False
         else:
-            extra = ()
-            update = _mixed_update(loss_fn, config)
+            factory = _mixed_update
+            update = factory(loss_fn, config)
 
     with tracer.span("fit.upload", "fit"):
-        dense = _put_epoch_tensor(dense, mesh, P(None, "data", None))
-        y = _put_epoch_tensor(y, mesh, P(None, "data"))
-        w = _put_epoch_tensor(w, mesh, P(None, "data"))
-        if ell_sharded:
-            specs = ([P(None, "data", None, None)] * 3
-                     + [P(None, "data", None)] * 3
-                     + [P(None, "data", None, None)])
-            extra = tuple(_put_epoch_tensor(a, mesh, s)
-                          for a, s in zip(extra, specs))
-        elif impl == "ell":
-            extra = tuple(jax.device_put(a) for a in extra)  # single-device
-        if impl in ("ell",):
-            # the ELL updates never read the raw index tensor — margins
-            # and scatters both ride the layout — so the (steps, batch,
-            # nnz) epoch tensor stays host-side (~steps*batch*nnz*4 B of
-            # HBM)
-            epoch_args = (dense,) + extra + (y, w)
+        if device is not None:
+            extra = tuple(put_in_rounds(extra, device))
         else:
-            cat = _put_epoch_tensor(cat, mesh, P(None, "data", None))
-            epoch_args = (dense, cat) + extra + (y, w)
-
+            row_specs = [P(None, "data", None), P(None, "data"),
+                         P(None, "data"), P(None, "data", None)]
+            tensors = [_put_epoch_tensor(t, mesh, spec)
+                       for t, spec in zip(tensors, row_specs)]
+            layout_specs = ([P(None, "data", None, None)] * 3
+                            + [P(None, "data", None)] * 3
+                            + [P(None, "data", None, None)])
+            extra = tuple(_put_epoch_tensor(a, mesh, spec)
+                          for a, spec in zip(extra, layout_specs))
+    dense, y, w = tensors[:3]
     params, loss_log = _run_minibatch_epochs(
-        update, epoch_args, init_params, steps, config,
-        mesh, place_params=place_params)
+        update, (dense,) + tuple(tensors[3:]) + extra + (y, w),
+        init_params, steps, config, mesh, place_params=place_params,
+        program_key=_epoch_program_key(
+            factory, loss_fn, config, mesh, steps,
+            num_features // _BLOCK_LANES if impl == "ell" else None))
     return LinearState(np.asarray(params["w"], np.float64),
                        float(params["b"]), planned_impl=impl), loss_log
 

@@ -257,12 +257,19 @@ def _ell_layout_native(lib, cat_indices: np.ndarray, num_features: int,
                        heavy_threshold: int,
                        values: "Optional[np.ndarray]",
                        pad_ovf_cap: Optional[int],
-                       pad_heavy_cap: Optional[int]):
+                       pad_heavy_cap: Optional[int],
+                       order: "Optional[np.ndarray]" = None):
     """Native counting-sort build; semantics identical to the numpy path
-    (heavy f32 value-sums may differ in summation order only)."""
+    (heavy f32 value-sums may differ in summation order only).  With
+    ``order`` the builder reads each step's rows of the table
+    ``cat_indices`` through it (:func:`ell_layout`)."""
     import ctypes
 
-    steps, batch, nnz = cat_indices.shape
+    if order is None:
+        steps, batch, nnz = cat_indices.shape
+    else:
+        (steps, batch), nnz = order.shape, cat_indices.shape[1]
+        order = np.ascontiguousarray(order, np.int32)
     rows = num_features // _LANES
     flat = np.ascontiguousarray(cat_indices, np.int32)
     with_values = values is not None
@@ -292,6 +299,7 @@ def _ell_layout_native(lib, cat_indices: np.ndarray, num_features: int,
 
         rc = lib.ell_build(
             ptr(flat, ctypes.c_int32), ptr(vals, ctypes.c_float),
+            ptr(order, ctypes.c_int32), ctypes.c_int64(flat.shape[0]),
             ctypes.c_int64(steps), ctypes.c_int64(batch),
             ctypes.c_int64(nnz), ctypes.c_int64(rows),
             ctypes.c_int64(heavy_threshold),
@@ -348,11 +356,19 @@ def ell_layout(cat_indices: np.ndarray, num_features: int,
                values: "Optional[np.ndarray]" = None,
                pad_ovf_cap: Optional[int] = None,
                pad_heavy_cap: Optional[int] = None,
-               device: bool = True) -> EllLayout:
+               device: bool = True,
+               order: "Optional[np.ndarray]" = None) -> EllLayout:
     """Build the static routing from a ``(steps, batch, nnz)`` int epoch
     tensor of categorical indices (host numpy; one-time per fit).  Pass
     ``values`` (same shape, float) for the generic sparse layout —
     slots then scatter ``value * r`` instead of ``r``.
+
+    With ``order`` (a ``(steps, batch)`` array of row numbers),
+    ``cat_indices`` and ``values`` are the ``(n, nnz)`` table itself and
+    row ``b`` of step ``s`` is its row ``order[s, b]``, a row of index 0
+    and value 0 where that is ``n`` or more: the layout of
+    ``sgd.prepare_epoch_tensor(table, perm, steps, batch)`` for the
+    ``order`` that is ``perm`` padded with ``n``, without that copy.
 
     ``pad_ovf_cap`` / ``pad_heavy_cap`` force EXACT capacities (for
     streaming callers whose every batch must share one compiled shape);
@@ -363,15 +379,15 @@ def ell_layout(cat_indices: np.ndarray, num_features: int,
     overlap).  Indices >= num_features are sentinels and drop out of
     the layout (padding rows)."""
     _check_heavy_threshold(heavy_threshold)
-    steps, batch, nnz = cat_indices.shape
     rows = num_features // _LANES
+    batch = (cat_indices if order is None else order).shape[1]
     wrap = jnp.asarray if device else np.asarray
     lib = _native_ell()
     if lib is not None:
         (n_src, n_pos, n_mask, n_oi, n_os, n_ov, n_hi, n_hc, n_val,
          need_o, need_h) = _ell_layout_native(
             lib, np.asarray(cat_indices), num_features, heavy_threshold,
-            values, pad_ovf_cap, pad_heavy_cap)
+            values, pad_ovf_cap, pad_heavy_cap, order)
         return EllLayout(
             src=wrap(n_src), pos=wrap(n_pos), mask=wrap(n_mask),
             ovf_idx=wrap(n_oi), ovf_src=wrap(n_os),
@@ -380,6 +396,10 @@ def ell_layout(cat_indices: np.ndarray, num_features: int,
             ovf_val=None if n_ov is None else wrap(n_ov),
             batch=batch, num_features=num_features,
             need_ovf=need_o, need_heavy=need_h)
+    if order is not None:
+        cat_indices = _rows_in_order(cat_indices, order)
+        values = None if values is None else _rows_in_order(values, order)
+    steps, _, nnz = cat_indices.shape
     outs = [_ell_one_step(
         np.asarray(cat_indices[s], np.int64).reshape(-1), batch, nnz, rows,
         heavy_threshold,
@@ -428,6 +448,15 @@ def ell_layout(cat_indices: np.ndarray, num_features: int,
         batch=batch, num_features=num_features,
         need_ovf=np.asarray([o[3].size for o in outs], np.int32),
         need_heavy=np.asarray([o[5].size for o in outs], np.int32))
+
+
+def _rows_in_order(table: np.ndarray, order: np.ndarray) -> np.ndarray:
+    """The ``(steps, batch, nnz)`` tensor :func:`ell_layout` reads
+    through ``order``: the table's rows in that order, 0 for a row
+    number past its end."""
+    real = order < table.shape[0]
+    return np.where(real[..., None], table[np.where(real, order, 0)], 0
+                    ).astype(table.dtype)
 
 
 def ell_layout_device(cat_indices: jnp.ndarray, num_features: int,
@@ -558,7 +587,7 @@ def ell_scatter_apply(w: jnp.ndarray, upd: jnp.ndarray, pos: jnp.ndarray,
     br = _pick_block_rows(rows)
     w2 = w.reshape(rows, _LANES)
     out = pl.pallas_call(
-        _kernel(br), grid=(rows // br,),
+        _kernel(br), grid=(rows // br,), name="ell_scatter_apply",
         in_specs=[pl.BlockSpec((br, 128), lambda i: (i, 0),
                                memory_space=pltpu.VMEM)] * 4,
         out_specs=pl.BlockSpec((br, 128), lambda i: (i, 0),
@@ -665,7 +694,7 @@ def ell_scatter_apply_fused(w: jnp.ndarray, r_ext: jnp.ndarray,
         in_specs.append(block)
     out = pl.pallas_call(
         _fused_kernel(br, r_rows, precision, val is not None),
-        grid=(rows // br,),
+        grid=(rows // br,), name="ell_scatter_apply",
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, 128), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
@@ -824,7 +853,7 @@ def ell_margin_fused(w: jnp.ndarray, src: jnp.ndarray, pos: jnp.ndarray,
         in_specs.append(block)
     out = pl.pallas_call(
         _margin_kernel(br, m_rows, precision, val is not None),
-        grid=(rows // br,),
+        grid=(rows // br,), name="ell_margin",
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m_rows, 128), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),

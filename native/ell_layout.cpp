@@ -16,6 +16,12 @@
 //   - P[row, lane] = (inclusive count of kept slots with lane' <= lane)
 //     - 1, clamped at 0, mask = count > 0
 //
+// With an order, ell_build reads a step's rows through it instead of a
+// (steps, batch, nnz) epoch tensor: row b of step s is the table's row
+// order[s*batch + b], or a row of index 0 (value 0) where that is n_table
+// or more -- the pad prepare_epoch_tensor gives the last step.  The
+// layout is then that of the permuted, padded tensor, which is never made.
+//
 // Capacity protocol: the caller passes ovf_cap/heavy_cap and
 // preallocated outputs; per-step needs are always written to
 // need_ovf/need_heavy.  Returns 0 on success, 1 when any step's needs
@@ -42,7 +48,8 @@ struct Spill {
 
 extern "C" {
 
-// flat: (steps*batch*nnz) int32; values: same shape float32 or nullptr.
+// flat: (steps*batch*nnz) int32, or the (n_table, nnz) table read
+// through order (steps*batch) int32; values: same shape float32 or nullptr.
 // Outputs (caller-allocated, row-major):
 //   src  (steps, rows, 128) int32     pos (steps, rows, 128) int32
 //   mask (steps, rows, 128) float32   val (steps, rows, 128) f32 | null
@@ -51,6 +58,7 @@ extern "C" {
 //   heavy_cnt (steps, heavy_cap, batch) int16 without values, f32 with
 //   need_ovf/need_heavy (steps,) int32
 static void build_steps(const int32_t* flat, const float* values,
+                        const int32_t* order, int64_t n_table,
                         int64_t s_begin, int64_t s_end,
                         int64_t batch, int64_t nnz, int64_t rows,
                         int64_t heavy_threshold, int64_t ovf_cap,
@@ -69,10 +77,36 @@ static void build_steps(const int32_t* flat, const float* values,
   std::vector<Spill> spills;
   std::vector<int32_t> hvec;
   std::vector<Spill> heavy_slots;
+  std::vector<int32_t> rows_f(order ? n : 0);
+  std::vector<float> rows_v(order && values ? n : 0);
 
   for (int64_t s = s_begin; s < s_end; ++s) {
     const int32_t* f = flat + s * n;
     const float* fv = values ? values + s * n : nullptr;
+    if (order) {
+      // this step's rows, gathered through the order (a pad row is 0s)
+      const int32_t* o = order + s * batch;
+      for (int64_t b = 0; b < batch; ++b) {
+        const int64_t row = o[b];
+        const bool real = row >= 0 && row < n_table;
+        if (real) {
+          std::memcpy(&rows_f[b * nnz], flat + row * nnz,
+                      nnz * sizeof(int32_t));
+        } else {
+          std::memset(&rows_f[b * nnz], 0, nnz * sizeof(int32_t));
+        }
+        if (values) {
+          if (real) {
+            std::memcpy(&rows_v[b * nnz], values + row * nnz,
+                        nnz * sizeof(float));
+          } else {
+            std::memset(&rows_v[b * nnz], 0, nnz * sizeof(float));
+          }
+        }
+      }
+      f = rows_f.data();
+      fv = values ? rows_v.data() : nullptr;
+    }
     std::memset(cnt.data(), 0, d * sizeof(int32_t));
     for (int64_t i = 0; i < n; ++i) {
       int32_t idx = f[i];
@@ -193,8 +227,10 @@ static void build_steps(const int32_t* flat, const float* values,
 
 // Entry point: steps are independent (disjoint output slices), so they
 // split across hardware threads, each with its own ~9 MB scratch.  On
-// the 1-core bench host this degenerates to the serial loop.
+// the 1-core bench host this degenerates to the serial loop.  order is
+// nullptr where flat is the (steps, batch, nnz) epoch tensor itself.
 int ell_build(const int32_t* flat, const float* values,
+              const int32_t* order, int64_t n_table,
               int64_t steps, int64_t batch, int64_t nnz, int64_t rows,
               int64_t heavy_threshold, int64_t ovf_cap, int64_t heavy_cap,
               int32_t* src, int32_t* pos, float* mask, float* val,
@@ -207,9 +243,10 @@ int ell_build(const int32_t* flat, const float* values,
   const int64_t n_threads = std::min<int64_t>(std::min<int64_t>(hw, 8),
                                               steps);
   if (n_threads <= 1) {
-    build_steps(flat, values, 0, steps, batch, nnz, rows, heavy_threshold,
-                ovf_cap, heavy_cap, src, pos, mask, val, ovf_idx, ovf_src,
-                ovf_val, heavy_idx, heavy_cnt, need_ovf, need_heavy, &rc);
+    build_steps(flat, values, order, n_table, 0, steps, batch, nnz, rows,
+                heavy_threshold, ovf_cap, heavy_cap, src, pos, mask, val,
+                ovf_idx, ovf_src, ovf_val, heavy_idx, heavy_cnt, need_ovf,
+                need_heavy, &rc);
     return rc.load();
   }
   std::vector<std::thread> pool;
@@ -218,10 +255,10 @@ int ell_build(const int32_t* flat, const float* values,
     const int64_t b = t * per;
     const int64_t e = std::min(steps, b + per);
     if (b >= e) break;
-    pool.emplace_back(build_steps, flat, values, b, e, batch, nnz, rows,
-                      heavy_threshold, ovf_cap, heavy_cap, src, pos, mask,
-                      val, ovf_idx, ovf_src, ovf_val, heavy_idx, heavy_cnt,
-                      need_ovf, need_heavy, &rc);
+    pool.emplace_back(build_steps, flat, values, order, n_table, b, e,
+                      batch, nnz, rows, heavy_threshold, ovf_cap, heavy_cap,
+                      src, pos, mask, val, ovf_idx, ovf_src, ovf_val,
+                      heavy_idx, heavy_cnt, need_ovf, need_heavy, &rc);
   }
   for (auto& th : pool) th.join();
   return rc.load();

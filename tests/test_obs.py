@@ -284,9 +284,9 @@ def test_the_dispatch_is_its_five_stages_in_the_profiler_trace(
         reused.append(stages[3][3]["reused"])
         for note in ("cache_hit", "reused"):
             assert all(note not in s[3] for s in stages[:3] + stages[4:])
-    # KMeans' body states its program key; LR's and the loop's are plain
-    # closures, built anew in every fit
-    assert reused == ([0, 1] if case == "kmeans" else [0, 0])
+    # KMeans' body and the linear family's epoch body state their program
+    # keys; the loop's is a plain closure, built anew in every fit
+    assert reused == ([0, 0] if case == "criteria" else [0, 1])
 
 
 @pytest.fixture

@@ -782,3 +782,67 @@ def test_gbt_fit_deeper_than_the_kernel_holds_compiles_on_segsum(
         (tuple(arg((n,), I32) for _ in range(d)), arg((n,), F32)),
     ).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_linear_epoch_gather_keeps_the_rows_compact(one_v5e):
+    """``sgd._epoch_tensors`` at ``lr_criteo.fit``'s size (5,242,880 rows
+    of 13 dense floats, the labels and the weights, put flat, into 160
+    steps of 32,768): the epoch tensors it returns hold the rows' own
+    bytes, 13 floats a row on the chip and not a row of 128 lanes
+    (2.68 GB), so the fit's program reads them as a put would lay them
+    out."""
+    from flink_ml_tpu.models.common.sgd import _epoch_tensors
+
+    n, steps = 5 << 20, 160
+    compiled = _epoch_tensors.lower(
+        Shape((n,), I32, sharding=one_v5e),
+        (Shape((13 * n,), F32, sharding=one_v5e),
+         Shape((n,), F32, sharding=one_v5e),
+         Shape((n,), F32, sharding=one_v5e)),
+        widths=((13,), (), ()), steps=steps).compile()
+    # 15 floats a row over the three, and a few hundred bytes of tiles
+    assert compiled.memory_analysis().output_size_in_bytes < 16 * n * 4
+
+
+def test_lr_fit_step_compiles_at_the_cell_with_its_kernels_named(
+        one_v5e, monkeypatch):
+    """Two epochs of ``lr_criteo.fit``'s ELL step (160 steps of batch
+    32,768 over 2^20 weights, 216 heavy indices and 67,600 overflow slots
+    a step), compiled for a v5e with the kernels the registry plans
+    there: the Pallas calls carry the names the benchmark's roofline
+    readers look for, the scopes reach the program, and the step keeps
+    its temporaries under 1 GB beside 4.7 GB of arguments."""
+    import re
+
+    from flink_ml_tpu.models.common import sgd as S
+    from flink_ml_tpu.models.common.losses import LOSSES
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    update = S._mixed_update_ell(
+        LOSSES["logistic"], S.SGDConfig(learning_rate=0.1, tol=0.0))
+    steps, b, f, h, o = 160, 32768, 1 << 20, 216, 67600
+
+    def run(params, data):
+        def epoch(p, _):
+            p, losses = jax.lax.scan(
+                lambda q, i: update(q, *(a[i] for a in data)), p,
+                jnp.arange(steps))
+            return p, jnp.mean(losses)
+
+        return jax.lax.scan(epoch, params, jnp.arange(2))
+
+    def arg(shape, dtype):
+        return Shape(shape, dtype, sharding=one_v5e)
+
+    grid = (steps, f // 128, 128)
+    compiled = jax.jit(run).lower(
+        {"w": arg((f,), F32), "b": arg((), F32)},
+        (arg((steps, b, 13), F32), arg(grid, I32), arg(grid, I32),
+         arg(grid, F32), arg((steps, o), I32), arg((steps, o), I32),
+         arg((steps, h), I32), arg((steps, h, b), jnp.int16),
+         arg((steps, b), F32), arg((steps, b), F32))).compile()
+    text = compiled.as_text()
+    assert set(re.findall(r"%(ell_\w+)\.\d+ = \S+ custom-call\(", text)) == {
+        "ell_margin", "ell_scatter_apply"}
+    assert "linear.margin" in text and "linear.apply" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
